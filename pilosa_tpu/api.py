@@ -225,6 +225,9 @@ class API:
             mesh_engine=mesh_engine,
         )
         self.mesh_engine = mesh_engine
+        # Set by the server when ``[mesh] devices >= 0``: /readyz then
+        # names a missing engine instead of passing a host-loop node.
+        self.mesh_required = False
         # Multi-host collective replay worker (lazy; see
         # mesh_collective_accept).  ``_mesh_pending`` holds accepted-but-
         # uncommitted two-phase dispatches: did -> (payload, expiry Timer).
@@ -1054,7 +1057,7 @@ class API:
     def readiness(self) -> Tuple[bool, List[str]]:
         """Readiness verdict with reason strings (the GET /readyz
         contract): ready iff the holder is open, the engine (when
-        configured) has not been closed, the cluster state is NORMAL,
+        configured) exists and has not been closed, the cluster state is NORMAL,
         and gossip has converged (no member stuck in SUSPECT).  A node
         that answers /healthz (alive) but not /readyz should be kept in
         the pool but taken out of rotation — e.g. while a resize is
@@ -1063,7 +1066,9 @@ class API:
         if not self.holder.opened:
             reasons.append("holder not opened")
         eng = self.mesh_engine
-        if eng is not None and getattr(eng, "_closed", False):
+        if eng is None and self.mesh_required:
+            reasons.append("mesh engine missing")
+        elif eng is not None and getattr(eng, "_closed", False):
             reasons.append("engine closed")
         # Overlapped warm-start (docs/durability.md): while residency is
         # being re-established from snapshots the node ANSWERS queries

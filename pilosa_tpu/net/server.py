@@ -1135,6 +1135,20 @@ class Handler:
         # series.
         if eng is not None and hasattr(eng, "cache_snapshot"):
             out["engineCaches"] = eng.cache_snapshot()
+        # Where the device programs run, as JAX reports it: platform,
+        # device kind and count, per-device memory in use.
+        if eng is not None and hasattr(eng, "mesh"):
+            import jax
+
+            from ..parallel.mesh import describe
+
+            out["mesh"] = describe(eng.mesh)
+            out["compileCacheDir"] = jax.config.jax_compilation_cache_dir
+        # Whether the C++ codec/merge libraries were built on this host
+        # or the node dropped to the NumPy paths.
+        from .. import native
+
+        out["native"] = native.status()
         # Continuous-query state (docs/incremental.md) — probe the slot
         # directly: a scrape must not conjure the sweeper thread.
         cq = getattr(self.api, "_cq", None)

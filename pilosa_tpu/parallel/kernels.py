@@ -24,8 +24,8 @@ slower on v5e (95 vs 705 GB/s effective).  Padding shards are zero.  ``mask`` is
 
 These are plain-XLA programs by measurement, not by default: a Pallas
 VMEM-pipelined version of the fragment-matrix sweep benchmarked within
-noise of XLA's fusion on the real chip (scripts/pallas_vs_xla.json), so
-the hand-written kernel layer was deleted.
+noise of XLA's fusion on a v5e in round 4 (scripts/pallas_vs_xla.py
+re-runs the comparison), so the hand-written kernel layer was deleted.
 """
 
 from __future__ import annotations
@@ -35,10 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 keeps shard_map in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..ops import bsi as bsi_ops

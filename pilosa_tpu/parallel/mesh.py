@@ -19,6 +19,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 SHARD_AXIS = "shard"
@@ -58,35 +59,43 @@ def _row_major_format(sh: NamedSharding, ndim: int):
     jit adopts the argument's layout, so no copy anywhere."""
     if ndim < 2:
         return sh
-    try:
-        from jax.experimental.layout import Format, Layout
-    except ImportError:  # older jax: device_put keeps row-major already
-        return sh
     return Format(Layout(major_to_minor=tuple(range(ndim))), sh)
 
 
 def put_global(mesh: Mesh, arr, spec: PartitionSpec):
-    """Place a host array on the mesh with ``spec``.  Single-process this
-    is a plain sharded device_put; in a multi-process runtime
-    (jax.distributed) it assembles a GLOBAL array where each process
-    contributes only the blocks its addressable devices own — the only
-    legal way to build shard_map operands on a pod.  Layout is pinned
-    row-major (see _row_major_format)."""
-    import jax.numpy as jnp
-
-    sh = NamedSharding(mesh, spec)
-    if jax.process_count() == 1:
-        return jax.device_put(jnp.asarray(arr), _row_major_format(sh, np.ndim(arr)))
+    """Place a host array on the mesh with ``spec``: each addressable
+    device receives exactly the block it owns, straight from host
+    memory — nothing is staged whole on one device first, and in a
+    multi-process runtime (jax.distributed) each process contributes
+    only its own devices' blocks, the only legal way to build shard_map
+    operands on a pod.  Layout is pinned row-major (see
+    _row_major_format)."""
     host = np.asarray(arr)
-    try:  # pin the layout on the multi-process path too
-        return jax.make_array_from_callback(
-            host.shape, _row_major_format(sh, host.ndim), lambda idx: host[idx]
-        )
-    except (TypeError, ValueError):
-        # jax without Format support in make_array_from_callback: accept
-        # the compiler-preferred layout (a per-dispatch relayout risk on
-        # pods — see _row_major_format).
-        return jax.make_array_from_callback(host.shape, sh, lambda idx: host[idx])
+    fmt = _row_major_format(NamedSharding(mesh, spec), host.ndim)
+    return jax.make_array_from_callback(host.shape, fmt, lambda idx: host[idx])
+
+
+def describe(mesh: Mesh) -> dict:
+    """Where this mesh runs, as JAX reports it: the /debug/vars ``mesh``
+    block and the server's start-up line.  ``bytes_in_use`` /
+    ``bytes_limit`` come from ``memory_stats()`` and are absent on
+    backends that keep none (CPU)."""
+    devices = list(mesh.devices.flat)
+    per_device = []
+    for d in devices:
+        if d.process_index != jax.process_index():
+            continue
+        stats = d.memory_stats() or {}
+        per_device.append({
+            "id": d.id,
+            **{k: stats[k] for k in ("bytes_in_use", "bytes_limit") if k in stats},
+        })
+    return {
+        "platform": devices[0].platform,
+        "deviceKind": devices[0].device_kind,
+        "devices": len(devices),
+        "perDevice": per_device,
+    }
 
 
 def pad_shards(n_shards: int, mesh: Mesh) -> int:
@@ -115,8 +124,6 @@ def stack_sharded(arrays: Sequence[np.ndarray], mesh: Mesh, pad_to: Optional[int
     mesh axis, zero-padding to the mesh multiple.  An empty shard list
     has no element shape/dtype to build from and is rejected explicitly
     (callers short-circuit empty queries before placement)."""
-    import jax.numpy as jnp
-
     n = len(arrays)
     if n == 0:
         raise ValueError("stack_sharded: empty shard list")
@@ -125,6 +132,4 @@ def stack_sharded(arrays: Sequence[np.ndarray], mesh: Mesh, pad_to: Optional[int
     out = np.zeros((padded,) + base.shape, dtype=base.dtype)
     for i, a in enumerate(arrays):
         out[i] = a
-    return jax.device_put(
-        jnp.asarray(out), _row_major_format(shard_sharding(mesh), out.ndim)
-    )
+    return put_global(mesh, out, PartitionSpec(SHARD_AXIS))

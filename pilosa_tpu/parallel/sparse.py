@@ -18,16 +18,22 @@ the surviving block fraction is under a density threshold it ships tiny
 per-shard block lists and dispatches one of the kernels here instead of
 the dense ``kernels.count_tree``:
 
-- ``count_tree_blocks``: plain-XLA block gather — each leaf row is
-  re-indexed ``[S, OCC_BLOCKS, BW]`` and only the listed blocks are
-  gathered before the fused popcount.  This is also the portable
-  fallback (CPU meshes, ``JAX_PLATFORMS=cpu`` tier-1, pods).
+- ``count_tree_blocks``: plain-XLA block gather — one ``lax.gather``
+  per leaf slices the listed ``(row, shard, block)`` windows straight
+  out of the ``[R, S, WORDS]`` stack before the fused popcount.  The
+  portable form (CPU meshes, ``JAX_PLATFORMS=cpu`` tier-1, pods) and
+  the tests' oracle.  The stack is never reshaped to a block view
+  first: under the TPU's (8, 128) tiling of ``[S, WORDS]`` that
+  reshape is a relayout of every row of the stack, not a bitcast.
 - ``count_tree_blocks_pallas``: a TPU Pallas kernel that scalar-
-  prefetches the block lists and explicitly DMAs ONLY the occupied
-  2 KiB blocks HBM->VMEM (grid over (local shard, block slot); the
-  operand stacks stay in HBM/ANY memory space and are never streamed
-  wholesale).  Selected on TPU backends; any failure to trace/compile
-  permanently falls back to the XLA form (engine logs once).
+  prefetches the block lists and explicitly DMAs the occupied blocks
+  HBM->VMEM (grid over (local shard, block slot); the operand stacks
+  stay in HBM/ANY memory space and are never streamed wholesale).
+  The DMA window is the tile-aligned ``(SHARD_GROUP shards, 512
+  words)`` slab holding the wanted block — the smallest window the
+  (8, 128) tiling admits — and the kernel selects the shard's sublane
+  from it.  Selected on TPU backends when the per-device shard count
+  is a multiple of ``SHARD_GROUP`` (engine._dispatch_sparse).
 
 The earlier "Pallas was deleted" note in kernels.py applies only to the
 DENSE sweep, where a hand pipeline tied XLA's fusion at the same
@@ -49,13 +55,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 keeps shard_map in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..ops.bitops import OCC_BLOCK_WORDS, OCC_BLOCKS
+from ..ops.bitops import OCC_BLOCK_WORDS
 from .mesh import SHARD_AXIS
 
 
@@ -63,35 +66,52 @@ def _pc(x):
     return jax.lax.population_count(x).astype(jnp.int32)
 
 
-def _apply_blocks(prog, rowvec, bidx, mats, S_local, Kb):
-    """Evaluate a normalized sparse prog over gathered blocks only:
-    each leaf materializes ``uint32[S_local, Kb, BW]`` — the listed
-    blocks of its row, nothing else."""
+_BLOCK_GATHER = jax.lax.GatherDimensionNumbers(
+    offset_dims=(2,), collapsed_slice_dims=(0, 1), start_index_map=(0, 1, 2)
+)
+
+
+def _combine(prog, leaf, zeros):
+    """Trace-time evaluation of a normalized sparse prog: ``leaf(mat_slot,
+    row_slot)`` yields a row leaf's gathered blocks, ``zeros`` the value
+    of a ``("zero",)`` leaf; interior nodes are the set algebra."""
     kind = prog[0]
     if kind == "zero":
-        return jnp.zeros((S_local, Kb, OCC_BLOCK_WORDS), jnp.uint32)
+        return zeros
     if kind == "row":
-        mat = mats[prog[1]]
-        R = mat.shape[0]
-        matr = mat.reshape(R, S_local, OCC_BLOCKS, OCC_BLOCK_WORDS)
-        row = jax.lax.dynamic_index_in_dim(
-            matr, rowvec[prog[2]], axis=0, keepdims=False
-        )  # [S_local, OCC_BLOCKS, BW]
-        return jnp.take_along_axis(row, bidx[:, :, None], axis=1)
-    subs = [_apply_blocks(p, rowvec, bidx, mats, S_local, Kb) for p in prog[1:]]
+        return leaf(prog[1], prog[2])
+    subs = [_combine(p, leaf, zeros) for p in prog[1:]]
     out = subs[0]
     for s in subs[1:]:
         if kind == "or":
-            out = jnp.bitwise_or(out, s)
+            out = out | s
         elif kind == "and":
-            out = jnp.bitwise_and(out, s)
+            out = out & s
         elif kind == "andnot":
-            out = jnp.bitwise_and(out, jnp.bitwise_not(s))
+            out = out & ~s
         elif kind == "xor":
-            out = jnp.bitwise_xor(out, s)
+            out = out ^ s
         else:
             raise ValueError(f"bad sparse op {kind}")
     return out
+
+
+def _gather_blocks(mat, row, bidx):
+    """``uint32[S_local, Kb, BW]``: the listed blocks of one row — one
+    gather of (1, 1, BW) windows at (row, shard, block * BW) from the
+    stack as it lies in HBM.  Indexing the row and taking a
+    [S, OCC_BLOCKS, BW] view first costs a stack-sized temp on TPU (see
+    the module docstring)."""
+    shard = jax.lax.broadcasted_iota(jnp.int32, bidx.shape, 0)
+    idx = jnp.stack(
+        [jnp.broadcast_to(row, bidx.shape), shard, bidx * OCC_BLOCK_WORDS],
+        axis=-1,
+    )
+    return jax.lax.gather(
+        mat, idx, _BLOCK_GATHER,
+        slice_sizes=(1, 1, OCC_BLOCK_WORDS),
+        mode=jax.lax.GatherScatterMode.CLIP,
+    )
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -106,8 +126,12 @@ def count_tree_blocks(mesh, prog, mask, blk_idx, blk_n, rowvec, *mats):
     dense-path contract anyway)."""
 
     def body(m, bidx, bn, rv, *ms):
-        S_local, Kb = bidx.shape
-        out = _apply_blocks(prog, rv, bidx, ms, S_local, Kb)
+        Kb = bidx.shape[1]
+        out = _combine(
+            prog,
+            lambda mslot, rslot: _gather_blocks(ms[mslot], rv[rslot], bidx),
+            jnp.zeros(bidx.shape + (OCC_BLOCK_WORDS,), jnp.uint32),
+        )
         pc = jnp.sum(_pc(out), axis=-1)  # [S_local, Kb]
         valid = jnp.arange(Kb, dtype=jnp.int32)[None, :] < bn[:, None]
         pc = jnp.where(valid, pc, 0)
@@ -138,27 +162,10 @@ def _prog_leaves(prog, out=None):
     return out
 
 
-def _combine_from_scratch(prog, scratch, leaf_counter):
-    """Trace-time tree combine over the DMA'd leaf blocks in VMEM."""
-    kind = prog[0]
-    if kind == "zero":
-        return jnp.zeros((OCC_BLOCK_WORDS,), jnp.uint32)
-    if kind == "row":
-        i = leaf_counter[0]
-        leaf_counter[0] += 1
-        return scratch[i, :]
-    subs = [_combine_from_scratch(p, scratch, leaf_counter) for p in prog[1:]]
-    out = subs[0]
-    for s in subs[1:]:
-        if kind == "or":
-            out = out | s
-        elif kind == "and":
-            out = out & s
-        elif kind == "andnot":
-            out = out & ~s
-        elif kind == "xor":
-            out = out ^ s
-    return out
+# Shards per DMA window: the sublane count of the (8, 128) tiling XLA
+# gives the stack's trailing [S, WORDS] dims on TPU.  A DMA source must
+# be tile-aligned, so one shard's block travels with its group's.
+SHARD_GROUP = 8
 
 
 def _pallas_shard_count(prog, bidx, bn, rowvec, mats, interpret=False):
@@ -166,22 +173,26 @@ def _pallas_shard_count(prog, bidx, bn, rowvec, mats, interpret=False):
     shard block.  Grid = (S_local, Kb); the block lists and row indices
     are SCALAR-PREFETCH operands (available before the body runs, per
     the Pallas TPU scalar-prefetch contract), the stacks stay in ANY
-    (HBM) memory space, and each grid step DMAs exactly the listed
-    2 KiB block of each leaf row into VMEM scratch before the combine +
-    popcount.  Padding slots (j >= bn[s]) and unrequested shards
-    (bn == 0) do no DMA and add nothing."""
+    (HBM) memory space, and each grid step DMAs the listed block of
+    each leaf row — as the aligned ``(SHARD_GROUP, BW)`` slab around it
+    — into VMEM scratch, then combines + popcounts the shard's own
+    sublane.  Padding slots (j >= bn[s]) and unrequested shards
+    (bn == 0) do no DMA and add nothing.  ``S_local`` must be a
+    multiple of ``SHARD_GROUP``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    leaves = tuple(_prog_leaves(prog))
+    leaves = tuple(dict.fromkeys(_prog_leaves(prog)))  # distinct, in order
     n_leaf = max(1, len(leaves))
     S_local, Kb = bidx.shape
+    if S_local % SHARD_GROUP:
+        raise ValueError(
+            f"{S_local} local shards is not a multiple of {SHARD_GROUP}"
+        )
 
     def kernel(bidx_ref, bn_ref, rv_ref, *rest):
         mats_refs = rest[: len(mats)]
-        out_ref = rest[len(mats)]
-        scratch = rest[len(mats) + 1]
-        sems = rest[len(mats) + 2]
+        out_ref, scratch, sems = rest[len(mats):]
         s = pl.program_id(0)
         j = pl.program_id(1)
 
@@ -191,32 +202,42 @@ def _pallas_shard_count(prog, bidx, bn, rowvec, mats, interpret=False):
 
         @pl.when(j < bn_ref[s])
         def _work():
-            b = bidx_ref[s, j]
+            group = pl.multiple_of((s // SHARD_GROUP) * SHARD_GROUP, SHARD_GROUP)
+            word = pl.multiple_of(
+                bidx_ref[s * Kb + j] * OCC_BLOCK_WORDS, OCC_BLOCK_WORDS
+            )
             copies = []
             for li, (mslot, rslot) in enumerate(leaves):
                 cp = pltpu.make_async_copy(
                     mats_refs[mslot].at[
-                        rv_ref[rslot], s, pl.ds(b * OCC_BLOCK_WORDS, OCC_BLOCK_WORDS)
+                        rv_ref[rslot],
+                        pl.ds(group, SHARD_GROUP),
+                        pl.ds(word, OCC_BLOCK_WORDS),
                     ],
-                    scratch.at[li, :],
+                    scratch.at[li],
                     sems.at[li],
                 )
                 cp.start()
                 copies.append(cp)
             for cp in copies:
                 cp.wait()
-            val = _combine_from_scratch(prog, scratch, [0])
-            out_ref[0, 0] += jnp.sum(
-                jax.lax.population_count(val).astype(jnp.int32)
+            lane = s % SHARD_GROUP
+            val = _combine(
+                prog,
+                lambda *leaf: scratch[leaves.index(leaf), pl.ds(lane, 1), :],
+                jnp.zeros((1, OCC_BLOCK_WORDS), jnp.uint32),
             )
+            out_ref[0, 0] += jnp.sum(_pc(val))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # bidx, bn, rowvec
+        # bidx (flat, so SMEM holds S*Kb words, not S lane-padded rows),
+        # bn, rowvec
+        num_scalar_prefetch=3,
         grid=(S_local, Kb),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY) for _ in mats],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in mats],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         scratch_shapes=[
-            pltpu.VMEM((n_leaf, OCC_BLOCK_WORDS), jnp.uint32),
+            pltpu.VMEM((n_leaf, SHARD_GROUP, OCC_BLOCK_WORDS), jnp.uint32),
             pltpu.SemaphoreType.DMA((n_leaf,)),
         ],
     )
@@ -225,7 +246,7 @@ def _pallas_shard_count(prog, bidx, bn, rowvec, mats, interpret=False):
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(bidx, bn, rowvec, *mats)
+    )(bidx.reshape(-1), bn, rowvec, *mats)
     return out[0, 0]
 
 
@@ -240,10 +261,14 @@ def count_tree_blocks_pallas(mesh, prog, interpret, mask, blk_idx, blk_n, rowvec
         total = _pallas_shard_count(prog, bidx, bn, rv, ms, interpret=interpret)
         return jax.lax.psum(total, SHARD_AXIS)
 
+    # check_vma off: pallas_call's output carries no varying-axes type,
+    # and the interpreter's own loop mixes replicated indices into the
+    # varying stacks; the psum makes the result replicated regardless.
     return shard_map(
         body,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P())
         + (P(None, SHARD_AXIS),) * len(mats),
         out_specs=P(),
+        check_vma=False,
     )(mask, blk_idx, blk_n, rowvec, *mats)

@@ -295,6 +295,7 @@ class Server:
         # The readiness probe's gossip-convergence check reads the
         # transport directly (None when gossip is not configured).
         self.api.gossip = getattr(self, "gossip", None)
+        self.api.mesh_required = self.config.mesh_devices >= 0
         if mesh_engine is not None and self.config.mesh_sequencer:
             mesh_engine.ticket = self._make_ticket_fn()
         self._http, self._http_thread = serve(
@@ -357,27 +358,27 @@ class Server:
         node metadata so capacity-weighted shard ownership
         (cluster.place_partition) gives an 8-chip host 8x the shards of
         a 1-chip host — its in-mesh psum then covers them with zero
-        extra network hops (docs/mesh.md).  1 when the mesh is disabled
-        or devices are unreachable (the per-shard host path still
-        works, so the node still takes a single-device share)."""
+        extra network hops (docs/mesh.md).  1 in the explicit host-only
+        mode (``[mesh] devices = -1``); otherwise unreachable devices
+        fail the open, like the engine build they precede."""
         if self.config.mesh_devices < 0:
             return 1
-        try:
-            import jax
+        import jax
 
-            n = jax.local_device_count()
-            if self.config.mesh_devices and jax.process_count() == 1:
-                # A single-process mesh trimmed by [mesh] devices owns
-                # only the trimmed slice.
-                n = min(n, self.config.mesh_devices)
-            return max(1, int(n))
-        except Exception as e:  # noqa: BLE001 — no devices is a 1-weight
-            self.logger.printf("device probe failed (weight=1): %s", e)
-            return 1
+        n = jax.local_device_count()
+        if self.config.mesh_devices and jax.process_count() == 1:
+            # A single-process mesh trimmed by [mesh] devices owns
+            # only the trimmed slice.
+            n = min(n, self.config.mesh_devices)
+        return max(1, int(n))
 
     def _make_mesh_engine(self):
-        """Fused device query path over the local mesh (parallel package);
-        None when no usable devices (the per-shard path still works).
+        """Fused device query path over the local mesh (parallel
+        package).  ``[mesh] devices = -1`` is the explicit host-only
+        mode and returns None; with ``devices >= 0`` an engine that
+        cannot be built raises and fails ``open()`` — a node must not
+        come up answering every query from the per-shard host loop
+        while /readyz says 200.
 
         With ``--jax-coordinator`` the JAX distributed runtime is
         initialized FIRST (the analogue of setupNetworking,
@@ -386,54 +387,49 @@ class Server:
         servers so the psum can rendezvous (SPMD serving)."""
         if self.config.mesh_devices < 0:
             return None
-        try:
-            from .parallel import MeshEngine, make_mesh
+        from . import compile_cache
+        from .parallel import MeshEngine, make_mesh
+        from .parallel.mesh import describe
 
-            if self.config.jax_coordinator:
-                # jax.distributed is up (see _open_bound): the mesh spans
-                # every host's devices; collectives ride ICI/DCN while
-                # the cluster control plane stays per-host HTTP/gossip.
-                from .parallel import multihost
+        cache_dir = compile_cache.configure()
+        if self.config.jax_coordinator:
+            # jax.distributed is up (see _open_bound): the mesh spans
+            # every host's devices; collectives ride ICI/DCN while
+            # the cluster control plane stays per-host HTTP/gossip.
+            from .parallel import multihost
 
-                mesh = multihost.global_mesh(self.config.mesh_devices or None)
-            else:
-                mesh = make_mesh(self.config.mesh_devices or None)
-            kwargs = {}
-            if self.config.engine_device_budget_bytes > 0:
-                kwargs["max_resident_bytes"] = (
-                    self.config.engine_device_budget_bytes
-                )
-            engine = MeshEngine(
-                self.holder, mesh, logger=self.logger, journal=self.journal,
-                **kwargs,
+            mesh = multihost.global_mesh(self.config.mesh_devices or None)
+        else:
+            mesh = make_mesh(self.config.mesh_devices or None)
+        where = describe(mesh)
+        self.logger.printf(
+            "mesh: platform=%s deviceKind=%s devices=%d compileCache=%s",
+            where["platform"], where["deviceKind"], where["devices"],
+            cache_dir,
+        )
+        kwargs = {}
+        if self.config.engine_device_budget_bytes > 0:
+            kwargs["max_resident_bytes"] = (
+                self.config.engine_device_budget_bytes
             )
-            # Seed the residency/warm-start cost signal from the last
-            # run's persisted per-tenant device-cost EWMAs
-            # (docs/residency.md): a restarted node re-warms its HOT
-            # tenants' stacks first instead of holder iteration order.
-            self._load_tenant_costs()
-            if self.config.mesh_peers:
-                from concurrent.futures import ThreadPoolExecutor
+        engine = MeshEngine(
+            self.holder, mesh, logger=self.logger, journal=self.journal,
+            **kwargs,
+        )
+        # Seed the residency/warm-start cost signal from the last
+        # run's persisted per-tenant device-cost EWMAs
+        # (docs/residency.md): a restarted node re-warms its HOT
+        # tenants' stacks first instead of holder iteration order.
+        self._load_tenant_costs()
+        if self.config.mesh_peers:
+            from concurrent.futures import ThreadPoolExecutor
 
-                self._mesh_pool = ThreadPoolExecutor(
-                    max_workers=max(4, len(self.config.mesh_peers)),
-                    thread_name_prefix="mesh-peer",
-                )
-                engine.collective_broadcast = self._broadcast_dispatch
-            return engine
-        except Exception as e:
-            self.logger.printf("mesh engine unavailable: %s", e)
-            # The gossip weight advertised in _setup_cluster assumed a
-            # live mesh; without one this node serves via the per-shard
-            # host loop and must take a single-device share — an 8x
-            # weight on the slowest member would skew the whole cluster
-            # onto it.  Peers that saw the optimistic weight reweigh via
-            # the gossip meta update (push-pull carries it).
-            if self.cluster is not None and self.cluster.node.devices != 1:
-                self.cluster.node.devices = 1
-                if getattr(self, "gossip", None) is not None:
-                    self.gossip.meta["devices"] = 1
-            return None
+            self._mesh_pool = ThreadPoolExecutor(
+                max_workers=max(4, len(self.config.mesh_peers)),
+                thread_name_prefix="mesh-peer",
+            )
+            engine.collective_broadcast = self._broadcast_dispatch
+        return engine
 
     def _make_ticket_fn(self):
         """Collective sequence tickets (symmetric initiation): local
@@ -921,10 +917,12 @@ class Server:
         self._monitors.append(t)
 
     def _monitor_cache_flush(self):
-        for idx in self.holder.indexes.values():
-            for f in idx.fields.values():
-                for v in f.views.values():
-                    for frag in v.fragments.values():
+        # Snapshots: an import creating fragments mid-walk must not void
+        # the tick ("dictionary changed size during iteration").
+        for idx in list(self.holder.indexes.values()):
+            for f in list(idx.fields.values()):
+                for v in list(f.views.values()):
+                    for frag in list(v.fragments.values()):
                         frag.flush_cache()
         # Piggyback the per-tenant device-cost EWMA persistence on the
         # flush tick: the snapshot is tiny (<=256 tenants) and feeds the

@@ -1,10 +1,13 @@
 #!/usr/bin/env bash
-# Build the native (C++) extensions ahead of time:
-#   pilosa_tpu/native/libroaring_codec.so  (fragment-file codec, PR 5)
-#   pilosa_tpu/native/libsparse_merge.so   (bulk-ingest merge kernels)
+# Build the native (C++) extensions ahead of time, for THIS host:
+#   roaring_codec  (fragment-file codec, PR 5)
+#   sparse_merge   (bulk-ingest merge kernels)
 #
-# The ctypes loader (pilosa_tpu/native/__init__.py) also builds lazily on
-# first use; this script exists for CI images and for debugging:
+# The ctypes loader (pilosa_tpu/native/__init__.py) builds lazily on
+# first use and only loads lib<name>.<key>.so, keyed on source + its
+# build flags + this host's CPU; this script writes to exactly that
+# path, so what it builds is what the loader finds here (and nowhere
+# else).  It exists for CI images and for debugging:
 #
 #   scripts/build_native.sh           # -O2 -Wall (warnings are errors)
 #   scripts/build_native.sh --asan    # AddressSanitizer debug build
@@ -26,7 +29,7 @@ fi
 
 for name in roaring_codec sparse_merge; do
     src="$NATIVE_DIR/$name.cpp"
-    out="$NATIVE_DIR/lib$name.so"
+    out=$(python3 -c "from pilosa_tpu import native; print(native._lib_path('$name', '$src'))")
     echo "building $out"
     "$CXX" "${FLAGS[@]}" -o "$out" "$src"
 done

@@ -211,6 +211,17 @@ def test_healthz_readyz_flip_across_startup_and_resize(tmp_path):
         assert any("RESIZING" in r for r in doc["reasons"])
         h[0].cluster.set_state("NORMAL")
         assert readyz()[0] == 200
+        # A node configured for a mesh ([mesh] devices >= 0) names a
+        # missing engine instead of passing as a host-loop node.
+        api = h[0].api
+        assert api.mesh_required and api.mesh_engine is not None
+        eng, api.mesh_engine = api.mesh_engine, None
+        try:
+            code, doc = readyz()
+            assert code == 503 and "mesh engine missing" in doc["reasons"]
+        finally:
+            api.mesh_engine = eng
+        assert readyz()[0] == 200
         # Liveness is unaffected by readiness the whole way.
         assert _get_json(port, "/healthz")["status"] == "ok"
         # The state flips were journaled (cluster.state from/to).

@@ -454,35 +454,38 @@ def test_debug_vars_carries_engine_caches(holder, mesh):
 # -- Pallas kernel (interpret mode) -----------------------------------------
 
 
-def test_pallas_block_kernel_interpret_matches_numpy():
+def test_pallas_block_kernel_interpret_matches_numpy(mesh):
     import jax.numpy as jnp
 
     from pilosa_tpu.parallel import sparse
 
     rng = np.random.default_rng(0)
-    R, S = 4, 2
+    R, S = 4, 2 * sparse.SHARD_GROUP * mesh.devices.size
     mat = np.zeros((R, S, WORDS), dtype=np.uint32)
-    for r in (0, 1):
-        for s in range(S):
-            for b in (3, 7, 40):
+    bidx = np.zeros((S, 4), np.int32)
+    bn = np.zeros(S, np.int32)
+    for s in range(S):
+        # Per-shard block lists differ, so a wrong sublane pick shows.
+        blocks = np.sort(rng.choice(64, size=s % 5, replace=False))
+        bidx[s, : len(blocks)] = blocks
+        bn[s] = len(blocks)
+        for r in (0, 1, 2):
+            for b in blocks:
                 mat[r, s, b * OCC_BLOCK_WORDS:(b + 1) * OCC_BLOCK_WORDS] = (
                     rng.integers(0, 1 << 32, OCC_BLOCK_WORDS, dtype=np.uint32)
                 )
-    prog = ("andnot", ("and", ("row", 0, 0), ("row", 0, 1)), ("zero",))
-    bidx = np.tile(np.array([3, 7, 40, 0], np.int32), (S, 1))
-    bn = np.array([3, 3], np.int32)
-    rv = np.array([0, 1], np.int32)
-    want = sum(
-        int(np.sum(np.bitwise_count(mat[0, s] & mat[1, s]))) for s in range(S)
-    )
-    try:
-        out = sparse._pallas_shard_count(
-            prog, jnp.asarray(bidx), jnp.asarray(bn), jnp.asarray(rv),
-            (jnp.asarray(mat),), interpret=True,
-        )
-    except Exception as e:  # pragma: no cover — older pallas interpreters
-        pytest.skip(f"pallas interpret unsupported here: {e!r}")
-    assert int(out) == want
+    prog = ("andnot", ("and", ("row", 0, 0), ("row", 0, 1)),
+            ("xor", ("row", 0, 2), ("zero",)))
+    rv = jnp.asarray(np.array([0, 1, 2], np.int32))
+    mask = np.full((S, 1), 0xFFFFFFFF, np.uint32)
+    mask[3] = 0  # a gated shard must add nothing
+    live = mask[:, 0] != 0
+    want = int(np.sum(np.bitwise_count((mat[0] & mat[1] & ~mat[2])[live])))
+    args = (jnp.asarray(mask), jnp.asarray(bidx), jnp.asarray(bn), rv,
+            jnp.asarray(mat))
+    got = sparse.count_tree_blocks_pallas(mesh, prog, True, *args)
+    assert int(got) == want
+    assert int(sparse.count_tree_blocks(mesh, prog, *args)) == want
 
 
 # -- bench guard -------------------------------------------------------------
