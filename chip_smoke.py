@@ -17,9 +17,12 @@ counter advanced.
 This process never imports JAX: a parent that touched JAX would hold the
 chip and the server child could not.  The platform is asserted from the
 child, through ``/debug/vars`` ``mesh``: anything but ``tpu`` exits
-non-zero with no result line (``--allow-cpu`` is the sandbox rehearsal
+non-zero with nothing on stdout (``--allow-cpu`` is the sandbox rehearsal
 and prints ``"platform": "cpu"``).  No phase is wrapped in a catch that
-lets the run end 0; the last line of stdout is the one JSON result.
+lets the run end 0.  A passing run writes two lines to stdout: the JSON
+report (scale, resident bytes, set-up seconds, per-query outcomes), then,
+last, the verdict ``{"ok": true, "device": {"platform", "kind", "count"}}``
+with exactly those keys, the device as the server's JAX reports it.
 """
 
 import argparse
@@ -53,7 +56,7 @@ T0 = time.monotonic()
 
 
 class SmokeFailure(Exception):
-    """A phase failed; the run ends non-zero with no result line."""
+    """A phase failed; the run ends non-zero with nothing on stdout."""
 
 
 def log(msg):
@@ -468,8 +471,6 @@ def run(args, port, child) -> dict:
         raise SmokeFailure(f"per-device bytes differ: {per_device}")
     return {
         "ok": True,
-        "device": {"platform": mesh["platform"], "kind": mesh["deviceKind"],
-                   "count": mesh["devices"]},
         "platform": mesh["platform"], "device_kind": mesh["deviceKind"],
         "n_devices": mesh["devices"],
         "shards": args.shards, "columns": args.shards * SHARD_WIDTH,
@@ -521,7 +522,7 @@ def main() -> int:
                 start_new_session=True,
             )
         try:
-            result = run(args, port, child)
+            report = run(args, port, child)
         except BaseException:
             with open(server_log, "rb") as f:
                 tail = f.read()[-6000:].decode(errors="replace")
@@ -536,7 +537,10 @@ def main() -> int:
                 except subprocess.TimeoutExpired:
                     os.killpg(child.pid, signal.SIGKILL)
                     child.wait(30)
-    print(json.dumps(result), flush=True)
+    print(json.dumps(report))
+    print(json.dumps({"ok": True, "device": {
+        "platform": report["platform"], "kind": report["device_kind"],
+        "count": report["n_devices"]}}), flush=True)
     return 0
 
 

@@ -26,9 +26,13 @@ def test_cpu_rehearsal_passes_and_names_the_cpu(tmp_path):
     cache = str(tmp_path / "cache")
     proc = _run("--allow-cpu", "--shards", "8", JAX_COMPILATION_CACHE_DIR=cache)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    result = json.loads(proc.stdout.splitlines()[-1])
-    assert result["ok"] is True
-    assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    lines = proc.stdout.splitlines()
+    # Last line: the verdict, exactly the keys the chip check reads.
+    assert json.loads(lines[-1]) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    assert len(lines) == 2
+    result = json.loads(lines[0])
+    assert result["ok"] is True and result["platform"] == "cpu"
     assert result["reduced"] == ["shards 8 < 960"]
     assert result["host_fallbacks"] == 0 and result["psum_dispatches"] > 0
     assert result["native"] == "built"
