@@ -248,7 +248,7 @@ def report_pipeline(eng):
     for stage, s in sorted(snap["stages"].items()):
         progress(
             f"  pipeline stage {stage}: n={s['count']} "
-            f"mean={s['meanSeconds'] * 1e3:.2f}ms max={s['maxSeconds'] * 1e3:.2f}ms"
+            f"mean={s['meanSeconds'] * 1e3:.2f}ms p99={s['p99Seconds'] * 1e3:.2f}ms"
         )
 
 

@@ -67,6 +67,7 @@ class QueryRequest:
         tenant: str = "default",
         replica_read: str = "",
         freshness_ms: Optional[float] = None,
+        clock=None,
     ):
         self.index = index
         self.query = query
@@ -90,6 +91,10 @@ class QueryRequest:
         # — the same key admission fairness uses).
         self.profile = profile
         self.tenant = tenant or "default"
+        # The HTTP layer's tracing.RequestClock (first byte in -> last
+        # byte out); the API hands it the request's root span so its
+        # http_read/respond stages carry the path the query took.
+        self.clock = clock
 
 
 class ImportRequest:
@@ -305,6 +310,8 @@ class API:
         with self.tracer.start_span(
             "api.Query", parent=parent, index=req.index, remote=req.remote
         ) as span, plans.attach(plan):
+            if req.clock is not None:
+                req.clock.span = span
             resp = self.executor.execute(req.index, req.query, req.shards, opt)
         elapsed = time.monotonic() - start
         trace_id = span.trace_id if span is not None else None
@@ -395,6 +402,8 @@ class API:
             return None
         fut.trace_span = span
         fut.query_plan = plan
+        if req.clock is not None:
+            req.clock.span = span
 
         def _finish(_f):
             elapsed = time.monotonic() - start

@@ -542,18 +542,22 @@ class Executor:
     _PARSE_CACHE_MAX = 512
 
     def _parse_cached(self, s: str) -> Query:
-        with self._parse_lock:
-            q = self._parse_cache.get(s)
-            if q is not None:
-                self._parse_cache.move_to_end(s)
-                return q
-        q = pql.parse(s)
-        if all(_call_cacheable(c) for c in q.calls):
+        """PQL text -> calls: the ``parse`` stage, parse-cache hits
+        included and tagged."""
+        with tracing_mod.stage("parse", cache="hit") as st:
             with self._parse_lock:
-                self._parse_cache[s] = q
-                while len(self._parse_cache) > self._PARSE_CACHE_MAX:
-                    self._parse_cache.popitem(last=False)
-        return q
+                q = self._parse_cache.get(s)
+                if q is not None:
+                    self._parse_cache.move_to_end(s)
+                    return q
+            st.tags["cache"] = "miss"
+            q = pql.parse(s)
+            if all(_call_cacheable(c) for c in q.calls):
+                with self._parse_lock:
+                    self._parse_cache[s] = q
+                    while len(self._parse_cache) > self._PARSE_CACHE_MAX:
+                        self._parse_cache.popitem(last=False)
+            return q
 
     # -- entry point (executor.go Execute :84) -----------------------------
 
@@ -564,6 +568,13 @@ class Executor:
         shards: Optional[List[int]] = None,
         opt: Optional[ExecOptions] = None,
     ) -> QueryResponse:
+        # The ``plan`` stage is the executor's own host time: this call
+        # minus the stages inside it (parse, and whatever lane each call
+        # takes through the batcher).
+        with tracing_mod.stage("plan", self_time=True):
+            return self._execute_entry(index, query, shards, opt)
+
+    def _execute_entry(self, index, query, shards, opt) -> QueryResponse:
         # O(1) small-query lane: a bare Count(Row(f=n)) on a single node
         # answers from maintained row cardinalities without touching the
         # dispatch stack (reference analogue: summing roaring container
@@ -594,6 +605,13 @@ class Executor:
         the HTTP layer uses to stop parking a handler thread per
         in-flight query: completion callbacks resolve pending responses
         when the fused batch's readback lands."""
+        with tracing_mod.stage("plan", self_time=True) as st:
+            fut = self._execute_async(index, query, shards, opt)
+            if fut is not None:
+                st.path = "deferred"
+        return fut
+
+    def _execute_async(self, index, query, shards, opt):
         eng = self.mesh_engine
         if eng is None or eng._peerless_multiproc:
             return None

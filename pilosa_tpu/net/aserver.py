@@ -55,6 +55,7 @@ from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
 from ..util import plans as plans_mod
+from ..util import tracing
 from ..util.stats import (
     METRIC_SERVER_CONNECTIONS,
     METRIC_SERVER_CONNECTIONS_TOTAL,
@@ -137,6 +138,7 @@ class _BlockingPool:
         return True
 
     def _worker(self):
+        tracing.name_thread()
         while True:
             with self._lock:
                 self._idle += 1
@@ -186,7 +188,7 @@ class _Conn:
         "next_slot", "next_write", "ready", "out",
         "inflight", "paused", "stop_reading", "closed",
         "last_recv", "last_progress", "want_write", "handshaking",
-        "tls_want_write", "registered",
+        "tls_want_write", "registered", "t_first",
     )
 
     HEAD = 0
@@ -214,6 +216,10 @@ class _Conn:
         self.handshaking = handshaking
         self.tls_want_write = False
         self.registered = True
+        # When the select that delivered the first byte of the request
+        # now being read returned (None between requests): where
+        # tracing.RequestClock starts.
+        self.t_first = None
 
     def mid_request(self) -> bool:
         """A request is partially read (slow-loris exposure window)."""
@@ -238,6 +244,7 @@ class _Reactor(threading.Thread):
         self.conns: set = set()
         self._stopping = False
         self._last_sweep = time.monotonic()
+        self._t_select = self._last_sweep  # when the last select returned
         self._tid: Optional[int] = None
         # (sock, callback) pairs registered before start(): extra
         # readable fds the loop watches alongside its connections —
@@ -297,6 +304,7 @@ class _Reactor(threading.Thread):
     # -- loop ---------------------------------------------------------------
 
     def run(self):
+        tracing.name_thread()
         self._tid = threading.get_ident()
         self.sel.register(self.lsock, selectors.EVENT_READ, ("accept", None))
         self.sel.register(self._wake_r, selectors.EVENT_READ, ("wake", None))
@@ -308,6 +316,7 @@ class _Reactor(threading.Thread):
                 events = self.sel.select(
                     timeout=0.0 if self._pending else 0.5
                 )
+                self._t_select = time.monotonic()
                 self._signaled = False
                 while self._pending:
                     try:
@@ -463,6 +472,8 @@ class _Reactor(threading.Thread):
         if conn.paused or conn.stop_reading:
             self._interest(conn, read=False, write=bool(conn.out))
             return
+        if conn.t_first is None:
+            conn.t_first = self._t_select
         got_any = False
         while True:
             try:
@@ -562,7 +573,12 @@ class _Reactor(threading.Thread):
             conn.state = _Conn.HEAD
             method, target, version, headers = conn.head
             conn.head = None
-            self._dispatch(conn, method, target, version, headers, body)
+            t_first = conn.t_first
+            # Bytes of the next pipelined request came with this one's.
+            conn.t_first = self._t_select if conn.rbuf else None
+            self._dispatch(
+                conn, method, target, version, headers, body, t_first
+            )
 
     @staticmethod
     def _parse_head(head: memoryview):
@@ -604,7 +620,8 @@ class _Reactor(threading.Thread):
 
     # -- dispatch -----------------------------------------------------------
 
-    def _dispatch(self, conn: _Conn, method, target, version, headers, body):
+    def _dispatch(self, conn: _Conn, method, target, version, headers, body,
+                  t_first=None):
         srv = self.srv
         slot = conn.next_slot
         conn.next_slot += 1
@@ -669,6 +686,12 @@ class _Reactor(threading.Thread):
         cors_origin = self._cors_origin(handler, headers)
         vary = bool(handler.allowed_origins)
         released = []
+        # A query request carries its clock to the handler and back:
+        # first byte in (the select that delivered it) -> last byte out
+        # (_flush), pilosa_http_request_seconds.
+        clock = None
+        if t_first is not None and method == "POST" and path.endswith("/query"):
+            clock = headers[tracing.CLOCK] = tracing.RequestClock(t_first)
 
         def release_once():
             if admission is not None and not released:
@@ -682,7 +705,7 @@ class _Reactor(threading.Thread):
                 close=not keep_alive,
                 cors_origin=cors_origin, vary=vary,
             )
-            self.call_soon(lambda: self._complete(conn, slot, raw))
+            self.call_soon(lambda: self._complete(conn, slot, raw, clock))
 
         # Fast path: deferred queries decode + submit into the batch
         # pipeline's accumulate stage right here on the reactor —
@@ -826,16 +849,17 @@ class _Reactor(threading.Thread):
 
     # -- ordered completion + writes ---------------------------------------
 
-    def _complete(self, conn: _Conn, slot: int, raw: bytes):
+    def _complete(self, conn: _Conn, slot: int, raw: bytes, clock=None):
         """Reactor-thread only: park ``raw`` in its request-order slot
-        and flush everything now in order."""
+        and flush everything now in order.  ``clock`` (a query's
+        tracing.RequestClock) is finished when the last byte of ``raw``
+        has been handed to the socket."""
         if conn.closed:
             return
-        conn.ready[slot] = raw
+        conn.ready[slot] = (raw, clock)
         progressed = False
         while conn.next_write in conn.ready:
-            buf = conn.ready.pop(conn.next_write)
-            conn.out.append(buf)
+            conn.out.append(conn.ready.pop(conn.next_write))
             conn.next_write += 1
             conn.inflight -= 1
             progressed = True
@@ -848,14 +872,14 @@ class _Reactor(threading.Thread):
 
     def _enqueue_raw(self, conn: _Conn, raw: bytes):
         """Out-of-band bytes (100-continue) — not a response slot."""
-        conn.out.append(raw)
+        conn.out.append((raw, None))
         self._flush(conn)
 
     def _flush(self, conn: _Conn):
         if conn.closed:
             return
         while conn.out:
-            buf = conn.out[0]
+            buf, clock = conn.out[0]
             try:
                 n = conn.sock.send(buf)
             except (BlockingIOError, InterruptedError):
@@ -869,9 +893,10 @@ class _Reactor(threading.Thread):
                 return
             if n == len(buf):
                 conn.out.popleft()
+                if clock is not None:
+                    clock.finish()
             else:
-                conn.out[0] = buf[n:] if n else buf
-            if n < len(buf):
+                conn.out[0] = (buf[n:] if n else buf, clock)
                 break
         want_write = bool(conn.out)
         if (

@@ -25,7 +25,8 @@ from ..executor.executor import Error as ExecError, FieldNotFoundError, IndexNot
 from ..executor.translate import TranslateError
 from ..pql import ParseError
 from ..util import plans as plans_mod
-from ..util.stats import METRIC_SERVER_ERRORS, REGISTRY
+from ..util import tracing
+from ..util.stats import METRIC_SERVER_ERRORS, METRIC_UPTIME, REGISTRY
 from .admission import tenant_of
 from .wire import count_response_bytes, response_to_json
 
@@ -506,6 +507,11 @@ class Handler:
         fast path.  The reference reads the body as raw PQL unless it's
         protobuf (http/handler.go handlePostQuery); accept JSON
         {"query": ...} as well as a bare PQL string."""
+        # The HTTP layer's clock for this request, if it made one: the
+        # handler is entered here (the end of the http_read stage).
+        clock = (headers or {}).get(tracing.CLOCK)
+        if clock is not None:
+            clock.handler_entered()
         doc = decode_query_doc(q, b)
         # Replica-read routing override + freshness bound
         # (docs/durability.md): X-Pilosa-Replica-Read selects
@@ -555,6 +561,7 @@ class Handler:
             # admission fairness uses (header, else index name).
             profile=doc["profile"],
             tenant=tenant_of(headers or {}, f"/index/{index}/query"),
+            clock=clock,
         )
 
     def _defer_query(self, req: QueryRequest):
@@ -570,6 +577,8 @@ class Handler:
         d = DeferredResponse()
 
         def _done(f):
+            if req.clock is not None:
+                req.clock.result_ready()  # the respond stage starts
             try:
                 resp = f.result(0)
                 span = getattr(f, "trace_span", None)
@@ -644,6 +653,8 @@ class Handler:
         if d is not None:
             return d
         resp = self.api.query(req)
+        if req.clock is not None:
+            req.clock.result_ready()  # the respond stage starts
         if getattr(resp, "plan", None) is None:
             # Fast JSON encode for int and TopN (id, count) results —
             # byte-identical to the generic walk (net/wire.py).  The
@@ -789,6 +800,12 @@ class Handler:
         # at pull time too (docs/observability.md): the query hot path
         # only touches the ledger's own lock.
         plans_mod.LEDGER.refresh_series()
+        # The stage clock's two window denominators, current to the
+        # scrape: the open part of the in-flight union, and the uptime.
+        tracing.INFLIGHT.flush()
+        REGISTRY.set_gauge(
+            METRIC_UPTIME, time.monotonic() - _START_MONOTONIC
+        )
         return REGISTRY.prometheus_text(openmetrics=openmetrics)
 
     def _node_metrics_text(self, openmetrics: bool = False) -> str:
@@ -1369,7 +1386,15 @@ class Handler:
         reference's CPU pprof cannot see).  ?seconds=N (capped at 10)
         captures a bounded trace into ?dir= (default: a fresh temp dir).
         Concurrent captures are rejected instead of crashing the
-        profiler."""
+        profiler.
+
+        The Python tracer is OFF unless ?python=1: it slows the
+        server's host code up to threefold and takes tens of seconds to
+        stop, so a capture with it is not the serving path's.  Without
+        it the host side of the trace is the stage clock's
+        ``pilosa.<stage>`` annotations (util/tracing.stage), switched
+        on for the length of the capture, on the device planes' clock
+        (scripts/trace_gaps.py reads them)."""
         import tempfile
         import time as time_mod
 
@@ -1378,19 +1403,24 @@ class Handler:
         seconds = min(float(q.get("seconds", ["1"])[0]), 10.0)
         dirs = q.get("dir")
         trace_dir = dirs[0] if dirs else tempfile.mkdtemp(prefix="pilosa-xprof-")
+        python = _qbool(q, "python")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python else 0
         if not Handler._pprof_trace_lock.acquire(blocking=False):
             raise ValueError("a profiler trace is already running")
         try:
-            jax.profiler.start_trace(trace_dir)
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+            tracing.capturing = True
             try:
                 time_mod.sleep(seconds)
             finally:
+                tracing.capturing = False
                 # stop unconditionally: a profiler left running would fail
                 # every later trace request with "already started".
                 jax.profiler.stop_trace()
         finally:
             Handler._pprof_trace_lock.release()
-        return {"traceDir": trace_dir, "seconds": seconds}
+        return {"traceDir": trace_dir, "seconds": seconds, "python": python}
 
     def _cluster_message(self, q, b, **kw):
         """POST /internal/cluster/message: [1-byte type][protobuf] frames
@@ -1570,17 +1600,21 @@ class _ResponseSequencer:
             self._next_slot += 1
             return slot
 
-    def complete(self, slot: int, raw: bytes):
+    def complete(self, slot: int, raw: bytes, clock=None):
+        """``clock`` (a query's tracing.RequestClock) is finished when
+        ``raw`` has been handed to the socket."""
         with self._cond:
-            self._ready[slot] = raw
+            self._ready[slot] = (raw, clock)
             while not self.dead and self._next_write in self._ready:
-                buf = self._ready.pop(self._next_write)
+                buf, written = self._ready.pop(self._next_write)
                 try:
                     self._wfile.write(buf)
                 except Exception:  # noqa: BLE001 — client went away
                     self.dead = True
                     self._ready.clear()
                     break
+                if written is not None:
+                    written.finish()
                 self._next_write += 1
             self._cond.notify_all()
 
@@ -1662,6 +1696,12 @@ class _HTTPRequestHandler(BaseHTTPRequestHandler):
                 )
         return b"\r\n".join(head) + b"\r\n\r\n" + payload
 
+    def parse_request(self):
+        # The request line has just been read: the nearest this server
+        # comes to the request's first byte (tracing.RequestClock).
+        self._t_first = time.monotonic()
+        return super().parse_request()
+
     def _dispatch(self, method):
         parsed = urlparse(self.path)
         query = parse_qs(parsed.query)
@@ -1669,9 +1709,13 @@ class _HTTPRequestHandler(BaseHTTPRequestHandler):
         body = self.rfile.read(length) if length else b""
         seq = self._sequencer()
         slot = seq.open_slot()
+        headers = dict(self.headers)
+        clock = None
+        if method == "POST" and parsed.path.endswith("/query"):
+            clock = headers[tracing.CLOCK] = tracing.RequestClock(self._t_first)
         try:
             result = self.handler.handle(
-                method, parsed.path, query, body, dict(self.headers)
+                method, parsed.path, query, body, headers
             )
         except Exception as e:  # noqa: BLE001 — an opened slot must be
             # completed no matter what, or every later response on this
@@ -1689,6 +1733,7 @@ class _HTTPRequestHandler(BaseHTTPRequestHandler):
                     self._render_response(
                         status, ctype, payload, cors_origin, vary
                     ),
+                    clock,
                 )
             )
         else:
@@ -1702,6 +1747,7 @@ class _HTTPRequestHandler(BaseHTTPRequestHandler):
                     self._cors_origin(),
                     bool(self.handler.allowed_origins),
                 ),
+                clock,
             )
         if self.close_connection:
             # The last response of the connection may still be in

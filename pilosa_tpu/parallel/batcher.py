@@ -35,7 +35,9 @@ DEFAULT_INFLIGHT, env PILOSA_PIPELINE_DEPTH): the dispatch worker BLOCKS
 on the (depth+1)'th batch, so under overload the queue accumulates a
 full readback period of arrivals and batch size self-tunes to
 arrival_rate x readback_time / depth, while depth batches overlap in the
-transport + device.  Per-stage timings, in-flight depth, and batch
+transport + device.  Every stage is recorded by one call of the stage
+clock (util/tracing.py ``stage``/``waited``: histogram, span, plan and,
+during a capture, profiler annotation); in-flight depth and batch
 occupancy are tracked in a util.stats.PipelineStats (``pipeline``
 attribute; surfaced by /debug/vars and bench.py).
 
@@ -261,8 +263,16 @@ class CountBatcher:
                             memo_key=key, memo_note=memo_note)
         if item is None:
             return self._direct(index, call, shards, key, probed, memo_note)
-        if not item.event.wait(self.WAIT_TIMEOUT):
-            raise RuntimeError("batched count timed out (engine wedged?)")
+        return self._wait(item, "batched count")
+
+    def _wait(self, item: "_Item", what: str):
+        """Block a sync submitter on its queued item.  The pipeline's
+        workers record the stages of that interval, so it is a hole in
+        whatever stage the calling thread is inside."""
+        ok = item.event.wait(self.WAIT_TIMEOUT)
+        tracing.hole(item.t_submit, time.monotonic())
+        if not ok:
+            raise RuntimeError(f"{what} timed out (engine wedged?)")
         if item.error is not None:
             raise item.error
         return item.result
@@ -307,11 +317,7 @@ class CountBatcher:
                             kind=kind, spec=spec, memo_key=key)
         if item is None:
             return self._direct_op(index, kind, spec, shards, memo_key=key)
-        if not item.event.wait(self.WAIT_TIMEOUT):
-            raise RuntimeError("batched op timed out (engine wedged?)")
-        if item.error is not None:
-            raise item.error
-        return item.result
+        return self._wait(item, "batched op")
 
     def _memo_probe_op(self, index, kind, spec, shards):
         """engine.memo_probe_op, duck-typed like _memo_probe: the
@@ -323,9 +329,12 @@ class CountBatcher:
         return probe(index, kind, spec, shards)
 
     def _direct_op(self, index, kind, spec, shards, memo_key=None):
-        t0 = time.monotonic()
+        # The whole blocking call is the direct path's "execute" stage;
+        # the engine's lower/dispatch/device_get/decode nest inside it.
+        execute = tracing.stage("execute", "direct")
         try:
-            out = self.engine.solo_op(index, kind, spec, shards)
+            with execute:
+                out = self.engine.solo_op(index, kind, spec, shards)
             if memo_key is not None:
                 store = getattr(self.engine, "memo_store_op", None)
                 if store is not None:
@@ -341,9 +350,7 @@ class CountBatcher:
                 d.setdefault("op", OP_NAMES.get(kind, kind))
                 d.setdefault("path", "direct")
                 plan.note_op(**d)
-                elapsed = time.monotonic() - t0
-                plan.note_stage("execute", elapsed)
-                plan.note_device_seconds(elapsed)
+                plan.note_device_seconds(execute.t1 - execute.t0)
             with self._lock:
                 self._busy = False
                 if self._queue:
@@ -398,14 +405,17 @@ class CountBatcher:
 
     def _direct(self, index, call, shards, memo_key=None, probed=False,
                 memo_note=None) -> int:
-        t0 = time.monotonic()
+        execute = tracing.stage("execute", "direct")
         try:
-            if probed:
-                # submit() already probed (and missed): hand the key
-                # through so count_async stores the result without a
-                # second key walk or a double-counted miss.
-                return self.engine.count(index, call, shards, memo_key=memo_key)
-            return self.engine.count(index, call, shards)
+            with execute:
+                if probed:
+                    # submit() already probed (and missed): hand the key
+                    # through so count_async stores the result without a
+                    # second key walk or a double-counted miss.
+                    return self.engine.count(
+                        index, call, shards, memo_key=memo_key
+                    )
+                return self.engine.count(index, call, shards)
         finally:
             # Plan record for the unbatched path: the engine published
             # its dispatch decisions to this thread's note; the whole
@@ -418,11 +428,7 @@ class CountBatcher:
                 if memo_note is not None:
                     d["memo"], d["memo_reason"] = memo_note
                 plan.note_op(**d)
-                elapsed = time.monotonic() - t0
-                # The direct path has no pipeline stages: the whole
-                # blocking dispatch+readback is one "execute" stage.
-                plan.note_stage("execute", elapsed)
-                plan.note_device_seconds(elapsed)
+                plan.note_device_seconds(execute.t1 - execute.t0)
             with self._lock:
                 self._busy = False
                 if self._queue:
@@ -464,6 +470,7 @@ class CountBatcher:
                 self._collect_q.put(None)
 
     def _drain_loop(self):
+        tracing.name_thread("pq-drain")
         while not self._stopped:
             with self._lock:
                 while not self._queue:
@@ -572,6 +579,7 @@ class CountBatcher:
     # -- lower+dispatch stage -----------------------------------------------
 
     def _dispatch_loop(self):
+        tracing.name_thread("pq-dispatch")
         while True:
             got = self._dispatch_q.get()
             if got is None:
@@ -584,109 +592,34 @@ class CountBatcher:
             with self._lock:
                 self._live += 1
             self.pipeline.add_delta("inflight", 1)
+            path = self._PATHS[gkind]
             if not retried:
                 now = time.monotonic()
-                # Wall stages stamp ONCE per distinct plan: a query with
-                # several Counts rides the batch as several items sharing
-                # one plan, and their waits overlap in wall time — summing
-                # them would report stagesMs > durationMs and trip the
-                # analyzer's queue-wait check on a healthy pipeline.  The
-                # longest waiter is the query's wait.
-                plan_wait: dict = {}
                 for it in items:
-                    self.pipeline.record(
-                        "queue_wait", now - it.t_submit,
-                        exemplar=it.span.trace_id if it.span is not None else None,
-                    )
-                    if it.span is not None:
-                        it.span.record(
-                            "pipeline.queue_wait",
-                            start=it.t_submit,
-                            duration=now - it.t_submit,
-                        )
-                    if it.plan is not None:
-                        pid = id(it.plan)
-                        wait = now - it.t_submit
-                        prev = plan_wait.get(pid)
-                        if prev is None or wait > prev[1]:
-                            plan_wait[pid] = (it.plan, wait)
-                for plan, wait in plan_wait.values():
-                    plan.note_stage("queue_wait", wait)
+                    tracing.waited("queue_wait", path, it.t_submit, now, (it,))
             try:
-                t0 = time.monotonic()
                 decoders = None
                 weights = None
-                if gkind == "count":
-                    dev = self.engine.count_many_async(
-                        index,
-                        [it.call for it in items],
-                        [it.shards for it in items],
+                lowering = tracing.stage(
+                    "lower_dispatch", path, items, batch=len(items)
+                )
+                with lowering:
+                    dev, decoders, weights = self._lower_dispatch(
+                        gkind, index, items
                     )
-                elif gkind == "fused":
-                    specs = [
-                        it.spec
-                        if it.spec is not None
-                        else {"kind": "count", "call": it.call}
-                        for it in items
-                    ]
-                    drain = getattr(self.engine, "fused_drain_async", None)
-                    if drain is not None:
-                        fd = drain([
-                            (it.index, sp, it.shards)
-                            for it, sp in zip(items, specs)
-                        ])
-                    else:
-                        fd = self.engine.fused_many_async(
-                            index,
-                            [(sp, it.shards)
-                             for it, sp in zip(items, specs)],
-                        )
-                    dev = fd.dev
-                    live_items, decoders, weights = [], [], []
-                    for i, it in enumerate(items):
-                        if fd.errors[i] is not None:
-                            it.error = fd.errors[i]
-                            it._resolve()
-                            continue
-                        it.plan_extra = fd.item_notes[i]
-                        live_items.append(it)
-                        decoders.append(fd.decoders[i])
-                        weights.append(fd.weights[i])
-                    items = live_items
-                else:  # solo: one aggregate on its existing per-op program
-                    it0 = items[0]
-                    dev, dec = self.engine.solo_op_async(
-                        it0.index, it0.kind, it0.spec, it0.shards
-                    )
-                    decoders = [dec]
-                t1 = time.monotonic()
                 note = plans_mod.take_dispatch_note()
-                if note is None and gkind == "solo":
-                    # The per-op aggregate dispatches publish no note of
-                    # their own; name the lane so the plan still says
-                    # which path ran.
+                if gkind == "solo":
+                    # The per-op aggregate dispatches publish no op or
+                    # path of their own; name the lane so the plan still
+                    # says which path ran.
                     from .fusion import OP_NAMES
 
-                    note = {
-                        "op": OP_NAMES.get(items[0].kind, items[0].kind),
-                        "path": "solo",
-                    }
-                self._stamp_plans(items, note, t1 - t0, weights)
-                self.pipeline.record(
-                    "lower_dispatch", t1 - t0,
-                    exemplar=next(
-                        (it.span.trace_id for it in items if it.span is not None),
-                        None,
-                    ),
-                )
-                for it in items:
-                    if it.span is not None:
-                        it.span.record(
-                            "pipeline.lower_dispatch",
-                            start=t0,
-                            duration=t1 - t0,
-                            batch=len(items),
-                        )
+                    note = dict(note or ())
+                    note.setdefault(
+                        "op", OP_NAMES.get(items[0].kind, items[0].kind)
+                    )
+                    note.setdefault("path", "solo")
+                self._stamp_plans(items, note, weights)
             except BaseException as batch_err:  # noqa: BLE001 — the loop
                 # must survive anything; a dead dispatch worker wedges
                 # every later submit at WAIT_TIMEOUT.
@@ -739,9 +672,64 @@ class CountBatcher:
                 # kinds (smoke.sh and bench --dashboard-sweep read it).
                 self.pipeline.incr("fused_program_batches")
                 self.pipeline.incr("fused_program_queries", len(items))
+            # In flight from the jitted call's return to the collect
+            # worker's device_get (pilosa_engine_device_inflight_seconds_total).
+            tracing.INFLIGHT.begin(lowering.t1)
             self._collect_q.put(
-                (dev, items, time.monotonic(), decoders, weights)
+                (dev, items, time.monotonic(), decoders, weights, path)
             )
+
+    # Drain group kind -> the stage clock's path label.
+    _PATHS = {"count": "deferred", "fused": "fused", "solo": "solo"}
+
+    def _lower_dispatch(self, gkind, index, items: List[_Item]):
+        """Lower one drain group and enqueue its device program WITHOUT
+        waiting for the device: (device result, decoders, weights).
+        Fused items that failed at build resolve here and leave
+        ``items`` (in place: the lower_dispatch stage rides on it)."""
+        if gkind == "count":
+            dev = self.engine.count_many_async(
+                index,
+                [it.call for it in items],
+                [it.shards for it in items],
+            )
+            return dev, None, None
+        if gkind == "solo":  # one aggregate on its existing per-op program
+            it0 = items[0]
+            dev, dec = self.engine.solo_op_async(
+                it0.index, it0.kind, it0.spec, it0.shards
+            )
+            return dev, [dec], None
+        specs = [
+            it.spec
+            if it.spec is not None
+            else {"kind": "count", "call": it.call}
+            for it in items
+        ]
+        drain = getattr(self.engine, "fused_drain_async", None)
+        if drain is not None:
+            fd = drain([
+                (it.index, sp, it.shards)
+                for it, sp in zip(items, specs)
+            ])
+        else:
+            fd = self.engine.fused_many_async(
+                index,
+                [(sp, it.shards)
+                 for it, sp in zip(items, specs)],
+            )
+        live_items, decoders, weights = [], [], []
+        for i, it in enumerate(items):
+            if fd.errors[i] is not None:
+                it.error = fd.errors[i]
+                it._resolve()
+                continue
+            it.plan_extra = fd.item_notes[i]
+            live_items.append(it)
+            decoders.append(fd.decoders[i])
+            weights.append(fd.weights[i])
+        items[:] = live_items
+        return fd.dev, decoders, weights
 
     def _handle_batch_failure(self, gkind, index, items: List[_Item],
                               retried, batch_err):
@@ -821,8 +809,7 @@ class CountBatcher:
                 it._resolve()
 
     @staticmethod
-    def _stamp_plans(items: List[_Item], note, lower_seconds: float,
-                     weights=None):
+    def _stamp_plans(items: List[_Item], note, weights=None):
         """Fan the engine's dispatch note out to every rider's plan.
         Byte tallies divide by each rider's FOOTPRINT share when the
         fused planner measured one (``weights``) — a 1-mask Count rider
@@ -833,7 +820,6 @@ class CountBatcher:
             return
         n = len(items)
         total_w = sum(weights) if weights else 0.0
-        staged = set()
         for i, it in enumerate(items):
             if it.plan is None:
                 continue
@@ -846,11 +832,6 @@ class CountBatcher:
             if it.memo_note is not None:
                 d["memo"], d["memo_reason"] = it.memo_note
             it.plan.note_op(**d)
-            # One lower_dispatch stamp per distinct plan: the batch
-            # lowered once, however many of this query's Counts rode it.
-            if id(it.plan) not in staged:
-                staged.add(id(it.plan))
-                it.plan.note_stage("lower_dispatch", lower_seconds)
 
     # -- collect stage ------------------------------------------------------
 
@@ -858,44 +839,52 @@ class CountBatcher:
         import jax
         import numpy as np
 
+        tracing.name_thread(
+            threading.current_thread().name.replace("count-batch", "pq")
+        )
         while True:
             got = self._collect_q.get()
             if got is None:
                 return  # stop() sentinel
-            dev, items, t_dispatched, decoders, weights = got
+            dev, items, t_dispatched, decoders, weights, path = got
+            t_taken = time.monotonic()
             try:
-                if decoders is None:
-                    out = np.asarray(jax.device_get(dev))
-                else:
-                    out = jax.device_get(dev)
-                t_ready = time.monotonic()
-                self.pipeline.record(
-                    "device_readback", t_ready - t_dispatched,
-                    exemplar=next(
-                        (it.span.trace_id for it in items if it.span is not None),
-                        None,
-                    ),
+                # device_readback stays as it was defined: put into
+                # _collect_q -> device_get returned.  Its two parts are
+                # the wait for a collect worker and the blocking get.
+                readback = tracing.stage(
+                    "device_readback", path, items, t0=t_dispatched
                 )
-                for i, it in enumerate(items):
-                    it.result = (
-                        int(out[i]) if decoders is None else decoders[i](out)
+                with readback:
+                    tracing.waited(
+                        "collect_wait", None, t_dispatched, t_taken, None
                     )
-                    # Populate the result memo under the tokens read at
-                    # submit time (engine.memo_probe's ordering note).
-                    # Counts hand the tree through so the repair layer
-                    # can register the entry's footprint; aggregate ops
-                    # store through the per-kind op memo.
-                    if it.memo_key is not None:
-                        if it.kind == "count":
-                            self.engine.memo_store(
-                                it.memo_key, it.result, call=it.call
-                            )
+                    with tracing.stage("device_get"):
+                        if decoders is None:
+                            out = np.asarray(jax.device_get(dev))
                         else:
-                            self.engine.memo_store_op(
-                                it.memo_key, it.kind, it.spec, it.result
-                            )
-                t_done = time.monotonic()
-                self.pipeline.record("decode", t_done - t_ready)
+                            out = jax.device_get(dev)
+                with tracing.stage("decode", path, items):
+                    for i, it in enumerate(items):
+                        it.result = (
+                            int(out[i]) if decoders is None
+                            else decoders[i](out)
+                        )
+                        # Populate the result memo under the tokens read
+                        # at submit time (engine.memo_probe's ordering
+                        # note).  Counts hand the tree through so the
+                        # repair layer can register the entry's
+                        # footprint; aggregate ops store through the
+                        # per-kind op memo.
+                        if it.memo_key is not None:
+                            if it.kind == "count":
+                                self.engine.memo_store(
+                                    it.memo_key, it.result, call=it.call
+                                )
+                            else:
+                                self.engine.memo_store_op(
+                                    it.memo_key, it.kind, it.spec, it.result
+                                )
                 # Device-cost attribution: the batch held one device
                 # slot for the readback window; each rider is charged
                 # its FOOTPRINT share when the fused planner measured
@@ -903,40 +892,20 @@ class CountBatcher:
                 # masks split among sharers), an even share otherwise
                 # (the tenant ledger sums these into
                 # pilosa_tenant_device_seconds_total).
-                window = t_ready - t_dispatched
+                window = readback.t1 - t_dispatched
                 total_w = sum(weights) if weights else 0.0
-                staged = set()
                 for i, it in enumerate(items):
                     if it.plan is not None:
-                        # Wall stages once per distinct plan (shared batch
-                        # window); the device-cost SHARE stays per item —
-                        # each of a query's Counts consumed its own slice.
-                        if id(it.plan) not in staged:
-                            staged.add(id(it.plan))
-                            it.plan.note_stage(
-                                "device_readback", t_ready - t_dispatched
-                            )
-                            it.plan.note_stage("decode", t_done - t_ready)
                         it.plan.note_device_seconds(
                             window * weights[i] / total_w
                             if total_w
                             else window / max(1, len(items))
                         )
-                    if it.span is not None:
-                        it.span.record(
-                            "pipeline.device_readback",
-                            start=t_dispatched,
-                            duration=t_ready - t_dispatched,
-                        )
-                        it.span.record(
-                            "pipeline.decode",
-                            start=t_ready,
-                            duration=t_done - t_ready,
-                        )
             except BaseException as e:  # noqa: BLE001
                 for it in items:
                     it.error = e
             finally:
+                tracing.INFLIGHT.end()
                 with self._lock:
                     self._live -= 1
                 self.pipeline.add_delta("inflight", -1)

@@ -47,6 +47,7 @@ from ..pql import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ, Call, Condition
 from ..util import events as events_mod
 from ..util import heat as heat_mod
 from ..util import plans as plans_mod
+from ..util import tracing
 from ..util.stats import (
     COMPILE_PHASES,
     ENGINE_CACHES,
@@ -56,6 +57,10 @@ from ..util.stats import (
     METRIC_ENGINE_COMPILE,
     METRIC_ENGINE_COMPILE_KEYS,
     METRIC_ENGINE_COMPILE_SECONDS,
+    METRIC_ENGINE_DRAIN_PLANE_BYTES,
+    METRIC_ENGINE_DRAIN_REQUESTS,
+    METRIC_ENGINE_DRAIN_SLOTS,
+    METRIC_ENGINE_DRAINS,
     METRIC_ENGINE_EVICTED_BYTES,
     METRIC_ENGINE_EVICTIONS,
     METRIC_ENGINE_FUSED_EDGES,
@@ -981,6 +986,8 @@ class MeshEngine:
         self._bytes_skipped_counter = REGISTRY.counter(
             METRIC_DEVICE_BYTES_SKIPPED
         )
+        # (op, path) -> the four drain-record counter handles.
+        self._drain_counters: Dict[tuple, tuple] = {}
         # Residency/compile introspection handles (resolved once).
         self._evictions_counter = REGISTRY.counter(METRIC_ENGINE_EVICTIONS)
         self._rebuilds_counter = REGISTRY.counter(METRIC_ENGINE_REBUILDS)
@@ -994,6 +1001,102 @@ class MeshEngine:
         instead of a per-shard host loop / HTTP fan-out."""
         self.fused_dispatches += 1
         self._psum_dispatch_counter.inc()
+
+    # One row-plane of one shard: SHARD_WIDTH bits.
+    PLANE_BYTES = bitops.SHARD_WIDTH // 8
+
+    def _hint_planes(self, hints: dict) -> Tuple[int, int]:
+        """(row-planes, row-planes x shards) a row-hint map names: one
+        per row of a set view, bit depth + not-null for a BSI view (a
+        predicate or an aggregate walks every plane), the resident rows
+        for any other whole-stack hint.  The benchmark's own reckoning
+        of what a request reads, from inside."""
+        planes = shard_planes = 0
+        n_shards: Dict[str, int] = {}
+        for (index, field, view), rows in hints.items():
+            if rows is not None:
+                n = len(rows)
+            else:
+                n = 0
+                idx = self.holder.index(index)
+                f = idx.field(field) if idx is not None else None
+                bsig = f.bsi_group(field) if f is not None else None
+                if bsig is not None and view == view_bsi_name(field):
+                    n = bsig.bit_depth() + 1
+                else:
+                    st = self._stacks.get((index, field, view))
+                    if st is not None:
+                        n = len(st.row_index)
+            s = n_shards.get(index)
+            if s is None:
+                s = n_shards[index] = len(self.canonical_shards(index))
+            planes += n
+            shard_planes += n * s
+        return planes, shard_planes
+
+    def _note_drain(self, op: str, path: str, tier: int, live: int,
+                    per_request: Tuple[int, int],
+                    per_drain: Tuple[int, int],
+                    note_bytes: bool = True) -> dict:
+        """The drain record of ONE device dispatch: which program
+        (``op``, the dispatch note's ``path``), the slots it was
+        compiled for (``tier``), the requests it answers (``live``),
+        and the row-planes named — summed over the live requests, and
+        distinct over the whole drain (what a program that read each
+        plane once would read).  Counts the four
+        ``pilosa_engine_drain*`` series, publishes the plan's
+        ``bytes_touched`` (the drain's distinct planes, not whole
+        operands) and returns the tags of the ``dispatch`` stage."""
+        handles = self._drain_counters.get((op, path))
+        if handles is None:
+            handles = self._drain_counters[(op, path)] = (
+                REGISTRY.counter(METRIC_ENGINE_DRAINS, op=op, path=path),
+                REGISTRY.counter(METRIC_ENGINE_DRAIN_SLOTS, op=op, path=path),
+                REGISTRY.counter(
+                    METRIC_ENGINE_DRAIN_REQUESTS, op=op, path=path
+                ),
+                REGISTRY.counter(
+                    METRIC_ENGINE_DRAIN_PLANE_BYTES,
+                    op=op, path=path, counted="per_request",
+                ),
+                REGISTRY.counter(
+                    METRIC_ENGINE_DRAIN_PLANE_BYTES,
+                    op=op, path=path, counted="per_drain",
+                ),
+            )
+        handles[0].inc()
+        handles[1].inc(tier)
+        handles[2].inc(live)
+        handles[3].inc(per_request[1] * self.PLANE_BYTES)
+        handles[4].inc(per_drain[1] * self.PLANE_BYTES)
+        if note_bytes:
+            plans_mod.note_dispatch(
+                bytes_touched=per_drain[1] * self.PLANE_BYTES
+            )
+        return {
+            "op": op, "drain": path, "tier": tier, "live": live,
+            "planes_per_request": per_request[0],
+            "planes_per_drain": per_drain[0],
+        }
+
+    def _note_aggregate(self, op: str, index, field_name, filter_call) -> dict:
+        """Drain record of a solo BSI aggregate: one request, one slot,
+        the measure's planes plus the filter's."""
+        hints = {(index, field_name, view_bsi_name(field_name)): None}
+        if filter_call is not None:
+            self._collect_row_hints(index, filter_call, hints)
+        planes = self._hint_planes(hints)
+        return self._note_drain(op, "aggregate", 1, 1, planes, planes)
+
+    def _fetch(self, dev):
+        """The blocking readback of a sync wrapper: the ``device_get``
+        stage, and the end of the dispatch's in-flight interval."""
+        tracing.INFLIGHT.begin()
+        try:
+            with tracing.stage("device_get"):
+                return jax.device_get(dev)
+        finally:
+            tracing.INFLIGHT.end()
 
     def _cache_hit(self, name: str):
         self.cache_stats[name][0] += 1
@@ -2620,7 +2723,8 @@ class MeshEngine:
         self, index: str, c: Call, shards: List[int], memo_key=_MEMO_UNSET
     ) -> int:
         """Count(tree): one fused dispatch, one psum."""
-        return int(self.count_async(index, c, shards, memo_key=memo_key))
+        dev = self.count_async(index, c, shards, memo_key=memo_key)
+        return int(np.asarray(self._fetch(dev)))
 
     def count_async(
         self,
@@ -3060,52 +3164,63 @@ class MeshEngine:
             f"to the host path: {err!r}"
         )
 
-    @staticmethod
-    def _operand_bytes(lw: "_Lowering") -> int:
-        """Device bytes a dense dispatch over these operands sweeps —
-        the plan record's bytes_touched estimate."""
-        return sum(int(getattr(op, "nbytes", 0)) for op in lw.operands)
-
-    def _dispatch_count(self, index, c, shards, canonical):
-        lw = _Lowering(self, canonical)
-        lw.row_hints = self._collect_row_hints(index, c)
-        prog = self._lower(index, c, lw)
-        mask = self._mask_words(shards, canonical)
-        plan = self._sparse_plan(prog, lw, shards, canonical)
-        self._note_fused_dispatch()
-        self._note_touches(lw)
+    def _dispatch_count(self, index, c, shards, canonical, live=1):
+        """One Count tree on the scalar count program (dense, or the
+        occupancy-guided sparse plan): ``live`` callers share its
+        answer (a drain that CSE'd down to one unique query)."""
+        with tracing.stage("lower"):
+            lw = _Lowering(self, canonical)
+            lw.row_hints = self._collect_row_hints(index, c)
+            prog = self._lower(index, c, lw)
+            mask = self._mask_words(shards, canonical)
+            plan = self._sparse_plan(prog, lw, shards, canonical)
+            self._note_fused_dispatch()
+            self._note_touches(lw)
+            planes = self._hint_planes(lw.row_hints)
+            per_request = (planes[0] * live, planes[1] * live)
         if plan is not None:
-            return self._dispatch_sparse(plan, mask)
-        plans_mod.note_dispatch(
-            op="Count", path="dense", fused=True,
-            bytes_touched=self._operand_bytes(lw),
+            return self._dispatch_sparse(plan, mask, live, per_request, planes)
+        plans_mod.note_dispatch(op="Count", path="dense", fused=True)
+        drain = self._note_drain(
+            "Count", "dense", 1, live, per_request, planes
         )
-        return kernels.count_tree(
-            self.mesh, prog, tuple(lw.specs), mask, *lw.operands
-        )
+        with tracing.stage("dispatch", **drain):
+            return kernels.count_tree(
+                self.mesh, prog, tuple(lw.specs), mask, *lw.operands
+            )
 
-    def _dispatch_sparse(self, plan, mask):
+    def _dispatch_sparse(self, plan, mask, live=1, per_request=(0, 0),
+                         per_drain=(0, 0)):
         """Dispatch an occupancy-guided plan (_sparse_plan): the Pallas
         block-DMA kernel on TPU backends whose per-device shard count
         fills its aligned DMA windows, the XLA block-gather form
-        everywhere else."""
+        everywhere else.  The drain record counts the planes the tree
+        NAMES; the blocks the kernel skips are
+        pilosa_device_bytes_skipped_total's."""
         sprog, mats, rowvec, blk_idx, blk_n, skipped = plan
         self.sparse_dispatches += 1
         self.device_bytes_skipped += skipped
         self._bytes_skipped_counter.inc(skipped)
         s_local = blk_idx.shape[0] // self.mesh.devices.size
         pallas = self._sparse_pallas and s_local % sparse_mod.SHARD_GROUP == 0
+        # bytes_touched stays _sparse_plan's (the surviving blocks).
+        drain = self._note_drain(
+            "Count", "sparse", 1, live, per_request, per_drain,
+            note_bytes=False,
+        )
         plans_mod.note_dispatch(
             op="Count", path="sparse", fused=True, bytes_skipped=skipped,
             kernel="pallas" if pallas else "xla",
         )
-        if pallas:
-            return sparse_mod.count_tree_blocks_pallas(
-                self.mesh, sprog, False, mask, blk_idx, blk_n, rowvec, *mats
+        with tracing.stage("dispatch", **drain):
+            if pallas:
+                return sparse_mod.count_tree_blocks_pallas(
+                    self.mesh, sprog, False, mask, blk_idx, blk_n, rowvec,
+                    *mats
+                )
+            return sparse_mod.count_tree_blocks(
+                self.mesh, sprog, mask, blk_idx, blk_n, rowvec, *mats
             )
-        return sparse_mod.count_tree_blocks(
-            self.mesh, sprog, mask, blk_idx, blk_n, rowvec, *mats
-        )
 
     def _sparse_plan(self, prog, lw: _Lowering, shards, canonical):
         """Occupancy-guided dispatch plan for a lowered count tree, or
@@ -3428,7 +3543,8 @@ class MeshEngine:
         )
 
         def locked():
-            plan = self._fused_plan_for(sorted_entries, cache_key)
+            with tracing.stage("lower"):
+                plan = self._fused_plan_for(sorted_entries, cache_key)
             fd = fusion_mod.dispatch(self, plan)
             if order == list(range(n)):
                 return fd
@@ -3526,7 +3642,7 @@ class MeshEngine:
             # records no plan — claim it so a later plan-recorded query
             # on this thread can't inherit stale fused-program fields.
             plans_mod.take_dispatch_note()
-        host = jax.device_get(fd.dev)
+        host = self._fetch(fd.dev)
         out = []
         for i, dec in enumerate(fd.decoders):
             if fd.errors[i] is not None:
@@ -3717,7 +3833,7 @@ class MeshEngine:
         from goroutines sharing one mmap'd fragment set; on an
         accelerator the batching must happen before the program launch."""
         dev = self.count_many_async(index, calls, shards_list)
-        out = np.asarray(jax.device_get(dev))
+        out = np.asarray(self._fetch(dev))
         return [int(out[i]) for i in range(len(calls))]
 
     def count_many_async(
@@ -3793,63 +3909,73 @@ class MeshEngine:
         # do (the dedup is deterministic) — but the sparse plan is
         # local-only there, so the scalar detour buys nothing.
         if len(u_calls) == 1 and not self.multiproc:
-            lw1 = _Lowering(self, canonical)
-            lw1.row_hints = self._collect_row_hints(index, u_calls[0])
-            prog1 = self._lower(index, u_calls[0], lw1)
-            mask1 = self._mask_words(u_shards[0], canonical)
-            plan = self._sparse_plan(prog1, lw1, u_shards[0], canonical)
-            self._note_fused_dispatch()
-            self._note_touches(lw1)
             plans_mod.note_dispatch(
                 cse_unique=1, cse_deduped=deduped, batch_size=len(calls)
             )
-            if plan is not None:
-                dev = self._dispatch_sparse(plan, mask1)
-            else:
-                plans_mod.note_dispatch(
-                    op="Count", path="dense", fused=True,
-                    bytes_touched=self._operand_bytes(lw1),
-                )
-                dev = kernels.count_tree(
-                    self.mesh, prog1, tuple(lw1.specs), mask1, *lw1.operands
-                )
+            dev = self._dispatch_count(
+                index, u_calls[0], u_shards[0], canonical, len(calls)
+            )
             return jnp.broadcast_to(dev, (len(calls),))
-        lw = _Lowering(self, canonical, slot_vector=True)
-        for c in u_calls:
-            self._collect_row_hints(index, c, lw.row_hints)
-        progs = []
-        for c, shards in zip(u_calls, u_shards):
-            prog = self._lower(index, c, lw)
-            i_mask = lw.add_mask(self._mask_words(shards, canonical))
-            progs.append((prog, i_mask))
-        # Pad to the tier by RE-LOWERING query 0: padding entries then
-        # occupy their own deterministic slots, so the padded program is
-        # byte-identical for every batch of the same structure + tier
-        # (XLA CSEs the duplicate trees; the dead slots cost nothing).
-        # Repeating the LAST pair instead (round 4) kept the raw K in
-        # the operand indexing and compiled a fresh program per distinct
-        # drain size — ~2 s each, the entire QPS shortfall.
-        K = len(progs)
-        K_pad = next(
-            (t for t in self.BATCH_TIERS if K <= t),
-            max(1, 1 << (K - 1).bit_length()),
-        )
-        for _ in range(K_pad - K):
-            prog = self._lower(index, u_calls[0], lw)
-            i_mask = lw.add_mask(self._mask_words(u_shards[0], canonical))
-            progs.append((prog, i_mask))
-        lw.finish()
-        self._note_fused_dispatch()
-        self._note_touches(lw)
+        with tracing.stage("lower"):
+            lw = _Lowering(self, canonical, slot_vector=True)
+            # Row hints per unique call (each one's distinct planes are
+            # the drain record's per-request reckoning), merged into the
+            # drain's (the lowering's promotion hints; its distinct
+            # planes are the per-drain reckoning).
+            u_planes = []
+            for c in u_calls:
+                hints = self._collect_row_hints(index, c)
+                u_planes.append(self._hint_planes(hints))
+                fusion_mod.merge_hints(lw.row_hints, hints)
+            progs = []
+            for c, shards in zip(u_calls, u_shards):
+                prog = self._lower(index, c, lw)
+                i_mask = lw.add_mask(self._mask_words(shards, canonical))
+                progs.append((prog, i_mask))
+            # Pad to the tier by RE-LOWERING query 0: padding entries
+            # then occupy their own deterministic slots, so the padded
+            # program is byte-identical for every batch of the same
+            # structure + tier.  The dead slots are NOT free on the
+            # chip: XLA does not CSE the duplicate trees away (the row
+            # ids are data), each slot's fusion reads its planes again,
+            # and a tier-64 run costs 64 slots whatever its live count
+            # (on a v5e 61.5 ms for 16 live requests that asked for
+            # 15.4: PERF.md section 5; engine.tier_fill = requests /
+            # slots is the metric).
+            # Repeating the LAST pair instead (round 4) kept the raw K
+            # in the operand indexing and compiled a fresh program per
+            # distinct drain size — ~2 s each, the entire QPS shortfall.
+            K = len(progs)
+            K_pad = next(
+                (t for t in self.BATCH_TIERS if K <= t),
+                max(1, 1 << (K - 1).bit_length()),
+            )
+            for _ in range(K_pad - K):
+                prog = self._lower(index, u_calls[0], lw)
+                i_mask = lw.add_mask(
+                    self._mask_words(u_shards[0], canonical)
+                )
+                progs.append((prog, i_mask))
+            lw.finish()
+            self._note_fused_dispatch()
+            self._note_touches(lw)
         plans_mod.note_dispatch(
             op="Count", path="dense_batch", fused=True,
             cse_unique=len(u_calls), cse_deduped=deduped,
             batch_size=len(calls), tier=K_pad,
-            bytes_touched=self._operand_bytes(lw),
         )
-        dev = kernels.count_batch_tree(
-            self.mesh, tuple(progs), tuple(lw.specs), *lw.operands
+        drain = self._note_drain(
+            "Count", "dense_batch", K_pad, len(calls),
+            (
+                sum(u_planes[j][0] for j in mapping),
+                sum(u_planes[j][1] for j in mapping),
+            ),
+            self._hint_planes(lw.row_hints),
         )
+        with tracing.stage("dispatch", **drain):
+            dev = kernels.count_batch_tree(
+                self.mesh, tuple(progs), tuple(lw.specs), *lw.operands
+            )
         if deduped:
             # Fan the U unique answers back out to the K callers (a
             # trivial replicated gather — microseconds against the
@@ -3972,18 +4098,22 @@ class MeshEngine:
         mask = self._mask_words(shards, canonical)
 
         def dispatch():
-            lw = _Lowering(self, canonical)
-            prog = self._lower_filter(index, filter_call, lw)
-            self._note_fused_dispatch()
-            return kernels.sum_tree(
-                self.mesh,
-                prog,
-                tuple(lw.specs),
-                self._plane_spec(stack, depth),
-                mask,
-                stack.matrix,
-                *lw.operands,
-            )
+            with tracing.stage("lower"):
+                lw = _Lowering(self, canonical)
+                prog = self._lower_filter(index, filter_call, lw)
+                self._note_fused_dispatch()
+                drain = self._note_aggregate("Sum", index, field_name,
+                                             filter_call)
+            with tracing.stage("dispatch", **drain):
+                return kernels.sum_tree(
+                    self.mesh,
+                    prog,
+                    tuple(lw.specs),
+                    self._plane_spec(stack, depth),
+                    mask,
+                    stack.matrix,
+                    *lw.operands,
+                )
 
         dev = self._collective(
             "sum",
@@ -4009,7 +4139,9 @@ class MeshEngine:
         dev, depth, bsig = res
         # Host assembly shared with the fused/batched lanes — one
         # implementation, zero drift (fusion.py decode helpers).
-        return fusion_mod.decode_sum(jax.device_get(dev), depth, bsig.min)
+        host = self._fetch(dev)
+        with tracing.stage("decode"):
+            return fusion_mod.decode_sum(host, depth, bsig.min)
 
     def min_max_async(
         self,
@@ -4042,19 +4174,25 @@ class MeshEngine:
         mask = self._mask_words(shards, canonical)
 
         def dispatch():
-            lw = _Lowering(self, canonical)
-            prog = self._lower_filter(index, filter_call, lw)
-            self._note_fused_dispatch()
-            return kernels.minmax_tree(
-                self.mesh,
-                prog,
-                tuple(lw.specs),
-                self._plane_spec(stack, depth),
-                is_min,
-                mask,
-                stack.matrix,
-                *lw.operands,
-            )
+            with tracing.stage("lower"):
+                lw = _Lowering(self, canonical)
+                prog = self._lower_filter(index, filter_call, lw)
+                self._note_fused_dispatch()
+                drain = self._note_aggregate(
+                    "Min" if is_min else "Max", index, field_name,
+                    filter_call,
+                )
+            with tracing.stage("dispatch", **drain):
+                return kernels.minmax_tree(
+                    self.mesh,
+                    prog,
+                    tuple(lw.specs),
+                    self._plane_spec(stack, depth),
+                    is_min,
+                    mask,
+                    stack.matrix,
+                    *lw.operands,
+                )
 
         dev = self._collective(
             "minmax",
@@ -4088,9 +4226,11 @@ class MeshEngine:
         dev, canonical, depth, bsig = res
         # ValCount.smaller/larger reduce (executor.go:2652-2696), shared
         # with the fused/batched lanes (fusion.py decode helpers).
-        return fusion_mod.decode_min_max(
-            jax.device_get(dev), canonical, bsig.min, is_min
-        )
+        host = self._fetch(dev)
+        with tracing.stage("decode"):
+            return fusion_mod.decode_min_max(
+                host, canonical, bsig.min, is_min
+            )
 
     def topn_scores_async(
         self,
@@ -4178,7 +4318,7 @@ class MeshEngine:
         # device round-trip); np.array copy because
         # device-array views are read-only host buffers.  The kernel's
         # score matrix is rows-major [K, S]; callers consume [S, K].
-        scores, src_counts = jax.device_get((dev_scores, dev_counts))
+        scores, src_counts = self._fetch((dev_scores, dev_counts))
         scores = np.array(scores).T
         scores[:, ~present] = 0
         return scores, src_counts, pos
@@ -4386,7 +4526,7 @@ class MeshEngine:
                 *lw.operands,
             )
 
-        vals, idx, qual = jax.device_get(self._locked_dispatch(dispatch))
+        vals, idx, qual = self._fetch(self._locked_dispatch(dispatch))
         per_shard = []
         for s in shards:
             si = stack.pos.get(s)
@@ -4505,7 +4645,7 @@ class MeshEngine:
             return None
         cands, n_out, out = res
         return fusion_mod.decode_topn_full(
-            None if out is None else jax.device_get(out), cands, n_out
+            None if out is None else self._fetch(out), cands, n_out
         )
 
     def topn_cache_only(
@@ -4660,7 +4800,7 @@ class MeshEngine:
         dev = self.group_counts_async(index, fields, row_lists, filter_call, shards)
         if dev is None:
             return None
-        return np.asarray(dev)
+        return np.asarray(self._fetch(dev))
 
     # -- lifecycle / telemetry ----------------------------------------------
 

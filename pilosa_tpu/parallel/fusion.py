@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..util import heat as heat_mod
 from ..util import plans as plans_mod
+from ..util import tracing
 from . import kernels
 from .mesh import put_global
 
@@ -283,8 +284,8 @@ class FusedPlan:
         "index", "indexes", "fspec", "specs", "operands", "decoders",
         "weights", "item_notes", "errors", "sparse", "have_fused",
         "n_items", "fused_riders", "masks_evaluated", "masks_referenced",
-        "bytes_touched", "stack_tokens", "canonical", "cacheable",
-        "edge_kinds",
+        "planes_per_request", "planes_per_drain", "stack_tokens",
+        "canonical", "cacheable", "edge_kinds",
     )
 
 
@@ -302,9 +303,16 @@ def dispatch(engine, plan: FusedPlan) -> FusedDispatch:
         plans_mod.take_dispatch_note()
     if plan.have_fused:
         engine._note_fused_dispatch()
-        fused_out = kernels.fused_tree(
-            engine.mesh, plan.fspec, plan.specs, *plan.operands
+        # The drain record: the tier is the mask slots compiled for,
+        # live the items that ride the program.
+        drain = engine._note_drain(
+            "Fused", "fused_program", len(plan.fspec[0]), plan.fused_riders,
+            plan.planes_per_request, plan.planes_per_drain,
         )
+        with tracing.stage("dispatch", **drain):
+            fused_out = kernels.fused_tree(
+                engine.mesh, plan.fspec, plan.specs, *plan.operands
+            )
     else:
         fused_out = ()
     plans_mod.note_dispatch(
@@ -314,7 +322,7 @@ def dispatch(engine, plan: FusedPlan) -> FusedDispatch:
         masks_evaluated=plan.masks_evaluated,
         masks_referenced=plan.masks_referenced,
         masks_tier=len(plan.fspec[0]) if plan.have_fused else 0,
-        bytes_touched=plan.bytes_touched,
+        bytes_touched=plan.planes_per_drain[1] * engine.PLANE_BYTES,
         fused_indexes=len(plan.indexes),
     )
     # Counters record what actually rode a fused program: a drain whose
@@ -946,9 +954,19 @@ def build(engine, entries: List[tuple]) -> FusedPlan:
     )
     plan.masks_evaluated = masks_evaluated
     plan.masks_referenced = masks_referenced
-    plan.bytes_touched = sum(
-        int(getattr(op, "nbytes", 0)) for op in lw.operands
+    # The drain record's reckoning (engine._note_drain): the row-planes
+    # each item names, summed, and the distinct ones of the whole drain
+    # (lw.row_hints is the merge of the items' hints).
+    per_item = []
+    for idx, spec, _ in entries:
+        try:
+            per_item.append(engine._hint_planes(_item_hints(engine, idx, spec)))
+        except Exception:  # noqa: BLE001 — a malformed item rides as an error
+            pass
+    plan.planes_per_request = (
+        sum(p[0] for p in per_item), sum(p[1] for p in per_item)
     )
+    plan.planes_per_drain = engine._hint_planes(lw.row_hints)
     # Real (unpadded) per-kind edge census for the fused-program edge
     # counters (padding is a compile-key artifact, not traffic).
     plan.edge_kinds = {}

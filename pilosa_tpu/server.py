@@ -27,7 +27,6 @@ from .util import (
     NopLogger,
     NopStatsClient,
     NopTracer,
-    ProfilerTracer,
     StandardLogger,
     Tracer,
     VerboseLogger,
@@ -172,11 +171,12 @@ class Server:
 
     def _make_tracer(self):
         t = self.config.tracing_sampler_type
-        if t == "profiler":
-            return ProfilerTracer()
-        if t == "span":
+        if t in ("span", "profiler"):
             # The default: always-on span tracer with the recent + slow
-            # /debug/traces rings enabled out of the box.
+            # /debug/traces rings enabled out of the box.  "profiler"
+            # is accepted and means the same: every stage is a profiler
+            # annotation while POST /debug/pprof/trace holds a capture,
+            # whatever tracer collects the spans (util/tracing.stage).
             return Tracer()
         # "none" — and any unrecognized value: an operator's typo for
         # "none" must not silently enable span retention.

@@ -11,7 +11,7 @@ from .stats import (
     PipelineStats,
     StatsClient,
 )
-from .tracing import NopTracer, ProfilerTracer, Span, TraceContext, Tracer
+from .tracing import NopTracer, Span, TraceContext, Tracer
 
 __all__ = [
     "Event",
@@ -26,7 +26,6 @@ __all__ = [
     "NopStatsClient",
     "NopTracer",
     "PipelineStats",
-    "ProfilerTracer",
     "REGISTRY",
     "Span",
     "StandardLogger",

@@ -159,6 +159,7 @@ class QueryPlan:
         "duration",
         "ops",
         "_stage_events",
+        "_stage_trees",
         "fanouts",
         "annotations",
         "pipelined",
@@ -178,6 +179,9 @@ class QueryPlan:
         # (stage, seconds) events; "device" entries carry this query's
         # attributed share of a fused dispatch's device time.
         self._stage_events: List[tuple] = []
+        # Finished stage trees of the stage clock (util/tracing.py): one
+        # append a tree on the hot path, walked by ``stages()``.
+        self._stage_trees: list = []
         # (node_id, seconds, n_shards) per remote peer RPC.
         self.fanouts: List[tuple] = []
         self.annotations: List[str] = []
@@ -226,11 +230,23 @@ class QueryPlan:
         the separate "device" events, which DO sum — they are resource
         attribution, not wall time.)"""
         out: Dict[str, float] = {}
+
+        def put(stage, s):
+            prev = out.get(stage)
+            if prev is None or s > prev:
+                out[stage] = s
+
+        def walk(st):
+            put(st.name, st.observed)
+            for inner in st.inner or ():
+                if inner.shared:  # the others stamped their own riders
+                    walk(inner)
+
         for stage, s in self._stage_events:
             if stage != "device":
-                prev = out.get(stage)
-                if prev is None or s > prev:
-                    out[stage] = s
+                put(stage, s)
+        for st in list(self._stage_trees):
+            walk(st)
         return out
 
     def primary_op(self) -> str:

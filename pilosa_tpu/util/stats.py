@@ -369,6 +369,31 @@ REGISTRY = MetricsRegistry()
 METRIC_QUERY = "pilosa_query_seconds"
 METRIC_QUERY_OP = "pilosa_query_op_seconds"
 METRIC_PIPELINE_STAGE = "pilosa_pipeline_stage_seconds"
+# The stage clock (util/tracing.py stage/waited): one series per (path,
+# stage) from socket to socket; the four legacy deferred-path stages also
+# keep feeding METRIC_PIPELINE_STAGE with the values they always had.
+#   pilosa_query_stage_seconds{path,stage}  per-stage host time of a query
+#   pilosa_http_request_seconds             first byte in -> last byte out
+METRIC_QUERY_STAGE = "pilosa_query_stage_seconds"
+METRIC_HTTP_REQUEST = "pilosa_http_request_seconds"
+# One drain record per device dispatch (engine._note_drain):
+#   pilosa_engine_drains_total{op,path}                 programs dispatched
+#   pilosa_engine_drain_slots_total{op,path}            slots compiled for (tier)
+#   pilosa_engine_drain_requests_total{op,path}         requests answered (live)
+#   pilosa_engine_drain_plane_bytes_total{op,path,counted}
+#       counted="per_request": sum over the live requests of the distinct
+#       row-planes each names; "per_drain": the distinct row-planes of the
+#       whole drain (what a program reading each plane once would read);
+#       both x shards x 128 KiB
+#   pilosa_engine_device_inflight_seconds_total  union of [jitted call
+#       returned, its device_get returned] over all query dispatches
+#   pilosa_uptime_seconds                        monotonic since boot
+METRIC_ENGINE_DRAINS = "pilosa_engine_drains_total"
+METRIC_ENGINE_DRAIN_SLOTS = "pilosa_engine_drain_slots_total"
+METRIC_ENGINE_DRAIN_REQUESTS = "pilosa_engine_drain_requests_total"
+METRIC_ENGINE_DRAIN_PLANE_BYTES = "pilosa_engine_drain_plane_bytes_total"
+METRIC_ENGINE_DEVICE_INFLIGHT = "pilosa_engine_device_inflight_seconds_total"
+METRIC_UPTIME = "pilosa_uptime_seconds"
 METRIC_FRAGMENT_OP = "pilosa_fragment_op_seconds"
 #   pilosa_engine_cache_hits_total{cache=...}   engine cache hits
 #   pilosa_engine_cache_misses_total{cache=...} engine cache misses
@@ -712,6 +737,15 @@ for _stage in PIPELINE_STAGES:
         help="Batch-pipeline stage latency (seconds)",
         stage=_stage,
     )
+REGISTRY.histogram(
+    METRIC_HTTP_REQUEST,
+    help="Query request, first byte in to last byte out (seconds)",
+)
+REGISTRY.counter(
+    METRIC_ENGINE_DEVICE_INFLIGHT,
+    help="Seconds in which at least one query dispatch was in flight",
+)
+REGISTRY.set_gauge(METRIC_UPTIME, 0)
 REGISTRY.histogram(
     METRIC_FRAGMENT_OP, help="Fragment-level op latency (seconds)", op="row"
 )
@@ -1310,45 +1344,17 @@ class ExpvarStatsClient(StatsClient):
 
 
 class PipelineStats:
-    """Per-stage telemetry for the pipelined query path
-    (parallel/batcher.py): stage timings (queue wait, lower+dispatch,
-    device+readback, decode), the live/high-water in-flight batch depth,
-    and batch-occupancy counters.  Thread-safe; ``snapshot()`` is what
-    bench.py and /debug/vars surface so the pipeline's fill rate is
-    measurable, not inferred."""
+    """Depth gauges and batch-occupancy counters of the pipelined query
+    path (parallel/batcher.py).  The stage timings themselves live in
+    the registry (``pilosa_pipeline_stage_seconds``, fed by the stage
+    clock in util/tracing.py — one observe a record); ``snapshot()``
+    reads count, mean and quantiles from those series, so its
+    ``stages`` are the process's, not one batcher's.  Thread-safe."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        # stage -> [count, total_seconds, max_seconds]
-        self._stages: Dict[str, list] = {}
         self._gauges: Dict[str, float] = {}
         self._counters: Dict[str, int] = {}
-        # stage -> per-instance Histogram (quantiles in snapshot());
-        # observations also land in the process REGISTRY for /metrics.
-        # Registry handles are cached per stage: resolving through
-        # REGISTRY.observe would take the process-global registry lock
-        # on every record() — a contention point on the per-item
-        # queue_wait path.
-        self._hists: Dict[str, Histogram] = {}
-        self._reg_hists: Dict[str, Histogram] = {}
-
-    def record(self, stage: str, seconds: float, n: int = 1,
-               exemplar: Optional[str] = None):
-        with self._lock:
-            s = self._stages.setdefault(stage, [0, 0.0, 0.0])
-            s[0] += n
-            s[1] += seconds
-            s[2] = max(s[2], seconds)
-            h = self._hists.get(stage)
-            if h is None:
-                h = self._hists[stage] = Histogram()
-            rh = self._reg_hists.get(stage)
-            if rh is None:
-                rh = self._reg_hists[stage] = REGISTRY.histogram(
-                    METRIC_PIPELINE_STAGE, stage=stage
-                )
-        h.observe(seconds)
-        rh.observe(seconds, exemplar=exemplar)
 
     def gauge(self, name: str, value: float):
         with self._lock:
@@ -1377,24 +1383,21 @@ class PipelineStats:
 
     def snapshot(self) -> Dict[str, dict]:
         with self._lock:
-            stages = {
-                k: {
-                    "count": c,
-                    "totalSeconds": round(t, 6),
-                    "meanSeconds": round(t / c, 6) if c else 0.0,
-                    "maxSeconds": round(m, 6),
-                }
-                for k, (c, t, m) in self._stages.items()
-            }
-            hists = dict(self._hists)
             gauges = dict(self._gauges)
             counters = dict(self._counters)
-        for k, h in hists.items():
-            if k in stages:
-                snap = h.snapshot()
-                stages[k]["p50Seconds"] = snap["p50"]
-                stages[k]["p95Seconds"] = snap["p95"]
-                stages[k]["p99Seconds"] = snap["p99"]
+        stages = {}
+        for stage in PIPELINE_STAGES:
+            h = REGISTRY.get_histogram(METRIC_PIPELINE_STAGE, stage=stage)
+            snap = h.snapshot() if h is not None else None
+            if snap and snap["count"]:
+                stages[stage] = {
+                    "count": snap["count"],
+                    "totalSeconds": snap["sumSeconds"],
+                    "meanSeconds": snap["meanSeconds"],
+                    "p50Seconds": snap["p50"],
+                    "p95Seconds": snap["p95"],
+                    "p99Seconds": snap["p99"],
+                }
         return {
             "stages": stages,
             "gauges": gauges,

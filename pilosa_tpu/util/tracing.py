@@ -15,9 +15,12 @@ Cross-node: ``inject_headers``/``extract_headers`` carry the context as
 uber-trace-id the same way, tracing/opentracing/opentracing.go), so a
 remote shard fan-out joins the initiator's trace.
 
-The ProfilerTracer additionally brackets spans with
-``jax.profiler.TraceAnnotation`` so spans land in XPlane traces — the TPU
-equivalent of the reference's Jaeger adapter.
+The stage clock (``stage``/``waited``, below the tracers) is the one
+recorder of a query's host time from socket to socket: a histogram
+series per (path, stage), a child span on each rider, a stage stamp on
+each rider's plan and — only while a profiler capture runs
+(``capturing``) — a live ``jax.profiler.TraceAnnotation`` on the device
+trace's clock, the TPU equivalent of the reference's Jaeger adapter.
 """
 
 from __future__ import annotations
@@ -29,6 +32,16 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+from . import plans as plans_mod
+from .stats import (
+    METRIC_ENGINE_DEVICE_INFLIGHT,
+    METRIC_HTTP_REQUEST,
+    METRIC_PIPELINE_STAGE,
+    METRIC_QUERY_STAGE,
+    PIPELINE_STAGES,
+    REGISTRY,
+)
 
 # Module-level current-span slot: shared by every Tracer so code that
 # only has *a* span (a batcher worker, an internal HTTP client) can
@@ -73,8 +86,8 @@ def attach(span: Optional["Span"]):
 def inject_headers(headers: Dict[str, str]):
     """Stamp the calling thread's current span into outbound request
     headers (X-Trace-Id/X-Span-Id/X-Trace-Name) — the single wire-
-    propagation implementation (Tracer.inject_headers delegates here,
-    and the internal HTTP client calls it without a tracer)."""
+    propagation implementation (the internal HTTP client calls it
+    without a tracer)."""
     cur = getattr(_LOCAL, "current", None)
     if cur is not None:
         headers["X-Trace-Id"] = cur.trace_id
@@ -103,7 +116,8 @@ class Span:
         "start",
         "start_wall",
         "duration",
-        "children",
+        "_children",
+        "_staged",
         "parent",
         "trace_id",
         "span_id",
@@ -118,7 +132,11 @@ class Span:
         self.start = time.monotonic()
         self.start_wall = time.time()
         self.duration = None
-        self.children: List["Span"] = []
+        self._children: List["Span"] = []
+        # Finished stage trees (the stage clock's) not yet turned into
+        # child spans: a stage costs the query one list append here,
+        # and its spans are made when somebody looks (``children``).
+        self._staged: list = []
         self.span_id = new_id()
         self._tracer = tracer
         if isinstance(parent, Span):
@@ -139,6 +157,18 @@ class Span:
             self.trace_id = new_id()
             self.parent_span_id = ""
 
+    @property
+    def children(self) -> List["Span"]:
+        """The child spans, in the order they were attached; staged
+        stage trees become ``pipeline.<stage>`` children first."""
+        staged = self._staged
+        while staged:
+            try:
+                staged.pop(0)._to_span(self)
+            except IndexError:  # another reader took it
+                break
+        return self._children
+
     def context(self) -> TraceContext:
         return TraceContext(self.trace_id, self.span_id)
 
@@ -149,7 +179,7 @@ class Span:
         """Start a child span attached to this span (explicit-parent
         form for worker threads; finish() it when done)."""
         span = Span(name, tags, self)
-        self.children.append(span)
+        self._children.append(span)
         return span
 
     def record(self, name: str, start: Optional[float] = None,
@@ -158,14 +188,25 @@ class Span:
         time.monotonic timestamp (defaults to now - duration).  This is
         how the pipeline stamps per-stage timings onto a query's tree
         without holding a span open across worker threads."""
-        span = Span(name, tags, self)
-        self.children.append(span)
         if start is None:
             start = time.monotonic() - duration
-        delta = span.start - start
+        # No clock is read: the child's wall time follows from this
+        # span's two clocks (the stage recorder stamps several children
+        # per query from here).
+        span = Span.__new__(Span)
+        span.name = name
+        span.tags = tags
         span.start = start
-        span.start_wall -= delta
+        span.start_wall = self.start_wall + (start - self.start)
         span.duration = duration
+        span._children = []
+        span._staged = []
+        span.parent = self
+        span.trace_id = self.trace_id
+        span.span_id = new_id()
+        span.parent_span_id = self.span_id
+        span._tracer = self._tracer
+        self._children.append(span)
         return span
 
     def finish(self):
@@ -182,7 +223,9 @@ class Span:
             "tags": self.tags,
             "startTime": self.start_wall,
             "durationMs": None if self.duration is None else self.duration * 1e3,
-            "children": [c.to_dict() for c in self.children],
+            "children": [
+                c.to_dict() for c in sorted(self.children, key=lambda c: c.start)
+            ],
         }
 
 
@@ -218,7 +261,7 @@ class Tracer:
             parent = getattr(_LOCAL, "current", None)
         span = Span(name, tags, parent, tracer=self)
         if isinstance(parent, Span):
-            parent.children.append(span)
+            parent._children.append(span)
         prev = getattr(_LOCAL, "current", None)
         _LOCAL.current = span
         try:
@@ -236,7 +279,7 @@ class Tracer:
             parent = getattr(_LOCAL, "current", None)
         span = Span(name, tags, parent, tracer=self)
         if isinstance(parent, Span):
-            parent.children.append(span)
+            parent._children.append(span)
         return span
 
     def _record_finished(self, span: Span):
@@ -267,10 +310,7 @@ class Tracer:
         }
 
     # HTTP header propagation for cross-node traces
-    # (tracing/tracing.go:18-28).
-    def inject_headers(self, headers: Dict[str, str]):
-        inject_headers(headers)
-
+    # (tracing/tracing.go:18-28); the inject half is module-level.
     def extract_headers(self, headers: Dict[str, str]) -> Optional[TraceContext]:
         """TraceContext from incoming request headers, or None.  Header
         dicts may arrive with original casing; check both forms."""
@@ -289,47 +329,353 @@ class NopTracer(Tracer):
     def begin(self, name: str, parent=None, **tags):
         return None
 
-    def inject_headers(self, headers: Dict[str, str]):
+
+# -- the stage clock ---------------------------------------------------------
+
+# True while POST /debug/pprof/trace holds a profiler capture (the route
+# sets and clears it).  Off, a stage costs this one attribute test more
+# than its bookkeeping; on, every stage brackets its block with a live
+# TraceAnnotation so the host's work lands on the device trace's clock.
+capturing = False
+
+# (path, stage) -> Histogram and stage -> legacy Histogram: handles
+# resolved once (GIL-atomic dict reads), so a record pays the series'
+# own lock and never the registry's.
+_STAGE_HISTS: Dict[tuple, object] = {}
+_LEGACY_HISTS: Dict[str, object] = {}
+_HTTP_HIST = REGISTRY.histogram(METRIC_HTTP_REQUEST)
+
+
+def _stage_hist(path: str, name: str):
+    h = _STAGE_HISTS.get((path, name))
+    if h is None:
+        h = _STAGE_HISTS[(path, name)] = REGISTRY.histogram(
+            METRIC_QUERY_STAGE,
+            help="Per-stage host time of a query, socket to socket (seconds)",
+            path=path, stage=name,
+        )
+    return h
+
+
+def _annotation(name: str, path: Optional[str], tags: dict):
+    """A live TraceMe for the calling thread (a TraceMe cannot be
+    written after the fact, which is why ``stage`` wraps the work)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation("pilosa." + name, path=path or "", **tags)
+
+
+def name_thread(name: Optional[str] = None):
+    """Give the calling OS thread a name (the Python thread's, cut to
+    the kernel's 15 characters): the profiler names a host line after
+    its OS thread, and CPython before 3.14 leaves every thread it
+    starts with the process's (``python``).  Linux only; elsewhere a
+    no-op."""
+    try:
+        import ctypes
+
+        name = name or threading.current_thread().name
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)  # PR_SET_NAME
+    except Exception:  # noqa: BLE001 — a nameless line is only less readable
         pass
 
 
-class ProfilerTracer(Tracer):
-    """Tracer that also emits jax.profiler trace annotations, so spans are
-    visible in XPlane/TensorBoard device traces.  The profiler module is
-    resolved ONCE at construction (the old per-span import was a dict
-    lookup plus import machinery on every hot-path span); when jax or
-    its profiler is unavailable the tracer degrades to plain spans with
-    a one-time warning."""
+class Ambient:
+    """The calling thread's own span and plan as a stage rider: what a
+    blocking (direct or host) call rides on."""
 
-    _warned = False
+    __slots__ = ("span", "plan")
 
-    def __init__(self, *args, **kwargs):
-        super().__init__(*args, **kwargs)
-        try:
-            import jax.profiler as _profiler
+    def __init__(self):
+        self.span = getattr(_LOCAL, "current", None)
+        self.plan = plans_mod.current_plan()
 
-            self._profiler = _profiler
-        except Exception:  # noqa: BLE001 — missing/broken jax: degrade
-            self._profiler = None
-            self._warn_once()
 
-    @classmethod
-    def _warn_once(cls):
-        if not cls._warned:
-            cls._warned = True
-            import sys
+class stage:
+    """``with tracing.stage(name, path, riders, **tags):`` around one
+    stage of a query, on the thread that does the work.  On exit it
+    observes ``pilosa_query_stage_seconds{path,stage}`` (and, for the
+    four legacy stages of the deferred path,
+    ``pilosa_pipeline_stage_seconds{stage}``), records a
+    ``pipeline.<name>`` child on each rider's span and stamps each
+    distinct plan once; while a capture runs the block is also a
+    ``pilosa.<name>`` TraceAnnotation carrying ``path`` and ``tags``.
 
-            sys.stderr.write(
-                "pilosa-tpu: jax.profiler unavailable; ProfilerTracer "
-                "degrading to plain spans\n"
-            )
+    ``riders`` are objects with ``.span`` and ``.plan`` (the batcher's
+    items).  Left out, a stage nested in another on the same thread
+    rides with the outer's riders and path and becomes a child of the
+    outer stage's span; with no outer it rides the thread's own span
+    and plan (``Ambient``), and a path left open is the path of the
+    stages inside it, else the one already stamped on the request's
+    root span, else ``host``.  ``self_time=True`` observes the block
+    minus the stages (and ``hole``s) inside it and leaves no span of
+    its own — the executor's ``plan`` stage, whose time in the tree is
+    the executor spans' own — so the stages inside it ride the thread's
+    span again.  A block left by an exception records nothing.  A
+    slotted class, not a @contextmanager: this sits on the per-query
+    hot path (cf. plans.attach)."""
 
-    @contextmanager
-    def start_span(self, name: str, parent=None, **tags):
-        if self._profiler is None:
-            with super().start_span(name, parent=parent, **tags) as span:
-                yield span
+    __slots__ = ("name", "path", "riders", "tags", "t0", "t1", "inner",
+                 "hole_s", "self_time", "shared", "observed", "_outer",
+                 "_ann")
+
+    def __init__(self, name: str, path: Optional[str] = None, riders=None,
+                 t0: Optional[float] = None, self_time: bool = False, **tags):
+        self.name = name
+        self.path = path
+        self.riders = riders
+        self.tags = tags
+        self.t0 = t0
+        self.t1 = None
+        self.inner: Optional[list] = None
+        self.hole_s = 0.0
+        self.self_time = self_time
+        self.shared = False  # rides (and nests) with the enclosing stage
+        self._ann = None
+
+    def _adopt(self, outer: Optional["stage"]):
+        """Path and riders left open come from the enclosing stage."""
+        if outer is not None and self.path is None:
+            self.path = outer.path
+        if self.riders is None:
+            if outer is not None and not outer.self_time:
+                self.riders = outer.riders
+            else:
+                self.riders = (Ambient(),)
+
+    def __enter__(self):
+        outer = self._outer = getattr(_LOCAL, "stage", None)
+        self._adopt(outer)
+        _LOCAL.stage = self
+        if capturing:
+            self._ann = _annotation(self.name, self.path, self.tags)
+            self._ann.__enter__()
+        if self.t0 is None:
+            self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _LOCAL.stage = self._outer
+        if exc_type is None:
+            self._done()
+        return False
+
+    def _done(self):
+        """Record now, or with the enclosing stage when that ends."""
+        outer = self._outer
+        if outer is None:
+            _emit(self, self.path or _open_path(self))
             return
-        with self._profiler.TraceAnnotation(name):
-            with super().start_span(name, parent=parent, **tags) as span:
-                yield span
+        self.shared = self.riders is outer.riders
+        if outer.inner is None:
+            outer.inner = [self]
+        else:
+            outer.inner.append(self)
+
+    def _to_span(self, parent: "Span"):
+        """This finished stage as a ``pipeline.<name>`` child of
+        ``parent``, the stages that rode with it as its children."""
+        span = parent.record(
+            "pipeline." + self.name, start=self.t0,
+            duration=self.t1 - self.t0, **self.tags
+        )
+        for inner in self.inner or ():
+            if inner.shared:
+                inner._to_span(span)
+
+
+def waited(name: str, path: Optional[str], t0: float, t1: float, riders):
+    """The timestamp form of ``stage`` for a wait no thread performs
+    (``queue_wait``, ``collect_wait``): records [t0, t1] as if a stage
+    had wrapped it, under the calling thread's enclosing stage if there
+    is one.  No annotation: a gap's owner is whatever span a host
+    thread was inside meanwhile."""
+    st = stage(name, path, riders, t0=t0)
+    st.t1 = t1
+    st._outer = getattr(_LOCAL, "stage", None)
+    st._adopt(st._outer)
+    st._done()
+
+
+def hole(t0: float, t1: float):
+    """[t0, t1] of the enclosing stage belongs to stages recorded on
+    other threads (a blocking wait on the pipeline): a ``self_time``
+    stage leaves it out."""
+    outer = getattr(_LOCAL, "stage", None)
+    if outer is not None:
+        outer.hole_s += t1 - t0
+
+
+def _root(span: "Span") -> "Span":
+    while span.parent is not None:
+        span = span.parent
+    return span
+
+
+def _open_path(st: "stage") -> str:
+    """The path of a stage that was opened without one (the front end
+    and the executor do not know which lane a call will take): the
+    last nested stage's, else the one stamped on the request's root
+    span by a stage recorded on another thread, else ``host``."""
+    for inner in reversed(st.inner or ()):
+        if inner.path is not None:
+            return inner.path
+    for r in st.riders:
+        if r.span is not None:
+            return _root(r.span).tags.get("path", "host")
+    return "host"
+
+
+def _emit(st: "stage", path: str, exemplar: Optional[str] = None):
+    """Record one finished stage tree: its histograms now, and one
+    append on each rider's span and on each distinct plan — the child
+    spans and the plan's ``stagesMs`` are made from the tree when
+    somebody looks (``Span.children``, ``QueryPlan.stages``)."""
+    explicit = st.path is not None
+    riders = st.riders
+    if exemplar is None:
+        for r in riders:
+            if r.span is not None:
+                exemplar = r.span.trace_id
+                break
+    path = _observe(st, path, exemplar)
+    seen_plans = None
+    for r in riders:
+        span = r.span
+        if span is not None and not st.self_time:
+            if explicit:
+                _root(span).tags["path"] = path
+            span._staged.append(st)
+        plan = r.plan
+        if plan is not None:
+            if seen_plans is None:
+                seen_plans = {id(plan)}
+            elif id(plan) in seen_plans:
+                continue
+            else:
+                seen_plans.add(id(plan))
+            plan._stage_trees.append(st)
+    st.riders = None  # the tree outlives the query in the rings: drop the items
+
+
+def _observe(st: "stage", path: str, exemplar: Optional[str]) -> str:
+    """Observe the histograms of ``st`` and of the stages nested in it
+    (those with riders of their own are recorded on their own)."""
+    path = st.path = st.path or path
+    observed = st.t1 - st.t0
+    inner = st.inner
+    if st.self_time:
+        observed -= st.hole_s
+        for i in inner or ():
+            observed -= i.t1 - i.t0
+        if observed < 0.0:
+            observed = 0.0
+    st.observed = observed
+    name = st.name
+    h = _STAGE_HISTS.get((path, name))
+    if h is None:
+        h = _stage_hist(path, name)
+    h.observe(observed)
+    if path == "deferred" and name in PIPELINE_STAGES:
+        h = _LEGACY_HISTS.get(name)
+        if h is None:
+            h = _LEGACY_HISTS[name] = REGISTRY.histogram(
+                METRIC_PIPELINE_STAGE, stage=name
+            )
+        h.observe(observed, exemplar=exemplar)
+    if inner:
+        for i in inner:
+            if i.shared:
+                _observe(i, path, exemplar)
+                i.riders = None
+            else:
+                _emit(i, path, exemplar if not st.self_time else None)
+    return path
+
+
+class RequestClock:
+    """One query request on the HTTP layer's clock: made at the first
+    byte, handed to the handler (``headers[CLOCK]``) and the API
+    (``QueryRequest.clock``), finished when the last byte of the reply
+    has been handed to the socket.  ``finish`` observes
+    ``pilosa_http_request_seconds`` and the two stages only the HTTP
+    layer sees — ``http_read`` (first byte -> handler entered) and
+    ``respond`` (result -> last byte) — under the path the request's
+    root span was stamped with."""
+
+    __slots__ = ("t0", "t_handler", "t_result", "span")
+
+    def __init__(self, t0: float):
+        self.t0 = t0
+        self.t_handler = None
+        self.t_result = None
+        self.span = None
+
+    def handler_entered(self):
+        # The last entry counts: a request the reactor's deferred
+        # attempt declines enters the handler again on a pool thread.
+        self.t_handler = time.monotonic()
+
+    def result_ready(self):
+        self.t_result = time.monotonic()
+
+    def finish(self):
+        now = time.monotonic()
+        _HTTP_HIST.observe(now - self.t0)
+        if self.t_handler is None:
+            return
+        span = self.span
+        path = span.tags.get("path", "host") if span is not None else "host"
+        _stage_hist(path, "http_read").observe(self.t_handler - self.t0)
+        if self.t_result is not None:
+            _stage_hist(path, "respond").observe(now - self.t_result)
+        if span is not None:
+            span.tags["http_read_ms"] = round((self.t_handler - self.t0) * 1e3, 3)
+            span.tags["http_ms"] = round((now - self.t0) * 1e3, 3)
+
+
+# The key under which the HTTP servers hand a request's clock to the
+# handler in its headers dict: no header name holds a colon.
+CLOCK = ":clock"
+
+
+class Inflight:
+    """Seconds in which the host had given the device anything at all:
+    the union, over all query dispatches, of [jitted call returned, its
+    device_get returned].  A depth counter and the time the depth left
+    zero; the union's closed part goes to
+    ``pilosa_engine_device_inflight_seconds_total`` whenever the depth
+    returns to zero (and at scrape time, ``flush``)."""
+
+    def __init__(self, counter=None):
+        self._lock = threading.Lock()
+        self._depth = 0
+        self._since = 0.0
+        self._counter = counter or REGISTRY.counter(METRIC_ENGINE_DEVICE_INFLIGHT)
+
+    def begin(self, now: Optional[float] = None):
+        with self._lock:
+            if self._depth == 0:
+                self._since = time.monotonic() if now is None else now
+            self._depth += 1
+
+    def end(self, now: Optional[float] = None):
+        with self._lock:
+            self._depth -= 1
+            if self._depth == 0:
+                now = time.monotonic() if now is None else now
+                self._counter.inc(max(0.0, now - self._since))
+
+    def flush(self):
+        """Count the open interval up to now (a scrape mid-drain)."""
+        with self._lock:
+            if self._depth > 0:
+                now = time.monotonic()
+                self._counter.inc(max(0.0, now - self._since))
+                self._since = now
+
+
+INFLIGHT = Inflight()
