@@ -57,6 +57,7 @@ from ..util.stats import (
     METRIC_ENGINE_COMPILE,
     METRIC_ENGINE_COMPILE_KEYS,
     METRIC_ENGINE_COMPILE_SECONDS,
+    METRIC_ENGINE_DRAIN_EVALUATED,
     METRIC_ENGINE_DRAIN_PLANE_BYTES,
     METRIC_ENGINE_DRAIN_REQUESTS,
     METRIC_ENGINE_DRAIN_SLOTS,
@@ -1037,16 +1038,21 @@ class MeshEngine:
     def _note_drain(self, op: str, path: str, tier: int, live: int,
                     per_request: Tuple[int, int],
                     per_drain: Tuple[int, int],
-                    note_bytes: bool = True) -> dict:
+                    note_bytes: bool = True,
+                    evaluated: Optional[int] = None) -> dict:
         """The drain record of ONE device dispatch: which program
         (``op``, the dispatch note's ``path``), the slots it was
-        compiled for (``tier``), the requests it answers (``live``),
-        and the row-planes named — summed over the live requests, and
-        distinct over the whole drain (what a program that read each
-        plane once would read).  Counts the four
+        compiled for (``tier``), the slots the device runs
+        (``evaluated``: the tier, unless the program skips its unused
+        slots as the batched Count program does), the requests it
+        answers (``live``), and the row-planes named — summed over the
+        live requests, and distinct over the whole drain (what a
+        program that read each plane once would read).  Counts the five
         ``pilosa_engine_drain*`` series, publishes the plan's
         ``bytes_touched`` (the drain's distinct planes, not whole
         operands) and returns the tags of the ``dispatch`` stage."""
+        if evaluated is None:
+            evaluated = tier
         handles = self._drain_counters.get((op, path))
         if handles is None:
             handles = self._drain_counters[(op, path)] = (
@@ -1063,18 +1069,23 @@ class MeshEngine:
                     METRIC_ENGINE_DRAIN_PLANE_BYTES,
                     op=op, path=path, counted="per_drain",
                 ),
+                REGISTRY.counter(
+                    METRIC_ENGINE_DRAIN_EVALUATED, op=op, path=path
+                ),
             )
         handles[0].inc()
         handles[1].inc(tier)
         handles[2].inc(live)
         handles[3].inc(per_request[1] * self.PLANE_BYTES)
         handles[4].inc(per_drain[1] * self.PLANE_BYTES)
+        handles[5].inc(evaluated)
         if note_bytes:
             plans_mod.note_dispatch(
                 bytes_touched=per_drain[1] * self.PLANE_BYTES
             )
         return {
             "op": op, "drain": path, "tier": tier, "live": live,
+            "evaluated": evaluated,
             "planes_per_request": per_request[0],
             "planes_per_drain": per_drain[0],
         }
@@ -3862,8 +3873,9 @@ class MeshEngine:
 
     # Fixed batch-program tiers: the compile key is (query structure,
     # tier), NOT the raw batch size — a drain of 17 and a drain of 23
-    # run the SAME 64-slot executable.  Three executables per structure
-    # family total, each warmable ahead of load.
+    # run the SAME 64-slot executable, each for its own 17 or 23 slots'
+    # worth of device time (the live count is an operand).  One
+    # executable per structure and tier, each warmable ahead of load.
     BATCH_TIERS = (8, 64, 256, 512)
 
     def _dispatch_count_batch(self, index, calls, shards_list, canonical):
@@ -3935,13 +3947,12 @@ class MeshEngine:
             # Pad to the tier by RE-LOWERING query 0: padding entries
             # then occupy their own deterministic slots, so the padded
             # program is byte-identical for every batch of the same
-            # structure + tier.  The dead slots are NOT free on the
-            # chip: XLA does not CSE the duplicate trees away (the row
-            # ids are data), each slot's fusion reads its planes again,
-            # and a tier-64 run costs 64 slots whatever its live count
-            # (on a v5e 61.5 ms for 16 live requests that asked for
-            # 15.4: PERF.md section 5; engine.tier_fill = requests /
-            # slots is the metric).
+            # structure + tier.  The tier is the program's CAPACITY, not
+            # what the chip pays: the program takes the live count K as
+            # a traced operand and skips every slot at or beyond it
+            # (kernels.count_batch_tree), so the pad slots are lowered
+            # here, for their static positions, and never run.  (XLA
+            # cannot drop them itself: their row ids are data.)
             # Repeating the LAST pair instead (round 4) kept the raw K
             # in the operand indexing and compiled a fresh program per
             # distinct drain size — ~2 s each, the entire QPS shortfall.
@@ -3971,10 +3982,12 @@ class MeshEngine:
                 sum(u_planes[j][1] for j in mapping),
             ),
             self._hint_planes(lw.row_hints),
+            evaluated=K,
         )
         with tracing.stage("dispatch", **drain):
             dev = kernels.count_batch_tree(
-                self.mesh, tuple(progs), tuple(lw.specs), *lw.operands
+                self.mesh, tuple(progs), tuple(lw.specs), self._scalar(K),
+                *lw.operands
             )
         if deduped:
             # Fan the U unique answers back out to the K callers (a

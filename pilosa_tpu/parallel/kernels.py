@@ -249,32 +249,50 @@ def count_tree(mesh, prog, specs, mask, *operands):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def count_batch_tree(mesh, progs, specs, *operands):
+def count_batch_tree(mesh, progs, specs, n_live, *operands):
     """K Count(tree) queries in ONE dispatch: each program evaluates +
     popcounts over the shared operand list (field stacks appear once no
-    matter how many queries touch them; XLA CSEs identical subtrees) and
-    a single psum reduces the stacked int32[K] — K answers for one
-    dispatch-floor cost + one readback.  This is the serving-tier answer
-    to the JAX per-program dispatch floor (~100-400 us): small queries
-    batch K-for-one instead of paying it each (BASELINE config #2).
+    matter how many queries touch them) and a single psum reduces the
+    stacked int32[K] — K answers for one dispatch-floor cost + one
+    readback.  This is the serving-tier answer to the JAX per-program
+    dispatch floor (~100-400 us): small queries batch K-for-one instead
+    of paying it each (BASELINE config #2).
 
     ``progs`` is a static tuple of (prog, i_mask) pairs — i_mask the
     operand index of that query's requested-shard mask (uint32[S, 1]).
-    The engine pads batches to FIXED TIERS by re-lowering query 0 into
-    fresh slots (engine.BATCH_TIERS), so the compile key depends only
-    on (structure, tier) — never on the raw drain size (XLA CSEs the
-    duplicated pad entries)."""
+    Its length is the batch TIER (engine.BATCH_TIERS): the program's
+    capacity and, with the structure, its whole compile key.  ``n_live``
+    is a traced replicated int32 scalar: slot j runs only if j < n_live
+    and answers 0 otherwise, so device time follows the drain's live
+    count, never the tier.  The branch is real control flow on purpose:
+    the engine fills the slots past n_live by re-lowering query 0, row
+    ids are slot-vector DATA, and XLA cannot CSE those copies away —
+    unconditioned, a tier-64 run read 64 slots' planes for 16 requests
+    (61.5 ms against 15.4 on a v5e, PERF.md section 6, PR 27).
+    n_live is the same on every device, so the psum stays outside the
+    branches and uniform."""
 
-    def body(*ops):
+    def body(n, *ops):
+        def count(prog, i_mask):
+            row = jnp.bitwise_and(apply_prog(prog, ops), ops[i_mask])
+            return jnp.sum(_pc(row))
+
+        def skipped():
+            # Per-device like the count it stands in for (the branches
+            # of a cond must agree on what varies over the mesh).
+            return jax.lax.pcast(jnp.int32(0), (SHARD_AXIS,), to="varying")
+
         outs = [
-            jnp.sum(_pc(jnp.bitwise_and(apply_prog(prog, ops), ops[i_mask])))
-            for prog, i_mask in progs
+            jax.lax.cond(
+                j < n, functools.partial(count, prog, i_mask), skipped
+            )
+            for j, (prog, i_mask) in enumerate(progs)
         ]
         return jax.lax.psum(jnp.stack(outs), SHARD_AXIS)
 
     return shard_map(
-        body, mesh=mesh, in_specs=specs, out_specs=P()
-    )(*operands)
+        body, mesh=mesh, in_specs=(P(),) + specs, out_specs=P()
+    )(n_live, *operands)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))

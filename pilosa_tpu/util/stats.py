@@ -380,6 +380,9 @@ METRIC_HTTP_REQUEST = "pilosa_http_request_seconds"
 #   pilosa_engine_drains_total{op,path}                 programs dispatched
 #   pilosa_engine_drain_slots_total{op,path}            slots compiled for (tier)
 #   pilosa_engine_drain_requests_total{op,path}         requests answered (live)
+#   pilosa_engine_drain_evaluated_slots_total{op,path}  slots the device runs:
+#       the drain's unique entries on the batched Count program (slots past
+#       them are skipped on the device), the slots compiled for elsewhere
 #   pilosa_engine_drain_plane_bytes_total{op,path,counted}
 #       counted="per_request": sum over the live requests of the distinct
 #       row-planes each names; "per_drain": the distinct row-planes of the
@@ -391,6 +394,7 @@ METRIC_HTTP_REQUEST = "pilosa_http_request_seconds"
 METRIC_ENGINE_DRAINS = "pilosa_engine_drains_total"
 METRIC_ENGINE_DRAIN_SLOTS = "pilosa_engine_drain_slots_total"
 METRIC_ENGINE_DRAIN_REQUESTS = "pilosa_engine_drain_requests_total"
+METRIC_ENGINE_DRAIN_EVALUATED = "pilosa_engine_drain_evaluated_slots_total"
 METRIC_ENGINE_DRAIN_PLANE_BYTES = "pilosa_engine_drain_plane_bytes_total"
 METRIC_ENGINE_DEVICE_INFLIGHT = "pilosa_engine_device_inflight_seconds_total"
 METRIC_UPTIME = "pilosa_uptime_seconds"

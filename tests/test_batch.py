@@ -393,6 +393,92 @@ def test_singleflight_collapses_identical_aggregates(holder, mesh):
     assert (s2.val, s2.count) == (s1.val + 9, s1.count + 1)
 
 
+def _subset_entries(k, text="Union(Row(f=10), Row(f=11))"):
+    """k distinct drain entries of ONE structure: the same Count over k
+    different non-empty subsets of the 8 shards (an entry is keyed by
+    text and shard set), so every slot's answer is its own."""
+    return [(_call(text), [s for s in range(8) if (i + 1) >> s & 1])
+            for i in range(k)]
+
+
+def _captured_batch_program(eng, entries):
+    """(arguments of the one kernels.count_batch_tree call, its answers)
+    for a count_many of ``entries``."""
+    from pilosa_tpu.parallel import kernels as k_mod
+
+    seen = []
+    orig = k_mod.count_batch_tree
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(
+            k_mod, "count_batch_tree",
+            lambda *args: seen.append(args) or orig(*args),
+        )
+        out = eng.count_many(
+            "i", [c for c, _ in entries], [s for _, s in entries]
+        )
+    (args,) = seen
+    return args, out
+
+
+@pytest.mark.parametrize(
+    "tier,n_live",
+    [(64, 2), (64, 9), (64, 16), (64, 63), (64, 64), (8, 2), (8, 8)],
+)
+def test_count_batch_tree_runs_only_live_slots(holder, mesh, tier, n_live):
+    """The tier is the program's capacity; the traced live count is what
+    it runs.  A full drain's program, handed a smaller n_live, answers 0
+    in every slot at or beyond it — whatever rows those slots name —
+    and leaves the slots below it as they were."""
+    from pilosa_tpu.parallel import kernels as k_mod
+
+    eng = MeshEngine(holder, mesh)
+    args, full = _captured_batch_program(eng, _subset_entries(tier))
+    kmesh, progs, specs, n, *operands = args
+    assert len(progs) == tier and int(n) == tier
+    assert all(full), "every slot of the full drain has rows to count"
+    got = np.asarray(k_mod.count_batch_tree(
+        kmesh, progs, specs, eng._scalar(n_live), *operands))
+    assert got[:n_live].tolist() == full[:n_live]
+    assert not got[n_live:].any()
+
+
+@pytest.mark.parametrize("n_live", [2, 7, 8, 9, 16, 63, 64])
+def test_count_many_matches_scalar_count_at_live_counts(holder, mesh, n_live):
+    """n_live unique entries — present rows, a missing row, per-entry
+    shard subsets — plus duplicates that fan back out through the CSE
+    mapping: every caller gets the scalar count's answer, and the drain
+    took the tier it should."""
+    from pilosa_tpu.util import plans
+
+    eng = MeshEngine(holder, mesh)
+    uniq = _subset_entries(n_live - 1) + [
+        (_call("Union(Row(f=10), Row(f=999))"), list(range(8)))]
+    entries = uniq + [uniq[0], uniq[-1], uniq[n_live // 2]]
+    order = np.random.default_rng(n_live).permutation(len(entries))
+    entries = [entries[i] for i in order]
+    want = [eng.count("i", c, s) for c, s in entries]
+    plans.take_dispatch_note()
+    got = eng.count_many("i", [c for c, _ in entries], [s for _, s in entries])
+    note = plans.take_dispatch_note()
+    assert got == want
+    assert (note["tier"], note["cse_unique"], note["cse_deduped"]) == (
+        8 if n_live <= 8 else 64, n_live, 3)
+
+
+def test_batch_program_keeps_dynamic_control_flow(holder, mesh):
+    """The tier-64 program skips dead slots by control flow the device
+    executes (a conditional per slot, or a loop bounded by the live
+    count) — unrolled back into 64 unconditional slots it would read
+    every pad slot's planes again."""
+    from pilosa_tpu.parallel import kernels as k_mod
+
+    eng = MeshEngine(holder, mesh)
+    args, _ = _captured_batch_program(eng, _subset_entries(9))
+    assert len(args[1]) == 64
+    hlo = k_mod.count_batch_tree.lower(*args).compile().as_text()
+    assert " conditional(" in hlo or " while(" in hlo
+
+
 def test_batch_tier_compile_key_stability(holder, mesh):
     """THE round-5 serving guarantee: batched count programs compile per
     (structure, tier), never per drain size — distinct batch sizes

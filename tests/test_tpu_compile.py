@@ -105,6 +105,11 @@ def recorded():
         eng.count_many(
             "i", [parse(f"Union(Row(f={a}), Row(f={a + 1}))").calls[0] for a in range(1, 5)],
             [shards] * 4)
+        # Nine distinct Counts: the tier-64 batched program, 9 slots live.
+        eng.count_many(
+            "i", [parse(f"Union(Row(f={a}), Row(s={b}))").calls[0]
+                  for a in range(1, 4) for b in range(1, 4)],
+            [shards] * 9)
         eng.fused_many("i", [
             ({"kind": "count", "call": parse("Intersect(Row(f=3), Row(s=2))").calls[0]}, shards),
             ({"kind": "sum", "field": "v", "filter": parse("Row(f=1)").calls[0]}, shards),
@@ -172,6 +177,38 @@ def test_smoke_programs_compile_for_v5e(topology, recorded, n_dev):
     for key, temp in temps.items():
         if key[0].startswith("count_tree_blocks"):
             assert temp < row_block // 16, (key, temp)
+
+
+def test_batched_count_branches_hold_no_operand_copies_on_v5e(topology, recorded):
+    """The tier-64 batched Count program runs a slot only below its
+    traced live count (real control flow in the compiled HLO), and a
+    branch reads the resident stacks in place.  Against the same slots
+    evaluated unconditionally — the form that read every pad slot's
+    planes on the chip — it holds 43 KB more scratch a slot (2.7 MB:
+    each branch keeps its own reduce scratch); one operand row copied
+    into one branch would be a row block, 126 MB."""
+    mesh = Mesh(np.asarray(topology.devices[:1]), (SHARD_AXIS,))
+    (static, arrays), = [
+        (static, arrays) for (name, static), (_, arrays) in recorded.items()
+        if name == "count_batch_tree" and len(static[0]) == 64]
+    progs, specs = static
+
+    @jax.jit
+    def every_slot(*operands):
+        def body(*ops):
+            return jax.lax.psum(jnp.stack([
+                jnp.sum(jax.lax.population_count(
+                    kernels.apply_prog(prog, ops) & ops[i_mask]).astype(jnp.int32))
+                for prog, i_mask in progs]), SHARD_AXIS)
+        return kernels.shard_map(body, mesh=mesh, in_specs=specs, out_specs=P())(*operands)
+
+    n_live, *operands = [_abstract(a, mesh) for a in arrays]
+    compiled = kernels.count_batch_tree.lower(mesh, *static, n_live, *operands).compile()
+    assert compiled.as_text().count(" conditional(") == 64
+    unconditional = every_slot.lower(*operands).compile()
+    extra = (compiled.memory_analysis().temp_size_in_bytes
+             - unconditional.memory_analysis().temp_size_in_bytes)
+    assert extra < FULL_SHARDS * ROW_BYTES // 16, extra
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])

@@ -21,6 +21,7 @@ from pilosa_tpu.parallel import MeshEngine, make_mesh
 from pilosa_tpu.parallel.batcher import _Item
 from pilosa_tpu.util import plans, tracing
 from pilosa_tpu.util.stats import (
+    METRIC_ENGINE_DRAIN_EVALUATED,
     METRIC_ENGINE_DRAIN_PLANE_BYTES,
     METRIC_ENGINE_DRAIN_REQUESTS,
     METRIC_ENGINE_DRAIN_SLOTS,
@@ -167,8 +168,8 @@ def test_stages_tile_one_trace(served, path):
         _assert_tiles(root)
         assert root.tags["path"] == path
         dispatch = next(s for s in _walk(root) if s.name == "pipeline.dispatch")
-        assert {"tier", "live", "planes_per_request", "planes_per_drain"} <= set(
-            dispatch.tags)
+        assert {"tier", "live", "evaluated", "planes_per_request",
+                "planes_per_drain"} <= set(dispatch.tags)
 
 
 def test_http_clock_and_front_end_stages(served):
@@ -205,6 +206,7 @@ def _drain_counters(op, path):
                          counted="per_request").get(),
         REGISTRY.counter(METRIC_ENGINE_DRAIN_PLANE_BYTES, op=op, path=path,
                          counted="per_drain").get(),
+        REGISTRY.counter(METRIC_ENGINE_DRAIN_EVALUATED, op=op, path=path).get(),
     ]
 
 
@@ -221,8 +223,23 @@ def test_drain_record_of_three_counts_at_tier_eight(served):
     assert len(out) == 3
     moved = [a - b for a, b in zip(_drain_counters("Count", "dense_batch"), before)]
     # one program, 8 slots, 3 requests; each Count names 2 distinct
-    # planes, the drain 3.
-    assert moved == [1, 8, 3, (2 + 2 + 2) * SHARDS * PLANE, 3 * SHARDS * PLANE]
+    # planes, the drain 3; the device runs the 3 live slots.
+    assert moved == [1, 8, 3, (2 + 2 + 2) * SHARDS * PLANE, 3 * SHARDS * PLANE, 3]
+
+
+def test_drain_record_of_sixteen_counts_at_tier_sixty_four(served):
+    """A drain of 16 on the 64-slot program: the tier is what was
+    compiled for, the evaluated slots what the device runs."""
+    eng, _api, _uri = served
+    pairs = [(a, b) for a in range(10, 14) for b in range(10, 14)]
+    calls = [pql.parse(f"Difference(Row(f={a}), Row(f={b}))").calls[0]
+             for a, b in pairs]
+    before = _drain_counters("Count", "dense_batch")
+    out = eng.count_many("i", calls, [list(range(SHARDS))] * 16)
+    plans.take_dispatch_note()
+    assert len(out) == 16
+    moved = [a - b for a, b in zip(_drain_counters("Count", "dense_batch"), before)]
+    assert moved[:3] == [1, 64, 16] and moved[5] == 16
 
 
 def test_drain_record_of_a_bsi_aggregate(served):
@@ -232,7 +249,7 @@ def test_drain_record_of_a_bsi_aggregate(served):
     plans.take_dispatch_note()
     moved = [a - b for a, b in zip(_drain_counters("Sum", "aggregate"), before)]
     # v is 0..255: 8 planes + not-null, and the filter's one row.
-    assert moved == [1, 1, 1, 10 * SHARDS * PLANE, 10 * SHARDS * PLANE]
+    assert moved == [1, 1, 1, 10 * SHARDS * PLANE, 10 * SHARDS * PLANE, 1]
 
 
 def test_no_annotation_without_a_capture(served, monkeypatch):
@@ -286,8 +303,8 @@ def test_capture_holds_stage_annotations_and_no_python_tracer(served, tmp_path):
     assert {"pilosa.lower", "pilosa.dispatch", "pilosa.device_get"} <= names, names
     _, dispatch = next(e for e in events if e[1].name == "pilosa.dispatch")
     stats = dict(dispatch.stats)
-    assert {"tier", "live", "planes_per_request", "planes_per_drain",
-            "path"} <= set(stats), stats
+    assert {"tier", "live", "evaluated", "planes_per_request",
+            "planes_per_drain", "path"} <= set(stats), stats
 
 
 def test_legacy_pipeline_series_move_as_before(served):
