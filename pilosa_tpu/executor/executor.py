@@ -58,6 +58,15 @@ from ..pql import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ, Call, Condition, Query
 
 TIME_FORMAT = "%Y-%m-%dT%H:%M"  # pilosa.TimeFormat
 
+# Call name -> the executor lane that takes a run of consecutive calls
+# as a whole (Executor._execute): calls of one lane form one run.
+_RUN_LANES = {
+    "Count": "_mesh_count_many",
+    "Sum": "_mesh_aggregate_run",
+    "Min": "_mesh_aggregate_run",
+    "Max": "_mesh_aggregate_run",
+}
+
 DEFAULT_MIN_THRESHOLD = 1
 DEFAULT_FIELD = "general"
 DEFAULT_MAX_WRITES_PER_REQUEST = 5000
@@ -837,44 +846,51 @@ class Executor:
         if query.calls and all(c.name == "SetRowAttrs" for c in query.calls):
             return self._execute_bulk_set_row_attrs(index, query.calls, opt)
 
-        # Multi-call Count batching: a run of CONSECUTIVE Count() calls
-        # (pql.Query carries Calls [] and the reference executes them per
-        # request, ast.go:27) evaluates as ONE fused device dispatch —
-        # consecutive only, because a write call between two Counts must
-        # be visible to the second.
+        # Runs: CONSECUTIVE Count() calls (pql.Query carries Calls []
+        # and the reference executes them per request, ast.go:27)
+        # evaluate as ONE fused device dispatch, and consecutive
+        # Sum/Min/Max calls are dispatched together and read back ONCE —
+        # consecutive only, because a write call between two reads must
+        # be visible to the second.  A run of one takes the per-call
+        # path untouched.
         results: list = []
         i = 0
         n = len(query.calls)
         while i < n:
-            c = query.calls[i]
-            if c.name == "Count" and self.mesh_engine is not None:
-                j = i
-                while j < n and query.calls[j].name == "Count":
+            j = i + 1
+            lane = _RUN_LANES.get(query.calls[i].name)
+            if lane is not None and self.mesh_engine is not None:
+                while j < n and _RUN_LANES.get(query.calls[j].name) == lane:
                     j += 1
-                if j - i >= 2:
-                    t0 = time.monotonic()
-                    with self.tracer.start_span(
-                        "executor.Count", index=index, batch=j - i
-                    ):
-                        batch = self._mesh_count_many(
-                            index, query.calls[i:j], shards, opt
-                        )
-                    _op_hist("Count").observe(time.monotonic() - t0)
-                    if batch is not None:
-                        results.extend(batch)
-                    else:
-                        # The whole run declined (remote shards, an
-                        # unlowerable tree): execute it per-call ONCE —
-                        # re-screening every suffix would be O(n^2).
-                        results.extend(
-                            self._execute_call(index, cc, shards, opt)
-                            for cc in query.calls[i:j]
-                        )
-                    i = j
-                    continue
-            results.append(self._execute_call(index, c, shards, opt))
-            i += 1
+            run = query.calls[i:j]
+            batch = None
+            if j - i >= 2:
+                batch = self._execute_run(lane, index, run, shards, opt)
+            if batch is None:
+                # A run of one; or the whole run declined (remote
+                # shards, an unlowerable tree): execute it per-call ONCE
+                # — re-screening every suffix would be O(n^2).
+                batch = [
+                    self._execute_call(index, c, shards, opt) for c in run
+                ]
+            results.extend(batch)
+            i = j
         return results
+
+    def _execute_run(self, lane: str, index, run, shards, opt):
+        """A run of two or more calls of one lane (``_RUN_LANES``)
+        through the mesh engine together; the results in call order, or
+        None when the lane declines the whole run."""
+        names = list(dict.fromkeys(c.name for c in run))
+        t0 = time.monotonic()
+        with self.tracer.start_span(
+            "executor." + "+".join(names), index=index, batch=len(run)
+        ):
+            batch = getattr(self, lane)(index, run, shards, opt)
+        dt = time.monotonic() - t0
+        for name in names:
+            _op_hist(name).observe(dt)
+        return batch
 
     # -- dispatch (executor.go executeCall :245-295) -----------------------
 
@@ -1800,6 +1816,15 @@ class Executor:
         result = result or ValCount()
         return ValCount() if result.count == 0 else result
 
+    @staticmethod
+    def _aggregate_flight(seq, index, c: Call, local) -> tuple:
+        """Single-flight key of one fused Sum/Min/Max (the call's text
+        names the op).  ``seq`` is the write sequence read BEFORE any
+        derived state (shard lists, row sets) was computed, so a leader
+        that computed stale derivations keys as pre-write and can never
+        share with a post-write waiter."""
+        return ("aggregate", seq, index, str(c), tuple(local))
+
     def _mesh_sum(self, index, c: Call, shards, opt):
         """Fused BSI Sum over the local shard set; (local_shards, ValCount)
         or None when unsupported."""
@@ -1808,11 +1833,7 @@ class Executor:
         field_name = c.args.get("field")
         if not field_name or len(c.children) > 1:
             return None
-        # Key the flight on the write sequence AS OF NOW — before any
-        # derived state (shard lists, row sets) is computed — so a
-        # leader that computed stale derivations keys as pre-write and
-        # can never share with a post-write waiter.
-        seq = frag_mod.WRITE_SEQ.v
+        seq = frag_mod.WRITE_SEQ.v  # first (see _aggregate_flight)
         local = self._local_shards(index, shards, opt.remote)
         if not local:
             return None
@@ -1823,7 +1844,7 @@ class Executor:
             # concurrent callers coalesce into a fused whole-program
             # dispatch with their drain-mates (docs/fusion.md).
             total, n = self._sflight.do(
-                ("sum", seq, index, str(c), tuple(local)),
+                self._aggregate_flight(seq, index, c, local),
                 lambda: self.mesh_engine.batched_sum(
                     index, field_name, filter_call, local
                 ),
@@ -1831,6 +1852,65 @@ class Executor:
         except (ValueError, PeerlessMeshError):
             return None
         return set(local), ValCount(total, n)
+
+    def _mesh_aggregate_run(self, index, calls, shards, opt):
+        """A run of consecutive Sum/Min/Max calls — independent reads of
+        the same committed state — offered whole to the engine's batch
+        lane (engine.batched_ops): a lone caller dispatches every call's
+        own program before it reads any back, so the run pays ONE
+        readback round trip; under concurrent traffic each call queues
+        as it would alone.  Returns the ValCounts in call order, or None
+        to fall back to the per-call path: a call _mesh_sum /
+        _mesh_min_max would not take, an unlowerable filter, remote
+        shards, a peer re-entry, a multi-process mesh (its collectives
+        are ordered a call at a time), or an identical call already in
+        flight from another request (joining it there costs nothing;
+        waiting for it here would be a second readback)."""
+        eng = self.mesh_engine
+        if eng is None or eng.multiproc or opt.remote:
+            return None
+        ops = []
+        for c in calls:
+            self._validate_call_args(c)
+            field_name = c.args.get("field")
+            if not field_name or len(c.children) > 1:
+                return None
+            filter_call = c.children[0] if c.children else None
+            if filter_call is not None and not eng.lowerable(filter_call):
+                return None
+            kind = c.name.lower()
+            ops.append(
+                (kind, {"kind": kind, "field": field_name,
+                        "filter": filter_call})
+            )
+        seq = frag_mod.WRITE_SEQ.v  # first (see _aggregate_flight)
+        local = self._local_shards(index, shards)
+        if not local or len(local) != len(shards):
+            return None  # remote shards: the per-call path splits
+        plan = plans_mod.current_plan()
+        # As in _mesh_count_many: a decline re-executes EVERY call on
+        # the per-call path, so the ops this attempt stamped are unwound.
+        ops_mark = len(plan.ops) if plan is not None else 0
+        try:
+            try:
+                out = self._sflight.do_all(
+                    [self._aggregate_flight(seq, index, c, local)
+                     for c in calls],
+                    lambda: eng.batched_ops(index, ops, local),
+                )
+            finally:
+                # A half-written note must not reach the next query on
+                # this pooled thread (_mesh_count_many's finally).
+                plans_mod.take_dispatch_note()
+        except (ValueError, PeerlessMeshError):
+            out = None
+        if out is None:
+            if plan is not None:
+                del plan.ops[ops_mark:]
+            return None
+        for c in calls:
+            self.stats.count(c.name, 1, tags=[f"index:{index}"])
+        return [ValCount(v, n) if n else ValCount() for v, n in out]
 
     def _execute_min_max(self, index, c: Call, shards, opt, is_min: bool) -> ValCount:
         from ..ops import bsi as bsi_ops
@@ -1877,14 +1957,14 @@ class Executor:
         field_name = c.args.get("field")
         if not field_name or len(c.children) > 1:
             return None
-        seq = frag_mod.WRITE_SEQ.v  # before derived state (see _mesh_sum)
+        seq = frag_mod.WRITE_SEQ.v  # first (see _aggregate_flight)
         local = self._local_shards(index, shards, opt.remote)
         if not local:
             return None
         filter_call = c.children[0] if c.children else None
         try:
             val, n = self._sflight.do(
-                ("minmax", seq, is_min, index, str(c), tuple(local)),
+                self._aggregate_flight(seq, index, c, local),
                 lambda: self.mesh_engine.batched_min_max(
                     index, field_name, filter_call, local, is_min
                 ),
@@ -1943,7 +2023,7 @@ class Executor:
             return None
         if len(c.children) > 1:
             raise Error("TopN() can only have one input bitmap")
-        seq = frag_mod.WRITE_SEQ.v  # before derived state (see _mesh_sum)
+        seq = frag_mod.WRITE_SEQ.v  # first (see _aggregate_flight)
         local = set(self._local_shards(index, shards, opt.remote))
         if any(s not in local for s in shards):
             return None
@@ -2306,7 +2386,7 @@ class Executor:
             if child.name != "Rows" or extra:
                 return None
         seq = frag_mod.WRITE_SEQ.v  # BEFORE row_lists: a leader with
-        # stale row sets must key as pre-write (see _mesh_sum)
+        # stale row sets must key as pre-write (see _aggregate_flight)
         shards = self._local_shards(index, shards, opt.remote)
         if not shards:
             return None
@@ -2348,8 +2428,8 @@ class Executor:
                     # Through the batcher: a GroupBy arriving alongside
                     # a dashboard drain rides the SAME fused program as
                     # its drain-mates (a "group" edge); lone callers
-                    # take the batcher's idle direct path (solo_op →
-                    # group_counts) unchanged.
+                    # take the batcher's idle direct path (solo_op_async
+                    # → group_counts_async, one readback).
                     lambda: self.mesh_engine.batched_group_counts(
                         index, fields, row_lists, filter_call, shards
                     ),

@@ -58,6 +58,7 @@ from typing import Callable, List, Optional
 from ..util import plans as plans_mod
 from ..util import tracing
 from ..util.stats import PipelineStats
+from .fusion import OP_NAMES
 
 # Submission-origin tag (process-per-core serving mode, docs/serving.md
 # "Process mode"): the device-owner's per-worker IPC reader threads each
@@ -263,19 +264,21 @@ class CountBatcher:
                             memo_key=key, memo_note=memo_note)
         if item is None:
             return self._direct(index, call, shards, key, probed, memo_note)
-        return self._wait(item, "batched count")
+        return self._wait([item], "batched count")[0]
 
-    def _wait(self, item: "_Item", what: str):
-        """Block a sync submitter on its queued item.  The pipeline's
-        workers record the stages of that interval, so it is a hole in
-        whatever stage the calling thread is inside."""
-        ok = item.event.wait(self.WAIT_TIMEOUT)
-        tracing.hole(item.t_submit, time.monotonic())
+    def _wait(self, items: List["_Item"], what: str) -> list:
+        """Block a sync submitter on its queued items; their results in
+        order, or the first one's error.  The pipeline's workers record
+        the stages of that interval, so it is a hole in whatever stage
+        the calling thread is inside."""
+        ok = all([it.event.wait(self.WAIT_TIMEOUT) for it in items])
+        tracing.hole(items[0].t_submit, time.monotonic())
         if not ok:
             raise RuntimeError(f"{what} timed out (engine wedged?)")
-        if item.error is not None:
-            raise item.error
-        return item.result
+        for it in items:
+            if it.error is not None:
+                raise it.error
+        return [it.result for it in items]
 
     def submit_async(self, index: str, call, shards) -> _Item:
         """Queue one Count into the pipeline and return its future
@@ -297,27 +300,53 @@ class CountBatcher:
 
     def submit_op(self, index: str, kind: str, spec: dict, shards):
         """One aggregate op (sum/min/max/topn/topnf) through the batch
-        lane: a lone caller runs the blocking single-op program directly
-        (zero added latency — exactly the pre-fusion path); callers
-        arriving while the pipe is busy queue into the drain, where the
-        planner fuses them with their drain-mates into ONE device
-        program (docs/fusion.md).  Returns the op's standard result
-        shape; raises the item's own error on failure."""
-        key, hit = self._memo_probe_op(index, kind, spec, shards)
-        if hit is not None:
-            plan = plans_mod.current_plan()
-            if plan is not None:
-                from .fusion import OP_NAMES
+        lane: a run of one (``submit_ops``).  Returns the op's standard
+        result shape; raises the item's own error on failure."""
+        return self.submit_ops(index, [(kind, spec)], shards)[0]
 
-                plan.note_op(
-                    op=OP_NAMES.get(kind, kind), path="memo", memo="hit"
-                )
-            return hit
-        item = self._submit(index, None, shards, allow_direct=True,
-                            kind=kind, spec=spec, memo_key=key)
-        if item is None:
-            return self._direct_op(index, kind, spec, shards, memo_key=key)
-        return self._wait(item, "batched op")
+    def submit_ops(self, index: str, ops, shards) -> list:
+        """A run of independent aggregate ops ``[(kind, spec), ...]``
+        over the same shards through the batch lane; results in call
+        order.  Each call probes the memo first (a hit is answered and
+        leaves the run).  Then ONE decision for the rest: a lone caller
+        runs them directly (``_direct_ops``: zero added latency, every
+        call dispatched before the one readback); a caller arriving
+        while the pipe is busy queues each call as an item of its own
+        into the drain, where the planner fuses them with their
+        drain-mates into ONE device program (docs/fusion.md), and waits
+        for all of them."""
+        results: list = [None] * len(ops)
+        rest = []  # (position in the run, kind, spec, memo key)
+        for k, (kind, spec) in enumerate(ops):
+            key, hit = self._memo_probe_op(index, kind, spec, shards)
+            if hit is not None:
+                plan = plans_mod.current_plan()
+                if plan is not None:
+                    plan.note_op(
+                        op=OP_NAMES.get(kind, kind), path="memo", memo="hit"
+                    )
+                results[k] = hit
+            else:
+                rest.append((k, kind, spec, key))
+        if not rest:
+            return results
+
+        def queue(call, allow_direct):
+            _k, kind, spec, key = call
+            return self._submit(index, None, shards, allow_direct,
+                                kind=kind, spec=spec, memo_key=key)
+
+        first = queue(rest[0], True)
+        if first is None:  # leadership taken: the whole run goes direct
+            outs = self._direct_ops(index, rest, shards)
+        else:  # behind a queued call the others queue too, in call order
+            outs = self._wait(
+                [first] + [queue(call, False) for call in rest[1:]],
+                "batched op",
+            )
+        for (k, *_), out in zip(rest, outs):
+            results[k] = out
+        return results
 
     def _memo_probe_op(self, index, kind, spec, shards):
         """engine.memo_probe_op, duck-typed like _memo_probe: the
@@ -328,28 +357,57 @@ class CountBatcher:
             return None, None
         return probe(index, kind, spec, shards)
 
-    def _direct_op(self, index, kind, spec, shards, memo_key=None):
-        # The whole blocking call is the direct path's "execute" stage;
-        # the engine's lower/dispatch/device_get/decode nest inside it.
+    def _direct_ops(self, index, rest, shards) -> list:
+        """The lone caller's run; its results in order.  Every call
+        is lowered and dispatched on its existing per-op program before
+        anything is read back, then ONE ``device_get`` brings every
+        result to the host (each leaf's copy is started before any is
+        waited for), then each call is decoded and stored in the memo.
+        The whole blocking run is the direct path's one ``execute``
+        stage; the engine's ``lower`` and ``dispatch`` nest inside it
+        once a call, ``device_get`` once a run, ``decode`` once a call.
+        Each call keeps its own drain record and its own plan op."""
+        eng = self.engine
+        notes: list = []
+        results: list = []
         execute = tracing.stage("execute", "direct")
         try:
             with execute:
-                out = self.engine.solo_op(index, kind, spec, shards)
-            if memo_key is not None:
-                store = getattr(self.engine, "memo_store_op", None)
-                if store is not None:
-                    store(memo_key, kind, spec, out)
-            return out
+                devs, decoders = [], []
+                since = None
+                for _k, kind, spec, _key in rest:
+                    try:
+                        dev, dec = eng.solo_op_async(index, kind, spec, shards)
+                    finally:
+                        notes.append(plans_mod.take_dispatch_note())
+                    if since is None and dev is not None:
+                        since = time.monotonic()
+                    devs.append(dev)
+                    decoders.append(dec)
+                # An op that answers without device work (missing
+                # field or stack) has no device result: None stays None.
+                host = devs if since is None else eng._fetch(devs, since)
+                for dec, got in zip(decoders, host):
+                    with tracing.stage("decode"):
+                        results.append(dec(got))
+            store = getattr(eng, "memo_store_op", None)
+            if store is not None:
+                for (_k, kind, spec, key), out in zip(rest, results):
+                    if key is not None:
+                        store(key, kind, spec, out)
+            return results
         finally:
-            note = plans_mod.take_dispatch_note()
             plan = plans_mod.current_plan()
             if plan is not None:
-                from .fusion import OP_NAMES
-
-                d = dict(note) if note else {}
-                d.setdefault("op", OP_NAMES.get(kind, kind))
-                d.setdefault("path", "direct")
-                plan.note_op(**d)
+                # One op a call that reached the engine, with what its
+                # dispatch published (a failed one's host_fallback stamp
+                # included); the blocking run is the query's device
+                # attribution (it held dispatch and readback alone).
+                for (_k, kind, _spec, _key), note in zip(rest, notes):
+                    d = dict(note) if note else {}
+                    d.setdefault("op", OP_NAMES.get(kind, kind))
+                    d.setdefault("path", "direct")
+                    plan.note_op(**d)
                 plan.note_device_seconds(execute.t1 - execute.t0)
             with self._lock:
                 self._busy = False
@@ -612,8 +670,6 @@ class CountBatcher:
                     # The per-op aggregate dispatches publish no op or
                     # path of their own; name the lane so the plan still
                     # says which path ran.
-                    from .fusion import OP_NAMES
-
                     note = dict(note or ())
                     note.setdefault(
                         "op", OP_NAMES.get(items[0].kind, items[0].kind)

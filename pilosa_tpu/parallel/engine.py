@@ -1099,10 +1099,12 @@ class MeshEngine:
         planes = self._hint_planes(hints)
         return self._note_drain(op, "aggregate", 1, 1, planes, planes)
 
-    def _fetch(self, dev):
+    def _fetch(self, dev, since: Optional[float] = None):
         """The blocking readback of a sync wrapper: the ``device_get``
-        stage, and the end of the dispatch's in-flight interval."""
-        tracing.INFLIGHT.begin()
+        stage, and the end of the dispatch's in-flight interval, which
+        began now or, for a run of dispatches read back together, at
+        ``since`` (when the first jitted call returned)."""
+        tracing.INFLIGHT.begin(since)
         try:
             with tracing.stage("device_get"):
                 return jax.device_get(dev)
@@ -3722,35 +3724,6 @@ class MeshEngine:
             return dev, lambda host: np.asarray(host)
         raise ValueError(f"unknown solo op kind: {kind!r}")
 
-    def solo_op(self, index: str, kind: str, spec: dict, shards):
-        """Blocking single-op dispatch (the batcher's idle direct path)."""
-        if kind == "count":
-            return self.count(index, spec["call"], shards)
-        if kind == "sum":
-            return self.sum(index, spec["field"], spec.get("filter"), shards)
-        if kind in ("min", "max"):
-            return self.min_max(
-                index, spec["field"], spec.get("filter"), shards, kind == "min"
-            )
-        if kind == "topn":
-            return self.topn_scores(
-                index, spec["field"], spec["rows"], spec["src"], shards
-            )
-        if kind == "topnf":
-            out = self.topn_full(
-                index, spec["field"], spec["src"], shards,
-                spec.get("n") or 0, spec.get("threshold") or 1,
-                spec.get("row_ids"),
-            )
-            return fusion_mod.DECLINED if out is None else out
-        if kind == "group":
-            out = self.group_counts(
-                index, spec["fields"], spec["rows"], spec.get("filter"),
-                shards,
-            )
-            return fusion_mod.DECLINED if out is None else out
-        raise ValueError(f"unknown solo op kind: {kind!r}")
-
     def probe_fused_item(self, index: str, spec: dict, shards):
         """Host-only lowering probe for batch-failure attribution: lower
         the item's mask tree(s) without dispatching; raises the item's
@@ -3768,6 +3741,15 @@ class MeshEngine:
             self._lower(index, t, lw)
 
     # -- batch-lane aggregate entry points (executor routing) ---------------
+
+    def batched_ops(self, index: str, ops, shards):
+        """A run of independent aggregates ``[(kind, spec), ...]`` over
+        the same shards through the batcher together (``submit_ops``):
+        a lone caller dispatches every one before it reads any back, in
+        one ``device_get``.  Results in call order.  Not for a
+        multi-process mesh, whose collectives are ordered a call at a
+        time (the executor declines the run there)."""
+        return self.batcher().submit_ops(index, ops, shards)
 
     def batched_sum(self, index: str, field: str, filter_call, shards):
         """BSI Sum through the cross-request batcher: lone callers run

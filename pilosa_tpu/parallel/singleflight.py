@@ -56,13 +56,40 @@ class SingleFlight:
             if f.error is not None:
                 raise f.error
             return f.result
+        return self._fly({key: f}, [key], lambda: [fn()])[0]
+
+    def do_all(self, keys, fn: Callable):
+        """``do`` for a run of keys that ONE ``fn()`` answers together
+        (a list of results, in key order); concurrent callers of ``do``
+        with one of the keys share that key's result.  All or nothing:
+        when any key already has a flight up, nothing is led, ``fn`` is
+        not run and None is returned — the caller takes its one-key-a-
+        call path and joins the twin there (waiting for it here and
+        flying the rest afterwards would be two readbacks, not one)."""
+        with self._lock:
+            if any(k in self._flights for k in keys):
+                return None
+            flights = {k: _Flight() for k in keys}
+            self._flights.update(flights)
+            self.flights += len(flights)
+        return self._fly(flights, keys, fn)
+
+    def _fly(self, flights: Dict[tuple, _Flight], keys, fn: Callable) -> list:
+        """The leader's half: ``fn()`` answers ``keys`` (a list, in
+        their order; a key may repeat); result or exception reaches
+        each flight's waiters."""
         try:
-            f.result = fn()
-            return f.result
+            results = fn()
+            for k, r in zip(keys, results):
+                flights[k].result = r
+            return results
         except BaseException as e:
-            f.error = e
+            for f in flights.values():
+                f.error = e
             raise
         finally:
             with self._lock:
-                self._flights.pop(key, None)
-            f.event.set()
+                for k in flights:
+                    self._flights.pop(k, None)
+            for f in flights.values():
+                f.event.set()

@@ -244,7 +244,9 @@ def test_executor_mesh_topn(holder, mesh):
     plain = Executor(holder)
     engine = MeshEngine(holder, mesh)
     calls = []
-    for name in ("topn_scores", "topn_full", "topn_cache_only"):
+    # The dispatching forms: the sync wrappers and the batch lane's
+    # direct path (solo_op_async) both go through them.
+    for name in ("topn_scores_async", "topn_full_async", "topn_cache_only"):
         orig = getattr(engine, name)
         setattr(
             engine,
@@ -288,8 +290,9 @@ def test_executor_mesh_group_by(holder, mesh):
 
     engine = MeshEngine(holder, mesh)
     calls = []
-    orig = engine.group_counts
-    engine.group_counts = lambda *x, **k: calls.append(1) or orig(*x, **k)
+    orig = engine.group_counts_async  # under group_counts and the batch lane
+    engine.group_counts_async = (
+        lambda *x, **k: calls.append(1) or orig(*x, **k))
     plain = Executor(holder)
     fused = Executor(holder, mesh_engine=engine)
     for q in [
