@@ -1,8 +1,8 @@
 """Placement of JAX's persistent compilation cache.
 
-Every entry point that compiles device programs (the server — which is
-also chip_smoke.py's only JAX process — and bench.py) calls
-``configure()`` before it builds its mesh, so a restarted node finds
+The server (which is also chip_smoke.py's and the benchmark's only JAX
+process) calls ``configure()`` before it builds its mesh, so a
+restarted node finds
 the executables its last run compiled instead of paying each batch
 tier's compile on live traffic.  The cache directory
 is part of each entry's key, so it is a fixed path: never a temp name,

@@ -173,8 +173,7 @@ class Config:
         # the history sampler writes every registry series into the internal
         # `_system` index each sample-interval, retention drops expired YMDH
         # views, and the SLO watcher evaluates burn rates over that history.
-        # History is OFF by default (tests/dev opt in); the smoke lane runs
-        # it at 1s.
+        # History is OFF by default (tests/dev opt in).
         self.obs_history = False
         self.obs_sample_interval = 10.0
         self.obs_retention = 3600.0

@@ -1154,7 +1154,7 @@ class Fragment:
         (bulkImportMutex :1538) driven by the occupancy vector; a CLEAR
         import bypasses it (fragment.go:1451 `!options.Clear`).  The
         pre-vectorization per-row walk survives as
-        ``bulk_import_rowloop`` (differential oracle + bench baseline)."""
+        ``bulk_import_rowloop`` (the differential oracle)."""
         self._check_open()
         row_ids = np.asarray(row_ids, dtype=np.int64)
         column_ids = np.asarray(column_ids, dtype=np.int64)
@@ -1214,8 +1214,7 @@ class Fragment:
         """The pre-vectorization per-row import walk, byte-for-byte:
         RowStore.union/difference once per row with per-row touch and
         count bookkeeping.  Kept as the differential oracle for the
-        ingest tests and the same-machine baseline for
-        ``bench.py --ingest-sweep`` — NOT a serving path."""
+        ingest tests — NOT a serving path."""
         self._check_open()
         row_ids = np.asarray(list(row_ids), dtype=np.int64)
         column_ids = np.asarray(list(column_ids), dtype=np.int64)
@@ -1373,8 +1372,8 @@ class Fragment:
         benchmarks/restore (no op-log, no snapshot; caller invalidates the
         rank cache once after the batch).  Deliberately publishes OPAQUE
         (no delta capture): a load is not a serving write, and the
-        repair layer MUST fall back to recompute over it — bench's
-        --repair-sweep uses exactly this hole as its forced-stale
+        repair layer MUST fall back to recompute over it —
+        tests/test_repair.py uses exactly this hole as its forced-stale
         probe."""
         self._check_open()
         n = self._store.set_dense(
@@ -1418,8 +1417,7 @@ class Fragment:
         """The pre-vectorization roaring ingest, byte-for-byte: scalar
         container decode (codec._deserialize_py), per-row store walk,
         and full-store count sweeps for ``changed``.  Kept as the
-        differential oracle for the ingest tests and the same-machine
-        baseline for ``bench.py --ingest-sweep`` — NOT a serving path."""
+        differential oracle for the ingest tests — NOT a serving path."""
         self._check_open()
         dec = codec._deserialize_py(data)
         before = sum(self._store.counts.values())

@@ -26,10 +26,10 @@ from ..ops import bitops
 WORDS64 = bitops.WORDS64
 
 # Lazily-resolved native sparse-merge library (pilosa_tpu/native/
-# sparse_merge.cpp): None = not yet resolved, False = unavailable or
-# disabled (PILOSA_NATIVE_MERGE=0).  The numpy implementations below are
-# the automatic fallback AND the differential oracle
-# (tests/test_native_merge.py); both produce bit-identical stores.
+# sparse_merge.cpp): None = not yet resolved, False = unavailable.
+# The numpy implementations below are the automatic fallback AND the
+# differential oracle (tests/test_native_merge.py); both produce
+# bit-identical stores.
 _MERGE = None
 
 _ERR_RANGE = -(1 << 63)  # sm_apply_dense out-of-range sentinel

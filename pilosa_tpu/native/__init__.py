@@ -13,7 +13,7 @@ debugging).
 Two libraries share the loader:
 - ``roaring_codec``  — fragment-file decode/encode (PR 5);
 - ``sparse_merge``   — the bulk-ingest sorted-merge + dense-apply kernels
-  (docs/ingest.md); disable with ``PILOSA_NATIVE_MERGE=0``.
+  (docs/ingest.md).
 """
 
 from __future__ import annotations
@@ -183,14 +183,8 @@ def load():
 
 
 def load_merge():
-    """The sparse-merge library (``PILOSA_NATIVE_MERGE=0`` disables it);
-    None when disabled or unavailable — callers take the numpy path."""
-    if os.environ.get("PILOSA_NATIVE_MERGE", "1").lower() in (
-        "0",
-        "false",
-        "no",
-    ):
-        return None
+    """The sparse-merge library; None when unavailable — callers take
+    the numpy path."""
     return _load("sparse_merge", _configure_merge)
 
 

@@ -29,13 +29,11 @@ Two mechanisms, both O(1) per request under one lock:
 Telemetry: ``pilosa_admission_admitted_total``,
 ``pilosa_admission_shed_total{reason}``, and pull-time gauges
 ``pilosa_admission_inflight`` / ``pilosa_admission_active_tenants`` —
-the series scripts/smoke.sh and the ops runbook (docs/serving.md)
-assert on.
+the series the ops runbook (docs/serving.md) reads.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Dict, Optional, Tuple
 
@@ -54,13 +52,6 @@ SHED_TENANT = (429, "tenant_fair")
 SHED_QUEUE = (503, "queue_full")
 
 TENANT_HEADER = "X-Pilosa-Tenant"
-
-
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, default))
-    except ValueError:
-        return default
 
 
 def _parse_weights(spec: str) -> Dict[str, float]:
@@ -87,24 +78,13 @@ class AdmissionController:
 
     def __init__(
         self,
-        max_inflight: Optional[int] = None,
-        fair_start: Optional[float] = None,
+        max_inflight: int = 1024,
+        fair_start: float = 0.5,
         weights: Optional[Dict[str, float]] = None,
     ):
-        if max_inflight is None:
-            max_inflight = _env_int("PILOSA_TPU_MAX_INFLIGHT", 1024)
         self.max_inflight = max(1, int(max_inflight))
-        if fair_start is None:
-            try:
-                fair_start = float(os.environ.get("PILOSA_TPU_FAIR_START", 0.5))
-            except ValueError:
-                fair_start = 0.5
         self.fair_start = min(max(fair_start, 0.0), 1.0)
-        if weights is None:
-            weights = _parse_weights(
-                os.environ.get("PILOSA_TPU_TENANT_WEIGHTS", "")
-            )
-        self.weights = dict(weights)
+        self.weights = dict(weights or {})
         self._lock = threading.Lock()
         self._inflight = 0
         self._tenants: Dict[str, int] = {}

@@ -3,10 +3,8 @@
 The threaded front end (net/server.py, stdlib ``ThreadingHTTPServer``)
 pays one OS thread per connection: at the concurrency the batch pipeline
 wants (hundreds of live connections feeding fused device batches), the
-scheduler churn of ~640 handler threads was the serving bottleneck —
-BENCH_r05 measured the engine 100-250x over baseline while
-``http_count_qps`` sat BELOW it.  This module replaces the front end
-with a reactor:
+scheduler churn of one handler thread per connection is the serving
+bottleneck.  This module replaces the front end with a reactor:
 
 * **One event loop per acceptor** (``selectors``-based), N acceptors
   behind ``SO_REUSEPORT`` as the scale-out knob (``reactors=``; default
@@ -33,8 +31,8 @@ with a reactor:
   parsed header block and answers 429/503 BEFORE any engine work, with
   per-tenant weighted-fair isolation.
 
-The threaded server remains available (``PILOSA_TPU_SERVER_BACKEND=
-threaded`` or config ``[server] backend``) as the differential oracle;
+The threaded server remains available (config ``[server] backend =
+"threaded"``) as the differential oracle;
 both servers share the same ``Handler`` route table.  docs/serving.md
 is the operator guide.
 """
@@ -43,7 +41,6 @@ from __future__ import annotations
 
 import collections
 import json
-import os
 import selectors
 import socket
 import ssl as ssl_mod
@@ -78,20 +75,6 @@ MAX_PENDING = 64
 # admission layer exists to survive.  These also run inline on the
 # reactor if the worker pool is saturated (cheap, and they must answer).
 ADMISSION_EXEMPT = frozenset({"/healthz", "/readyz", "/metrics"})
-
-
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, default))
-    except ValueError:
-        return default
-
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, default))
-    except ValueError:
-        return default
 
 
 class _BlockingPool:
@@ -977,14 +960,16 @@ class AsyncHTTPServer:
         host: str = "localhost",
         port: int = 10101,
         ssl_context=None,
-        reactors: Optional[int] = None,
-        pool_workers: Optional[int] = None,
-        queue_depth: Optional[int] = None,
+        reactors: int = 1,
+        pool_workers: int = 256,
+        queue_depth: int = 1024,
         admission: Optional[AdmissionController] = None,
-        max_body_bytes: Optional[int] = None,
-        read_timeout: Optional[float] = None,
-        idle_timeout: Optional[float] = None,
-        response_timeout: Optional[float] = None,
+        max_body_bytes: int = 256 * 1024 * 1024,
+        read_timeout: float = 120.0,
+        idle_timeout: float = 120.0,
+        # Above the batcher's 300 s wedge bound (net/server.py
+        # DRAIN_TIMEOUT rationale).
+        response_timeout: float = 330.0,
         reuseport: Optional[bool] = None,
     ):
         self.ssl_context = ssl_context
@@ -997,37 +982,13 @@ class AsyncHTTPServer:
         # for the threaded server; aliasing the class to the instance
         # keeps that assignment working unchanged.
         self.RequestHandlerClass = self
-        if reactors is None:
-            reactors = _env_int("PILOSA_TPU_SERVER_REACTORS", 1)
         self.n_reactors = max(1, int(reactors))
-        if pool_workers is None:
-            pool_workers = _env_int("PILOSA_TPU_SERVER_POOL_WORKERS", 256)
-        if queue_depth is None:
-            queue_depth = _env_int("PILOSA_TPU_SUBMIT_QUEUE", 1024)
         self.pool = _BlockingPool(pool_workers, queue_depth)
         self.admission = admission
-        if max_body_bytes is None:
-            max_body_bytes = _env_int(
-                "PILOSA_TPU_MAX_BODY_BYTES", 256 * 1024 * 1024
-            )
         self.max_body_bytes = max_body_bytes
-        self.read_timeout = (
-            read_timeout
-            if read_timeout is not None
-            else _env_float("PILOSA_TPU_READ_TIMEOUT", 120.0)
-        )
-        self.idle_timeout = (
-            idle_timeout
-            if idle_timeout is not None
-            else _env_float("PILOSA_TPU_IDLE_TIMEOUT", 120.0)
-        )
-        # Above the batcher's 300 s wedge bound (net/server.py
-        # DRAIN_TIMEOUT rationale).
-        self.response_timeout = (
-            response_timeout
-            if response_timeout is not None
-            else _env_float("PILOSA_TPU_RESPONSE_TIMEOUT", 330.0)
-        )
+        self.read_timeout = read_timeout
+        self.idle_timeout = idle_timeout
+        self.response_timeout = response_timeout
         self._c_accepted = REGISTRY.counter(METRIC_SERVER_CONNECTIONS_TOTAL)
         self._c_req_inline = REGISTRY.counter(
             METRIC_SERVER_REQUESTS, path="inline"

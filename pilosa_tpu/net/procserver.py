@@ -63,7 +63,7 @@ from ..util.stats import (
 )
 from . import ipc
 from .admission import AdmissionController
-from .aserver import ADMISSION_EXEMPT, _BlockingPool, _env_float, _env_int
+from .aserver import ADMISSION_EXEMPT, _BlockingPool
 from .wire import fast_result_values, response_to_json
 
 # How long a scrape waits for each worker's STATS reply before marking
@@ -84,8 +84,8 @@ class _WorkerConn:
         self.reader = ipc.FrameReader(sock)
         self.sender = ipc.FrameSender(sock, name=f"ipc-send-w{wid}")
         # Distinct per (worker, pid): a respawned worker is a new
-        # origin, so the smoke assertion "fused batch spans worker
-        # PIDS" is literal.
+        # origin, so "fused batch spans worker PIDS"
+        # (tests/test_procserver.py) is literal.
         self.origin = f"worker-{wid}:{pid}"
         self._slock = threading.Lock()
         self._stats_pending: Dict[int, tuple] = {}
@@ -171,14 +171,14 @@ class ProcessHTTPServer:
         # cannot cross the process boundary).
         tls_certificate: str = "",
         tls_key: str = "",
-        reactors: Optional[int] = None,
-        pool_workers: Optional[int] = None,
-        queue_depth: Optional[int] = None,
+        reactors: int = 1,
+        pool_workers: int = 256,
+        queue_depth: int = 1024,
         admission: Optional[AdmissionController] = None,
-        max_body_bytes: Optional[int] = None,
-        read_timeout: Optional[float] = None,
-        idle_timeout: Optional[float] = None,
-        response_timeout: Optional[float] = None,
+        max_body_bytes: int = 256 * 1024 * 1024,
+        read_timeout: float = 120.0,
+        idle_timeout: float = 120.0,
+        response_timeout: float = 330.0,
     ):
         if ssl_context is not None and not tls_certificate:
             raise ValueError(
@@ -200,14 +200,9 @@ class ProcessHTTPServer:
             "tls_certificate": tls_certificate,
             "tls_key": tls_key,
         }
-        if pool_workers is None:
-            pool_workers = _env_int("PILOSA_TPU_SERVER_POOL_WORKERS", 256)
-        if queue_depth is None:
-            queue_depth = _env_int("PILOSA_TPU_SUBMIT_QUEUE", 1024)
         # Engine-side pool: generic HTTP passthrough frames (imports,
         # debug routes, sync queries) block here, never on a reader.
         self.pool = _BlockingPool(pool_workers, queue_depth)
-        self._stats_timeout = _env_float("PILOSA_TPU_STATS_TIMEOUT", STATS_TIMEOUT)
         # The device-owner keeps ITS OWN reactor in the SO_REUSEPORT
         # accept group: it resolves the ephemeral port before cluster /
         # gossip advertisement, holds the port continuously (every
@@ -230,7 +225,7 @@ class ProcessHTTPServer:
         self.inner = AsyncHTTPServer(
             host, port,
             ssl_context=inner_ctx,
-            reactors=reactors or 1,
+            reactors=reactors,
             pool_workers=pool_workers,
             queue_depth=queue_depth,
             admission=None,  # serve() wires the ONE global controller
@@ -671,7 +666,7 @@ class ProcessHTTPServer:
             # already be free — releasing after the send let a client
             # act on the response milliseconds before the slot freed,
             # and anything keying on in-flight state (tenant fair
-            # shares, the smoke's saturate-then-shed stage) raced it.
+            # shares, a saturate-then-shed test) raced it.
             release_once()
         conn.send_response(rid, status, ctype, payload)
 
@@ -687,7 +682,7 @@ class ProcessHTTPServer:
         waits = [
             (wid, wc, *wc.request_stats()) for wid, wc in conns.items()
         ]
-        deadline = time.monotonic() + self._stats_timeout
+        deadline = time.monotonic() + STATS_TIMEOUT
         others: Dict[str, str] = {}
         for wid, wc, ev, slot in waits:
             ev.wait(max(0.0, deadline - time.monotonic()))

@@ -10,7 +10,6 @@ examples use).
 from __future__ import annotations
 
 import json
-import os
 import re
 import threading
 import time
@@ -39,15 +38,9 @@ OPENMETRICS_CONTENT_TYPE = (
 
 # Serving backend selection (docs/serving.md): "async" is the event-loop
 # reactor (net/aserver.py); "threaded" is the stdlib thread-per-connection
-# server kept as the differential oracle.  Config [server] backend /
-# PILOSA_TPU_SERVER_BACKEND override.
+# server kept as the differential oracle.  Config [server] backend
+# selects it (config.py).
 DEFAULT_BACKEND = "async"
-
-
-def _resolve_backend(backend: Optional[str]) -> str:
-    if backend:
-        return backend
-    return os.environ.get("PILOSA_TPU_SERVER_BACKEND", DEFAULT_BACKEND)
 
 # Process start reference for /healthz uptime.
 _START_MONOTONIC = time.monotonic()
@@ -1807,7 +1800,7 @@ def bind_http(
     port: int = 10101,
     ssl_context=None,
     backend: Optional[str] = None,
-    workers: Optional[int] = None,
+    workers: int = 0,
     tls_certificate: str = "",
     tls_key: str = "",
     **server_opts,
@@ -1824,16 +1817,10 @@ def bind_http(
     (the stdlib thread-per-connection oracle).  ``workers > 0`` selects
     PROCESS mode on the async backend: N shared-nothing worker
     processes behind SO_REUSEPORT forward decoded frames to this
-    process over AF_UNIX (net/procserver.py; ``[server] workers`` /
-    ``PILOSA_TPU_SERVER_WORKERS``, default 0 = the in-process reactor,
-    byte-identical to pre-process-mode behavior).  ``server_opts`` are
+    process over AF_UNIX (net/procserver.py; ``[server] workers``,
+    default 0 = the in-process reactor).  ``server_opts`` are
     passed through to the chosen server (reactors=, admission=, ...)."""
-    if _resolve_backend(backend) != "threaded":
-        if workers is None:
-            try:
-                workers = int(os.environ.get("PILOSA_TPU_SERVER_WORKERS", 0))
-            except ValueError:
-                workers = 0
+    if (backend or DEFAULT_BACKEND) != "threaded":
         if workers and int(workers) > 0:
             from .procserver import ProcessHTTPServer
 

@@ -285,7 +285,7 @@ def main():
     wid = int(spec["wid"])
     link = EngineLink(
         spec["ipc"], wid,
-        response_timeout=float(spec.get("response_timeout") or 330.0),
+        response_timeout=float(spec["response_timeout"]),
     )
     handler = WorkerHandler(link, spec.get("allowed_origins"))
     ssl_ctx = None
@@ -299,14 +299,14 @@ def main():
         spec["host"],
         int(spec["port"]),
         ssl_context=ssl_ctx,
-        reactors=spec.get("reactors") or 1,
-        pool_workers=spec.get("pool_workers"),
-        queue_depth=spec.get("queue_depth"),
+        reactors=spec["reactors"],
+        pool_workers=spec["pool_workers"],
+        queue_depth=spec["queue_depth"],
         admission=None,  # admission is GLOBAL: the device-owner arbitrates
-        max_body_bytes=spec.get("max_body_bytes"),
-        read_timeout=spec.get("read_timeout"),
-        idle_timeout=spec.get("idle_timeout"),
-        response_timeout=spec.get("response_timeout"),
+        max_body_bytes=spec["max_body_bytes"],
+        read_timeout=spec["read_timeout"],
+        idle_timeout=spec["idle_timeout"],
+        response_timeout=spec["response_timeout"],
         reuseport=True,  # share the port with sibling workers
     )
     srv.RequestHandlerClass.handler = handler

@@ -191,13 +191,13 @@ def minmax_valcount_nd(planes, filter_row, is_min: bool):
     ``sel != 0`` — elementwise), keeping a word-local candidate mask and
     value.  The former formulation then took THREE separate reductions
     (min value, then attain mask, then count), which XLA implemented by
-    re-walking the planes — measured 380 GB/s on a 1.13 GB plane read.
+    re-walking the planes.
     Here the shard min and its attaining-column count come from ONE
     variadic ``lax.reduce`` over (hi, lo, count) word triples with a
     lexicographic-argmin combiner that merges counts on ties: XLA fuses
     the walk into the reduce's operands and the planes stream exactly
-    once — measured 755 GB/s (the chip's HBM ceiling) on the same
-    shapes (scripts/kernel_opt.py).
+    once (no cell runs Min/Max yet: its share of the roofline is not
+    measured).
 
     ``planes`` is uint32[depth+1, ..., W]; ``filter_row`` broadcasts
     against planes[0].  The reduce runs over the LAST axis; leading

@@ -6,8 +6,8 @@ promote-ahead: every advice set it issues is also pushed into
 resident), behind the exact admission scoring, decline cooldowns, and
 version-token commit gate demand promotions use — so speculative
 promotions compete with demand traffic but can never corrupt it, and
-they inherit a prediction quality that was already observable and
-bench-guarded (``prefetch_advisor_hit_rate``) before the first byte
+they inherit a prediction quality that was already observable
+(``/debug/prefetch_advice`` ``hitRate``) before the first byte
 moved.  The residency worker additionally serves demand (non-advisor)
 requests first, so promote-ahead never starves a miss.
 
@@ -75,7 +75,7 @@ class PrefetchAdvisor:
         # advice must not pin a closed engine alive.
         self._engine_ref = None
         # Kill switch: False returns the advisor to ISSUE 19's
-        # report-only behavior (the bench A/B arm flips this).
+        # report-only behavior (tests flip this for an A/B arm).
         self.drive_promotions = True
         self.driven_rows = 0
         self.driven_requests = 0

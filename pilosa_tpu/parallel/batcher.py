@@ -10,11 +10,10 @@ single-Count HTTP requests executed one dispatch each would serialize
 kernels.count_batch_tree dispatch: K answers for one floor + one
 readback.
 
-Round 5 ran exactly one fused batch at a time (plus 2 pipelined
-readbacks), so device compute, host lowering, and the readback RTT
-serialized — QPS was capped at batch_size x readbacks_per_second
-(0.67x baseline).  This version decouples the path into STAGES with
-their own worker loops and a bounded number of fused batches in flight:
+With one fused batch at a time, device compute, host lowering, and
+the readback RTT serialize — the rate is capped at batch_size x
+readbacks_per_second.  The path is decoupled into STAGES with their
+own worker loops and a bounded number of fused batches in flight:
 
   accumulate  submit() queues arrivals; the drain worker gives
               concurrent arrivals a short window to pile into one drain
@@ -31,7 +30,7 @@ their own worker loops and a bounded number of fused batches in flight:
               submitters' futures (HTTP completion callbacks fire here)
 
 In-flight depth is bounded by a semaphore (``max_inflight``, default
-DEFAULT_INFLIGHT, env PILOSA_PIPELINE_DEPTH): the dispatch worker BLOCKS
+DEFAULT_INFLIGHT): the dispatch worker BLOCKS
 on the (depth+1)'th batch, so under overload the queue accumulates a
 full readback period of arrivals and batch size self-tunes to
 arrival_rate x readback_time / depth, while depth batches overlap in the
@@ -39,7 +38,7 @@ transport + device.  Every stage is recorded by one call of the stage
 clock (util/tracing.py ``stage``/``waited``: histogram, span, plan and,
 during a capture, profiler annotation); in-flight depth and batch
 occupancy are tracked in a util.stats.PipelineStats (``pipeline``
-attribute; surfaced by /debug/vars and bench.py).
+attribute; surfaced by /debug/vars).
 
 Policy: pass-through when idle (a lone query runs on its own thread with
 zero added latency — exactly the unbatched path), batch under load.
@@ -49,7 +48,6 @@ adapts to the actual concurrency.
 
 from __future__ import annotations
 
-import os
 import queue as queue_mod
 import threading
 import time
@@ -196,29 +194,24 @@ class CountBatcher:
     # costs latency only when the system is already saturated.
     # The window breaks EARLY when arrivals go quiet (depth stable
     # across one poll), so a lone straggler pays ~one poll, not the
-    # whole window.  Env-tunable (PILOSA_BATCH_WINDOW / PILOSA_BATCH_POLL,
-    # seconds): the event-loop server feeds the queue from EVERY live
-    # connection (docs/serving.md), and the right window tracks the
-    # deployment's readback RTT, not a constant.
-    ACCUM_WINDOW = float(os.environ.get("PILOSA_BATCH_WINDOW", 0.15))
-    ACCUM_POLL = float(os.environ.get("PILOSA_BATCH_POLL", 0.005))
+    # whole window.  Seconds.  The event-loop server feeds the queue
+    # from EVERY live connection (docs/serving.md).
+    ACCUM_WINDOW = 0.15
+    ACCUM_POLL = 0.005
 
     # Fused batches allowed in flight at once (the pipeline depth): the
     # dispatch worker blocks on the (depth+1)'th batch, so the queue
     # accumulates while depth batches overlap lowering, device
-    # execution, and readback.  Round 5's value of 2 left the device
-    # idle whenever both readbacks were in the transport; >=4 keeps a
-    # batch in every stage of the pipe.  Tunable per deployment via
-    # PILOSA_PIPELINE_DEPTH or the constructor.
+    # execution, and readback.  A depth of 2 leaves the device idle
+    # whenever both readbacks are in the transport; >=4 keeps a batch
+    # in every stage of the pipe.  The constructor can override it.
     DEFAULT_INFLIGHT = 4
 
     def __init__(self, engine, max_batch: int = 512, max_inflight: Optional[int] = None):
         self.engine = engine
         self.max_batch = max_batch
         if max_inflight is None:
-            max_inflight = int(
-                os.environ.get("PILOSA_PIPELINE_DEPTH", self.DEFAULT_INFLIGHT)
-            )
+            max_inflight = self.DEFAULT_INFLIGHT
         self.max_inflight = max(1, int(max_inflight))
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -238,7 +231,7 @@ class CountBatcher:
         # counter skewed forever.  Reads stay lock-free (stale by at
         # most one transition — fine for a heuristic).
         self._live = 0
-        # Telemetry the QPS bench and tests assert on.
+        # Telemetry the tests assert on.
         self.batches = 0
         self.batched_queries = 0
         self._stopped = False
@@ -513,8 +506,8 @@ class CountBatcher:
 
     def stop(self):
         """Shut down the stage workers (drain/dispatch/collect).  Used
-        when a batcher is REPLACED (bench --depth-sweep rebuilds one per
-        depth) — without it each discarded batcher leaks 2+depth daemon
+        when a batcher is REPLACED (tests rebuild one per depth) —
+        without it each discarded batcher leaks 2+depth daemon
         threads for the life of the process.  In-queue items resolve
         before the workers exit; new submits after stop() would queue
         forever, so only call on a batcher no longer reachable from the
@@ -707,15 +700,14 @@ class CountBatcher:
             self.pipeline.incr("batched_queries", len(items))
             self.pipeline.gauge_max("max_batch_occupancy", len(items))
             if len(items) >= 2:
-                # Cross-request coalescing evidence (bench --conn-sweep
-                # reads these): how many batches actually fused, and how
-                # many answers rode them.
+                # Cross-request coalescing evidence: how many batches
+                # actually fused, and how many answers rode them.
                 self.pipeline.incr("fused_batches")
                 self.pipeline.incr("fused_queries", len(items))
                 self._last_fused = time.monotonic()
                 # Process mode: a fused batch whose riders arrived via
                 # DIFFERENT worker processes proves the cross-process
-                # coalescing property (smoke.sh asserts this moves).
+                # coalescing property (tests/test_procserver.py).
                 origins = {it.origin for it in items if it.origin}
                 if len(origins) >= 2:
                     self.pipeline.incr("cross_worker_fused_batches")
@@ -725,7 +717,7 @@ class CountBatcher:
             if gkind == "fused":
                 # Heterogeneous whole-program evidence (docs/fusion.md):
                 # this drain lowered to ONE device program across op
-                # kinds (smoke.sh and bench --dashboard-sweep read it).
+                # kinds.
                 self.pipeline.incr("fused_program_batches")
                 self.pipeline.incr("fused_program_queries", len(items))
             # In flight from the jitted call's return to the collect
@@ -1006,8 +998,7 @@ class CountBatcher:
         )
 
     def pipeline_snapshot(self) -> dict:
-        """Stage timings + depth gauges + occupancy, for /debug/vars and
-        bench.py."""
+        """Stage timings + depth gauges + occupancy, for /debug/vars."""
         snap = self.pipeline.snapshot()
         snap["depth"] = self.max_inflight
         snap["batches"] = self.batches

@@ -27,7 +27,6 @@ explicit HBM residency manager.
 from __future__ import annotations
 
 import functools
-import os
 import threading
 import time
 import weakref
@@ -501,7 +500,7 @@ class _ResultMemo:
 
 DEFAULT_RESIDENCY_BYTES = 8 << 30  # HBM budget for resident field stacks
 
-# Result-memo capacity (entries); PILOSA_RESULT_MEMO=0 disables it.
+# Result-memo capacity (entries); 0 disables it.
 DEFAULT_RESULT_MEMO = 4096
 
 # Sentinel distinguishing "caller did not probe the memo" from "caller
@@ -802,8 +801,7 @@ class MeshEngine:
             METRIC_ENGINE_PROMOTIONS, cause="warm_start"
         )
         # Queries answered from the host tier because their stack (or
-        # the rows they touch) was not resident (bench's hit-rate
-        # numerator pairs this with the stack cache-hit counter).
+        # the rows they touch) was not resident.
         self.host_fallbacks = 0
         # Thread-local probe marker: re-raising fallback paths (batch
         # failure attribution, promotion-commit reconcile) must not
@@ -892,26 +890,18 @@ class MeshEngine:
         # combines the resident stacks' occupancy summaries through the
         # query tree and, when the surviving block fraction is at or
         # under this threshold, dispatches the block-gather kernel
-        # instead of the dense sweep.  The default came out of the
-        # density sweep (bench.py --density-sweep): the sparse form's
-        # gather overhead crosses the dense roofline around 50% block
-        # occupancy, so 25% keeps a 2x bytes margin.
-        self.sparse_threshold = float(
-            os.environ.get("PILOSA_SPARSE_THRESHOLD", "0.25")
-        )
-        self.sparse_enabled = os.environ.get("PILOSA_SPARSE", "1") != "0"
+        # instead of the dense sweep.  No cell of the benchmark stands
+        # on either side of the threshold yet (ROADMAP S6 (b)): the
+        # crossover is not measured on the chip.
+        self.sparse_threshold = 0.25
+        self.sparse_enabled = True
         # Pallas block-DMA form: TPU backends only (_dispatch_sparse).
-        self._sparse_pallas = (
-            os.environ.get("PILOSA_SPARSE_PALLAS", "1") != "0"
-            and jax.default_backend() == "tpu"
-        )
+        self._sparse_pallas = jax.default_backend() == "tpu"
         self.sparse_dispatches = 0
         self.device_bytes_skipped = 0
         # Versioned result memo: fused Counts repeated against unchanged
         # data are answered with NO device dispatch (_ResultMemo).
-        self.result_memo = _ResultMemo(
-            int(os.environ.get("PILOSA_RESULT_MEMO", DEFAULT_RESULT_MEMO))
-        )
+        self.result_memo = _ResultMemo(DEFAULT_RESULT_MEMO)
         # Tree-signature cache for _memo_key: (str(c), fields) is a pure
         # function of the tree, and the executor's parse cache hands the
         # SAME Call object back for a repeated query text — so the
@@ -949,17 +939,13 @@ class MeshEngine:
         # Device-resident TopN trim for the fused lane: topnf edges run
         # gate + exact totals + top_k on device (kernels.fused_tree).
         # False routes through the retained host gate+trim oracle
-        # (fusion._TopNFullDecode) — the differential tests and bench
-        # flip this to compare bit-exactly.
-        self.topn_device_trim = (
-            os.environ.get("PILOSA_TOPN_DEVICE", "1") != "0"
-        )
+        # (fusion._TopNFullDecode) — the differential tests flip this
+        # to compare bit-exactly.
+        self.topn_device_trim = True
         # Device TopN slab lane (executor._mesh_topn_shards): per-shard
         # threshold-prune + top-k on device, host merges O(K·shards)
         # pairs.  False forces the exact host walk (the oracle).
-        self.topn_slab_enabled = (
-            os.environ.get("PILOSA_TOPN_SLAB", "1") != "0"
-        )
+        self.topn_slab_enabled = True
         # (index, field) -> (stack token, slab candidate entry): the
         # ranked-cache-fed candidate build for the slab lane, rebuilt
         # when the field stack's token changes (same discipline as
@@ -3638,15 +3624,14 @@ class MeshEngine:
 
     def fused_many(self, index: str, entries):
         """Synchronous fused drain: dispatch + one readback, results in
-        entry order (the differential-test / bench convenience)."""
+        entry order (the differential tests' convenience)."""
         return self.fused_drain(
             [(index, spec, shards) for spec, shards in entries]
         )
 
     def fused_drain(self, entries):
         """Synchronous cross-index drain over (index, spec, shards)
-        triples — the test/bench convenience twin of
-        fused_drain_async."""
+        triples — the tests' convenience twin of fused_drain_async."""
         try:
             fd = self.fused_drain_async(entries)
         finally:
@@ -4805,8 +4790,8 @@ class MeshEngine:
         candidates, the result memo — and stop the batcher's worker
         threads.  Without this, teardown returned HBM only when the
         engine object happened to be garbage-collected, which on a
-        long-lived process (server restart-in-place, bench sweeps, test
-        suites sharing a runtime) is 'never': the OrderedDict caches
+        long-lived process (server restart-in-place, test suites
+        sharing a runtime) is 'never': the OrderedDict caches
         keep every buffer reachable.  Wired from server.close().
         Idempotent; a closed engine can still serve (caches simply
         rebuild) but deployments shouldn't."""

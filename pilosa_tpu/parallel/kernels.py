@@ -22,10 +22,9 @@ slower on v5e (95 vs 705 GB/s effective).  Padding shards are zero.  ``mask`` is
 ``uint32[S, 1]`` (broadcasts against the word axis); a filter prog of
 ``("ones",)`` means mask-only.
 
-These are plain-XLA programs by measurement, not by default: a Pallas
-VMEM-pipelined version of the fragment-matrix sweep benchmarked within
-noise of XLA's fusion on a v5e in round 4 (scripts/pallas_vs_xla.py
-re-runs the comparison), so the hand-written kernel layer was deleted.
+These are plain-XLA programs: the batched Count program reads its
+planes at 89 % of a v5e's HBM roofline (PERF.md §5, taxi cell), and no
+hand-written kernel layer is kept for the dense sweep.
 """
 
 from __future__ import annotations
@@ -198,9 +197,8 @@ def _sum_many(ops_list, axes):
     fuses the virtual elementwise operands (pc(a & b), ...) into the
     reduce loop, so every distinct input plane streams from HBM exactly
     once — where K separate ``jnp.sum`` calls re-read the shared
-    operand K times (measured: TopN scoring 489 -> 756 GB/s, 3-field
-    GroupBy 173 -> 751 GB/s; scripts/kernel_opt.py).  Returns a list of
-    reduced arrays in input order."""
+    operand K times.  Returns a list of reduced arrays in input
+    order."""
     out = []
     for c in range(0, len(ops_list), VARIADIC_CHUNK):
         chunk = tuple(ops_list[c : c + VARIADIC_CHUNK])
@@ -736,9 +734,8 @@ def groupn_tree(mesh, prog, specs, idx_specs, mask, *operands):
     Every combination count is one operand of a variadic popcount
     reduce (_sum_many): XLA fuses the &-chains into the reduce loop and
     each field plane streams from HBM exactly once, instead of the
-    virtual [K1..Kn, S, W] tensor's per-combination re-reads (measured
-    173 -> 751 GB/s on the 3-field bench shape).  The combination loop
-    is trace-time Python, so the engine caps prod(K)
+    virtual [K1..Kn, S, W] tensor's per-combination re-reads.  The
+    combination loop is trace-time Python, so the engine caps prod(K)
     (MAX_GROUP_COMBOS) and overflow falls back to the host iterator."""
     n = len(idx_specs)
 

@@ -217,7 +217,7 @@ class RepairLayer:
 
     @contextmanager
     def suspended(self):
-        """Disable probe AND registration (the bench oracle's recompute
+        """Disable probe AND registration (a test oracle's recompute
         arm must hit the real dispatch path, not the repair layer)."""
         self._suspended += 1
         try:

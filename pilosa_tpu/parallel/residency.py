@@ -249,7 +249,7 @@ class ResidencyManager:
 
     def flush(self, timeout: float = 30.0) -> bool:
         """Block until the queue is drained and the worker idle; False
-        on timeout.  Tests and bench phase boundaries only."""
+        on timeout.  Tests only."""
         deadline = time.monotonic() + timeout
         with self._cv:
             while self._pending or self._busy:

@@ -33,7 +33,7 @@ class SingleFlight:
     def __init__(self):
         self._lock = threading.Lock()
         self._flights: Dict[tuple, _Flight] = {}
-        # Telemetry (bench/tests assert on shared counts).
+        # Telemetry (tests assert on shared counts).
         self.flights = 0
         self.shared = 0
 

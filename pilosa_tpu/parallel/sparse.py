@@ -3,9 +3,9 @@
 The reference's whole reason for roaring bitmaps is to never touch empty
 regions (SURVEY §2.1: container ops skip absent containers).  Our dense
 ``uint32[R, S, WORDS]`` device layout lost that: the dense sweep reads
-every word of every operand row, and BENCH_r05 shows those kernels
-already at the HBM roofline (~750 GB/s implied) — the only remaining
-device-side lever is reading FEWER BYTES.
+every word of every operand row, and the batched Count program already
+reads them at 89 % of the HBM roofline (PERF.md §5, taxi cell) — the
+remaining device-side lever is reading FEWER BYTES.
 
 This module is that lever for the dominant count/intersect sweep.  The
 engine keeps an EXACT per-(row, shard) block-occupancy bitmap on every

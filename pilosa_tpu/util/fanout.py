@@ -9,7 +9,7 @@ per-fragment fan-out), and nested waits on a single bounded pool
 deadlock.  Thread spin-up is ~50 us — noise against a shard's worth of
 import work.
 
-``PILOSA_IMPORT_FANOUT`` caps the width (default 8; 0 or 1 = serial).
+``DEFAULT_IMPORT_FANOUT`` caps the width (8, or the core count if less).
 """
 
 from __future__ import annotations
@@ -20,18 +20,10 @@ DEFAULT_IMPORT_FANOUT = 8
 
 
 def fanout_width(n_tasks: int) -> int:
-    """Width cap: the env value verbatim when set; otherwise
-    min(DEFAULT, cpu_count) — oversubscribing threads past the cores
-    measurably HURTS the import path (the python glue between the
+    """Width cap: min(DEFAULT, cpu_count) — oversubscribing threads
+    past the cores HURTS the import path (the python glue between the
     GIL-releasing numpy/native kernels thrashes under contention)."""
-    env = os.environ.get("PILOSA_IMPORT_FANOUT")
-    if env is not None:
-        try:
-            cap = int(env)
-        except ValueError:
-            cap = DEFAULT_IMPORT_FANOUT
-    else:
-        cap = min(DEFAULT_IMPORT_FANOUT, os.cpu_count() or 1)
+    cap = min(DEFAULT_IMPORT_FANOUT, os.cpu_count() or 1)
     return max(1, min(cap, n_tasks))
 
 

@@ -43,14 +43,13 @@ touches weighted by row count, and ops without touches accumulate into
 the ``untracked`` bucket — so the sum over tables plus untracked equals
 the op-note total exactly.
 
-Kill switch: ``PILOSA_HEAT=0`` (or ``HEAT.enabled = False`` at
-runtime) drops the recorder to a no-op; the plans layer's own
-``PILOSA_PLANS=0`` disables it transitively (no plans are recorded).
+``HEAT.enabled = False`` drops the recorder to a no-op (tests compare
+against it); ``plans.ENABLED = False`` silences it transitively (no
+plans are recorded).
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import weakref
 from collections import OrderedDict
@@ -177,7 +176,7 @@ class HeatRecorder:
     """Process-wide working-set heat state, fed by ``plans.record``."""
 
     def __init__(self):
-        self.enabled = os.environ.get("PILOSA_HEAT", "1") != "0"
+        self.enabled = True
         self._lock = threading.Lock()
         self._tables: "OrderedDict[Tuple[str, str, str], _Table]" = (
             OrderedDict()

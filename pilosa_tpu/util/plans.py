@@ -24,11 +24,11 @@ Three surfaces feed off the same records:
   controller, so weighted-fair shares are judged against MEASURED cost
   rather than request count.
 
-Recording is always-on and built to vanish in the noise (<2% on the
-count_intersect p50 — ``bench.py --profile-overhead`` guards it):
+Recording is always-on and built to be cheap (its cost on the chip
+is not measured; the stage clock's is, PERF.md §6, PR 26):
 plans are append-only lists of small dicts, the engine->batcher seam is
 one thread-local dict per DISPATCH (not per query), and the analyzer
-runs only at record time.  ``PILOSA_PLANS=0`` disables the whole layer.
+runs only at record time.
 
 Thread model: mirrors util/tracing.py.  The plan rides a module-level
 thread-local slot (``current_plan``/``attach``) captured explicitly at
@@ -43,7 +43,6 @@ the plans of every query that rode the dispatch.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -60,9 +59,9 @@ from .stats import (
     REGISTRY,
 )
 
-# Kill switch for the whole layer (bench.py --profile-overhead measures
-# the delta; operators can flip it on a pathological workload).
-ENABLED = os.environ.get("PILOSA_PLANS", "1") != "0"
+# Switch for the whole layer: tests set it off to compare against a
+# server that records no plans.
+ENABLED = True
 
 _TLS = threading.local()
 
@@ -77,8 +76,8 @@ class attach:
     (the capture half of a thread hop is just ``current_plan()`` on the
     submitting thread).  ``attach(None)`` is a no-op block.  A slotted
     class, not a @contextmanager: this sits on the per-query hot path
-    and the generator protocol costs ~2x the plain __enter__/__exit__
-    pair (bench.py --profile-overhead)."""
+    and the generator protocol costs more than the plain
+    __enter__/__exit__ pair."""
 
     __slots__ = ("_plan", "_prev")
 

@@ -458,8 +458,7 @@ METRIC_ENGINE_REBUILDS = "pilosa_engine_stack_rebuilds_total"
 #                                           busy seconds live in the manager
 #                                           snapshot — the ratio is the
 #                                           host-decode/device-upload overlap
-#                                           throughput bench.py reports as
-#                                           promotion_overlap_mbits_s)
+#                                           throughput)
 #   pilosa_engine_host_fallbacks_total      queries served from the host tier
 #                                           because their stack was not (yet)
 #                                           resident — each enqueued an async
@@ -686,8 +685,7 @@ SERVER_REQUEST_PATHS = ("inline", "pool", "shed")
 #   pilosa_history_dropped_total{reason=}   series values NOT stored
 #                                           (stride | clamp | error)
 #   pilosa_history_tick_seconds             histogram: cost of one sampler
-#                                           pass — the measured numerator of
-#                                           bench.py --history-overhead
+#                                           pass
 #   pilosa_slo_burn_total{slo=}             SLO burn events journaled
 METRIC_HISTORY_SAMPLES = "pilosa_history_samples_total"
 METRIC_HISTORY_TICKS = "pilosa_history_ticks_total"

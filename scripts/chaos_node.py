@@ -1,10 +1,7 @@
-"""One pilosa-tpu node for the chaos drills — THE shared boot script.
+"""One pilosa-tpu node for the chaos drills.
 
-tests/test_chaos_drill.py, bench.py --chaos-sweep, and scripts/smoke.sh
-all spawn their cluster members through this file, so the drill, the
-bench headlines, and the smoke stage can never measure with diverged
-boot wiring (the same can't-diverge rule as bench's shared id-pairs
-headline helper).  The node id ``n0`` is the coordinator; every other
+tests/test_chaos_drill.py spawns its cluster members through this file.
+The node id ``n0`` is the coordinator; every other
 node seeds from SEED_PORT.  Fast failure detection (0.2 s probes,
 suspicion x2) and a short anti-entropy interval make the drills land
 in seconds instead of minutes.

@@ -49,9 +49,8 @@ def _env():
     return env
 
 
-# The shared chaos node bootstrap (also used by bench.py --chaos-sweep
-# and scripts/smoke.sh, so the three lanes can never diverge): n0 is
-# the coordinator, replicas=2, ack=logged, fast gossip + anti-entropy.
+# The chaos node bootstrap: n0 is the coordinator, replicas=2,
+# ack=logged, fast gossip + anti-entropy.
 CHAOS_NODE = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "scripts", "chaos_node.py",

@@ -3,9 +3,8 @@ structured event journal (ring bounding, type filtering, trace-id
 linkage), health/readiness probes (/healthz always-alive, /readyz
 flipping across startup and resize), the /cluster/metrics federation
 (both nodes' series labeled by node id, degraded nodes reported as
-scrape errors), anti-entropy pass journaling, engine HBM introspection
-(eviction events + gauge flush at close), and the bench_guard prom
-snapshot format."""
+scrape errors), anti-entropy pass journaling and engine HBM
+introspection (eviction events + gauge flush at close)."""
 
 import json
 import time
@@ -324,48 +323,3 @@ def test_debug_events_limit_and_type_filter_over_http(tmp_path):
         assert doc["node"] == "node0"
     finally:
         h.close()
-
-
-# -- bench_guard prom format -------------------------------------------------
-
-
-def test_bench_guard_prom_snapshot_diff(tmp_path):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_guard",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "bench_guard.py"),
-    )
-    bg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bg)
-
-    base = tmp_path / "base.prom"
-    cur = tmp_path / "cur.prom"
-    base.write_text(
-        "# HELP pilosa_engine_compile_total x\n"
-        "# TYPE pilosa_engine_compile_total counter\n"
-        "pilosa_engine_compile_total 5\n"
-        'pilosa_engine_compile_seconds{phase="compile"} 1.25\n'
-        'pilosa_query_seconds_bucket{le="+Inf"} 10\n'
-        "pilosa_query_seconds_count 10\n"
-    )
-    cur.write_text(
-        "pilosa_engine_compile_total 7\n"
-        'pilosa_engine_compile_seconds{phase="compile"} 2.5\n'
-        "pilosa_query_seconds_count 40\n"
-    )
-    # Prom samples are dimensionless: informational diff, rc 0.
-    rc = bg.main([str(cur), "--baseline", str(base), "--format", "prom",
-                  "--require", "pilosa_engine_compile_total", "--quiet"])
-    assert rc == 0
-    # Buckets are skipped, labeled series keyed with their labels.
-    metrics = bg.load_metrics(str(base), "prom")
-    assert 'pilosa_query_seconds_bucket{le="+Inf"}' not in metrics
-    assert metrics['pilosa_engine_compile_seconds{phase="compile"}']["value"] == 1.25
-    # Auto-sniff detects the exposition without --format.
-    assert bg.load_metrics(str(base)) == metrics
-    # A required series missing from the new snapshot fails.
-    rc = bg.main([str(cur), "--baseline", str(base), "--format", "prom",
-                  "--require", "pilosa_engine_resident_bytes", "--quiet"])
-    assert rc == 1

@@ -216,88 +216,6 @@ def test_client_attempt_timeout_bounds_each_dial():
     assert c._connect().timeout == 0.5
 
 
-# -- bench_guard chaos headlines --------------------------------------------
-
-
-def test_bench_guard_chaos_headlines(tmp_path):
-    """availability_under_failure_pct and replica_read_qps_gain are
-    AUTO_REQUIREd once baselined, with HIGHER-better polarity (the unit
-    map alone would read 'pct' as lower-better) and an absolute 90%
-    availability floor."""
-    import subprocess
-    import sys
-
-    base = tmp_path / "base.jsonl"
-    cur = tmp_path / "cur.jsonl"
-    base.write_text(
-        '{"metric": "availability_under_failure_pct", "value": 99.0,'
-        ' "unit": "pct"}\n'
-        '{"metric": "replica_read_qps_gain", "value": 1.5, "unit": "x"}\n'
-    )
-
-    def run():
-        return subprocess.run(
-            [sys.executable, "scripts/bench_guard.py", str(cur),
-             "--baseline", str(base)],
-            capture_output=True, text=True, cwd="/root/repo",
-        )
-
-    # Missing from the new run -> both required -> fail, both named.
-    cur.write_text('{"metric": "other", "value": 1.0, "unit": "us"}\n')
-    rc = run()
-    assert rc.returncode == 1
-    assert "availability_under_failure_pct" in rc.stderr
-    assert "replica_read_qps_gain" in rc.stderr
-
-    # Availability DROPPED (93 vs 99 is within 15% relative tolerance
-    # of a lower-better pct — the override makes it higher-better, and
-    # 93 < 99 by ~6%, within tol) but BELOW the 90 floor fails hard.
-    cur.write_text(
-        '{"metric": "availability_under_failure_pct", "value": 85.0,'
-        ' "unit": "pct"}\n'
-        '{"metric": "replica_read_qps_gain", "value": 1.5, "unit": "x"}\n'
-    )
-    rc = run()
-    assert rc.returncode == 1
-    assert "floor" in rc.stderr
-
-    # The gain ratio regresses DOWN (higher-better override on a
-    # dimensionless unit): 0.5 vs 1.5 is past even the wide 50%
-    # ratio tolerance.
-    cur.write_text(
-        '{"metric": "availability_under_failure_pct", "value": 100.0,'
-        ' "unit": "pct"}\n'
-        '{"metric": "replica_read_qps_gain", "value": 0.5, "unit": "x"}\n'
-    )
-    rc = run()
-    assert rc.returncode == 1
-    assert "replica_read_qps_gain" in rc.stderr
-
-    # Healthy run passes: availability UP must never fail (a raw
-    # lower-better 'pct' read would have called +1% a regression at
-    # tight tolerances).
-    cur.write_text(
-        '{"metric": "availability_under_failure_pct", "value": 100.0,'
-        ' "unit": "pct"}\n'
-        '{"metric": "replica_read_qps_gain", "value": 1.6, "unit": "x"}\n'
-    )
-    rc = run()
-    assert rc.returncode == 0, rc.stderr
-
-    # The floor binds on the metric's FIRST appearance too: a baseline
-    # that predates the chaos sweep must not let 40% availability pass
-    # as "new metric (no baseline)".
-    base.write_text('{"metric": "other", "value": 1.0, "unit": "us"}\n')
-    cur.write_text(
-        '{"metric": "availability_under_failure_pct", "value": 40.0,'
-        ' "unit": "pct"}\n'
-        '{"metric": "other", "value": 1.0, "unit": "us"}\n'
-    )
-    rc = run()
-    assert rc.returncode == 1
-    assert "floor" in rc.stderr
-
-
 # -- warm-start -------------------------------------------------------------
 
 

@@ -515,52 +515,6 @@ def test_bsi_value_import_hints_under_down_owner(tmp_path):
         h.close()
 
 
-def test_bench_guard_destructive_availability_headline(tmp_path):
-    """destructive_write_availability_pct is AUTO_REQUIREd once
-    baselined, HIGHER-better despite its 'pct' unit, and floored at an
-    absolute 90 — a regression to the fail-loud policy (0%) can never
-    pass, even as a brand-new metric with no baseline."""
-    import subprocess
-    import sys
-
-    base = tmp_path / "base.jsonl"
-    cur = tmp_path / "cur.jsonl"
-    base.write_text(
-        '{"metric": "destructive_write_availability_pct", "value": 100.0,'
-        ' "unit": "pct"}\n'
-    )
-
-    def run(baseline=True):
-        args = [sys.executable, "scripts/bench_guard.py", str(cur)]
-        if baseline:
-            args += ["--baseline", str(base)]
-        return subprocess.run(
-            args, capture_output=True, text=True, cwd="/root/repo",
-        )
-
-    # Dropped from the run entirely -> required -> fail, named.
-    cur.write_text('{"metric": "other", "value": 1.0, "unit": "us"}\n')
-    rc = run()
-    assert rc.returncode == 1
-    assert "destructive_write_availability_pct" in rc.stderr
-
-    # Below the 90 floor fails hard even against a 100 baseline...
-    cur.write_text(
-        '{"metric": "destructive_write_availability_pct", "value": 50.0,'
-        ' "unit": "pct"}\n'
-    )
-    assert run().returncode == 1
-    # ...and on FIRST appearance with no baseline at all.
-    assert run(baseline=False).returncode == 1
-
-    # Healthy run passes.
-    cur.write_text(
-        '{"metric": "destructive_write_availability_pct", "value": 100.0,'
-        ' "unit": "pct"}\n'
-    )
-    assert run().returncode == 0, run().stderr
-
-
 def test_write_replicated_hint_survives_for_additive_sets(tmp_path):
     """Additive sets hint too (faster convergence than waiting for a
     full anti-entropy pass), and the degraded-batches counter does NOT

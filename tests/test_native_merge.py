@@ -115,11 +115,6 @@ def test_fallback_is_automatic_when_loader_absent(rng, monkeypatch):
     _assert_fragments_equal(fa, fb, "loader-absent")
 
 
-def test_env_gate_disables_native(monkeypatch):
-    monkeypatch.setenv("PILOSA_NATIVE_MERGE", "0")
-    assert native.load_merge() is None
-
-
 @pytest.mark.skipif(not HAVE_NATIVE, reason="no C++ toolchain")
 def test_shard_split_native_matches_argsort(rng, monkeypatch):
     """field._shard_groups: the native counting sort and the argsort

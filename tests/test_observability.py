@@ -413,6 +413,155 @@ def test_metrics_endpoint_serves_required_series(holder, mesh):
         srv.shutdown()
 
 
+# -- the scrape surface of a default serving stack ---------------------------
+
+# Every family an operator's dashboards and alerts are built on, by the
+# document that defines it.  A default stack that has served a query and
+# taken an import must expose each at /metrics.
+SURFACE_SERIES = [
+    # docs/observability.md
+    "pilosa_query_seconds_bucket",
+    "pilosa_query_op_seconds_bucket",
+    "pilosa_pipeline_stage_seconds_bucket",
+    "pilosa_fragment_op_seconds_bucket",
+    "pilosa_engine_cache_hits_total",
+    "pilosa_engine_cache_misses_total",
+    "pilosa_device_bytes_skipped_total",
+    "pilosa_engine_resident_bytes",
+    "pilosa_engine_evicted_bytes",
+    "pilosa_engine_evictions_total",
+    "pilosa_engine_stack_rebuilds_total",
+    "pilosa_engine_compile_total",
+    "pilosa_engine_compile_seconds",
+    "pilosa_engine_compile_cache_keys",
+    "pilosa_engine_heat_tracked_rows",
+    "pilosa_engine_residency_gap_bytes",
+    "pilosa_advisor_predictions_total",
+    "pilosa_advisor_hits_total",
+    "pilosa_advisor_misses_total",
+    # docs/mesh.md
+    "pilosa_mesh_devices",
+    "pilosa_mesh_local_devices",
+    "pilosa_mesh_shards_per_device",
+    "pilosa_mesh_psum_dispatches_total",
+    "pilosa_cluster_remote_calls_total",
+    # docs/durability.md
+    "pilosa_ingest_acked_unsynced_bytes",
+    "pilosa_replica_reads_total",
+    "pilosa_ingest_degraded_batches_total",
+    "pilosa_client_retries_total",
+    "pilosa_hints_queued_total",
+    "pilosa_hints_replayed_total",
+    "pilosa_hints_dropped_total",
+    "pilosa_hints_pending",
+    "pilosa_faults_injected_total",
+    # docs/fusion.md
+    "pilosa_engine_fused_program_programs_total",
+    "pilosa_engine_fused_program_queries_total",
+    "pilosa_engine_fused_program_masks_evaluated_total",
+    "pilosa_engine_fused_program_masks_referenced_total",
+    # docs/residency.md
+    "pilosa_engine_promotions_total",
+    "pilosa_engine_partial_promotions_total",
+    "pilosa_engine_promotions_declined_total",
+    "pilosa_engine_host_fallbacks_total",
+    "pilosa_engine_resident_block_fraction",
+    # docs/ingest.md
+    "pilosa_ingest_batches_total",
+    "pilosa_ingest_bits_total",
+    "pilosa_ingest_changed_total",
+    "pilosa_ingest_seconds_bucket",
+    "pilosa_ingest_sync_chunks_total",
+    "pilosa_ingest_sync_coalesced_total",
+    "pilosa_ingest_sync_dispatches_total",
+    'pilosa_cache_entries{cache_type="ranked"}',
+    "pilosa_cache_recalculate_seconds_bucket",
+    # docs/serving.md
+    "pilosa_admission_inflight",
+    "pilosa_admission_active_tenants",
+    "pilosa_admission_admitted_total",
+    "pilosa_admission_shed_total",
+    "pilosa_server_connections",
+    "pilosa_server_connections_total",
+    "pilosa_server_requests_total",
+]
+
+
+@pytest.fixture(scope="module")
+def surface():
+    """One default stack (event-loop front end, its own admission
+    controller, a one-device mesh engine) that answers a cardinality
+    Count and a fused Count, takes a roaring and an id-pairs import over
+    HTTP and reads both back; yields the /metrics text after that."""
+    from pilosa_tpu.api import API
+    from pilosa_tpu.net import serve
+    from pilosa_tpu.roaring import codec
+
+    h = Holder()
+    h.open()
+    h.create_index("smoke").create_field("f").import_bulk([1, 1, 1], [0, 5, 9])
+    eng = MeshEngine(h, make_mesh(1))
+    srv, _thread = serve(API(holder=h, mesh_engine=eng), port=0)
+    uri = f"http://localhost:{srv.server_address[1]}"
+
+    def post(path, data):
+        req = urllib.request.Request(uri + path, data=data, method="POST")
+        return json.loads(urllib.request.urlopen(req, timeout=60).read())
+
+    try:
+        assert type(srv).__name__ == "AsyncHTTPServer"
+        doc = post("/index/smoke/query", b"Count(Row(f=1))")
+        assert doc["results"][0] == 3 and "traceID" in doc, doc
+        # An Intersect cannot take the O(1) cardinality lane: it runs as
+        # a fused mesh dispatch.
+        doc = post("/index/smoke/query", b"Count(Intersect(Row(f=1), Row(f=1)))")
+        assert doc["results"][0] == 3, doc
+        vals = np.asarray(
+            [(3 << 20) | 1, (3 << 20) | 2, (3 << 20) | 70000], dtype=np.uint64
+        )
+        doc = post("/index/smoke/field/f/import-roaring/0", codec.serialize(vals))
+        assert doc["changed"] == 3, doc
+        assert post("/index/smoke/query", b"Count(Row(f=3))")["results"][0] == 3
+        post(
+            "/index/smoke/field/f/import",
+            json.dumps(
+                {"rowIDs": [7, 7, 7, 8], "columnIDs": [11, 12, 70000, 11]}
+            ).encode(),
+        )
+        # A read of the just-written bits reflects them at once.
+        assert post("/index/smoke/query", b"Count(Row(f=7))")["results"][0] == 3
+        yield urllib.request.urlopen(uri + "/metrics", timeout=30).read().decode()
+    finally:
+        srv.shutdown()
+        eng.close()
+
+
+@pytest.mark.parametrize("series", SURFACE_SERIES)
+def test_metrics_surface_carries_series(surface, series):
+    assert series in surface, f"/metrics is missing {series}"
+
+
+def test_metrics_surface_values_moved(surface):
+    """The samples say what the stack did: the fused Count was one psum
+    dispatch and no internal-client call, and each import path counted
+    its batch."""
+    _assert_prometheus_conformant(surface)
+    assert 'le="+Inf"' in surface
+    samples = {}
+    for line in surface.splitlines():
+        if line and not line.startswith("#"):
+            name, _, value = line.rpartition(" ")
+            samples[name] = float(value)
+    assert samples["pilosa_mesh_devices"] >= 1
+    assert samples["pilosa_mesh_shards_per_device"] >= 1
+    assert samples["pilosa_mesh_psum_dispatches_total"] > 0
+    assert samples['pilosa_ingest_batches_total{path="roaring"}'] >= 1
+    assert samples['pilosa_ingest_batches_total{path="bits"}'] >= 1
+    assert samples['pilosa_cache_entries{cache_type="ranked"}'] >= 1
+    assert samples["pilosa_admission_admitted_total"] >= 6
+    assert samples["pilosa_server_connections"] >= 1  # the scrape's own
+
+
 # -- 2-node remote fan-out ---------------------------------------------------
 
 

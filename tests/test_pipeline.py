@@ -154,12 +154,6 @@ def test_inflight_depth_is_bounded():
     assert b.pipeline_snapshot()["gauges"]["inflight_max"] <= 2
 
 
-def test_pipeline_depth_env_override(monkeypatch):
-    monkeypatch.setenv("PILOSA_PIPELINE_DEPTH", "7")
-    b = CountBatcher(_StubEngine())
-    assert b.max_inflight == 7
-
-
 # -- signature regression (satellite: literal-only masking) ----------------
 
 

@@ -406,53 +406,6 @@ def test_worker_kill_respawn_readyz_and_surviving_acks(proc_server):
     assert 'pilosa_process_up{proc="worker-1"} 1' in text
 
 
-def test_bench_guard_auto_requires_topn_and_worker_qps(tmp_path):
-    """topn_1B_cols_p50 (us: regresses UP) and http_count_qps_w{N}
-    (qps: regresses DOWN) auto-require once a baseline records them."""
-    import subprocess
-    import sys
-
-    base = tmp_path / "base.jsonl"
-    cur = tmp_path / "cur.jsonl"
-    base.write_text(
-        '{"metric": "topn_1B_cols_p50", "value": 4500.0, "unit": "us"}\n'
-        '{"metric": "http_count_qps_w0", "value": 1000.0, "unit": "qps"}\n'
-        '{"metric": "http_count_qps_w2", "value": 2000.0, "unit": "qps"}\n'
-    )
-
-    def run():
-        return subprocess.run(
-            [sys.executable, "scripts/bench_guard.py", str(cur),
-             "--baseline", str(base)],
-            capture_output=True, text=True, cwd="/root/repo",
-        )
-
-    # Missing from the new run -> all required -> fail, each named.
-    cur.write_text('{"metric": "other", "value": 1.0, "unit": "us"}\n')
-    rc = run()
-    assert rc.returncode == 1
-    assert "topn_1B_cols_p50" in rc.stderr
-    assert "http_count_qps_w2" in rc.stderr
-    # Present but regressed: TopN slower (us UP) and w2 QPS down.
-    cur.write_text(
-        '{"metric": "topn_1B_cols_p50", "value": 9000.0, "unit": "us"}\n'
-        '{"metric": "http_count_qps_w0", "value": 1000.0, "unit": "qps"}\n'
-        '{"metric": "http_count_qps_w2", "value": 900.0, "unit": "qps"}\n'
-    )
-    rc = run()
-    assert rc.returncode == 1
-    assert "topn_1B_cols_p50" in rc.stderr
-    assert "http_count_qps_w2" in rc.stderr
-    # Within tolerance -> pass.
-    cur.write_text(
-        '{"metric": "topn_1B_cols_p50", "value": 4400.0, "unit": "us"}\n'
-        '{"metric": "http_count_qps_w0", "value": 1050.0, "unit": "qps"}\n'
-        '{"metric": "http_count_qps_w2", "value": 2100.0, "unit": "qps"}\n'
-    )
-    rc = run()
-    assert rc.returncode == 0, rc.stderr
-
-
 def test_config_workers_and_pool_workers_keys(tmp_path):
     """[server] workers is the PROCESS count (default 0); the blocking
     pool ceiling moved to pool-workers / SERVER_POOL_WORKERS."""

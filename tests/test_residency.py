@@ -353,7 +353,17 @@ def test_residency_miss_type_and_metrics_surface(holder, mesh1):
     snap = eng.cache_snapshot()
     assert snap["hostFallbacks"] >= 1
     assert "pendingPromotions" in snap["workingSet"]
+    assert "evictionPressure" in snap["workingSet"]
     assert snap["workingSet"]["deviceBudgetBytes"] == eng.max_resident_bytes
+    # Once the promotion lands the stack is PARTLY resident, and both
+    # the gauge and /debug/vars' working set say so.
+    assert eng.residency.flush(30.0)
+    eng.refresh_metrics()
+    frac = REGISTRY.get_gauge("pilosa_engine_resident_block_fraction")
+    assert 0.0 < frac < 1.0, frac
+    per = eng.cache_snapshot()["workingSet"]["perIndex"]["i"]
+    assert per["partialStacks"] >= 1, per
+    assert 0.0 < per["residentFraction"] < 1.0, per
     eng.close()
 
 
@@ -531,3 +541,83 @@ def test_promotion_declined_cooldown(holder, mesh1):
     # Still correct, still host-served.
     assert ex.execute("i", q).results[0] == want
     eng.close()
+
+
+@pytest.mark.parametrize("drive", [False, True], ids=["demand", "advisor"])
+def test_promote_ahead_spares_host_fallbacks(mesh1, drive):
+    """The promote-ahead chain end to end, as an A/B at one budget: two
+    dashboards over disjoint fields alternate under a budget that holds
+    one block pool and a half, so every swing needs a promotion.  On
+    demand alone every query of the graded cycles falls back to the host
+    tier; once the miner has learned the alternation the advisor promotes
+    the other dashboard's rows ahead of its query and fewer fall back.
+    Every answer is the NumPy popcount's on both arms."""
+    from pilosa_tpu.api import API, QueryRequest
+    from pilosa_tpu.ops import bitops
+    from pilosa_tpu.parallel.advisor import ADVISOR
+    from pilosa_tpu.util import plan_miner
+    from pilosa_tpu.util.events import EventJournal
+    from pilosa_tpu.util.heat import HEAT
+
+    h = Holder()
+    h.open()
+    idx = h.create_index("ab")
+    rng = np.random.default_rng(11)
+    shards = (0, 1)
+    words_set = 8 * bitops.OCC_BLOCK_WORDS // 2  # 8 occupied blocks a row
+    reqs = []
+    for name in ("fa", "fb"):
+        view = idx.create_field(name).view_if_not_exists("standard")
+        want = 0
+        for s in shards:
+            pair = []
+            for r in (0, 1):
+                words = np.zeros(bitops.WORDS64, dtype=np.uint64)
+                words[:words_set] = rng.integers(
+                    0, 2**63, words_set, dtype=np.uint64
+                )
+                view.fragment_if_not_exists(s).load_row_words(r, words)
+                pair.append(words)
+            want += int(np.sum(np.bitwise_count(pair[0] & pair[1])))
+        for frag in view.fragments.values():
+            frag.cache.invalidate()
+        reqs.append((
+            QueryRequest("ab", f"Count(Intersect(Row({name}=0), Row({name}=1)))"),
+            want,
+        ))
+    pool64 = 64 * len(shards) * bitops.OCC_BLOCK_WORDS * 4
+    cycles, learn = 8, 2
+    HEAT.reset()
+    plan_miner.MINER.reset()
+    ADVISOR.reset()
+    ADVISOR.drive_promotions = drive
+    journal = EventJournal()
+    eng = MeshEngine(
+        h, mesh1, max_resident_bytes=(3 * pool64) // 2, journal=journal
+    )
+    eng.result_memo.maxsize = 0  # the residency path, not the memo lane
+    api = API(holder=h, mesh_engine=eng)
+    try:
+        for cyc in range(cycles):
+            if cyc == learn:
+                fb0 = eng.host_fallbacks
+            for req, want in reqs:
+                assert int(api.query(req).results[0]) == want
+                assert eng.residency.flush(60.0)
+        fallbacks = eng.host_fallbacks - fb0
+    finally:
+        ADVISOR.drive_promotions = True
+        ADVISOR.reset()
+        plan_miner.MINER.reset()
+        HEAT.reset()
+        eng.close()
+    causes = {
+        e.fields.get("cause") for e in journal.events(type="engine.promotion")
+    }
+    graded = 2 * (cycles - learn)
+    if drive:
+        assert "advisor" in causes, causes
+        assert fallbacks < graded, (fallbacks, graded)
+    else:
+        assert causes == {"reactive"}, causes
+        assert fallbacks == graded, (fallbacks, graded)

@@ -22,6 +22,7 @@ from pilosa_tpu.ops.bitops import (
     occupancy64_from_positions,
 )
 from pilosa_tpu.parallel import MeshEngine, make_mesh
+from pilosa_tpu.parallel.engine import _ResultMemo
 from pilosa_tpu.roaring import codec
 
 N_SHARDS = 8
@@ -214,6 +215,41 @@ def test_sparse_count_matches_dense(holder, mesh):
     assert eng.count("i", call, [1, 4]) == dense.count("i", call, [1, 4])
 
 
+@pytest.mark.parametrize("blocks", [1, 2, 6, 32])
+def test_density_points_sparse_dense_and_numpy_agree(holder, mesh, blocks):
+    """The density points ROADMAP S6 (b) carries for the cell to come:
+    rows half-filling 1 / 2 / 6 / 32 of a shard's 64 occupancy blocks.
+    The occupancy-guided engine, the dense sweep and a NumPy popcount of
+    the host words give one answer; at or under the threshold (a quarter
+    of the blocks) the sparse form runs and skips bytes, above it the
+    dense sweep does."""
+    rng = np.random.default_rng(7)
+    f = holder.create_index("i").create_field("f")
+    shards = list(range(N_SHARDS))
+    cols = {0: [], 1: []}
+    for s in shards:
+        for b in range(blocks):
+            picks = s * SHARD_WIDTH + b * OCC_BLOCK_BITS + rng.choice(
+                OCC_BLOCK_BITS, size=OCC_BLOCK_BITS // 2, replace=False
+            )
+            # Row 0 and row 1 share the middle half of the picks.
+            cols[0] += picks[: 3 * len(picks) // 4].tolist()
+            cols[1] += picks[len(picks) // 4:].tolist()
+    f.import_bulk([0] * len(cols[0]) + [1] * len(cols[1]), cols[0] + cols[1])
+    want = len(set(cols[0]) & set(cols[1]))
+    assert want == N_SHARDS * blocks * OCC_BLOCK_BITS // 4
+    eng = MeshEngine(holder, mesh)
+    dense = MeshEngine(holder, mesh)
+    dense.sparse_enabled = False
+    call = pql.parse("Intersect(Row(f=0), Row(f=1))").calls[0]
+    assert eng.count("i", call, shards) == want
+    assert dense.count("i", call, shards) == want
+    assert dense.sparse_dispatches == 0
+    took_sparse = blocks / 64 <= eng.sparse_threshold
+    assert (eng.sparse_dispatches > 0) == took_sparse
+    assert (eng.device_bytes_skipped > 0) == took_sparse
+
+
 def test_dense_rows_keep_dense_path(holder, mesh):
     """Above the density threshold the dense sweep runs (the earlier
     Pallas deletion note applies to IT; sparsity is a different
@@ -302,10 +338,10 @@ def test_result_memo_through_batcher(holder, mesh):
     assert eng.fused_dispatches == fd
 
 
-def test_result_memo_disabled(holder, mesh, monkeypatch):
-    monkeypatch.setenv("PILOSA_RESULT_MEMO", "0")
+def test_result_memo_disabled(holder, mesh):
     build_clustered(holder, {10: (0,)})
     eng = MeshEngine(holder, mesh)
+    eng.result_memo = _ResultMemo(0)
     call = pql.parse("Row(f=10)").calls[0]
     shards = list(range(N_SHARDS))
     a = eng.count("i", call, shards)
@@ -487,58 +523,3 @@ def test_pallas_block_kernel_interpret_matches_numpy(mesh):
     assert int(got) == want
     assert int(sparse.count_tree_blocks(mesh, prog, *args)) == want
 
-
-# -- bench guard -------------------------------------------------------------
-
-
-def test_bench_guard(tmp_path):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_guard",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "bench_guard.py"),
-    )
-    bg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bg)
-
-    def jsonl(path, recs):
-        import json
-
-        p = tmp_path / path
-        p.write_text("\n".join(json.dumps(r) for r in recs) + "\n")
-        return str(p)
-
-    base = jsonl("base.jsonl", [
-        {"metric": "count_p50", "value": 100.0, "unit": "us", "vs_baseline": 2.0},
-        {"metric": "qps", "value": 1000.0, "unit": "qps", "vs_baseline": 1.0},
-        {"metric": "occupancy", "value": 16.0, "unit": "queries/batch",
-         "vs_baseline": 1.0},
-    ])
-    good = jsonl("good.jsonl", [
-        {"metric": "count_p50", "value": 108.0, "unit": "us"},
-        {"metric": "qps", "value": 960.0, "unit": "qps"},
-        {"metric": "occupancy", "value": 2.0, "unit": "queries/batch"},
-        {"metric": "sparse_new", "value": 5.0, "unit": "us"},
-    ])
-    bad = jsonl("bad.jsonl", [
-        {"metric": "count_p50", "value": 140.0, "unit": "us"},  # +40% latency
-        {"metric": "qps", "value": 700.0, "unit": "qps"},  # -30% qps
-    ])
-    assert bg.main([good, "--baseline", base, "--quiet"]) == 0
-    assert bg.main([bad, "--baseline", base, "--quiet"]) == 1
-    # Per-metric tolerance override lets a known change through.
-    assert bg.main([
-        bad, "--baseline", base, "--quiet",
-        "--metric-tolerance", "count_p50=0.5",
-        "--metric-tolerance", "qps=0.5",
-    ]) == 0
-    # A required metric missing from the new run fails.
-    assert bg.main([
-        good, "--baseline", base, "--quiet", "--require", "gone_p50",
-    ]) == 1
-    # Snapshot shape round-trips as a baseline.
-    snap = str(tmp_path / "snap.json")
-    assert bg.main([good, "--baseline", base, "--quiet",
-                    "--write-baseline", snap]) == 0
-    assert bg.main([good, "--baseline", snap, "--quiet"]) == 0
