@@ -55,7 +55,7 @@ from typing import Callable, List, Optional
 
 from ..util import plans as plans_mod
 from ..util import tracing
-from ..util.stats import PipelineStats
+from ..util.stats import METRIC_PIPELINE_ACCUM_CLOSE, REGISTRY, PipelineStats
 from .fusion import OP_NAMES
 
 # Submission-origin tag (process-per-core serving mode, docs/serving.md
@@ -185,19 +185,47 @@ class CountBatcher:
     HOT_WINDOW = 0.25
 
     # Accumulation window: once the queue is non-empty, give concurrent
-    # arrivals this long to pile into the SAME drain before dispatching.
+    # arrivals a moment to pile into the SAME drain before dispatching.
     # Readback round trips serialize in the transport, so throughput is
     # (answers per readback) x (readbacks per second) — an eager worker
     # fragments arrivals into many small batches and caps throughput at
     # the readback rate; a short accumulation multiplies it by K.  Idle
     # single queries never pass through here (direct path), so this
-    # costs latency only when the system is already saturated.
-    # The window breaks EARLY when arrivals go quiet (depth stable
-    # across one poll), so a lone straggler pays ~one poll, not the
-    # whole window.  Seconds.  The event-loop server feeds the queue
-    # from EVERY live connection (docs/serving.md).
+    # costs latency only when the system is already saturated.  The
+    # event-loop server feeds the queue from EVERY live connection
+    # (docs/serving.md).
+    #
+    # The window closes when the burst has gone QUIET: nothing has
+    # arrived for a quiet interval read off the queue's own timestamps
+    # (every item carries ``t_submit``), and the drain worker sleeps
+    # until ``last arrival + quiet``, not in fixed steps.  While the
+    # pipe is idle (``_live == 0``) every millisecond waited is an idle
+    # device, so the interval is QUIET_GAPS x the mean of the burst's
+    # last QUIET_SPAN inter-arrival gaps, held between QUIET_MIN (one
+    # thread wake-up and a straggler's jitter) and QUIET_MAX; a gap the
+    # burst has not shown yet counts as QUIET_MAX / QUIET_GAPS, so a
+    # lone arrival waits the ceiling and a burst earns the short
+    # patience over its first QUIET_SPAN gaps.  While a batch is in
+    # flight the interval is QUIET_MAX: waiting costs a query nothing
+    # it could have had (it could not dispatch ahead of the in-flight
+    # batch's slot anyway) and sustained unsynchronised load does not
+    # fragment into small drains.  QUIET_MAX is the step of the fixed
+    # poll this replaced (which closed 5-10 ms after the last arrival),
+    # so no drain leaves later than it did then.  ACCUM_WINDOW bounds a
+    # window under arrivals that never go quiet.  Seconds.
+    #
+    # The multiple, the span and the floor are constants of the
+    # algorithm, not settings, checked against the taxi cell on one v5e
+    # (PERF.md section 6, PR 32): sixteen arrivals ~0.54 ms apart, but
+    # the reactor reads its sockets in batches with 0.8 ms (p99 2.7 ms)
+    # between them, so the mean spans more than one batch: 4 x the mean
+    # of 4 gaps split one burst in ten there, of 8 gaps one in
+    # twenty-five.
     ACCUM_WINDOW = 0.15
-    ACCUM_POLL = 0.005
+    QUIET_MAX = 0.005
+    QUIET_MIN = 0.001
+    QUIET_GAPS = 4
+    QUIET_SPAN = 8
 
     # Fused batches allowed in flight at once (the pipeline depth): the
     # dispatch worker blocks on the (depth+1)'th batch, so the queue
@@ -237,6 +265,15 @@ class CountBatcher:
         self._stopped = False
         self.pipeline = PipelineStats()
         self.pipeline.gauge("depth_configured", self.max_inflight)
+        # How each accumulation window ended (``_drain_loop``).
+        self._closes = {
+            reason: REGISTRY.counter(
+                METRIC_PIPELINE_ACCUM_CLOSE,
+                help="Batcher accumulation windows closed, by how they ended",
+                reason=reason,
+            )
+            for reason in ("quiet", "full", "deadline", "idle_lone")
+        }
 
     # -- accumulate stage ---------------------------------------------------
 
@@ -277,8 +314,9 @@ class CountBatcher:
         """Queue one Count into the pipeline and return its future
         (_Item).  Never takes the direct path — the caller is handing
         off completion (an HTTP deferral), so blocking here would defeat
-        it; a lone async query pays ~one accumulation poll.  A memo hit
-        returns an already-resolved future."""
+        it; a lone async query in an idle pipe is dispatched at once
+        (inside the hot window it waits one quiet interval for peers).
+        A memo hit returns an already-resolved future."""
         key, hit = self._memo_probe(index, call, shards)
         memo_note = self._plan_memo_note(
             getattr(self.engine, "memo_probe", None) is not None, key, hit
@@ -447,10 +485,12 @@ class CountBatcher:
             self._queue.append(item)
             self._ensure_workers()
             # Wake the drain worker on the empty->non-empty transition
-            # only (it polls during accumulation): per-submit notify_all
+            # and for the arrival that fills a drain only (it times its
+            # own sleeps during accumulation): per-submit notify_all
             # was measurable lock churn at ~1k submits/s on a
             # single-core host.
-            if len(self._queue) == 1:
+            n = len(self._queue)
+            if n == 1 or n == self.max_batch:
                 self._cond.notify_all()
         return item
 
@@ -522,41 +562,78 @@ class CountBatcher:
 
     def _drain_loop(self):
         tracing.name_thread("pq-drain")
+        queue = self._queue
         while not self._stopped:
             with self._lock:
-                while not self._queue:
+                while not queue:
                     if self._stopped:
                         return
                     self._cond.wait(timeout=60.0)
-                depth0 = len(self._queue)
-            # A lone queued query in an IDLE pipe (no batch in flight,
-            # outside the hot window) dispatches immediately: the
-            # accumulation window exists to fuse CONCURRENT arrivals,
-            # and a lone caller paying a poll sleep would tax idle
-            # latency for nothing.  But when a batch is already in
-            # flight (``_live``), waiting costs this query nothing — it
-            # could not dispatch ahead of the in-flight batch's slot
-            # anyway — and the window lets its peers pile in.  Without
-            # this, sustained load that happened to arrive one-at-a-time
-            # between drain wakeups would never bootstrap the first
-            # fused batch (the hot window only opens AFTER one).
-            if depth0 > 1 or self._live > 0 or (
-                time.monotonic() - self._last_fused < self.HOT_WINDOW
+                # A lone queued query in an IDLE pipe (no batch in flight,
+                # outside the hot window) dispatches immediately: the
+                # accumulation window exists to fuse CONCURRENT arrivals,
+                # and a lone caller paying a quiet interval would tax idle
+                # latency for nothing.  But when a batch is already in
+                # flight (``_live``), waiting costs this query nothing — it
+                # could not dispatch ahead of the in-flight batch's slot
+                # anyway — and the window lets its peers pile in.  Without
+                # this, sustained load that happened to arrive one-at-a-time
+                # between drain wakeups would never bootstrap the first
+                # fused batch (the hot window only opens AFTER one).
+                if len(queue) > 1 or self._live > 0 or (
+                    time.monotonic() - self._last_fused < self.HOT_WINDOW
+                ):
+                    reason = self._accumulate()
+                else:
+                    reason = "idle_lone"
+                batch = queue[: self.max_batch]
+                del queue[: len(batch)]
+            self._closes[reason].inc()
+            groups = self._plan_drain(batch)
+            # accum_tail: last arrival of the drain -> the drain handed
+            # to the dispatch worker — what the window's close decision
+            # cost the drain beyond its own arrivals.  The wait is the
+            # worker asleep on the condition; the annotation marks the
+            # hand-off, with how the window ended.
+            with tracing.stage(
+                "accum_tail", self._PATHS[groups[0][0]],
+                t0=batch[-1].t_submit, batch=len(batch), reason=reason,
             ):
-                deadline = time.monotonic() + self.ACCUM_WINDOW
-                prev = -1
-                while time.monotonic() < deadline:
-                    with self._lock:
-                        depth = len(self._queue)
-                    if depth >= self.max_batch or depth == prev:
-                        break  # full drain ready, or arrivals went quiet
-                    prev = depth
-                    time.sleep(self.ACCUM_POLL)
-            with self._lock:
-                batch = self._queue[: self.max_batch]
-                del self._queue[: len(batch)]
-            for group in self._plan_drain(batch):
-                self._dispatch_q.put(group + (False,))
+                for group in groups:
+                    self._dispatch_q.put(group + (False,))
+
+    def _accumulate(self) -> str:
+        """Hold the window open until the burst in the queue has gone
+        quiet (the comment over ``ACCUM_WINDOW``); returns how it closed.
+        Called with ``_lock`` held; every wait releases it.  The worker
+        sleeps to ``last + quiet`` and reads the queue again — sooner
+        (every QUIET_MIN) while a burst is still showing its first
+        QUIET_SPAN gaps, each of which shortens the interval."""
+        queue = self._queue
+        deadline = time.monotonic() + self.ACCUM_WINDOW
+        while True:
+            n = len(queue)
+            if n >= self.max_batch:
+                return "full"
+            now = time.monotonic()
+            if now >= deadline:
+                return "deadline"
+            last = queue[-1].t_submit
+            quiet = ceiling = self.QUIET_MAX
+            wake = deadline
+            if self._live == 0:
+                # QUIET_GAPS x the mean of the last QUIET_SPAN gaps; a
+                # gap the burst has not shown yet counts as the ceiling's.
+                span = self.QUIET_SPAN
+                k = min(n - 1, span)
+                shown = self.QUIET_GAPS * (last - queue[-1 - k].t_submit)
+                quiet = (shown + (span - k) * ceiling) / span
+                quiet = min(max(quiet, self.QUIET_MIN), ceiling)
+                if k < span:  # a young burst shows a gap with every arrival
+                    wake = now + self.QUIET_MIN
+            if now >= last + quiet:
+                return "quiet"
+            self._cond.wait(min(last + quiet, wake) - now)
 
     def _plan_drain(self, batch):
         """The whole-program planning stage between accumulate and

@@ -369,6 +369,12 @@ REGISTRY = MetricsRegistry()
 METRIC_QUERY = "pilosa_query_seconds"
 METRIC_QUERY_OP = "pilosa_query_op_seconds"
 METRIC_PIPELINE_STAGE = "pilosa_pipeline_stage_seconds"
+#   pilosa_pipeline_accum_close_total{reason}  how each accumulation window
+#       of the batcher ended: "quiet" (no arrival for the burst's quiet
+#       interval), "full" (max_batch queued), "deadline" (arrivals never
+#       went quiet for ACCUM_WINDOW), "idle_lone" (one query, idle pipe:
+#       no window at all)
+METRIC_PIPELINE_ACCUM_CLOSE = "pilosa_pipeline_accum_close_total"
 # The stage clock (util/tracing.py stage/waited): one series per (path,
 # stage) from socket to socket; the four legacy deferred-path stages also
 # keep feeding METRIC_PIPELINE_STAGE with the values they always had.

@@ -63,7 +63,7 @@ from pilosa_tpu.parallel.batcher import CountBatcher
 from pilosa_tpu.util import plans
 print(json.dumps({
     "PILOSA_BATCH_WINDOW": CountBatcher.ACCUM_WINDOW,
-    "PILOSA_BATCH_POLL": CountBatcher.ACCUM_POLL,
+    "PILOSA_BATCH_POLL": CountBatcher.QUIET_MAX,
     "PILOSA_PLANS": plans.ENABLED,
 }))
 """

@@ -350,6 +350,33 @@ def _hot(batcher):
     batcher._last_fused = time.monotonic() + 10_000
 
 
+def _submit_together(batcher, jobs):
+    """Run each of ``jobs`` (name -> callable, each queueing one item)
+    on a thread of its own, all released at once, and return their
+    results by name.  The accumulation window fuses what arrives
+    inside its quiet interval (5 ms at most), and threads on a busy
+    host can arrive further apart than that: here the window waits
+    for all of them — it closes full at ``len(jobs)``, and until then
+    stays open for the whole ACCUM_WINDOW."""
+    batcher.max_batch = len(jobs)
+    batcher.QUIET_MIN = batcher.QUIET_MAX = batcher.ACCUM_WINDOW
+    gate = threading.Barrier(len(jobs))
+    results = {}
+
+    def run(name, fn):
+        gate.wait(30)
+        results[name] = fn()
+
+    threads = [
+        threading.Thread(target=run, args=job) for job in jobs.items()
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+    return results
+
+
 def test_batcher_heterogeneous_drain(holder, mesh):
     """Concurrent mixed submissions drain into fused programs through
     the real accumulate/dispatch/collect pipeline, bit-exact."""
@@ -362,27 +389,13 @@ def test_batcher_heterogeneous_drain(holder, mesh):
     want_min = eng.min_max("i", "v", _call(SEG), SHARDS, True)
     want_tf = eng.topn_full("i", "w", _call(SEG), SHARDS, 3, 1)
     _hot(b)
-    results = {}
-
-    def run(name, fn):
-        results[name] = fn()
-
-    threads = [
-        threading.Thread(target=run, args=(
-            "count", lambda: b.submit("i", count_q, SHARDS))),
-        threading.Thread(target=run, args=(
-            "sum", lambda: eng.batched_sum("i", "v", _call(SEG), SHARDS))),
-        threading.Thread(target=run, args=(
-            "min", lambda: eng.batched_min_max(
-                "i", "v", _call(SEG), SHARDS, True))),
-        threading.Thread(target=run, args=(
-            "tf", lambda: eng.batched_topn_full(
-                "i", "w", _call(SEG), SHARDS, 3, 1))),
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(60)
+    results = _submit_together(b, {
+        "count": lambda: b.submit("i", count_q, SHARDS),
+        "sum": lambda: eng.batched_sum("i", "v", _call(SEG), SHARDS),
+        "min": lambda: eng.batched_min_max("i", "v", _call(SEG), SHARDS, True),
+        "tf": lambda: eng.batched_topn_full(
+            "i", "w", _call(SEG), SHARDS, 3, 1),
+    })
     assert results["count"] == want_count
     assert results["sum"] == want_sum
     assert results["min"] == want_min
@@ -504,24 +517,20 @@ def test_fused_cost_attribution_weighted_by_footprint(holder, mesh):
         "count": plans_mod.QueryPlan("i", "count"),
         "sum": plans_mod.QueryPlan("i", "sum"),
     }
-    results = {}
 
-    def run(name, fn):
-        with plans_mod.attach(plans[name]):
-            results[name] = fn()
+    def planned(name, fn):
+        def run():
+            with plans_mod.attach(plans[name]):
+                return fn()
+
+        return run
 
     count_q = _call("Intersect(Row(f=11), Row(w=6))")
-    threads = [
-        threading.Thread(target=run, args=(
-            "count", lambda: b.submit("i", count_q, SHARDS))),
-        threading.Thread(target=run, args=(
-            "sum", lambda: eng.batched_sum(
-                "i", "v", _call("Row(f=11)"), SHARDS))),
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(60)
+    _submit_together(b, {
+        "count": planned("count", lambda: b.submit("i", count_q, SHARDS)),
+        "sum": planned("sum", lambda: eng.batched_sum(
+            "i", "v", _call("Row(f=11)"), SHARDS)),
+    })
     assert eng.fused_programs >= 1
     dev_count = plans["count"].device_seconds
     dev_sum = plans["sum"].device_seconds
@@ -759,23 +768,11 @@ def test_cross_index_batcher_pools_one_program(holder, mesh):
     want_sum = eng.sum("i", "v", _call(SEG), SHARDS)
     _hot(b)
     p0 = eng.fused_programs
-    results = {}
-
-    def run(name, fn):
-        results[name] = fn()
-
-    threads = [
-        threading.Thread(target=run, args=(
-            "ci", lambda: b.submit("i", ci, SHARDS))),
-        threading.Thread(target=run, args=(
-            "cj", lambda: b.submit("j", cj, SHARDS))),
-        threading.Thread(target=run, args=(
-            "sum", lambda: eng.batched_sum("i", "v", _call(SEG), SHARDS))),
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(60)
+    results = _submit_together(b, {
+        "ci": lambda: b.submit("i", ci, SHARDS),
+        "cj": lambda: b.submit("j", cj, SHARDS),
+        "sum": lambda: eng.batched_sum("i", "v", _call(SEG), SHARDS),
+    })
     assert results["ci"] == want_i
     assert results["cj"] == want_j
     assert results["sum"] == want_sum

@@ -423,6 +423,7 @@ SURFACE_SERIES = [
     "pilosa_query_seconds_bucket",
     "pilosa_query_op_seconds_bucket",
     "pilosa_pipeline_stage_seconds_bucket",
+    'pilosa_pipeline_accum_close_total{reason="quiet"}',
     "pilosa_fragment_op_seconds_bucket",
     "pilosa_engine_cache_hits_total",
     "pilosa_engine_cache_misses_total",

@@ -154,6 +154,290 @@ def test_inflight_depth_is_bounded():
     assert b.pipeline_snapshot()["gauges"]["inflight_max"] <= 2
 
 
+# -- the accumulation window's close decision ------------------------------
+
+
+def _closes(b, since=None):
+    """The process-wide close counters by reason, or their rise since
+    an earlier reading (no other batcher drains meanwhile)."""
+    return {r: int(c.get()) - (since[r] if since else 0)
+            for r, c in b._closes.items()}
+
+
+class _VirtualWindow:
+    """The real ``CountBatcher._drain_loop`` and ``_submit`` on a virtual
+    clock, in the calling thread: arrivals happen at given times, a wait
+    on the condition jumps the clock to its timeout or to the arrival
+    that notifies, so every close time is exact and no case leans on the
+    container's scheduling.  No stage worker runs: ``live`` and ``hot``
+    are what the test says they are.  ``handoffs`` are (time, arrival
+    times of the drain's items, how the window closed), in order."""
+
+    def __init__(self, monkeypatch, arrivals, live=0, hot=True, max_batch=512):
+        from pilosa_tpu.parallel import batcher as mod
+
+        self.now = 1000.0
+        self.arrivals = [self.now + a for a in arrivals]
+        self.handoffs = []
+        self.notified = False
+        b = self.b = CountBatcher(_StubEngine(), max_batch=max_batch)
+        b._workers_started = True  # the loop below is the only worker
+        b._cond = self
+        b._live = live
+        b._last_fused = float("inf") if hot else 0.0
+        self.call = _call("Row(f=1)")
+        window = self
+
+        class _Clock:
+            @staticmethod
+            def monotonic():
+                return window.now
+
+        class _Stage:
+            def __init__(self, name, path, t0=None, **tags):
+                assert (name, path) == ("accum_tail", "deferred")
+                self.t0, self.tags = t0, tags
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                n = self.tags["batch"]
+                times = window.submitted[window.taken:window.taken + n]
+                window.taken += n
+                assert times[-1] == self.t0  # t0 is the drain's last arrival
+                window.handoffs.append((window.now, times, self.tags["reason"]))
+
+        class _Tracing:
+            stage = _Stage
+            current_span = staticmethod(lambda: None)
+            name_thread = staticmethod(lambda name=None: None)
+
+        self.submitted, self.taken = [], 0
+        monkeypatch.setattr(mod, "time", _Clock)
+        monkeypatch.setattr(mod, "tracing", _Tracing)
+        before = _closes(b)
+        b._drain_loop()
+        self.closes = _closes(b, before)
+
+    def _arrive(self):
+        self.now = self.arrivals.pop(0)
+        self.submitted.append(self.now)
+        self.b._submit("i", self.call, [0], allow_direct=False)
+
+    def notify_all(self):
+        self.notified = True
+
+    def wait(self, timeout=None):
+        b = self.b
+        b._lock.release()
+        try:
+            if timeout is None or timeout >= 60.0:  # the empty queue's wait
+                if not self.arrivals:
+                    b._stopped = True
+                    return
+                self._arrive()
+                return
+            end = self.now + timeout
+            self.notified = False
+            while self.arrivals and self.arrivals[0] <= end and not self.notified:
+                self._arrive()
+            if not self.notified:
+                self.now = end
+        finally:
+            b._lock.acquire()
+
+    def handoff_of_each_item(self):
+        return [t for t, times, _ in self.handoffs for _ in times]
+
+
+def _fixed_poll_rule(arrivals, live, hot, max_batch, window=0.15, poll=0.005):
+    """The window this one replaced, as a reference on the same virtual
+    clock: wake on the first arrival, sleep in fixed ``poll`` steps,
+    close when one whole step passed with the depth unchanged.  Returns
+    each item's hand-off time."""
+    out, taken, now = [], 0, 0.0
+
+    def depth():
+        return sum(1 for a in arrivals[taken:] if a <= now)
+
+    while taken < len(arrivals):
+        now = max(now, arrivals[taken])
+        if depth() > 1 or live or hot:
+            deadline, prev = now + window, -1
+            while now < deadline:
+                d = depth()
+                if d >= max_batch or d == prev:
+                    break
+                prev = d
+                now += poll
+        n = min(depth(), max_batch)
+        out += [now] * n
+        taken += n
+    return out
+
+
+def _burst(n, gap, start=0.0):
+    return [start + k * gap for k in range(n)]
+
+
+def _random_schedule(seed):
+    """Bursts of 1-24 arrivals, 0.05-8 ms apart, 0-40 ms between bursts."""
+    rng = np.random.default_rng(seed)
+    out, t = [], 0.0
+    for _ in range(int(rng.integers(1, 6))):
+        t += float(rng.uniform(0.0, 0.040))
+        gap = float(rng.choice([5e-5, 3e-4, 5e-4, 2e-3, 4.9e-3, 5.1e-3, 8e-3]))
+        for _ in range(int(rng.integers(1, 25))):
+            out.append(t)
+            t += gap * float(rng.uniform(0.5, 1.5))
+    return out
+
+
+_MS = 1e-3
+# (arrivals, live, hot, max_batch) -> what the window must have done.
+_WINDOW_CASES = {
+    # (i) a burst of 16 at 0.5 ms gaps into an idle pipe: ONE drain of 16,
+    # closed a quiet interval of 4 gaps after its last arrival — inside
+    # the ceiling, well under the 5-10 ms the fixed poll took.
+    "burst16_idle": dict(
+        arrivals=_burst(16, 0.5 * _MS), sizes=[16], reasons=["quiet"],
+        tails=[2.0 * _MS],
+    ),
+    # The floor: gaps far under a thread wake-up still wait QUIET_MIN.
+    "burst16_idle_tiny_gaps": dict(
+        arrivals=_burst(16, 0.02 * _MS), sizes=[16], reasons=["quiet"],
+        tails=[CountBatcher.QUIET_MIN],
+    ),
+    # A burst earns the short patience over its first QUIET_SPAN gaps (one
+    # it has not shown counts as the ceiling's): two arrivals that happen
+    # to come 50 us apart do not send the pair off ahead of their peers.
+    "early_gaps_do_not_split": dict(
+        arrivals=[0.0, 0.05 * _MS, 1.5 * _MS, 1.55 * _MS, 3.0 * _MS],
+        sizes=[5], reasons=["quiet"], tails=[4.0 * _MS],
+    ),
+    # (ii) arrivals at least a ceiling apart close a ceiling after each:
+    # never later than the fixed poll did, idle or busy.
+    "sparse_idle": dict(
+        arrivals=_burst(4, 6.0 * _MS), sizes=[1, 1, 1, 1],
+        reasons=["quiet"] * 4, tails=[CountBatcher.QUIET_MAX] * 4,
+    ),
+    "sparse_busy": dict(
+        arrivals=_burst(4, 6.0 * _MS), live=1, sizes=[1, 1, 1, 1],
+        reasons=["quiet"] * 4, tails=[CountBatcher.QUIET_MAX] * 4,
+    ),
+    # (iii) while a batch is in flight the window keeps the ceiling's
+    # patience: two runs 2.5 ms apart coalesce into one drain, as they did
+    # under the fixed poll (an idle pipe lets the first run go).
+    "pause_busy_coalesces": dict(
+        arrivals=_burst(10, 0.3 * _MS) + _burst(5, 0.3 * _MS, 5.2 * _MS),
+        live=1, sizes=[15], reasons=["quiet"], tails=[CountBatcher.QUIET_MAX],
+    ),
+    "pause_idle_lets_go": dict(
+        arrivals=_burst(10, 0.3 * _MS) + _burst(5, 0.3 * _MS, 5.2 * _MS),
+        sizes=[10, 5], reasons=["quiet", "quiet"], tails=[1.2 * _MS, 3.1 * _MS],
+    ),
+    # (iv) continuous arrivals run to max_batch (the filling arrival wakes
+    # the worker: no tail) or to the outer deadline, as before.
+    "continuous_full": dict(
+        arrivals=_burst(192, 0.2 * _MS), max_batch=64, sizes=[64, 64, 64],
+        reasons=["full"] * 3, tails=[0.0] * 3,
+    ),
+    "continuous_deadline_busy": dict(
+        arrivals=_burst(100, 2.0 * _MS), live=1, sizes=[76, 24],
+        reasons=["deadline", "quiet"], tails=[0.0, CountBatcher.QUIET_MAX],
+    ),
+    "continuous_deadline_idle": dict(
+        arrivals=_burst(100, 2.0 * _MS), sizes=[76, 24],
+        reasons=["deadline", "quiet"], tails=[0.0, CountBatcher.QUIET_MAX],
+    ),
+    # (v) a lone query in an idle pipe leaves at once: no window, no tail.
+    "lone_idle": dict(
+        arrivals=[0.0], hot=False, sizes=[1], reasons=["idle_lone"],
+        tails=[0.0],
+    ),
+    # Inside the hot window a lone query waits the ceiling for peers.
+    "lone_hot": dict(
+        arrivals=[0.0], sizes=[1], reasons=["quiet"],
+        tails=[CountBatcher.QUIET_MAX],
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+def test_accumulation_window_close_decision(case, monkeypatch):
+    spec = dict(_WINDOW_CASES[case])
+    arrivals = spec["arrivals"]
+    live, hot = spec.get("live", 0), spec.get("hot", True)
+    max_batch = spec.get("max_batch", 512)
+    w = _VirtualWindow(monkeypatch, arrivals, live, hot, max_batch)
+    assert [len(times) for _, times, _ in w.handoffs] == spec["sizes"]
+    assert [reason for _, _, reason in w.handoffs] == spec["reasons"]
+    tails = [t - times[-1] for t, times, _ in w.handoffs]
+    assert tails == pytest.approx(spec["tails"], abs=1e-9)
+    assert sum(w.closes.values()) == len(w.handoffs)
+    for reason in set(spec["reasons"]):
+        assert w.closes[reason] == spec["reasons"].count(reason)
+    # No window outlasts the ceiling after its last arrival, and no item
+    # is handed off later than the fixed poll handed it off.
+    assert max(tails) <= CountBatcher.QUIET_MAX + 1e-9
+    was = _fixed_poll_rule(arrivals, live, hot, max_batch)
+    now = [t - 1000.0 for t in w.handoff_of_each_item()]
+    assert len(now) == len(was) == len(arrivals)
+    assert all(n <= o + 1e-9 for n, o in zip(now, was)), (now, was)
+
+
+@pytest.mark.parametrize("live", [0, 1], ids=["idle", "busy"])
+def test_accumulation_window_never_later_than_fixed_poll(live, monkeypatch):
+    """Over 150 random schedules of bursts and pauses: every item leaves
+    no later than the fixed poll would have sent it, and while a batch is
+    in flight the drains are never more, nor smaller, than they were."""
+    for seed in range(150):
+        arrivals = _random_schedule(seed)
+        with monkeypatch.context() as mp:
+            w = _VirtualWindow(mp, arrivals, live=live, max_batch=64)
+        was = _fixed_poll_rule(arrivals, live, True, 64)
+        now = [t - 1000.0 for t in w.handoff_of_each_item()]
+        assert len(now) == len(arrivals), seed
+        late = [(n, o) for n, o in zip(now, was) if n > o + 1e-9]
+        assert not late, (seed, late[:3])
+
+
+def test_accumulation_window_on_real_threads():
+    """The same decision with the real condition, clock and workers:
+    sixteen back-to-back submits into an idle pipe, then a lone one
+    after the hot window.  Only what no scheduling hiccup can change is
+    asserted: everything resolves, each window is counted once with its
+    reason, and the stage clock saw one ``accum_tail`` a drain."""
+    from pilosa_tpu.util.stats import METRIC_QUERY_STAGE, REGISTRY
+
+    eng = _StubEngine()
+    eng.release.set()
+    b = CountBatcher(eng)
+    hist = REGISTRY.histogram(METRIC_QUERY_STAGE, path="deferred", stage="accum_tail")
+    count0 = hist.snapshot()["count"]
+    before = _closes(b)
+    try:
+        items = [b.submit_async("i", _call(f"Row(f={k})"), [0]) for k in range(16)]
+        for k, it in enumerate(items):
+            assert it.event.wait(30) and it.error is None and it.result == k
+        time.sleep(CountBatcher.HOT_WINDOW + 0.1)
+        lone = b.submit_async("i", _call("Row(f=99)"), [0])
+        assert lone.event.wait(30) and lone.result == 99
+        closes = _closes(b, before)
+        assert closes["idle_lone"] >= 1  # the lone one, at least
+        assert closes["full"] == closes["deadline"] == 0
+        assert sum(closes.values()) == b.batches
+        assert b.batched_queries == 17
+        deadline = time.monotonic() + 10  # the stage ends after the hand-off
+        while hist.snapshot()["count"] - count0 < b.batches:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        assert hist.snapshot()["count"] - count0 == b.batches
+    finally:
+        b.stop()
+
+
 # -- signature regression (satellite: literal-only masking) ----------------
 
 
