@@ -3,8 +3,9 @@
 reads: device busy seconds (the union of the intervals in which an
 operation ran, averaged over the device planes), the span from the first
 operation's start to the last one's end, the summed run time of the
-programs (XLA modules), the device operations by total time, the longest
-idle gaps, and each program's executions.  Runs as a short-lived child with
+programs (XLA modules), the seconds of cross-chip collectives among the
+operations, the device operations by total time, the longest idle gaps,
+and each program's executions.  Runs as a short-lived child with
 JAX_PLATFORMS=cpu after the server has exited; prints one JSON line.
 
 An empty device plane is an error: there is no fallback to a host clock.
@@ -18,6 +19,10 @@ import sys
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# HLO's cross-chip operations, by the start of an op's own name, which the
+# TPU's trace writes as "%all-reduce" (an asynchronous one is a "-start"
+# and a "-done" event: both count)
+COLLECTIVES = ("all-reduce", "all-gather", "collective-permute", "reduce-scatter", "all-to-all")
 
 
 def union_seconds(intervals: list) -> tuple:
@@ -59,7 +64,7 @@ def reduce(trace_dir: str, allow_host: bool) -> dict:
             host = [iv for name, evs in lines.items() for iv in evs
                     if name.startswith("tf_XLAPjRtCpuClient")]
             planes.append({OPS_LINE: host, MODULES_LINE: host})
-    busy, spans, totals, gaps, modules = [], [], {}, [], {}
+    busy, spans, totals, gaps, modules, collective_s = [], [], {}, [], {}, 0.0
     for lines in planes:
         ops = lines.get(OPS_LINE) or lines.get(MODULES_LINE) or []
         if not ops:
@@ -70,6 +75,8 @@ def reduce(trace_dir: str, allow_host: bool) -> dict:
         gaps.extend(g)
         for s, e, name in ops:
             totals[name] = totals.get(name, 0.0) + (e - s) / 1e9
+            if name.lstrip("%").startswith(COLLECTIVES) and OPS_LINE in lines:
+                collective_s += (e - s) / 1e9
         for s, e, name in lines.get(MODULES_LINE, []):
             modules.setdefault(name.split("(")[0], []).append((e - s) / 1e9)
     if not busy or sum(busy) <= 0:
@@ -84,6 +91,7 @@ def reduce(trace_dir: str, allow_host: bool) -> dict:
         "device_planes": n,
         "program_s": (sum(sum(v) for v in modules.values()) or sum(busy)) / n,
         "modules": {k: {"n": len(v), "seconds": sum(v)} for k, v in modules.items()},
+        "collective_s": collective_s / n,
         "breakdown": {
             "device_ops": [[k, v / n] for k, v in top],  # the three longest programs first
             "idle_gaps": [[f"unattributed, after {name}", g]

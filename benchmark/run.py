@@ -9,9 +9,12 @@ deployment through ``import-roaring`` while it adds each shard's raw
 columns into the joint table (the plain reference), warms up with the mix
 itself, measures the window with the mix's loop, stops the child, and
 compares every reply of the window with the table.  The last line of
-stdout is the result.  Everything that belongs to one configuration, mix,
-template or per-layer metric lives in a file of its own that this program
-finds by the name in BENCHMARK.json (benchmark/README.md).
+stdout is the result.  However the run ends (its end, a failure, its
+deadline, a signal), every process it started is ended first, and a run
+that cannot end one fails naming it (lib/served.py).  Everything that
+belongs to one configuration, mix, template or per-layer metric lives in a
+file of its own that this program finds by the name in BENCHMARK.json
+(benchmark/README.md).
 
 This process never imports JAX.  A server that is not on ``tpu`` with the
 cell's number of chips ends the run non-zero with no result; ``--rehearse``
@@ -54,6 +57,7 @@ TRACED_S = 3.0  # the loop's run inside the trace; the rest is its tail
 DEADLINE_S = 1150  # the contract allows 1200 for a run that compiles
 REHEARSAL_SHARDS = 8
 SERVER_ARGV = [sys.executable, "-m", "pilosa_tpu", "server"]
+ENDING_SIGNALS = (signal.SIGALRM, signal.SIGTERM, signal.SIGINT, signal.SIGHUP)
 
 
 def load_module(path: str):
@@ -166,13 +170,18 @@ class Traffic:
 
 
 def create_schema(client: Client, cfg: dict):
-    client.call("POST", f"/index/{cfg['index']}", b"{}")
+    """The configuration's index and fields.  Its optional ``index_options``
+    is the body of the index's POST; a set field's optional ``options``
+    (``cacheType``, ``cacheSize``, ``keys``, ``timeQuantum``) is its
+    field's, and an int field's range always is."""
+    index_body = {"options": cfg["index_options"]} if "index_options" in cfg else {}
+    client.call("POST", f"/index/{cfg['index']}", json.dumps(index_body).encode())
     for f in cfg["fields"]:
-        opts = {}
+        opts = dict(f.get("options", {}))
         if f["type"] == "int":
-            opts = {"options": {"type": "int", "min": f["min"], "max": f["max"]}}
+            opts.update(type="int", min=f["min"], max=f["max"])
         client.call("POST", f"/index/{cfg['index']}/field/{f['name']}",
-                    json.dumps(opts).encode())
+                    json.dumps({"options": opts} if opts else {}).encode())
 
 
 def ingest(cell: Cell, seed: int, shards: int, port: int, child, lost=None):
@@ -343,12 +352,10 @@ def run(args, cell: Cell, tmp: str, port: int, child) -> dict:
     served.wait_ready(admin)
     mesh = admin.debug_vars()["mesh"]
     log(f"server mesh: {mesh['platform']} / {mesh['deviceKind']} x {mesh['devices']}")
-    if args.rehearse:
-        if mesh["platform"] != "cpu":
-            raise BenchFailure("--rehearse is the CPU rehearsal")
-    elif mesh["platform"] != "tpu" or mesh["devices"] != cell.workload["chips"]:
+    platform = "cpu" if args.rehearse else "tpu"  # --rehearse is the CPU rehearsal
+    if mesh["platform"] != platform or mesh["devices"] != cell.workload["chips"]:
         raise BenchFailure(f"server runs on {mesh['platform']} x {mesh['devices']}, "
-                           f"the cell asks for tpu x {cell.workload['chips']}")
+                           f"the cell asks for {platform} x {cell.workload['chips']}")
     peaks = load_json(HERE, "lib", "peaks.json")
     if mesh["deviceKind"] not in peaks and not args.rehearse:
         raise BenchFailure(f"device kind {mesh['deviceKind']!r} is not in lib/peaks.json")
@@ -392,7 +399,7 @@ def run(args, cell: Cell, tmp: str, port: int, child) -> dict:
     resident = int(m1.get("pilosa_engine_resident_bytes", 0))
     loop.close()
     admin.close()
-    served.stop_server(child)  # frees the chip before the reference and the reducer run
+    served.stop_server(child, port)  # frees the chip before the reference and the reducer run
 
     verdict = judge(cell, exchanges, table)
 
@@ -516,16 +523,23 @@ def main(argv=None, server_argv=None) -> int:
         raise BenchFailure(f"no pilosa_tpu package in {ROOT}")
     cell = Cell(args.workload, args.traffic)
 
-    def on_alarm(signum, frame):
-        raise BenchFailure(f"not done after {DEADLINE_S} s")
+    def on_signal(signum, frame):
+        """Whatever ends the run from outside ends it through the
+        ``finally`` below; the first signal is the one that counts."""
+        for s in ENDING_SIGNALS:
+            signal.signal(s, signal.SIG_IGN)
+        raise BenchFailure(f"not done after {DEADLINE_S} s" if signum == signal.SIGALRM
+                           else f"ended by {signal.Signals(signum).name}")
 
-    signal.signal(signal.SIGALRM, on_alarm)
+    for s in ENDING_SIGNALS:
+        signal.signal(s, on_signal)
     signal.alarm(DEADLINE_S)
     port = served.free_port()
     tmp = tempfile.mkdtemp(prefix="pilosa_bench_")
     server_log = os.path.join(tmp, "server.log")
     child = served.start_server(server_argv or SERVER_ARGV, ROOT,
-                                os.path.join(tmp, "data"), port, server_log, args.rehearse)
+                                os.path.join(tmp, "data"), port, server_log,
+                                cpu_devices=cell.workload["chips"] if args.rehearse else 0)
     try:
         result = run(args, cell, tmp, port, child)
     except BaseException:
@@ -535,8 +549,12 @@ def main(argv=None, server_argv=None) -> int:
         raise
     finally:
         signal.alarm(0)
-        served.stop_server(child)
-        shutil.rmtree(tmp, ignore_errors=True)
+        for s in ENDING_SIGNALS:  # nothing cuts the last sweep short
+            signal.signal(s, signal.SIG_IGN)
+        try:
+            served.stop_server(child, port)  # a run that leaves a process has no result
+        finally:
+            shutil.rmtree(tmp, ignore_errors=True)
     for name, c in result["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['limit']})", file=sys.stderr)
     sys.stderr.flush()

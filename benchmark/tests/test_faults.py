@@ -9,11 +9,17 @@ Not collected by ``pytest tests/``: run ``python -m pytest benchmark/tests -q``
   read back — comes out not correct, while the program's own replies in
   the same window are all right;
 * a fault planted under the timed path — an answer altered where it is
-  produced; half of the shards left out — comes out not correct.
+  produced; half of the shards left out; in a cell on several chips, the
+  exchange between chips left out — comes out not correct.
 
-A step that returns its state unchanged and an exchange between chips left
-out are not faults these cells can have: neither writes in the window,
-and both run on one chip.
+The cells and their chips are read from BENCHMARK.json.  A cell on four
+chips is rehearsed on four devices of the CPU backend (4 shards, one a
+device), so its psum is a real all-reduce here; no cell asks for four yet
+(PERF.md, Open questions 000), and on one device the exchange fault reads
+right, so it has no case.  A step that returns its state unchanged is
+not a fault these cells can have: none writes in the window.  After
+every run no process of it is left (test_process_end.py has the ways a
+run can end badly).
 """
 
 import json
@@ -25,18 +31,21 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
-CELLS = ("taxi.segment_count", "ssb.flight1_stream")
+with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    CHIPS = {w["name"]: w["chips"] for w in json.load(f)["workloads"]}
+CELLS = {w: 2 if chips == 1 else chips for w, chips in CHIPS.items()}  # cell: shards
 
 
 def run_cell(workload, *extra, server=None):
     code = (
         "import sys; sys.path.insert(0, 'benchmark'); import run; "
-        f"sys.exit(run.main({['--workload', workload, '--seed', '2147483659', '--seconds', '3', '--trace', '0', '--rehearse', '--shards', '2', *extra]!r}, "
+        f"sys.exit(run.main({['--workload', workload, '--seed', '2147483659', '--seconds', '3', '--trace', '0', '--rehearse', '--shards', str(CELLS[workload]), *extra]!r}, "
         f"server_argv={server!r}))"
     )
     p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, capture_output=True,
                        text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
+    assert "end of run: 1 process(es) ended" in p.stderr and "SIGKILL not needed" in p.stderr
     return json.loads(p.stdout.splitlines()[-1]), p.stderr
 
 
@@ -57,8 +66,9 @@ def test_control_is_not_correct(workload):
     assert "check wrong_answers:" in err.splitlines()[-3]
 
 
-@pytest.mark.parametrize("fault", ("answer", "half"))
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload,fault", [
+    *((w, f) for f in ("answer", "half") for w in CELLS),
+    *((w, "exchange") for w in CELLS if CHIPS[w] > 1)])
 def test_fault_is_not_correct(workload, fault):
     server = [sys.executable, os.path.join(HERE, "faulty_server.py"), fault, "server"]
     result, _ = run_cell(workload, server=server)
