@@ -540,6 +540,7 @@ class Executor:
         from ..parallel.singleflight import SingleFlight
 
         self._sflight = SingleFlight()
+        self._group_rows_cache: Dict[tuple, tuple] = {}
         # Remote fan-out tally: one per peer RPC issued by the mapper.
         # With capacity-weighted ownership (cluster.place_partition) a
         # query whose shards are all locally owned must leave this at 0
@@ -2402,14 +2403,10 @@ class Executor:
             if filter_call is not None:
                 qsig += "|flt:" + str(filter_call)
             key, hit = probe(index, qsig, fields, filter_call, shards)
-        row_lists = []
-        for f in fields:
-            rows = set()
-            for s in shards:
-                frag = self.holder.fragment(index, f, VIEW_STANDARD, s)
-                if frag is not None:
-                    rows.update(frag.row_ids())
-            row_lists.append(sorted(rows))
+        with tracing_mod.stage("group_rows"):
+            row_lists = [
+                self._group_rows(index, f, shards) for f in fields
+            ]
         if any(not rows for rows in row_lists):
             return set(shards), []
         shape = tuple(len(rows) for rows in row_lists)
@@ -2447,29 +2444,61 @@ class Executor:
         if counts is None:
             return None
         limit_arg, has_limit = c.uint_arg("limit")
-        limit = limit_arg if has_limit else _MAXINT
-        results: List[GroupCount] = []
-        # np.ndindex walks the count tensor in row-major order — exactly
-        # the nested-iterator order of the reference (executor.go:2726),
-        # so the progressive limit truncation matches.
-        counts = np.asarray(counts).reshape(
-            tuple(len(rows) for rows in row_lists)
-        )
-        for combo in np.ndindex(counts.shape):
-            n = int(counts[combo])
-            if n > 0:
-                results.append(
-                    GroupCount(
-                        [
-                            FieldRow(fields[d], row_lists[d][combo[d]])
-                            for d in range(len(fields))
-                        ],
-                        n,
-                    )
-                )
-            if len(results) >= limit:
-                break
+        with tracing_mod.stage("group_decode"):
+            # np.nonzero walks the count tensor in row-major order —
+            # exactly the nested-iterator order of the reference
+            # (executor.go:2726), so cutting at ``limit`` non-zero groups
+            # is the progressive limit truncation.  One Python step a
+            # group that is returned, none a combination.
+            counts = np.asarray(counts).reshape(
+                tuple(len(rows) for rows in row_lists)
+            )
+            hit_idx = np.nonzero(counts > 0)
+            if has_limit:
+                hit_idx = tuple(ix[:limit_arg] for ix in hit_idx)
+            ns = counts[hit_idx].tolist()
+            # One FieldRow a (field, row), shared by the groups it is in:
+            # a reply of 3,570 groups allocates 2 objects a group, not 8
+            # (what a FieldRow says depends on its field and row alone).
+            cols = []
+            for f, rows, ix in zip(fields, row_lists, hit_idx):
+                frs = [FieldRow(f, r) for r in rows]
+                cols.append([frs[i] for i in ix.tolist()])
+            results = [
+                GroupCount(list(group), n) for group, n in zip(zip(*cols), ns)
+            ]
         return set(shards), results
+
+    # (index, field, shards) -> (version token, sorted row ids): the
+    # GroupBy axes of a field over a shard set, kept until the field's
+    # standard view or the index's shard set changes.
+    GROUP_ROWS_CACHE = 64
+
+    def _group_rows(self, index, field, shards) -> List[int]:
+        """Sorted distinct row ids of ``field`` over ``shards``: the
+        axis of a GroupBy's count tensor.  The walk is one
+        ``frag.row_ids()`` a shard; a request that finds the view at
+        the version it was walked at takes the list as it is."""
+        idx = self.holder.index(index)
+        f = idx.field(field) if idx is not None else None
+        view = f.views.get(VIEW_STANDARD) if f is not None else None
+        if view is None:
+            return []
+        token = (self.holder.shard_epoch(index), view.gen, view.version)
+        key = (index, field, tuple(shards))
+        hit = self._group_rows_cache.get(key)
+        if hit is not None and hit[0] == token:
+            return hit[1]
+        rows = set()
+        for s in shards:
+            frag = self.holder.fragment(index, field, VIEW_STANDARD, s)
+            if frag is not None:
+                rows.update(frag.row_ids())
+        out = sorted(rows)
+        if len(self._group_rows_cache) >= self.GROUP_ROWS_CACHE:
+            self._group_rows_cache.clear()
+        self._group_rows_cache[key] = (token, out)
+        return out
 
     def _execute_group_by_shard(
         self, index, c: Call, filter_call, shard, child_rows

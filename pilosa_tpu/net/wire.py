@@ -9,6 +9,9 @@ TopN pairs -> [{id|key, count}], Rows -> {rows|keys}, GroupBy ->
 
 from __future__ import annotations
 
+import json
+from typing import Optional
+
 from ..core.row import Row
 from ..executor import FieldRow, GroupCount, RowIdentifiers, ValCount
 
@@ -102,6 +105,11 @@ def fast_results_bytes(results, trace_id=None) -> bytes:
                 )
                 + "]"
             )
+    return _response_bytes(parts, trace_id)
+
+
+def _response_bytes(parts, trace_id) -> bytes:
+    """``{"results": [<parts>], "traceID": ...}`` as json.dumps spaces it."""
     body = '{"results": [' + ", ".join(parts) + "]"
     if trace_id:
         body += f', "traceID": "{trace_id}"'
@@ -119,8 +127,54 @@ def count_response_bytes(resp, trace_id=None):
     column attributes — callers fall back to the generic encoder."""
     results = fast_result_values(resp)
     if results is None:
-        return None
+        return group_response_bytes(resp, trace_id)
     return fast_results_bytes(results, trace_id)
+
+
+def _group_list_json(groups) -> Optional[str]:
+    """Exact ``json.dumps`` text of one GroupBy result over row ids
+    (``[g.to_dict() for g in groups]``), or None when a group carries a
+    row key or the groups do not share their fields.  One format string
+    a result and one pass a column: a reply of thousands of groups (taxi
+    query 4: 3,570, half a megabyte) skips a dict per group and per
+    field and the generic encoder's walk over them.  Each distinct
+    FieldRow is looked at once (the executor's device path shares one
+    per field and row among the groups)."""
+    if set(map(type, groups)) != {GroupCount}:
+        return None
+    rows = [g.group for g in groups]
+    fields = [fr.field for fr in rows[0]]
+    if set(map(len, rows)) != {len(fields)}:
+        return None
+    cols = []
+    for d, f in enumerate(fields):
+        col = [r[d] for r in rows]
+        for fr in {id(fr): fr for fr in col}.values():
+            if fr.row_key or fr.field != f:
+                return None
+        cols.append([fr.row_id for fr in col])
+    cols.append([g.count for g in groups])
+    fmt = '{"group": [' + ", ".join(
+        '{"field": %s, "rowID": %%d}' % json.dumps(f).replace("%", "%%")
+        for f in fields
+    ) + '], "count": %d}'
+    return "[" + ", ".join([fmt % t for t in zip(*cols)]) + "]"
+
+
+def group_response_bytes(resp, trace_id=None):
+    """``count_response_bytes`` for a response whose every result is a
+    non-empty GroupBy list over row ids; None otherwise."""
+    if resp.column_attr_sets is not None or not resp.results:
+        return None
+    parts = []
+    for r in resp.results:
+        if type(r) is not list or not r or type(r[0]) is not GroupCount:
+            return None
+        text = _group_list_json(r)
+        if text is None:
+            return None
+        parts.append(text)
+    return _response_bytes(parts, trace_id)
 
 
 def result_from_json(call_name: str, doc):

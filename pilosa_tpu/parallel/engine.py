@@ -68,6 +68,7 @@ from ..util.stats import (
     METRIC_ENGINE_FUSED_MASKS_REF,
     METRIC_ENGINE_FUSED_PROGRAMS,
     METRIC_ENGINE_FUSED_QUERIES,
+    METRIC_ENGINE_GROUP_COMBOS,
     METRIC_ENGINE_PROMOTIONS,
     METRIC_ENGINE_REBUILDS,
     METRIC_ENGINE_RESIDENT_BLOCK_FRACTION,
@@ -897,6 +898,9 @@ class MeshEngine:
         self.sparse_enabled = True
         # Pallas block-DMA form: TPU backends only (_dispatch_sparse).
         self._sparse_pallas = jax.default_backend() == "tpu"
+        # Likewise the GroupBy program's Pallas body
+        # (kernels.group_counts_local).
+        self._group_pallas = self._sparse_pallas
         self.sparse_dispatches = 0
         self.device_bytes_skipped = 0
         # Versioned result memo: fused Counts repeated against unchanged
@@ -972,6 +976,9 @@ class MeshEngine:
         }
         self._bytes_skipped_counter = REGISTRY.counter(
             METRIC_DEVICE_BYTES_SKIPPED
+        )
+        self._group_combos_counter = REGISTRY.counter(
+            METRIC_ENGINE_GROUP_COMBOS
         )
         # (op, path) -> the four drain-record counter handles.
         self._drain_counters: Dict[tuple, tuple] = {}
@@ -1084,6 +1091,21 @@ class MeshEngine:
             self._collect_row_hints(index, filter_call, hints)
         planes = self._hint_planes(hints)
         return self._note_drain(op, "aggregate", 1, 1, planes, planes)
+
+    def _note_group(self, index, fields, row_lists, filter_call,
+                    groups: int) -> dict:
+        """Drain record of a solo GroupBy: one request, one slot, every
+        group row's plane plus the filter's; counts the combinations
+        the device evaluates."""
+        hints: dict = {}
+        for fname, rows in zip(fields, row_lists):
+            hints[(index, fname, VIEW_STANDARD)] = set(rows)
+        if filter_call is not None:
+            self._collect_row_hints(index, filter_call, hints)
+        planes = self._hint_planes(hints)
+        self._group_combos_counter.inc(groups)
+        plans_mod.note_dispatch(groups=int(groups))
+        return self._note_drain("GroupBy", "group", 1, 1, planes, planes)
 
     def _fetch(self, dev, since: Optional[float] = None):
         """The blocking readback of a sync wrapper: the ``device_get``
@@ -4677,11 +4699,13 @@ class MeshEngine:
             pairs = pairs[: int(n)]
         return pairs
 
-    # Fused GroupBy combination cap: prod(K_i) above this falls back to
-    # the host iterator.  The [C, S, W] intersection tensor is virtual
-    # under XLA's reduce fusion, but the count OUTPUT (int32[C],
-    # replicated) and compile time grow with C, so bound it.
-    MAX_GROUP_COMBOS = 1024
+    # Bound on the count TENSOR of one GroupBy, in groups: int32[groups]
+    # is read back whole and walked by the executor (np.nonzero), 4 MiB
+    # and a few ms at this size.  Nothing in the program grows with the
+    # group count (kernels.group_tree), so this is no compile-time cap;
+    # past it the host iterator answers, whose progressive ``limit``
+    # never builds the tensor.
+    MAX_GROUPS = 1 << 20
 
     def group_counts_async(
         self,
@@ -4692,19 +4716,19 @@ class MeshEngine:
         shards: List[int],
         broadcast: bool = True,
     ):
-        """Fused GroupBy dispatch with the int32[K1, ..., Kn] count
-        tensor left on device; returns None when the fused path doesn't
-        apply (no shards, peerless multi-process mesh, or combination
-        count over MAX_GROUP_COMBOS — the host iterator handles
-        overflow)."""
+        """GroupBy dispatch (kernels.group_tree) with the
+        int32[K1, ..., Kn] count tensor left on device; returns None
+        when the device path doesn't apply (no shards, peerless
+        multi-process mesh, a missing stack, or a count tensor over
+        MAX_GROUPS)."""
         if broadcast and self._peerless_multiproc:
             return None
         if not fields:
             raise ValueError("fused GroupBy requires at least one field")
-        combos = 1
+        groups = 1
         for rows in row_lists:
-            combos *= max(len(rows), 1)
-        if combos > self.MAX_GROUP_COMBOS:
+            groups *= max(len(rows), 1)
+        if groups > self.MAX_GROUPS:
             return None
         canonical = self.canonical_shards(index)
         if not canonical:
@@ -4736,19 +4760,25 @@ class MeshEngine:
         extra_specs = (P(),) * len(extra_ops)
 
         def dispatch():
-            lw = _Lowering(self, canonical)
-            prog = self._lower_filter(index, filter_call, lw)
-            self._note_fused_dispatch()
-            return kernels.groupn_tree(
-                self.mesh,
-                prog,
-                extra_specs + tuple(lw.specs),
-                tuple(statics),
-                mask,
-                *[st.matrix for st in stacks],
-                *extra_ops,
-                *lw.operands,
-            )
+            with tracing.stage("lower"):
+                lw = _Lowering(self, canonical)
+                prog = self._lower_filter(index, filter_call, lw)
+                self._note_fused_dispatch()
+                drain = self._note_group(
+                    index, fields, row_lists, filter_call, groups
+                )
+            with tracing.stage("dispatch", **drain):
+                return kernels.group_tree(
+                    self.mesh,
+                    prog,
+                    extra_specs + tuple(lw.specs),
+                    tuple(statics),
+                    self._group_pallas,
+                    mask,
+                    *[st.matrix for st in stacks],
+                    *extra_ops,
+                    *lw.operands,
+                )
 
         return self._collective(
             "group",
@@ -4772,11 +4802,12 @@ class MeshEngine:
         filter_call: Optional[Call],
         shards: List[int],
     ):
-        """Fused GroupBy over 1 or 2 Rows children: every group combination
-        counted in ONE sharded dispatch — row gathers and the filter tree
-        evaluate in-body (BASELINE config #5's 8-way GroupBy+Count shard
-        reduce).  Returns int32[Ka(,Kb)] counts in row-id order, over the
-        requested shard subset only."""
+        """GroupBy over any number of Rows children: every group
+        combination counted in ONE sharded dispatch — row gathers and the
+        filter tree evaluate in-body (BASELINE config #5's 8-way
+        GroupBy+Count shard reduce).  Returns int32[K1, ..., Kn] counts
+        in row-id order, over the requested shard subset only, or None
+        where ``group_counts_async`` declines."""
         dev = self.group_counts_async(index, fields, row_lists, filter_call, shards)
         if dev is None:
             return None

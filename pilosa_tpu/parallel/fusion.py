@@ -347,6 +347,11 @@ def dispatch(engine, plan: FusedPlan) -> FusedDispatch:
             for ekind, n in plan.edge_kinds.items():
                 if n:
                     edge_counter(ekind).inc(n)
+        combos = sum(
+            n.get("fusedGroupBy", 0) for n in plan.item_notes if n
+        )
+        if combos:  # per request, as group_counts_async counts them
+            engine._group_combos_counter.inc(combos)
     return FusedDispatch(
         (fused_out, tuple(extras)), plan.decoders, plan.weights,
         plan.item_notes, plan.errors,
@@ -756,10 +761,10 @@ def build(engine, entries: List[tuple]) -> FusedPlan:
                 combos = 1
                 for rows in row_lists:
                     combos *= max(len(rows), 1)
-                if combos > engine.MAX_GROUP_COMBOS:
-                    # Same overflow contract as group_counts_async: the
-                    # host iterator handles it (DECLINED -> None at the
-                    # batched entry point).
+                if combos > engine.MAX_GROUPS:
+                    # Same bound as group_counts_async, on the count
+                    # tensor read back: the host iterator handles it
+                    # (DECLINED -> None at the batched entry point).
                     routes[i] = ("const", DECLINED)
                     continue
                 g_mats = []
@@ -777,7 +782,7 @@ def build(engine, entries: List[tuple]) -> FusedPlan:
                     t = tuple(stack.row_index.get(r, 0) for r in rows)
                     # Gather-free whole-row-table lists stay static
                     # compile keys; arbitrary subsets ride traced
-                    # operands (groupn_tree's idx_specs discipline).
+                    # operands (group_tree's idx_specs discipline).
                     if kernels.gather_free(t):
                         g_idx.append(t)
                     else:
@@ -802,7 +807,8 @@ def build(engine, entries: List[tuple]) -> FusedPlan:
                     top_slot[i] = ms
                 i_mask = lw.add_mask(engine._mask_words(shards, canonical))
                 edge = (
-                    "group", ms, i_mask, tuple(g_mats), tuple(g_idx)
+                    "group", ms, i_mask, tuple(g_mats), tuple(g_idx),
+                    engine._group_pallas,
                 )
                 ekey = edge + (
                     tuple(fields),

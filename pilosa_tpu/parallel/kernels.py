@@ -24,7 +24,11 @@ slower on v5e (95 vs 705 GB/s effective).  Padding shards are zero.  ``mask`` is
 
 These are plain-XLA programs: the batched Count program reads its
 planes at 89 % of a v5e's HBM roofline (PERF.md §5, taxi cell), and no
-hand-written kernel layer is kept for the dense sweep.
+hand-written kernel layer is kept for the dense sweep.  The one
+exception is GroupBy (``group_tree``), whose bound is the vector unit
+and not HBM: XLA re-reads the last field's planes for every prefix of
+the nest, a Pallas kernel holds a tile of every operand in VMEM and
+reads each plane once (PERF.md §6, PR 34).
 """
 
 from __future__ import annotations
@@ -585,11 +589,11 @@ def fused_tree(mesh, fspec, specs, *operands):
         ("topn",   slot, i_mask, i_cands, i_idxs)       -> scores[K,S], src[S]
         ("topnf",  slot, i_mask, i_cands, i_idxs, i_cnt, i_thr, n_sel)
                                                         -> vals[n], ids[n]
-        ("group",  slot, i_mask, (i_mat, ...), (idxs | i_idx, ...))
+        ("group",  slot, i_mask, (i_mat, ...), (idxs | i_idx, ...), pallas)
                                                         -> counts[prod(K_i)]
       Each edge body is the corresponding single-op kernel's body
       verbatim (sum_tree / minmax_tree / topn_tree / topn_full_tree /
-      groupn_tree) with the evaluated slot as its filter row —
+      group_tree) with the evaluated slot as its filter row —
       bit-exactness vs the solo programs is by construction, and
       tests/test_fusion.py pins it differentially.  "topnf" runs full
       TopN with the gate + exact psum totals + top-k trim ON DEVICE
@@ -680,27 +684,15 @@ def fused_tree(mesh, fspec, specs, *operands):
                 outs.append(vals)
                 outs.append(top_idx)
             elif kind == "group":
-                # groupn_tree's body with a flattened output (the host
+                # group_tree's body with a flattened output (the host
                 # decoder reshapes to the per-field dims).
-                _, slot, i_mask, i_mats, gidx = e
+                _, slot, i_mask, i_mats, gidx, pallas = e
                 f = masked(slot, i_mask)
                 grows = []
                 for i_pm, gspec in zip(i_mats, gidx):
                     gix = gspec if isinstance(gspec, tuple) else ops[gspec]
                     grows.append(gather_rows(ops[i_pm], gix))
-                gdims = tuple(r.shape[0] for r in grows)
-                gfb = jnp.broadcast_to(f, grows[0].shape[1:])
-                ng = len(grows)
-
-                def gbuild(i, acc, grows=grows, gdims=gdims, ng=ng):
-                    if i == ng:
-                        return [_pc(acc)]
-                    out = []
-                    for k in range(gdims[i]):
-                        out.extend(gbuild(i + 1, acc & grows[i][k]))
-                    return out
-
-                gcounts = jnp.stack(_sum_many(gbuild(0, gfb), (0, 1)))
+                gcounts = group_counts_local(f, grows, pallas)
                 outs.append(jax.lax.psum(gcounts, SHARD_AXIS))
             else:
                 raise ValueError(f"bad fused edge {kind}")
@@ -711,17 +703,210 @@ def fused_tree(mesh, fspec, specs, *operands):
         n_out += {"sum": 2, "minmax": 3, "topn": 2, "topnf": 2, "group": 1}[
             e[0]
         ]
+    # A Pallas group edge switches the varying-axes check off, as in
+    # group_tree: every output is a psum (or top_k of one) regardless.
+    pallas = any(e[0] == "group" and e[5] for e in agg_edges)
     return shard_map(
-        body, mesh=mesh, in_specs=specs, out_specs=(P(),) * n_out
+        body, mesh=mesh, in_specs=specs, out_specs=(P(),) * n_out,
+        check_vma=not pallas,
     )(*operands)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def groupn_tree(mesh, prog, specs, idx_specs, mask, *operands):
+# -- GroupBy ------------------------------------------------------------------
+#
+# counts[k1, ..., kn] = popcount(filter & r1[k1] & ... & rn[kn]) over every
+# column.  Both bodies below evaluate the nest BY PREFIX: the product of all
+# fields but the last is one flat loop (its row indices come from div/mod of
+# the loop counter), each prefix mask is then scored against every row of the
+# last field.  Neither unrolls anything per combination (the Pallas body
+# unrolls its inner loop over at most GROUP_UNROLL_WHOLE rows of the last
+# field), so the trace and the compile time do not grow with prod(K).
+
+# Pallas body: words of one column tile (lanes), shards of one tile
+# (sublanes).  The tile's blocks — filter + every group row — are double
+# buffered in VMEM; GROUP_IN_BYTES bounds them, and a wider row table takes
+# a narrower tile.
+GROUP_TILE_SHARDS = 8
+GROUP_TILE_WORDS = (2048, 1024, 512, 256, 128)
+GROUP_IN_BYTES = 24 << 20
+# Groups whose (8, 128) int32 lane accumulators stay in VMEM through one
+# pass over the columns (4 KiB each: 16 MiB).  A count tensor within it —
+# taxi query 4's 3,570 — reads every plane from HBM exactly once; a larger
+# one takes ceil(groups / GROUP_ACC_GROUPS) passes.
+GROUP_ACC_GROUPS = 4096
+GROUP_VMEM_LIMIT = 100 << 20
+# Rows of the last field scored a step of the inner loop, unrolled by
+# hand (Mosaic unrolls a loop wholly or not at all): a last field up to
+# GROUP_UNROLL_WHOLE rows is unrolled whole, a wider one GROUP_UNROLL rows
+# a step.  One row a step leaves the vector unit waiting on the loop:
+# 48.5 ms at taxi query 4's shape on a v5e, 24.9 at three, 20.0 at
+# eight, 17.2 with the 51 unrolled whole (PERF.md section 6, PR 34).
+GROUP_UNROLL = 16
+GROUP_UNROLL_WHOLE = 64
+
+
+def group_tile_words(dims) -> int:
+    """Column-tile width of the Pallas body for these field widths, or 0
+    when no tile fits VMEM (the XLA body answers)."""
+    if dims[-1] > GROUP_ACC_GROUPS:
+        return 0
+    planes = sum(dims) + 1
+    for w in GROUP_TILE_WORDS:
+        if 2 * planes * GROUP_TILE_SHARDS * w * 4 <= GROUP_IN_BYTES:
+            return w
+    return 0
+
+
+def _prefix_rows(p, dims):
+    """Row index per prefix field of flat prefix ``p`` (row-major)."""
+    ks = []
+    for d in reversed(dims):
+        ks.append(p % d)
+        p = p // d
+    return ks[::-1]
+
+
+def _group_counts_xla(f, rows):
+    """Per-device GroupBy counts, plain XLA: f uint32[S, W], rows a list
+    of uint32[Ki, S, W] -> int32[prod(K)] (row-major).  One loop step a
+    prefix: the prefix mask, then a broadcast popcount-reduce over the
+    last field's rows.  Every step re-reads the last field's planes, so
+    this is the body for backends without the Pallas one (the CPU) and
+    for shapes it declines, not the fast path on a TPU."""
+    pre_dims = tuple(r.shape[0] for r in rows[:-1])
+    last = rows[-1]
+
+    def one(p):
+        pre = f
+        for r, k in zip(rows[:-1], _prefix_rows(p, pre_dims)):
+            pre = pre & jax.lax.dynamic_index_in_dim(r, k, 0, keepdims=False)
+        return jnp.sum(_pc(last & pre[None]), axis=(1, 2))
+
+    n_pre = 1
+    for d in pre_dims:
+        n_pre *= d
+    if not pre_dims:
+        return one(jnp.int32(0))
+    return jax.lax.map(one, jnp.arange(n_pre, dtype=jnp.int32)).reshape(-1)
+
+
+def _group_counts_pallas(f, rows, tile_words, interpret=False):
+    """Per-device GroupBy counts as ONE Pallas kernel: same contract as
+    ``_group_counts_xla``.  Grid = (passes, shard tiles, word tiles); a
+    step holds the tile of the filter and of EVERY group row in VMEM, so
+    each plane leaves HBM once a pass, and the kernel's work is the
+    prod(K) AND + popcount passes over the tile on the vector unit:
+    per prefix, its mask stays in vector registers while the last
+    field's rows stream past it from VMEM, each adding a lane-wise
+    partial into its group's (8, 128) accumulator.  The accumulators are
+    the output block, resident across a pass; lanes are summed outside."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    dims = tuple(r.shape[0] for r in rows)
+    pre_dims, k_last = dims[:-1], dims[-1]
+    n_pre = 1
+    for d in pre_dims:
+        n_pre *= d
+    S, W = f.shape
+    ts, tw = GROUP_TILE_SHARDS, tile_words
+    if S % ts or W % tw:
+        raise ValueError(f"[{S}, {W}] is not a whole number of [{ts}, {tw}] tiles")
+    unroll = k_last if k_last <= GROUP_UNROLL_WHOLE else GROUP_UNROLL
+    pre_block = max(1, min(n_pre, GROUP_ACC_GROUPS // k_last))
+    passes = -(-n_pre // pre_block)
+    lanes = tw // 128
+
+    def kernel(f_ref, *refs):
+        row_refs, acc_ref = refs[:-1], refs[-1]
+        first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
+
+        @pl.when(first)
+        def _zero():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        p0 = pl.program_id(0) * pre_block
+        last_ref = row_refs[-1]
+
+        def prefix(pl_i, carry):
+            pre = f_ref[...]
+            for ref, k in zip(row_refs[:-1], _prefix_rows(p0 + pl_i, pre_dims)):
+                pre = pre & ref[k]
+            base = pl_i * k_last
+
+            def score(k):
+                hits = _pc(pre & last_ref[k])
+                part = hits[:, 0:128]
+                for j in range(1, lanes):
+                    part = part + hits[:, j * 128:(j + 1) * 128]
+                acc_ref[base + k] += part
+
+            def score_many(i, c):
+                for u in range(unroll):
+                    score(i * unroll + u)
+                return c
+
+            jax.lax.fori_loop(0, k_last // unroll, score_many, 0)
+            for k in range(k_last - k_last % unroll, k_last):
+                score(k)
+            return carry
+
+        # The last pass may hold fewer prefixes than a block.
+        jax.lax.fori_loop(0, jnp.minimum(pre_block, n_pre - p0), prefix, 0)
+
+    def tile(k):
+        return pl.BlockSpec((k, ts, tw), lambda g, i, j: (0, i, j))
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(passes, S // ts, W // tw),
+        in_specs=[pl.BlockSpec((ts, tw), lambda g, i, j: (i, j))]
+        + [tile(k) for k in dims],
+        out_specs=pl.BlockSpec(
+            (pre_block * k_last, ts, 128), lambda g, i, j: (g, 0, 0)
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (passes * pre_block * k_last, ts, 128), jnp.int32
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=GROUP_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+    )(f, *rows)
+    return jnp.sum(out, axis=(1, 2))[: n_pre * k_last]
+
+
+def group_counts_local(f, rows, pallas):
+    """The per-device GroupBy body both callers share (``group_tree``
+    and the fused program's ``group`` edge): the Pallas kernel where the
+    backend has it (``pallas``) and the local block is whole tiles, the
+    XLA loop otherwise."""
+    dims = tuple(r.shape[0] for r in rows)
+    # The widest field goes last: the inner loop is over its rows, the
+    # outer one over the product of the others (taxi query 4's nest as
+    # 51 x 10 x 7 ran 26 ms on a v5e against 17 as 10 x 7 x 51).
+    n = len(rows)
+    last = max(range(n), key=lambda i: (dims[i], i))
+    order = [i for i in range(n) if i != last] + [last]
+    rows = [rows[i] for i in order]
+    f = jnp.broadcast_to(f, rows[0].shape[1:])
+    tw = group_tile_words(tuple(dims[i] for i in order)) if pallas else 0
+    if tw and f.shape[0] % GROUP_TILE_SHARDS == 0 and f.shape[1] % tw == 0:
+        counts = _group_counts_pallas(f, rows, tw)
+    else:
+        counts = _group_counts_xla(f, rows)
+    if order == list(range(n)):
+        return counts
+    back = sorted(range(n), key=order.__getitem__)
+    return counts.reshape([dims[i] for i in order]).transpose(back).reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def group_tree(mesh, prog, specs, idx_specs, pallas, mask, *operands):
     """N-field GroupBy in ONE dispatch: every (K1 x K2 x ... x Kn) group
-    combination counted via broadcast intersection + one psum
-    (executeGroupByShard's nested iterator, executor.go:1056/2726-2890,
-    re-founded as a flattened combination tensor) ->
+    combination counted (executeGroupByShard's nested iterator,
+    executor.go:1056/2726-2890, as one count tensor) + one psum ->
     int32[K1, ..., Kn], replicated.
 
     ``idx_specs`` is a static tuple with one slot per field: a
@@ -729,14 +914,13 @@ def groupn_tree(mesh, prog, specs, idx_specs, mask, *operands):
     arrive as a traced int32[Ki] operand (client-controlled subsets must
     not become compile keys).  The first ``n`` operands after ``mask``
     are the field stacks, then the traced index vectors for the None
-    slots, then the filter-tree operands.
+    slots, then the filter-tree operands.  ``pallas`` selects the TPU
+    body (the engine sets it from the backend).
 
-    Every combination count is one operand of a variadic popcount
-    reduce (_sum_many): XLA fuses the &-chains into the reduce loop and
-    each field plane streams from HBM exactly once, instead of the
-    virtual [K1..Kn, S, W] tensor's per-combination re-reads.  The
-    combination loop is trace-time Python, so the engine caps prod(K)
-    (MAX_GROUP_COMBOS) and overflow falls back to the host iterator."""
+    The program is one per (filter structure, field widths): nothing is
+    unrolled per combination (``group_counts_local``), so there is no
+    cap on prod(K) here; the engine bounds the count tensor it reads
+    back (MeshEngine.MAX_GROUPS)."""
     n = len(idx_specs)
 
     def body(m, *ops):
@@ -748,23 +932,16 @@ def groupn_tree(mesh, prog, specs, idx_specs, mask, *operands):
         f = _filter(prog, m, tuple(rest))
         rows = [gather_rows(mats[i], idxs[i]) for i in range(n)]  # [Ki, S, W]
         dims = tuple(r.shape[0] for r in rows)
-        fb = jnp.broadcast_to(f, rows[0].shape[1:])
-
-        def build(i, acc):
-            if i == n:
-                return [_pc(acc)]
-            out = []
-            for k in range(dims[i]):
-                out.extend(build(i + 1, acc & rows[i][k]))
-            return out
-
-        ops_list = build(0, fb)
-        counts = jnp.stack(_sum_many(ops_list, (0, 1))).reshape(dims)
+        counts = group_counts_local(f, rows, pallas).reshape(dims)
         return jax.lax.psum(counts, SHARD_AXIS)
 
+    # check_vma off with the Pallas body: pallas_call's output carries no
+    # varying-axes type (sparse.count_tree_blocks_pallas); the psum makes
+    # the result replicated regardless.
     return shard_map(
         body,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS),) + (P(None, SHARD_AXIS),) * n + specs,
         out_specs=P(),
+        check_vma=not pallas,
     )(mask, *operands)

@@ -402,6 +402,9 @@ METRIC_ENGINE_DRAIN_SLOTS = "pilosa_engine_drain_slots_total"
 METRIC_ENGINE_DRAIN_REQUESTS = "pilosa_engine_drain_requests_total"
 METRIC_ENGINE_DRAIN_EVALUATED = "pilosa_engine_drain_evaluated_slots_total"
 METRIC_ENGINE_DRAIN_PLANE_BYTES = "pilosa_engine_drain_plane_bytes_total"
+#   pilosa_engine_group_combos_total             GroupBy combinations the
+#       device evaluated (the count tensor's size, every dispatch)
+METRIC_ENGINE_GROUP_COMBOS = "pilosa_engine_group_combos_total"
 METRIC_ENGINE_DEVICE_INFLIGHT = "pilosa_engine_device_inflight_seconds_total"
 METRIC_UPTIME = "pilosa_uptime_seconds"
 METRIC_FRAGMENT_OP = "pilosa_fragment_op_seconds"
@@ -767,6 +770,10 @@ for _cache in ENGINE_CACHES:
 REGISTRY.counter(
     METRIC_DEVICE_BYTES_SKIPPED,
     help="Device HBM bytes skipped by occupancy-guided sparse dispatches",
+)
+REGISTRY.counter(
+    METRIC_ENGINE_GROUP_COMBOS,
+    help="GroupBy combinations evaluated on the device",
 )
 for _kind in REPAIR_KINDS:
     REGISTRY.counter(

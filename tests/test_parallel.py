@@ -317,16 +317,20 @@ def test_executor_mesh_group_by(holder, mesh):
     calls.clear()
     assert fused.execute("i", q).results == plain.execute("i", q).results
     assert not calls
-    # Combination-count overflow falls back to the host iterator.  The
-    # earlier run of this exact query memoized its tensor — clear the
-    # memo (and keep repair out) so group_counts is really consulted.
-    engine.MAX_GROUP_COMBOS = 8
+    # A combination count past the old trace-time cap (30 > the 8 this
+    # test used to set) answers on the device with the iterator's
+    # result: nothing in the program grows with it.  The earlier run of
+    # this exact query memoized its tensor — clear the memo (and keep
+    # repair out) so group_counts is really consulted.
+    assert not hasattr(engine, "MAX_GROUP_COMBOS")
     engine.result_memo.clear()
     q = "GroupBy(Rows(field=a), Rows(field=b), Rows(field=c))"  # 5*3*2=30
-    calls.clear()
+    devs = []
+    engine.group_counts_async = (
+        lambda *x, **k: devs.append(orig(*x, **k)) or devs[-1])
     with engine.repairs.suspended():
         assert fused.execute("i", q).results == plain.execute("i", q).results
-    assert calls  # group_counts consulted but declined -> host path ran
+    assert len(devs) == 1 and devs[0].shape == (5, 3, 2)  # the device tensor
 
 
 def test_mesh_time_range(holder, mesh):

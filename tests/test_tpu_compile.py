@@ -40,7 +40,7 @@ ENTRY_POINTS = {
     (kernels, "count_tree"): 3, (kernels, "count_batch_tree"): 3,
     (kernels, "sum_tree"): 4, (kernels, "minmax_tree"): 5,
     (kernels, "topn_full_tree"): 5, (kernels, "topn_slab_tree"): 6,
-    (kernels, "groupn_tree"): 4, (kernels, "fused_tree"): 3,
+    (kernels, "group_tree"): 5, (kernels, "fused_tree"): 3,
     (sparse, "count_tree_blocks"): 2,
 }
 
@@ -149,7 +149,9 @@ TEMP_ROWS = {
     "count_tree": 1, "count_batch_tree": 1,
     "count_tree_blocks": 1, "count_tree_blocks_pallas": 1,
     "sum_tree": 1, "minmax_tree": 3, "topn_full_tree": 1, "topn_slab_tree": 1,
-    "groupn_tree": 1,
+    # The XLA body's loop holds a prefix mask; the Pallas body's lane
+    # accumulators ([groups, 8, 128] int32) are summed outside it.
+    "group_tree": 2, "group_tree_pallas": 2,
     # KNOWN DEBT, pinned so it cannot grow (ROADMAP S4(c)): the fused
     # "topnf" edge gathers its candidates by a TRACED index (jnp.take)
     # and XLA materializes the gather — 23.75 rows = 2.99 GB of temp on
@@ -171,6 +173,17 @@ def test_smoke_programs_compile_for_v5e(topology, recorded, n_dev):
             # the CPU engine above ran the XLA form): Mosaic must take it.
             temps["count_tree_blocks_pallas", static] = _temp_bytes(
                 sparse.count_tree_blocks_pallas, mesh, (*static, False), arrays)
+        if name == "group_tree":
+            # Likewise the GroupBy program's Pallas body (static 4).
+            temps["group_tree_pallas", static] = _temp_bytes(
+                fn, mesh, (*static[:3], True), arrays)
+        if name == "fused_tree":
+            # ... and the fused program with its group edge on that body.
+            slots, counts, aggs = static[0]
+            aggs = tuple(e[:5] + (True,) if e[0] == "group" else e for e in aggs)
+            assert any(e[0] == "group" for e in aggs)
+            temps["fused_tree", ("pallas",) + static] = _temp_bytes(
+                fn, mesh, ((slots, counts, aggs), *static[1:]), arrays)
     over = {k: v for k, v in temps.items() if v >= TEMP_ROWS[k[0]] * row_block}
     assert not over, f"temp >= allowed operand rows ({row_block} B each): {over}"
     # The sparse forms exist to read fewer bytes than a row.

@@ -392,3 +392,32 @@ def test_a_stage_left_by_an_exception_records_nothing():
     assert REGISTRY.get_histogram(METRIC_QUERY_STAGE, path="direct",
                                   stage="boom") is None
     assert getattr(tracing._LOCAL, "stage", None) is None
+
+
+def test_a_groupby_lands_on_the_direct_path_with_a_drain_record(served):
+    """Served over HTTP with ?profile=1: the plan op names a device
+    path, the direct path's stages and the executor's two wrap the one
+    program, and the drain record counts the grouped rows' planes and
+    the filter's (f's 4 rows + v's 8 planes and not-null = 13)."""
+    eng, api, uri = served
+
+    def n(path, stage):
+        h = REGISTRY.get_histogram(METRIC_QUERY_STAGE, path=path, stage=stage)
+        return h.count if h is not None else 0
+
+    stages = ("group_rows", "execute", "lower", "dispatch", "device_get",
+              "decode", "group_decode")
+    eng.batcher()._last_fused = float("-inf")  # a fused drain just ran: not a hot pipe
+    before = [n("direct", s) for s in stages]
+    drains = _drain_counters("GroupBy", "group")
+    doc = _post(uri, b"GroupBy(Rows(field=f), filter=Range(v > 3))",
+                path="/index/i/query?profile=1")
+    ops = [op for op in doc["plan"]["ops"] if "path" in op]
+    assert [(op["op"], op["path"], op["groups"]) for op in ops] == [("GroupBy", "direct", 4)]
+    assert sum(g["count"] for g in doc["results"][0]) > 0
+    assert [n("direct", s) - b for s, b in zip(stages, before)] == [1] * len(stages)
+    rose = [a - b for a, b in zip(_drain_counters("GroupBy", "group"), drains)]
+    assert rose == [1, 1, 1, 13 * SHARDS * PLANE, 13 * SHARDS * PLANE, 1]
+    root = _finished(api.tracer, doc["traceID"])
+    assert {"group_rows", "group_decode", "execute"} <= _stage_names(root)
+    _assert_tiles(root)
