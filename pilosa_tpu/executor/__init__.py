@@ -3,6 +3,7 @@ from .executor import (
     ExecOptions,
     Executor,
     FieldRow,
+    GroupColumns,
     GroupCount,
     QueryResponse,
     RowIdentifiers,
@@ -14,6 +15,7 @@ __all__ = [
     "ExecOptions",
     "Executor",
     "FieldRow",
+    "GroupColumns",
     "GroupCount",
     "QueryResponse",
     "RowIdentifiers",

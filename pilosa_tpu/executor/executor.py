@@ -12,20 +12,29 @@ Re-design of the reference's executor (executor.go:84-2890) for TPU:
 
 Results use the same shapes as the reference: Row for bitmap calls,
 ValCount for Sum/Min/Max, (id, count) pair lists for TopN, RowIdentifiers
-for Rows, GroupCount list for GroupBy, bool for mutations.
+for Rows, GroupCount list for GroupBy (a GroupColumns, the same sequence
+held as columns, where the device counted), bool for mutations.
 """
 
 from __future__ import annotations
 
 import datetime as dt
+import math
 import threading
 import time
 from collections import OrderedDict
+from collections.abc import Sequence
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..util.stats import METRIC_QUERY_OP, METRIC_REPLICA_READS, REGISTRY
+from ..util.stats import (
+    GROUP_RESULT_FORMS,
+    METRIC_EXECUTOR_GROUP_RESULTS,
+    METRIC_QUERY_OP,
+    METRIC_REPLICA_READS,
+    REGISTRY,
+)
 
 # Per-op histogram handles, cached so the dispatch path never takes the
 # global registry lock (GIL-atomic dict ops; a racing first-call for the
@@ -42,6 +51,14 @@ def _op_hist(op: str):
             op=op,
         )
     return h
+
+
+# GroupBy results by the form they left the executor in: handles of the
+# series stats.py registers at import.
+_GROUP_RESULTS = {
+    form: REGISTRY.counter(METRIC_EXECUTOR_GROUP_RESULTS, form=form)
+    for form in GROUP_RESULT_FORMS
+}
 
 from .. import ops, pql
 from ..parallel.errors import PeerlessMeshError
@@ -220,6 +237,99 @@ class GroupCount:
         return {"group": [g.to_dict() for g in self.group], "count": self.count}
 
 
+class GroupAxes:
+    """What a GroupBy's count tensor is indexed by: the grouped fields'
+    names and, a field, the sorted vector of its row ids; combination
+    ``i`` of the row-major product is one possible group.  The executor
+    keeps one a (index, fields, shards) for as long as it keeps the row
+    vectors (``Executor._group_axes``), so what a consumer derives from
+    the axes alone can stay with them: ``reply_texts`` is the JSON reply
+    encoder's (net/wire.py), None until it has filled it."""
+
+    __slots__ = ("fields", "rows", "shape", "size", "reply_texts")
+
+    def __init__(self, fields, rows):
+        self.fields = tuple(fields)
+        self.rows = tuple(rows)
+        self.shape = tuple(len(vec) for vec in self.rows)
+        self.size = math.prod(self.shape)  # combinations
+        self.reply_texts = None
+
+
+class GroupColumns(Sequence):
+    """A GroupBy result over row ids, held as columns: group ``i`` is
+    combination ``flat[i]`` of ``axes`` (``rows[d][i]`` is its row of
+    ``fields[d]``), counted ``counts[i]`` times; ``flat`` and ``counts``
+    are integer vectors of one length, in the nested-iterator order.
+    What the device path hands out: the reply encoder formats the
+    vectors as they are (net/wire.py), and slicing cuts them.  To
+    everything else it is the ``GroupCount`` list of the same groups:
+    the first ``__iter__`` or ``__getitem__(int)`` builds that list,
+    once, and from then on the objects are the result (they can be
+    written to: key translation, a merge), so ``objects`` is what an
+    encoder tests before it trusts the columns.  A field with keys never
+    stays columnar: ``translate`` turns it into the list."""
+
+    __slots__ = ("axes", "flat", "counts", "objects")
+
+    def __init__(self, axes: GroupAxes, flat, counts, objects=None):
+        self.axes = axes
+        self.flat = flat
+        self.counts = counts
+        self.objects: Optional[List[GroupCount]] = objects
+
+    @property
+    def fields(self):
+        return self.axes.fields
+
+    @property
+    def rows(self):
+        """One vector of row ids a field."""
+        ix = np.unravel_index(self.flat, self.axes.shape)
+        return [vec[i] for vec, i in zip(self.axes.rows, ix)]
+
+    def _materialise(self) -> List[GroupCount]:
+        if self.objects is None:
+            _GROUP_RESULTS["objects"].inc()
+            cols = []
+            for f, col in zip(self.fields, self.rows):
+                col = col.tolist()
+                # One FieldRow a (field, row), shared by its groups.
+                frs = {r: FieldRow(f, r) for r in set(col)}
+                cols.append([frs[r] for r in col])
+            self.objects = [
+                GroupCount(list(group), n)
+                for group, n in zip(zip(*cols), self.counts.tolist())
+            ]
+        return self.objects
+
+    def __len__(self):
+        return len(self.counts)
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return GroupColumns(
+                self.axes,
+                self.flat[i],
+                self.counts[i],
+                None if self.objects is None else self.objects[i],
+            )
+        return self._materialise()[i]
+
+    def __iter__(self):
+        return iter(self._materialise())
+
+    def __eq__(self, other):
+        if not isinstance(other, (list, GroupColumns)):
+            return NotImplemented
+        return self._materialise() == list(other)
+
+    __hash__ = None
+
+    def __repr__(self):
+        return f"GroupColumns({list(self.fields)}, groups={len(self)})"
+
+
 class RowIdentifiers:
     """Rows() result (executor.go:822-827)."""
 
@@ -329,6 +439,7 @@ def _merge_group_counts(
 
 
 _MAXINT = (1 << 63) - 1
+_NO_ROWS = np.empty(0, dtype=np.uint64)
 
 _WRITE_CALLS = {"Set", "Clear", "SetRowAttrs", "SetColumnAttrs", "Store", "ClearRow"}
 
@@ -541,6 +652,7 @@ class Executor:
 
         self._sflight = SingleFlight()
         self._group_rows_cache: Dict[tuple, tuple] = {}
+        self._group_axes_cache: Dict[tuple, GroupAxes] = {}
         # Remote fan-out tally: one per peer RPC issued by the mapper.
         # With capacity-weighted ownership (cluster.place_partition) a
         # query whose shards are all locally owned must leave this at 0
@@ -2312,7 +2424,9 @@ class Executor:
             start=start, column=column, limit=limit_arg if has_limit else None
         )
 
-    def _execute_group_by(self, index, c: Call, shards, opt) -> List[GroupCount]:
+    def _execute_group_by(
+        self, index, c: Call, shards, opt
+    ) -> Sequence[GroupCount]:
         if not c.children:
             raise Error("need at least one child call")
         limit_arg, has_limit = c.uint_arg("limit")
@@ -2363,7 +2477,9 @@ class Executor:
             results = (
                 self.map_reduce(index, shards, c, opt, map_fn, reduce_fn) or []
             )
+            _GROUP_RESULTS["objects"].inc()
 
+        # A GroupColumns stays one under both cuts (vector slices).
         offset, has_offset = c.uint_arg("offset")
         if has_offset and offset < len(results):
             results = results[offset:]
@@ -2404,9 +2520,9 @@ class Executor:
                 qsig += "|flt:" + str(filter_call)
             key, hit = probe(index, qsig, fields, filter_call, shards)
         with tracing_mod.stage("group_rows"):
-            row_lists = [
-                self._group_rows(index, f, shards) for f in fields
-            ]
+            row_lists, row_vecs = zip(
+                *(self._group_rows(index, f, shards) for f in fields)
+            )
         if any(not rows for rows in row_lists):
             return set(shards), []
         shape = tuple(len(rows) for rows in row_lists)
@@ -2445,60 +2561,75 @@ class Executor:
             return None
         limit_arg, has_limit = c.uint_arg("limit")
         with tracing_mod.stage("group_decode"):
-            # np.nonzero walks the count tensor in row-major order —
+            # np.flatnonzero walks the count tensor in row-major order —
             # exactly the nested-iterator order of the reference
             # (executor.go:2726), so cutting at ``limit`` non-zero groups
-            # is the progressive limit truncation.  One Python step a
-            # group that is returned, none a combination.
-            counts = np.asarray(counts).reshape(
-                tuple(len(rows) for rows in row_lists)
-            )
-            hit_idx = np.nonzero(counts > 0)
+            # is the progressive limit truncation.  No Python step a
+            # group: the index vector and the counts are the result.
+            counts = np.asarray(counts).reshape(shape).ravel()
+            flat = np.flatnonzero(counts > 0)
             if has_limit:
-                hit_idx = tuple(ix[:limit_arg] for ix in hit_idx)
-            ns = counts[hit_idx].tolist()
-            # One FieldRow a (field, row), shared by the groups it is in:
-            # a reply of 3,570 groups allocates 2 objects a group, not 8
-            # (what a FieldRow says depends on its field and row alone).
-            cols = []
-            for f, rows, ix in zip(fields, row_lists, hit_idx):
-                frs = [FieldRow(f, r) for r in rows]
-                cols.append([frs[i] for i in ix.tolist()])
-            results = [
-                GroupCount(list(group), n) for group, n in zip(zip(*cols), ns)
-            ]
+                flat = flat[:limit_arg]
+            if not len(flat):
+                return set(shards), []
+            results = GroupColumns(
+                self._group_axes(index, fields, shards, row_vecs),
+                flat,
+                counts[flat],
+            )
+        _GROUP_RESULTS["columns"].inc()
         return set(shards), results
 
-    # (index, field, shards) -> (version token, sorted row ids): the
-    # GroupBy axes of a field over a shard set, kept until the field's
-    # standard view or the index's shard set changes.
+    # (index, field, shards) -> (version token, sorted row ids, the same
+    # as a vector): the GroupBy axes of a field over a shard set, kept
+    # until the field's standard view or the index's shard set changes.
     GROUP_ROWS_CACHE = 64
+    # (index, fields, shards) -> GroupAxes over those vectors.  Few: an
+    # entry can hold wire.GROUP_TEXTS_MAX reply texts (~8 MB).
+    GROUP_AXES_CACHE = 8
 
-    def _group_rows(self, index, field, shards) -> List[int]:
+    def _group_axes(self, index, fields, shards, row_vecs) -> GroupAxes:
+        """The kept axes of a GroupBy over ``fields``: the same object
+        for as long as ``_group_rows`` hands out the same vectors, so
+        that what rides on it (``GroupAxes.reply_texts``) goes when a
+        write or a new shard moves a field's version."""
+        key = (index, tuple(fields), tuple(shards))
+        axes = self._group_axes_cache.get(key)
+        if axes is None or any(
+            kept is not vec for kept, vec in zip(axes.rows, row_vecs)
+        ):
+            if len(self._group_axes_cache) >= self.GROUP_AXES_CACHE:
+                self._group_axes_cache.clear()
+            axes = self._group_axes_cache[key] = GroupAxes(fields, row_vecs)
+        return axes
+
+    def _group_rows(self, index, field, shards) -> Tuple[List[int], np.ndarray]:
         """Sorted distinct row ids of ``field`` over ``shards``: the
-        axis of a GroupBy's count tensor.  The walk is one
-        ``frag.row_ids()`` a shard; a request that finds the view at
-        the version it was walked at takes the list as it is."""
+        axis of a GroupBy's count tensor, as the list the engine lowers
+        and as the vector the result's column is gathered from.  The
+        walk is one ``frag.row_ids()`` a shard; a request that finds the
+        view at the version it was walked at takes both as they are."""
         idx = self.holder.index(index)
         f = idx.field(field) if idx is not None else None
         view = f.views.get(VIEW_STANDARD) if f is not None else None
         if view is None:
-            return []
+            return [], _NO_ROWS
         token = (self.holder.shard_epoch(index), view.gen, view.version)
         key = (index, field, tuple(shards))
         hit = self._group_rows_cache.get(key)
         if hit is not None and hit[0] == token:
-            return hit[1]
+            return hit[1], hit[2]
         rows = set()
         for s in shards:
             frag = self.holder.fragment(index, field, VIEW_STANDARD, s)
             if frag is not None:
                 rows.update(frag.row_ids())
         out = sorted(rows)
+        vec = np.asarray(out, dtype=np.uint64)
         if len(self._group_rows_cache) >= self.GROUP_ROWS_CACHE:
             self._group_rows_cache.clear()
-        self._group_rows_cache[key] = (token, out)
-        return out
+        self._group_rows_cache[key] = (token, out, vec)
+        return out, vec
 
     def _execute_group_by_shard(
         self, index, c: Call, filter_call, shard, child_rows

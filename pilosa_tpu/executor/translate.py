@@ -14,7 +14,7 @@ from ..core.field import FIELD_TYPE_BOOL
 from ..core.fragment import FALSE_ROW_ID, TRUE_ROW_ID
 from ..core.row import Row
 from ..pql import Call
-from .executor import FieldRow, GroupCount, RowIdentifiers, ValCount
+from .executor import FieldRow, GroupColumns, GroupCount, RowIdentifiers, ValCount
 
 
 class TranslateError(Exception):
@@ -171,6 +171,15 @@ class QueryTranslator:
                     for row_id, count in result
                 ]
             return result
+        if isinstance(result, GroupColumns):
+            # Columns hold row ids only: they stay as they are unless a
+            # grouped field has keys, which the objects carry.
+            if not any(
+                f is not None and f.options.keys
+                for f in map(idx.field, result.fields)
+            ):
+                return result
+            result = list(result)
         if isinstance(result, list) and result and isinstance(result[0], GroupCount):
             for gc in result:
                 for fr in gc.group:

@@ -19,7 +19,7 @@ import struct
 from typing import Dict, List, Tuple
 
 from ..core.row import Row
-from ..executor import FieldRow, GroupCount, RowIdentifiers, ValCount
+from ..executor import FieldRow, GroupColumns, GroupCount, RowIdentifiers, ValCount
 
 CONTENT_TYPE = "application/x-protobuf"
 
@@ -303,6 +303,8 @@ def _decode_row(data) -> Row:
 def encode_result(result) -> bytes:
     """One QueryResult message (proto.go encodeQueryResult :410-445)."""
     out = b""
+    if isinstance(result, GroupColumns):
+        result = list(result)  # encoded group by group, below
     if result is None:
         typ = RESULT_NIL
     elif isinstance(result, Row):

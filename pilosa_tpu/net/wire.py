@@ -9,11 +9,13 @@ TopN pairs -> [{id|key, count}], Rows -> {rows|keys}, GroupBy ->
 
 from __future__ import annotations
 
+import itertools
 import json
-from typing import Optional
+
+import numpy as np
 
 from ..core.row import Row
-from ..executor import FieldRow, GroupCount, RowIdentifiers, ValCount
+from ..executor import FieldRow, GroupColumns, GroupCount, RowIdentifiers, ValCount
 
 
 def result_to_json(result):
@@ -34,6 +36,8 @@ def result_to_json(result):
         return result.to_dict()
     if isinstance(result, RowIdentifiers):
         return result.to_dict()
+    if isinstance(result, GroupColumns):
+        result = list(result)  # the generic walk is over GroupCounts
     if isinstance(result, list):
         if result and isinstance(result[0], tuple):
             # TopN pairs: (id_or_key, count)
@@ -131,50 +135,72 @@ def count_response_bytes(resp, trace_id=None):
     return fast_results_bytes(results, trace_id)
 
 
-def _group_list_json(groups) -> Optional[str]:
-    """Exact ``json.dumps`` text of one GroupBy result over row ids
-    (``[g.to_dict() for g in groups]``), or None when a group carries a
-    row key or the groups do not share their fields.  One format string
-    a result and one pass a column: a reply of thousands of groups (taxi
-    query 4: 3,570, half a megabyte) skips a dict per group and per
-    field and the generic encoder's walk over them.  Each distinct
-    FieldRow is looked at once (the executor's device path shares one
-    per field and row among the groups)."""
-    if set(map(type, groups)) != {GroupCount}:
-        return None
-    rows = [g.group for g in groups]
-    fields = [fr.field for fr in rows[0]]
-    if set(map(len, rows)) != {len(fields)}:
-        return None
-    cols = []
-    for d, f in enumerate(fields):
-        col = [r[d] for r in rows]
-        for fr in {id(fr): fr for fr in col}.values():
-            if fr.row_key or fr.field != f:
-                return None
-        cols.append([fr.row_id for fr in col])
-    cols.append([g.count for g in groups])
+# The most combinations whose reply texts are kept with a GroupBy's axes
+# (~130 bytes each for three fields: ~8 MB).
+GROUP_TEXTS_MAX = 1 << 16
+
+
+def _group_texts(axes):
+    """For every combination of ``axes`` in row-major order, the text
+    that precedes its count in ``json.dumps`` of the reply's list, led
+    by the text that ends the group before it: the reply is these
+    alternating with the counts, less the first three characters."""
+    cells = [
+        ['{"field": %s, "rowID": %d}' % (json.dumps(f), r) for r in vec.tolist()]
+        for f, vec in zip(axes.fields, axes.rows)
+    ]
+    return np.array(
+        [
+            '}, {"group": [' + ", ".join(c) + '], "count": '
+            for c in itertools.product(*cells)
+        ],
+        dtype=object,
+    )
+
+
+def _group_list_json(groups: GroupColumns) -> str:
+    """Exact ``json.dumps`` text of one GroupBy result held as columns
+    (``[g.to_dict() for g in groups]``), with no object a group on the
+    way from the count tensor to the socket.  A reply that lists a good
+    share of its axes' combinations (taxi query 4: ~3,500 of 3,570, half
+    a megabyte) is one gather from the texts kept with the axes and one
+    join; the texts are written at the first such reply and go with the
+    axes.  Any other is one format string and one ``%`` a group over the
+    row vectors."""
+    n = len(groups)
+    if not n:
+        return "[]"
+    axes = groups.axes
+    if axes.size <= GROUP_TEXTS_MAX and 4 * n >= axes.size:
+        texts = axes.reply_texts
+        if texts is None:
+            texts = axes.reply_texts = _group_texts(axes)
+        parts = [None] * (2 * n)
+        parts[0::2] = texts[groups.flat].tolist()
+        parts[1::2] = map(str, groups.counts.tolist())
+        return "[" + "".join(parts)[3:] + "}]"
     fmt = '{"group": [' + ", ".join(
         '{"field": %s, "rowID": %%d}' % json.dumps(f).replace("%", "%%")
-        for f in fields
+        for f in groups.fields
     ) + '], "count": %d}'
+    cols = [col.tolist() for col in groups.rows]
+    cols.append(groups.counts.tolist())
     return "[" + ", ".join([fmt % t for t in zip(*cols)]) + "]"
 
 
 def group_response_bytes(resp, trace_id=None):
     """``count_response_bytes`` for a response whose every result is a
-    non-empty GroupBy list over row ids; None otherwise."""
+    GroupBy result still held as columns (``GroupColumns``: row ids, no
+    keys, by construction); None otherwise, and once a result's objects
+    have been handed out, which may have been written to since."""
     if resp.column_attr_sets is not None or not resp.results:
         return None
-    parts = []
     for r in resp.results:
-        if type(r) is not list or not r or type(r[0]) is not GroupCount:
+        if type(r) is not GroupColumns or r.objects is not None:
             return None
-        text = _group_list_json(r)
-        if text is None:
-            return None
-        parts.append(text)
-    return _response_bytes(parts, trace_id)
+    return _response_bytes(
+        [_group_list_json(r) for r in resp.results], trace_id
+    )
 
 
 def result_from_json(call_name: str, doc):

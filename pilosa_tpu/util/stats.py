@@ -405,6 +405,12 @@ METRIC_ENGINE_DRAIN_PLANE_BYTES = "pilosa_engine_drain_plane_bytes_total"
 #   pilosa_engine_group_combos_total             GroupBy combinations the
 #       device evaluated (the count tensor's size, every dispatch)
 METRIC_ENGINE_GROUP_COMBOS = "pilosa_engine_group_combos_total"
+#   pilosa_executor_group_results_total{form}    GroupBy results by the form
+#       they left the executor in: form="columns" where the device path
+#       handed out a GroupColumns, form="objects" where one was turned into
+#       GroupCount objects (once a result) or the host iterator answered
+METRIC_EXECUTOR_GROUP_RESULTS = "pilosa_executor_group_results_total"
+GROUP_RESULT_FORMS = ("columns", "objects")
 METRIC_ENGINE_DEVICE_INFLIGHT = "pilosa_engine_device_inflight_seconds_total"
 METRIC_UPTIME = "pilosa_uptime_seconds"
 METRIC_FRAGMENT_OP = "pilosa_fragment_op_seconds"
@@ -775,6 +781,12 @@ REGISTRY.counter(
     METRIC_ENGINE_GROUP_COMBOS,
     help="GroupBy combinations evaluated on the device",
 )
+for _form in GROUP_RESULT_FORMS:
+    REGISTRY.counter(
+        METRIC_EXECUTOR_GROUP_RESULTS,
+        help="GroupBy results by the form they left the executor in",
+        form=_form,
+    )
 for _kind in REPAIR_KINDS:
     REGISTRY.counter(
         METRIC_RESULT_REPAIRS,

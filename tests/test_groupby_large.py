@@ -7,8 +7,10 @@ numpy: per-column row membership, then counts by explicit loops over the
 combinations; it never sees a bitmap.  Every case asserts the device
 program answered (a plan op with a device path, no host_fallback)."""
 
+import gc
 import json
 import time
+import urllib.request
 
 import numpy as np
 import pytest
@@ -16,11 +18,19 @@ import pytest
 from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.executor import Executor
-from pilosa_tpu.executor.executor import FieldRow, GroupCount, QueryResponse
+from pilosa_tpu.executor.executor import (
+    FieldRow,
+    GroupAxes,
+    GroupColumns,
+    GroupCount,
+    QueryResponse,
+    _merge_group_counts,
+)
 from pilosa_tpu.net import wire
 from pilosa_tpu.ops import SHARD_WIDTH
 from pilosa_tpu.parallel import MeshEngine, kernels, make_mesh
 from pilosa_tpu.util import plans
+from pilosa_tpu.util.stats import METRIC_EXECUTOR_GROUP_RESULTS, REGISTRY
 
 SHARDS = 3
 COLS = 600  # columns a shard, scattered over the shard's width
@@ -189,23 +199,316 @@ def _groups(fields, n, key=False):
                         for d, f in enumerate(fields)], i + 1) for i in range(n)]
 
 
-@pytest.mark.parametrize("results,fast", [
-    ([_groups(["pc", "yr", "mi"], 5)], True),
-    ([_groups(["a"], 1), _groups(['q"%d\\', "b"], 3)], True),  # two calls; a name json escapes
-    ([_groups(["pc"], 2, key=True)], False),  # a row key: the generic encoder
-    ([_groups(["pc"], 2), 7], False),  # mixed with a Count
-    ([_groups(["pc"], 2), []], False),  # an empty GroupBy beside a full one
-], ids=["three_fields", "two_calls", "row_key", "mixed", "empty"])
-def test_group_reply_bytes_are_json_dumps_bytes(results, fast):
-    """The GroupBy reply's fast encoder gives the generic encoder's bytes,
-    or declines."""
-    resp = QueryResponse(results=results)
-    for trace_id in (None, "abc123"):
-        want = wire.response_to_json(resp)
-        if trace_id:
-            want["traceID"] = trace_id
-        got = wire.count_response_bytes(resp, trace_id)
-        assert (got == json.dumps(want).encode()) if fast else got is None
+def _columns(fields, n):
+    """``_groups(fields, n)`` as the device path hands it out: the
+    diagonal of axes of n rows a field."""
+    i = np.arange(n)
+    axes = GroupAxes(fields, [(i * 7 + d).astype(np.uint64) for d in range(len(fields))])
+    return GroupColumns(axes, np.ravel_multi_index((i,) * len(fields), axes.shape),
+                        (i + 1).astype(np.int32))
+
+
+def _nest(shape=(10, 7, 51), empty=0.1, fields=FIELDS):
+    """A count tensor's groups as the device path hands them out, about
+    ``empty`` of the combinations at 0."""
+    rng = np.random.default_rng(sum(shape))
+    counts = rng.integers(1, 1 << 20, int(np.prod(shape))).astype(np.int32)
+    counts[rng.random(counts.size) < empty] = 0
+    axes = GroupAxes(fields, [np.arange(k, dtype=np.uint64) * 3 for k in shape])
+    flat = np.flatnonzero(counts)
+    return GroupColumns(axes, flat, counts[flat])
+
+
+def _forms():
+    return tuple(REGISTRY.counter(METRIC_EXECUTOR_GROUP_RESULTS, form=f).get()
+                 for f in ("columns", "objects"))
+
+
+def _iterated(cols):
+    list(cols)
+    return cols
+
+
+REPLIES = {
+    "one_field": (lambda: [_columns(["pc"], 4)], True),
+    "two_fields": (lambda: [_columns(["pc", "yr"], 4)], True),
+    "three_fields": (lambda: [_columns(["pc", "yr", "mi"], 5)], True),
+    "limit": (lambda: [_columns(["pc", "yr", "mi"], 9)[:4]], True),
+    "offset": (lambda: [_columns(["pc", "yr", "mi"], 9)[3:][:4]], True),
+    "one_group": (lambda: [_columns(["pc", "yr"], 1)], True),
+    # two GroupBys in one request; a name json escapes
+    "two_calls": (lambda: [_columns(["a"], 1), _columns(['q"%d\\', "b"], 3)], True),
+    "3570_groups": (lambda: [_columns(["pc", "yr", "mi"], 3570)], True),
+    # a well-filled nest: from the texts kept with its axes
+    "nest": (lambda: [_nest()], True),
+    "full_nest": (lambda: [_nest(empty=0)], True),
+    "nest_limit_offset": (lambda: [_nest()[:3000][40:]], True),
+    "nest_twice": (lambda: [_nest((4, 5), fields=("a%s", 'b"'))] * 2, True),
+    "thin_nest": (lambda: [_nest(empty=0.9)], True),  # a format a group
+    "nest_cut_to_none": (lambda: [_nest()[5000:]], True),
+    "empty": (lambda: [[]], True),  # no group: the executor hands out a plain list
+    "empty_beside_full": (lambda: [_columns(["pc"], 2), []], False),
+    "mixed": (lambda: [_columns(["pc"], 2), 7], False),  # with a Count
+    "objects": (lambda: [_groups(["pc"], 2)], False),  # the host iterator's list
+    "row_key": (lambda: [_groups(["pc"], 2, key=True)], False),
+    "handed_out": (lambda: [_iterated(_columns(["pc"], 2))], False),  # its objects may have been written to
+}
+
+
+@pytest.mark.parametrize("trace_id", [None, "abc123"], ids=["plain", "traceID"])
+@pytest.mark.parametrize("case", REPLIES)
+def test_group_reply_bytes_are_json_dumps_bytes(case, trace_id):
+    """The columnar GroupBy reply is the generic encoder's, byte for
+    byte, and builds no object on the way; anything else is declined."""
+    make, fast = REPLIES[case]
+    resp = QueryResponse(results=make())
+    before = _forms()
+    got = wire.count_response_bytes(resp, trace_id)
+    assert _forms() == before  # encoding hands out no object
+    want = wire.response_to_json(resp)
+    if trace_id:
+        want["traceID"] = trace_id
+    assert (got == json.dumps(want).encode()) if fast else got is None
+
+
+@pytest.mark.parametrize("empty,kept", [(0.1, True), (0.74, True), (0.76, False)])
+def test_reply_texts_are_kept_for_a_well_filled_nest_only(empty, kept, monkeypatch):
+    """A reply that lists a quarter of its axes' combinations writes
+    the texts before each combination's count onto the axes, where the
+    next finds them; a thinner one, or axes past GROUP_TEXTS_MAX, never."""
+    cols = _nest(empty=empty)
+    assert (4 * len(cols) >= 3570) == kept
+    wire.count_response_bytes(QueryResponse(results=[cols]), None)
+    texts = cols.axes.reply_texts
+    assert (texts is not None and len(texts) == 3570) == kept
+    wire.count_response_bytes(QueryResponse(results=[cols[10:]]), None)
+    assert cols.axes.reply_texts is texts
+    monkeypatch.setattr(wire, "GROUP_TEXTS_MAX", 3569)
+    full = _nest(empty=0)
+    resp = QueryResponse(results=[full])
+    got = wire.count_response_bytes(resp, None)
+    assert full.axes.reply_texts is None
+    assert got == json.dumps(wire.response_to_json(resp)).encode()
+
+
+def test_axes_and_their_texts_go_when_a_grouped_field_is_written():
+    """The executor hands every request the same axes until a write
+    moves a grouped field's version; the reply after it is written from
+    new texts and lists the new row."""
+    h = Holder()
+    h.open()
+    idx = h.create_index("w")
+    idx.create_field("a").import_bulk([0, 0, 1, 1], [1, 2, 2, 3])
+    idx.create_field("b").import_bulk([0, 1, 2, 2], [1, 2, 2, 3])
+    eng = MeshEngine(h, make_mesh(1))
+    eng.result_memo.maxsize = 0
+    ex = Executor(h, mesh_engine=eng)
+    q = "GroupBy(Rows(field=a), Rows(field=b))"
+
+    def reply():
+        resp = ex.execute("w", q)
+        cols = resp.results[0]
+        assert type(cols) is GroupColumns
+        got = wire.count_response_bytes(resp, None)
+        assert got == json.dumps(wire.response_to_json(resp)).encode()
+        return cols.axes, json.loads(got)["results"][0]
+
+    try:
+        axes, first = reply()
+        assert axes.shape == (2, 3) and len(first) == 5 and axes.reply_texts is not None
+        again, second = reply()
+        assert again is axes and second == first
+        ex.execute("w", "Set(3, a=5)")
+        moved, third = reply()
+        assert moved is not axes and moved.shape == (3, 3) and moved.reply_texts is not None
+        assert third == first + [{"group": [{"field": "a", "rowID": 5},
+                                            {"field": "b", "rowID": 2}], "count": 1}]
+        assert ex._group_axes_cache[("w", ("a", "b"), (0,))] is moved
+    finally:
+        eng.close()
+        h.close()
+
+
+@pytest.mark.parametrize("cut", [slice(None), slice(3, None), slice(None, 4), slice(2, 7),
+                                 slice(9, None)], ids=str)
+def test_columns_are_the_group_count_list(cut):
+    """Element for element, and under the executor's offset and limit
+    cuts, which stay vector slices."""
+    want = _groups(["pc", "yr", "mi"], 9)
+    cols = _columns(["pc", "yr", "mi"], 9)[cut]
+    assert type(cols) is GroupColumns and cols.objects is None
+    assert len(cols) == len(want[cut])
+    assert cols == want[cut] and want[cut] == cols and not cols != want[cut]
+    assert [g for g in cols] == want[cut]
+    assert all(cols[i] == g for i, g in enumerate(want[cut]))
+    assert cols[1:].objects == cols.objects[1:]  # handed out: the objects are the result
+    assert cols != want[cut] + want[:1] and cols != 7
+
+
+def test_columns_hand_out_their_objects_once():
+    cols = _columns(["pc", "yr"], 6)
+    before = _forms()
+    first = cols[0]
+    assert list(cols)[0] is first and cols[0] is first  # a merge writes to what it was handed
+    assert _forms() == (before[0], before[1] + 1)
+    first.count += 5
+    assert _merge_group_counts(cols, _groups(["pc", "yr"], 2), 100)[0].count == 7
+
+
+@pytest.mark.parametrize("texts_max", [1 << 16, 0], ids=["kept_texts", "format_a_group"])
+def test_the_reply_of_3570_groups_builds_no_object_a_group(monkeypatch, texts_max):
+    """Tensor to bytes under 100 GC-tracked objects, with the result and
+    the payload both still held (the parent: a GroupCount, a list and a
+    tuple a group, ~11,000), and never 100 at once on the way: no
+    collection falls due at a threshold of 100."""
+    monkeypatch.setattr(wire, "GROUP_TEXTS_MAX", texts_max)
+    rng = np.random.default_rng(35)
+    counts = rng.integers(1, 1 << 20, 3570).astype(np.int32)
+    axes = GroupAxes(FIELDS, [np.arange(k, dtype=np.uint64) for k in (10, 7, 51)])
+
+    def reply():
+        flat = np.flatnonzero(counts > 0)
+        cols = GroupColumns(axes, flat, counts[flat])
+        return cols, wire.count_response_bytes(QueryResponse(results=[cols]), None)
+
+    reply()  # whatever a first call keeps
+    gc.collect()
+    gc.disable()
+    try:
+        before = len(gc.get_objects())
+        held = reply()
+        made = len(gc.get_objects()) - before
+    finally:
+        gc.enable()
+    assert len(json.loads(held[1])["results"][0]) == 3570
+    assert made < 100 and (axes.reply_texts is not None) == bool(texts_max)
+
+    due = []
+    note = lambda phase, info: due.append(info["generation"]) if phase == "start" else None  # noqa: E731
+    threshold = gc.get_threshold()
+    for _ in range(3):  # another thread of the test process may allocate meanwhile
+        gc.collect()
+        del due[:]
+        gc.callbacks.append(note)
+        gc.set_threshold(100, *threshold[1:])
+        try:
+            reply()
+        finally:
+            gc.set_threshold(*threshold)
+            gc.callbacks.remove(note)
+        if not due:
+            break
+    assert not due
+
+
+@pytest.fixture(scope="module")
+def routed(data):
+    """The data behind an API and an HTTP server on one device, with a
+    keyed field ``kd`` beside it."""
+    from pilosa_tpu.api import API, QueryRequest
+    from pilosa_tpu.net import serve
+
+    eng = MeshEngine(data[0], make_mesh(1))
+    eng.result_memo.maxsize = 0
+    api = API(holder=data[0], mesh_engine=eng)
+    api.create_field("i", "kd", {"type": "set", "keys": True})
+    cols = data[2]
+    api.query(QueryRequest("i", " ".join(
+        f'Set({int(c)}, kd="{"near" if n % 3 else "far"}")' for n, c in enumerate(cols[::40]))))
+    srv, _thread = serve(api, port=0)
+    yield api, f"http://localhost:{srv.server_address[1]}/index/i/query"
+    srv.shutdown()
+    eng.close()
+
+
+def _post(uri, q, headers=()):
+    req = urllib.request.Request(uri, data=q.encode(), method="POST", headers=dict(headers))
+    return urllib.request.urlopen(req, timeout=120).read()
+
+
+def _as_json(want, fields=FIELDS):
+    return [{"group": [{"field": f, "rowID": r} for f, r in zip(fields, rows)], "count": n}
+            for rows, n in want]
+
+
+def test_json_route_sends_the_columns_and_hands_out_no_object(routed, data):
+    _, uri = routed
+    want = reference(data[1], np.ones(len(data[2]), bool))
+    before = _forms()
+    payload = _post(uri, f"GroupBy({ROWS})")
+    assert _forms() == (before[0] + 1, before[1])
+    doc = json.loads(payload)
+    assert doc["results"] == [_as_json(want)]
+    assert payload == json.dumps(doc).encode()  # the generic encoder's bytes
+    payload = _post(uri, f"GroupBy({ROWS}, limit=40, offset=13) GroupBy(Rows(field=yr))")
+    assert _forms() == (before[0] + 3, before[1])
+    doc = json.loads(payload)
+    assert doc["results"][0] == _as_json(want[:40][13:]) and len(doc["results"][1]) == 7
+    assert payload == json.dumps(doc).encode()
+
+
+def _profiled(uri, want):
+    doc = json.loads(_post(uri + "?profile=1", f"GroupBy({ROWS})"))
+    assert [op["path"] for op in doc["plan"]["ops"] if "path" in op] == ["direct"]
+    return doc["results"] == [_as_json(want)]
+
+
+def _protobuf(uri, want):
+    from pilosa_tpu.net import proto
+
+    doc = proto.decode_query_response(
+        _post(uri, f"GroupBy({ROWS})", {"Accept": proto.CONTENT_TYPE}))
+    return not doc["err"] and doc["results"] == [
+        [GroupCount([FieldRow(f, r) for f, r in zip(FIELDS, rows)], n) for rows, n in want]]
+
+
+def _keyed(uri, want):
+    doc = json.loads(_post(uri, "GroupBy(Rows(field=kd), Rows(field=yr))"))
+    groups = doc["results"][0]
+    keys = [g["group"][0] for g in groups]
+    return (all(set(k) == {"field", "rowKey"} for k in keys)
+            and {k["rowKey"] for k in keys} == {"near", "far"}
+            and len(groups) == 14
+            and sum(g["count"] for g in groups) >= SHARDS * COLS // 40)  # a column, a year or two
+
+
+@pytest.mark.parametrize("route", [_profiled, _protobuf, _keyed], ids=lambda f: f.__name__)
+def test_routes_that_walk_the_groups_answer_as_before(routed, data, route):
+    """?profile=1, the protobuf reply and a keyed field take the result
+    as GroupCount objects: the device path's columns, handed out once."""
+    _, uri = routed
+    before = _forms()
+    assert route(uri, reference(data[1], np.ones(len(data[2]), bool)))
+    assert _forms() == (before[0] + 1, before[1] + 1)
+
+
+def test_merge_with_a_remote_partial_answers_as_before(served, data, monkeypatch):
+    """Shards the device path does not hold come from the mapper (here
+    the host iterator, as a remote node's partial would) and merge into
+    the columns' objects."""
+    ex, _ = served
+    want = reference(data[1], np.ones(len(data[2]), bool))
+    local = ex._local_shards
+    monkeypatch.setattr(ex, "_local_shards", lambda index, shards, remote: local(index, shards, remote)[:2])
+    before = _forms()
+    got, paths = run(ex, f"GroupBy({ROWS})")
+    assert got == want and paths and "host_fallback" not in paths
+    assert _forms() == (before[0] + 1, before[1] + 1)
+
+
+def test_host_iterator_counts_as_objects_and_equals_the_columns(served, data):
+    """A child the device path declines (``limit`` on a Rows) is the
+    host iterator's: a list, counted as objects; for plain children its
+    list is the device path's columns, group for group."""
+    ex, _ = served
+    before = _forms()
+    res = ex.execute("i", "GroupBy(Rows(field=pc, limit=3), Rows(field=yr))").results[0]
+    assert type(res) is list and len(res) == 21
+    assert _forms() == (before[0], before[1] + 1)
+    host = Executor(data[0]).execute("i", f"GroupBy({ROWS}, limit=300, offset=7)").results[0]
+    cols = ex.execute("i", f"GroupBy({ROWS}, limit=300, offset=7)").results[0]
+    assert type(host) is list and type(cols) is GroupColumns and len(cols) == 293
+    assert cols == host and cols[5:50] == host[5:50]
 
 
 def test_fused_group_edge_counts_3570_combinations(served):
