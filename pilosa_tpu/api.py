@@ -1658,6 +1658,8 @@ class API:
             return eng.group_counts_async(
                 index, payload["fields"], payload["rows"], call_of("filter"),
                 shards, broadcast=False,
+                aggregate=payload.get("aggregate"),
+                traced=payload.get("traced"),
             )
         raise ApiError(f"unknown collective kind: {kind}")
 

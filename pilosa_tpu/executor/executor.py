@@ -19,6 +19,8 @@ held as columns, where the device counted), bool for mutations.
 from __future__ import annotations
 
 import datetime as dt
+import functools
+import itertools
 import math
 import threading
 import time
@@ -208,11 +210,17 @@ class FieldRow:
 
 
 class GroupCount:
-    __slots__ = ("group", "count")
+    """One group of a GroupBy reply.  ``sum`` is None unless the call
+    gave ``aggregate=Sum(field=...)``: then it is the sum of that field
+    over the group's columns that have a value."""
 
-    def __init__(self, group: List[FieldRow], count: int):
+    __slots__ = ("group", "count", "sum")
+
+    def __init__(self, group: List[FieldRow], count: int,
+                 sum: Optional[int] = None):
         self.group = group
         self.count = count
+        self.sum = sum
 
     def compare(self, other: "GroupCount") -> int:
         """Order by row ids, field-major (executor.go Compare :1043)."""
@@ -228,13 +236,19 @@ class GroupCount:
             isinstance(other, GroupCount)
             and self.group == other.group
             and self.count == other.count
+            and self.sum == other.sum
         )
 
     def __repr__(self):
-        return f"GroupCount({self.group}, count={self.count})"
+        if self.sum is None:
+            return f"GroupCount({self.group}, count={self.count})"
+        return f"GroupCount({self.group}, count={self.count}, sum={self.sum})"
 
     def to_dict(self):
-        return {"group": [g.to_dict() for g in self.group], "count": self.count}
+        d = {"group": [g.to_dict() for g in self.group], "count": self.count}
+        if self.sum is not None:
+            d["sum"] = self.sum
+        return d
 
 
 class GroupAxes:
@@ -244,23 +258,29 @@ class GroupAxes:
     keeps one a (index, fields, shards) for as long as it keeps the row
     vectors (``Executor._group_axes``), so what a consumer derives from
     the axes alone can stay with them: ``reply_texts`` is the JSON reply
-    encoder's (net/wire.py), None until it has filled it."""
+    encoder's (net/wire.py), None until it has filled it.  Axes that a
+    ``Rows`` child's ``previous`` / ``limit`` / ``column`` cut for one
+    request are made for that request and not ``kept``: nothing is
+    written on them."""
 
-    __slots__ = ("fields", "rows", "shape", "size", "reply_texts")
+    __slots__ = ("fields", "rows", "shape", "size", "reply_texts", "kept")
 
-    def __init__(self, fields, rows):
+    def __init__(self, fields, rows, kept=True):
         self.fields = tuple(fields)
         self.rows = tuple(rows)
         self.shape = tuple(len(vec) for vec in self.rows)
         self.size = math.prod(self.shape)  # combinations
         self.reply_texts = None
+        self.kept = kept
 
 
 class GroupColumns(Sequence):
     """A GroupBy result over row ids, held as columns: group ``i`` is
     combination ``flat[i]`` of ``axes`` (``rows[d][i]`` is its row of
-    ``fields[d]``), counted ``counts[i]`` times; ``flat`` and ``counts``
-    are integer vectors of one length, in the nested-iterator order.
+    ``fields[d]``), counted ``counts[i]`` times and, under
+    ``aggregate=Sum(...)``, summing to ``sums[i]`` (``sums`` is None
+    without the argument); ``flat``, ``counts`` and ``sums`` are integer
+    vectors of one length, in the nested-iterator order.
     What the device path hands out: the reply encoder formats the
     vectors as they are (net/wire.py), and slicing cuts them.  To
     everything else it is the ``GroupCount`` list of the same groups:
@@ -270,12 +290,13 @@ class GroupColumns(Sequence):
     encoder tests before it trusts the columns.  A field with keys never
     stays columnar: ``translate`` turns it into the list."""
 
-    __slots__ = ("axes", "flat", "counts", "objects")
+    __slots__ = ("axes", "flat", "counts", "sums", "objects")
 
-    def __init__(self, axes: GroupAxes, flat, counts, objects=None):
+    def __init__(self, axes: GroupAxes, flat, counts, objects=None, sums=None):
         self.axes = axes
         self.flat = flat
         self.counts = counts
+        self.sums = sums
         self.objects: Optional[List[GroupCount]] = objects
 
     @property
@@ -297,9 +318,13 @@ class GroupColumns(Sequence):
                 # One FieldRow a (field, row), shared by its groups.
                 frs = {r: FieldRow(f, r) for r in set(col)}
                 cols.append([frs[r] for r in col])
+            sums = (
+                itertools.repeat(None) if self.sums is None
+                else self.sums.tolist()
+            )
             self.objects = [
-                GroupCount(list(group), n)
-                for group, n in zip(zip(*cols), self.counts.tolist())
+                GroupCount(list(group), n, s)
+                for group, n, s in zip(zip(*cols), self.counts.tolist(), sums)
             ]
         return self.objects
 
@@ -313,6 +338,7 @@ class GroupColumns(Sequence):
                 self.flat[i],
                 self.counts[i],
                 None if self.objects is None else self.objects[i],
+                None if self.sums is None else self.sums[i],
             )
         return self._materialise()[i]
 
@@ -423,6 +449,8 @@ def _merge_group_counts(
             i += 1
         elif c == 0:
             a[i].count += b[j].count
+            if a[i].sum is not None and b[j].sum is not None:
+                a[i].sum += b[j].sum
             out.append(a[i])
             i += 1
             j += 1
@@ -2424,20 +2452,50 @@ class Executor:
             start=start, column=column, limit=limit_arg if has_limit else None
         )
 
+    # What GroupBy implements beside its Rows children.  ``previous`` (a
+    # list) is the translator's (translate._translate_group_by).
+    _GROUP_BY_ARGS = frozenset(
+        ("limit", "offset", "filter", "aggregate", "previous")
+    )
+
+    def _group_aggregate(self, idx, c: Call) -> Optional[str]:
+        """The int field F of ``aggregate=Sum(field=F)``, None without
+        the argument; anything else as ``aggregate`` is an error."""
+        agg = c.args.get("aggregate")
+        if agg is None:
+            return None
+        if (
+            not isinstance(agg, Call)
+            or agg.name != "Sum"
+            or agg.children
+            or set(agg.args) != {"field"}
+            or not isinstance(agg.args["field"], str)
+        ):
+            raise Error("GroupBy(): aggregate must be Sum(field=<int field>)")
+        fname = agg.args["field"]
+        f = idx.field(fname)
+        if f is None:
+            raise FieldNotFoundError(fname)
+        if f.bsi_group(fname) is None:
+            raise Error(f"GroupBy(): aggregate field '{fname}' is not an int field")
+        return fname
+
     def _execute_group_by(
         self, index, c: Call, shards, opt
     ) -> Sequence[GroupCount]:
         if not c.children:
             raise Error("need at least one child call")
+        unknown = sorted(set(c.args) - self._GROUP_BY_ARGS)
+        if unknown:
+            raise Error(f"GroupBy(): unknown argument '{unknown[0]}'")
         limit_arg, has_limit = c.uint_arg("limit")
         limit = limit_arg if has_limit else _MAXINT
         filter_call = c.call_arg("filter")
 
-        child_rows: List[Optional[List[int]]] = [None] * len(c.children)
         idx = self.holder.index(index)
         if idx is None:
             raise IndexNotFoundError(index)
-        for i, child in enumerate(c.children):
+        for child in c.children:
             if child.name != "Rows":
                 raise Error(
                     f"'{child.name}' is not a valid child query for GroupBy, "
@@ -2448,35 +2506,46 @@ class Executor:
             fname = child.args.get("field")
             if not isinstance(fname, str) or idx.field(fname) is None:
                 raise FieldNotFoundError(str(fname))
-            _, has_lim = child.uint_arg("limit")
-            _, has_col = child.uint_arg("column")
-            if has_lim or has_col:
-                child_rows[i] = self._execute_rows(index, child, shards, opt)
-                if not child_rows[i]:
-                    return []
+        aggregate = self._group_aggregate(idx, c)
 
-        def map_fn(shard):
-            return self._execute_group_by_shard(
-                index, c, filter_call, shard, child_rows
-            )
+        # The host iterator's row filters: the full result of every child
+        # that has limit/column, over all the shards.  Resolved once, and
+        # only where the iterator runs or the device path needs them.
+        @functools.cache
+        def child_rows() -> List[Optional[List[int]]]:
+            out: List[Optional[List[int]]] = [None] * len(c.children)
+            for i, child in enumerate(c.children):
+                _, has_lim = child.uint_arg("limit")
+                _, has_col = child.uint_arg("column")
+                if has_lim or has_col:
+                    out[i] = self._execute_rows(index, child, shards, opt)
+            return out
 
-        def reduce_fn(prev, v):
-            return _merge_group_counts(prev or [], v, limit)
+        def host(over):
+            rows = child_rows()
+            if any(r is not None and not r for r in rows):
+                return []
 
-        fused = self._mesh_group_by(index, c, filter_call, shards, opt)
+            def map_fn(shard):
+                return self._execute_group_by_shard(
+                    index, c, filter_call, shard, rows, aggregate
+                )
+
+            def reduce_fn(prev, v):
+                return _merge_group_counts(prev or [], v, limit)
+
+            return self.map_reduce(index, over, c, opt, map_fn, reduce_fn) or []
+
+        fused = self._mesh_group_by(
+            index, c, filter_call, shards, opt, aggregate, child_rows
+        )
         if fused is not None:
             local_shards, results = fused
             remote = [s for s in shards if s not in local_shards]
             if remote:
-                rres = (
-                    self.map_reduce(index, remote, c, opt, map_fn, reduce_fn)
-                    or []
-                )
-                results = _merge_group_counts(results, rres, limit)
+                results = _merge_group_counts(results, host(remote), limit)
         else:
-            results = (
-                self.map_reduce(index, shards, c, opt, map_fn, reduce_fn) or []
-            )
+            results = host(shards)
             _GROUP_RESULTS["objects"].inc()
 
         # A GroupColumns stays one under both cuts (vector slices).
@@ -2487,23 +2556,72 @@ class Executor:
             results = results[:limit]
         return results
 
-    def _mesh_group_by(self, index, c: Call, filter_call, shards, opt):
+    def _group_axis(self, index, child: Call, shards, all_local, child_rows, i):
+        """One axis of the tensor, from a ``Rows`` child: (row list,
+        row vector, whether the child cut it).  A plain ``Rows(field=f)``
+        and a child with ``previous`` alone take the field's kept rows
+        (the start of a page is a cut of the result, ``_group_start``);
+        ``limit`` cuts the kept vector where every shard is local (no
+        walk of a shard), else it and ``column`` are what
+        ``_execute_rows`` resolved for the host iterator."""
+        rows, vec = self._group_rows(index, child.args["field"], shards)
+        lim, has_lim = child.uint_arg("limit")
+        _, has_col = child.uint_arg("column")
+        if not (has_lim or has_col):
+            return rows, vec, False
+        if has_col or not all_local:
+            rows = child_rows()[i]
+            return rows, np.asarray(rows, dtype=np.uint64), True
+        prev, has_prev = child.uint_arg("previous")
+        lo = int(np.searchsorted(vec, prev, side="right")) if has_prev else 0
+        vec = vec[lo:lo + lim]
+        return vec.tolist(), vec, True
+
+    @staticmethod
+    def _group_start(children, row_vecs) -> int:
+        """Flat index of the first combination a GroupBy lists, given
+        its children's ``previous``: the nested iterator seeks child i
+        to ``previous`` (the last child past it), and where a seek does
+        not land on that row the later children start from their first
+        (executor.go newGroupByIterator).  Over sorted row vectors that
+        is the least combination not below the bound tuple in
+        lexicographic order, and row-major order is that order."""
+        start, last = 0, len(children) - 1
+        for i, (child, vec) in enumerate(zip(children, row_vecs)):
+            prev, has_prev = child.uint_arg("previous")
+            start *= len(vec)
+            if not has_prev:
+                continue  # at its first row: position 0, go on matching
+            bound = prev + 1 if i == last else prev
+            j = int(np.searchsorted(vec, bound, side="left"))
+            start += j  # j == len(vec) carries into the field before
+            if j == len(vec) or int(vec[j]) != bound:
+                for v in row_vecs[i + 1:]:
+                    start *= len(v)
+                break
+        return start
+
+    def _mesh_group_by(self, index, c: Call, filter_call, shards, opt,
+                       aggregate=None, child_rows=None):
         """Fused GroupBy over the LOCAL shard subset: all group-combination
         counts in one sharded dispatch; remote shards are looped/RPC'd by
         the caller and merged (the _mesh_count composition pattern).
-        Applies to any number of plain ``Rows(field=f)`` children (no
-        column/limit/previous) whose combination count fits the engine's
-        cap; the merged list is then truncated to `limit` like the
+        Applies to any number of ``Rows`` children whose tensor fits the
+        engine's cap: a child's ``limit`` / ``column`` (with or without
+        ``previous``) cuts its axis to the rows ``_execute_rows`` names,
+        which reach the program as a traced index vector, and
+        ``previous`` alone moves the start of the listing
+        (``_group_start``).  With ``aggregate`` (the int field of
+        ``aggregate=Sum(field=...)``) the program also counts every
+        group under the measure's planes and the result carries
+        ``sums``.  The merged list is then truncated to `limit` like the
         reference's progressive merge.  Returns (local_shard_set,
         results) or None."""
         if self.mesh_engine is None or not c.children:
             return None
-        for child in c.children:
-            extra = set(child.args) - {"field"}
-            if child.name != "Rows" or extra:
-                return None
         seq = frag_mod.WRITE_SEQ.v  # BEFORE row_lists: a leader with
         # stale row sets must key as pre-write (see _aggregate_flight)
+        all_shards = shards
         shards = self._local_shards(index, shards, opt.remote)
         if not shards:
             return None
@@ -2511,18 +2629,22 @@ class Executor:
         # The count TENSOR rides the versioned result memo (the
         # assembled list never does — limit/offset assembly below reruns
         # on every serve, so a memo hit cannot drift from a recompute).
+        # An aggregated tensor stays out of it: the memo's tokens and
+        # repair.py's entry know the grouped fields' count tensor only.
         eng = self.mesh_engine
         probe = getattr(eng, "memo_probe_groupby", None)
         key = hit = None
-        if probe is not None:
+        if probe is not None and aggregate is None:
             qsig = str(c)
             if filter_call is not None:
                 qsig += "|flt:" + str(filter_call)
             key, hit = probe(index, qsig, fields, filter_call, shards)
         with tracing_mod.stage("group_rows"):
-            row_lists, row_vecs = zip(
-                *(self._group_rows(index, f, shards) for f in fields)
-            )
+            all_local = len(shards) == len(all_shards)
+            row_lists, row_vecs, cut = zip(*(
+                self._group_axis(index, child, shards, all_local, child_rows, i)
+                for i, child in enumerate(c.children)
+            ))
         if any(not rows for rows in row_lists):
             return set(shards), []
         shape = tuple(len(rows) for rows in row_lists)
@@ -2544,7 +2666,8 @@ class Executor:
                     # take the batcher's idle direct path (solo_op_async
                     # → group_counts_async, one readback).
                     lambda: self.mesh_engine.batched_group_counts(
-                        index, fields, row_lists, filter_call, shards
+                        index, fields, row_lists, filter_call, shards,
+                        aggregate, cut,
                     ),
                 )
             except (ValueError, PeerlessMeshError):
@@ -2560,25 +2683,57 @@ class Executor:
         if counts is None:
             return None
         limit_arg, has_limit = c.uint_arg("limit")
-        with tracing_mod.stage("group_decode"):
+        start = self._group_start(c.children, row_vecs)
+        with tracing_mod.stage(
+            "group_decode" if aggregate is None else "group_aggregate"
+        ):
             # np.flatnonzero walks the count tensor in row-major order —
             # exactly the nested-iterator order of the reference
             # (executor.go:2726), so cutting at ``limit`` non-zero groups
             # is the progressive limit truncation.  No Python step a
             # group: the index vector and the counts are the result.
+            cells = None
+            if aggregate is not None:
+                cells = np.asarray(counts).reshape(math.prod(shape), -1)
+                counts = cells[:, -1]
             counts = np.asarray(counts).reshape(shape).ravel()
             flat = np.flatnonzero(counts > 0)
+            if start:
+                flat = flat[np.searchsorted(flat, start):]
             if has_limit:
                 flat = flat[:limit_arg]
             if not len(flat):
                 return set(shards), []
+            if any(cut):
+                axes = GroupAxes(fields, row_vecs, kept=False)
+            else:
+                axes = self._group_axes(index, fields, shards, row_vecs)
             results = GroupColumns(
-                self._group_axes(index, fields, shards, row_vecs),
+                axes,
                 flat,
                 counts[flat],
+                sums=None if cells is None else self._group_sums(
+                    index, aggregate, cells[flat]
+                ),
             )
         _GROUP_RESULTS["columns"].inc()
         return set(shards), results
+
+    def _group_sums(self, index, aggregate: str, cells: np.ndarray):
+        """A sum a row of ``cells`` (int32[n, depth + 2]: a group's
+        popcounts under each value plane, then under the not-null
+        plane, then its count): Σ_b 2^b · cells[:, b] + min · cells[:,
+        depth], in integers.  int64 holds it while depth + 31 bits and
+        the base's do (a cell is an int32 count of columns); a deeper
+        field is summed in Python's own integers."""
+        bsig = self.holder.index(index).field(aggregate).bsi_group(aggregate)
+        depth = cells.shape[1] - 2
+        wide = depth <= 31 and abs(bsig.min) < (1 << 31)
+        planes = cells[:, :depth].astype(np.int64 if wide else object)
+        weights = np.array([1 << b for b in range(depth)],
+                           dtype=np.int64 if wide else object)
+        have = cells[:, depth].astype(np.int64 if wide else object)
+        return planes @ weights + have * bsig.min if depth else have * bsig.min
 
     # (index, field, shards) -> (version token, sorted row ids, the same
     # as a vector): the GroupBy axes of a field over a shard set, kept
@@ -2632,7 +2787,7 @@ class Executor:
         return out, vec
 
     def _execute_group_by_shard(
-        self, index, c: Call, filter_call, shard, child_rows
+        self, index, c: Call, filter_call, shard, child_rows, aggregate=None
     ) -> List[GroupCount]:
         filter_row = None
         if filter_call is not None:
@@ -2642,6 +2797,8 @@ class Executor:
         )
         if iterator is None:
             return []
+        if aggregate is not None:
+            iterator.measure = self._shard_measure(index, aggregate, shard)
         limit_arg, has_limit = c.uint_arg("limit")
         limit = limit_arg if has_limit else _MAXINT
         results: List[GroupCount] = []
@@ -2652,6 +2809,19 @@ class Executor:
             if gc.count > 0:
                 results.append(gc)
         return results
+
+    def _shard_measure(self, index, aggregate: str, shard: int):
+        """(shard, planes uint32[depth + 1, WORDS] or None, min) of an
+        int field's fragment: what the host iterator sums a group
+        under (fragment.go sum, by plane popcounts)."""
+        bsig = self.holder.index(index).field(aggregate).bsi_group(aggregate)
+        frag = self.holder.fragment(
+            index, aggregate, view_bsi_name(aggregate), shard
+        )
+        planes = None
+        if frag is not None:
+            planes = np.asarray(frag.device_planes(bsig.bit_depth()))
+        return shard, planes, bsig.min
 
     # -- Options (executor.go :317) ----------------------------------------
 
@@ -3003,6 +3173,9 @@ class _GroupByIterator:
         self.fields: List[FieldRow] = []
         self.filter: Optional[Row] = None
         self.done = False
+        # (shard, planes, min) under aggregate=Sum(...): next() then
+        # sums every listed group (Executor._shard_measure).
+        self.measure = None
 
     @classmethod
     def create(
@@ -3082,5 +3255,24 @@ class _GroupByIterator:
             for f, (_, rid) in zip(self.fields, self.rows)
         ]
         ret = GroupCount(group, count)
+        if self.measure is not None:
+            ret.sum = self._sum() if count else 0
         self._next_at_idx(len(self.rows) - 1)
         return ret, False
+
+    def _sum(self) -> int:
+        """The measure summed over the current group's columns that
+        have a value: Σ_b 2^b · popcount(group & notnull & plane_b) +
+        min · popcount(group & notnull)."""
+        shard, planes, base = self.measure
+        row = self.rows[-1][0]
+        if len(self.rows) > 1:
+            row = row.intersect(self.rows[-2][0])
+        seg = row.segment(shard)
+        if planes is None or seg is None:
+            return 0
+        have = np.asarray(seg) & planes[-1]
+        counts = np.bitwise_count(planes[:-1] & have).sum(axis=1).tolist()
+        return int(np.bitwise_count(have).sum()) * base + sum(
+            n << b for b, n in enumerate(counts)
+        )

@@ -334,6 +334,8 @@ def encode_result(result) -> bytes:
                 frb = _str_field(1, fr.field) + _varint_field(2, fr.row_id)
                 body += _len_field(1, frb)
             body += _varint_field(2, gc.count)
+            if gc.sum is not None:  # aggregate=Sum(...): GroupCount.Sum, int64
+                body += _varint_field(3, gc.sum)
             out += _len_field(8, body)
     elif isinstance(result, list) and result and isinstance(result[0], tuple):
         typ = RESULT_PAIRS
@@ -432,6 +434,7 @@ def _decode_group_count(data) -> GroupCount:
     r = _Reader(data)
     group = []
     count = 0
+    total = None
     while not r.eof():
         f, w = r.tag()
         if f == 1:
@@ -448,9 +451,11 @@ def _decode_group_count(data) -> GroupCount:
             group.append(FieldRow(field, row_id))
         elif f == 2:
             count = r.uvarint()
+        elif f == 3:
+            total = r.svarint()
         else:
             r.skip(w)
-    return GroupCount(group, count)
+    return GroupCount(group, count, total)
 
 
 def _decode_row_identifiers(data) -> RowIdentifiers:

@@ -165,26 +165,37 @@ def _group_list_json(groups: GroupColumns) -> str:
     share of its axes' combinations (taxi query 4: ~3,500 of 3,570, half
     a megabyte) is one gather from the texts kept with the axes and one
     join; the texts are written at the first such reply and go with the
-    axes.  Any other is one format string and one ``%`` a group over the
-    row vectors."""
+    axes.  Any other (and every reply over axes made for one request,
+    which keep nothing) is one format string and one ``%`` a group over
+    the row vectors.  With ``sums`` a group's ``"sum"`` follows its
+    ``"count"``."""
     n = len(groups)
     if not n:
         return "[]"
     axes = groups.axes
-    if axes.size <= GROUP_TEXTS_MAX and 4 * n >= axes.size:
+    sums = groups.sums
+    if axes.kept and axes.size <= GROUP_TEXTS_MAX and 4 * n >= axes.size:
         texts = axes.reply_texts
         if texts is None:
             texts = axes.reply_texts = _group_texts(axes)
         parts = [None] * (2 * n)
         parts[0::2] = texts[groups.flat].tolist()
-        parts[1::2] = map(str, groups.counts.tolist())
+        if sums is None:
+            parts[1::2] = map(str, groups.counts.tolist())
+        else:
+            parts[1::2] = [
+                '%d, "sum": %d' % t
+                for t in zip(groups.counts.tolist(), sums.tolist())
+            ]
         return "[" + "".join(parts)[3:] + "}]"
     fmt = '{"group": [' + ", ".join(
         '{"field": %s, "rowID": %%d}' % json.dumps(f).replace("%", "%%")
         for f in groups.fields
-    ) + '], "count": %d}'
+    ) + '], "count": %d' + ("}" if sums is None else ', "sum": %d}')
     cols = [col.tolist() for col in groups.rows]
     cols.append(groups.counts.tolist())
+    if sums is not None:
+        cols.append(sums.tolist())
     return "[" + ", ".join([fmt % t for t in zip(*cols)]) + "]"
 
 
@@ -240,6 +251,7 @@ def result_from_json(call_name: str, doc):
                         for g in d["group"]
                     ],
                     d["count"],
+                    d.get("sum"),
                 )
                 for d in doc
             ]

@@ -664,6 +664,11 @@ class CountBatcher:
         for index, items in by_index.items():
             aggs = [it for it in items if it.kind != "count"]
             counts = [it for it in items if it.kind == "count"]
+            # An aggregated GroupBy keeps to its solo program: the fused
+            # "group" edge knows a count tensor only.
+            alone = [it for it in aggs if it.spec.get("aggregate")]
+            groups.extend(("solo", index, [it]) for it in alone)
+            aggs = [it for it in aggs if not it.spec.get("aggregate")]
             if aggs and fusion_ok:
                 from .fusion import item_texts, subtree_texts
 

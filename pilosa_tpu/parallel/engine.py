@@ -31,7 +31,7 @@ import threading
 import time
 import weakref
 from collections import OrderedDict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +69,7 @@ from ..util.stats import (
     METRIC_ENGINE_FUSED_PROGRAMS,
     METRIC_ENGINE_FUSED_QUERIES,
     METRIC_ENGINE_GROUP_COMBOS,
+    METRIC_ENGINE_GROUP_SUM_PASSES,
     METRIC_ENGINE_PROMOTIONS,
     METRIC_ENGINE_REBUILDS,
     METRIC_ENGINE_RESIDENT_BLOCK_FRACTION,
@@ -977,6 +978,9 @@ class MeshEngine:
         self._bytes_skipped_counter = REGISTRY.counter(
             METRIC_DEVICE_BYTES_SKIPPED
         )
+        self._group_sum_passes_counter = REGISTRY.counter(
+            METRIC_ENGINE_GROUP_SUM_PASSES
+        )
         self._group_combos_counter = REGISTRY.counter(
             METRIC_ENGINE_GROUP_COMBOS
         )
@@ -1093,18 +1097,31 @@ class MeshEngine:
         return self._note_drain(op, "aggregate", 1, 1, planes, planes)
 
     def _note_group(self, index, fields, row_lists, filter_call,
-                    groups: int) -> dict:
+                    groups: int, aggregate=None, cells: int = 0) -> dict:
         """Drain record of a solo GroupBy: one request, one slot, every
-        group row's plane plus the filter's; counts the combinations
-        the device evaluates."""
+        group row's plane plus the filter's (and the measure's, under
+        ``aggregate``); counts the combinations the device evaluates
+        and, with a measure, the popcount passes (``cells``:
+        combinations x (depth + 2))."""
         hints: dict = {}
         for fname, rows in zip(fields, row_lists):
             hints[(index, fname, VIEW_STANDARD)] = set(rows)
+        if aggregate is not None:
+            hints[(index, aggregate, view_bsi_name(aggregate))] = None
         if filter_call is not None:
             self._collect_row_hints(index, filter_call, hints)
         planes = self._hint_planes(hints)
         self._group_combos_counter.inc(groups)
-        plans_mod.note_dispatch(groups=int(groups))
+        note = {"groups": int(groups)}
+        if aggregate is not None:
+            self._group_sum_passes_counter.inc(cells)
+            # The result memo's tokens and repair.py's groupby entry
+            # know a count tensor of the fields' shape only.
+            note.update(
+                aggregate=f"Sum({aggregate})", memo="skipped",
+                memo_reason="aggregate: a plane axis is not memoized",
+            )
+        plans_mod.note_dispatch(**note)
         return self._note_drain("GroupBy", "group", 1, 1, planes, planes)
 
     def _fetch(self, dev, since: Optional[float] = None):
@@ -3724,7 +3741,8 @@ class MeshEngine:
         if kind == "group":
             dev = self.group_counts_async(
                 index, spec["fields"], spec["rows"], spec.get("filter"),
-                shards,
+                shards, aggregate=spec.get("aggregate"),
+                traced=spec.get("traced"),
             )
             if dev is None:
                 return None, fusion_mod._Const(fusion_mod.DECLINED)
@@ -3809,20 +3827,27 @@ class MeshEngine:
         return None if out is fusion_mod.DECLINED else out
 
     def batched_group_counts(self, index: str, fields, row_lists,
-                             filter_call, shards):
+                             filter_call, shards, aggregate=None,
+                             traced=None):
         """GroupBy combo counts through the batcher; returns the counts
         ndarray, or None when the fused path declines (combo blowup or
-        missing stack) — the caller falls back to the host path."""
+        missing stack) — the caller falls back to the host path.  With
+        ``aggregate`` (``group_counts_async``) the ndarray has the
+        measure's plane axis last, and the call keeps to its solo
+        program in every drain (the fused ``group`` edge knows counts
+        only: batcher._groups)."""
         if self.multiproc:
             return self.group_counts(
-                index, fields, row_lists, filter_call, shards
+                index, fields, row_lists, filter_call, shards,
+                aggregate, traced,
             )
-        out = self.batcher().submit_op(
-            index, "group",
-            {"kind": "group", "fields": list(fields),
-             "rows": [list(r) for r in row_lists], "filter": filter_call},
-            shards,
-        )
+        spec = {"kind": "group", "fields": list(fields),
+                "rows": [list(r) for r in row_lists], "filter": filter_call}
+        if aggregate is not None:
+            spec["aggregate"] = aggregate
+        if traced is not None and any(traced):
+            spec["traced"] = tuple(bool(t) for t in traced)
+        out = self.batcher().submit_op(index, "group", spec, shards)
         return None if out is fusion_mod.DECLINED else out
 
     def count_many(self, index: str, calls, shards_list) -> List[int]:
@@ -4699,12 +4724,13 @@ class MeshEngine:
             pairs = pairs[: int(n)]
         return pairs
 
-    # Bound on the count TENSOR of one GroupBy, in groups: int32[groups]
-    # is read back whole and walked by the executor (np.nonzero), 4 MiB
-    # and a few ms at this size.  Nothing in the program grows with the
-    # group count (kernels.group_tree), so this is no compile-time cap;
-    # past it the host iterator answers, whose progressive ``limit``
-    # never builds the tensor.
+    # Bound on the TENSOR of one GroupBy that is read back, in int32
+    # cells: the groups, times the measure's depth + 2 under
+    # ``aggregate=Sum(...)``.  It is read back whole and walked by the
+    # executor (np.nonzero), 4 MiB and a few ms at this size.  Nothing in
+    # the program grows with the group count (kernels.group_tree), so
+    # this is no compile-time cap; past it the host iterator answers,
+    # whose progressive ``limit`` never builds the tensor.
     MAX_GROUPS = 1 << 20
 
     def group_counts_async(
@@ -4715,11 +4741,21 @@ class MeshEngine:
         filter_call: Optional[Call],
         shards: List[int],
         broadcast: bool = True,
+        aggregate: Optional[str] = None,
+        traced: Optional[Sequence[bool]] = None,
     ):
         """GroupBy dispatch (kernels.group_tree) with the
-        int32[K1, ..., Kn] count tensor left on device; returns None
-        when the device path doesn't apply (no shards, peerless
-        multi-process mesh, a missing stack, or a count tensor over
+        int32[K1, ..., Kn] count tensor left on device, or, with
+        ``aggregate`` (an int field: ``aggregate=Sum(field=...)``),
+        the int32[K1, ..., Kn, depth + 2] tensor of every group's
+        popcounts under the measure's value planes, under its not-null
+        plane, and alone (``decode_group_sums`` assembles the sums).
+        ``traced[i]`` says that field i's row list changes from request
+        to request (a ``Rows`` child with ``previous`` / ``limit`` /
+        ``column``): its indices then ride a traced operand whatever
+        they are, so that the program is one per list LENGTH.  Returns
+        None when the device path doesn't apply (no shards, peerless
+        multi-process mesh, a missing stack, or a tensor over
         MAX_GROUPS)."""
         if broadcast and self._peerless_multiproc:
             return None
@@ -4728,15 +4764,30 @@ class MeshEngine:
         groups = 1
         for rows in row_lists:
             groups *= max(len(rows), 1)
-        if groups > self.MAX_GROUPS:
-            return None
         canonical = self.canonical_shards(index)
         if not canonical:
             return None
+        cells, plane_stack, pspec = groups, None, None
+        if aggregate is not None:
+            idx = self.holder.index(index)
+            f = idx.field(aggregate) if idx is not None else None
+            bsig = f.bsi_group(aggregate) if f is not None else None
+            if bsig is None:
+                raise ValueError(f"not an int field: {aggregate}")
+            depth = bsig.bit_depth()
+            cells = groups * (depth + 2)
+            view = view_bsi_name(aggregate)
+            plane_stack = self.field_stack(index, aggregate, view, canonical)
+            if plane_stack is None:
+                return None
+            self._require_full_stack(index, aggregate, view, plane_stack)
+            pspec = self._plane_spec(plane_stack, depth)
+        if cells > self.MAX_GROUPS:
+            return None
         stacks = []
         statics = []
-        extra_ops = []
-        for fname, rows in zip(fields, row_lists):
+        traced_idx = []
+        for i, (fname, rows) in enumerate(zip(fields, row_lists)):
             stack = self.field_stack(index, fname, VIEW_STANDARD, canonical)
             if stack is None:
                 return None
@@ -4747,15 +4798,17 @@ class MeshEngine:
             # keys; subset lists (shard-restricted queries, child limit/
             # column args) stay traced — they vary per query and must
             # not recompile.
-            if kernels.gather_free(t):
+            if kernels.gather_free(t) and not (traced and traced[i]):
                 statics.append(t)
             else:
                 statics.append(None)
-                extra_ops.append(
-                    put_global(
-                        self.mesh, np.asarray(t, dtype=np.int32), P()
-                    )
-                )
+                traced_idx.append(np.asarray(t, dtype=np.int32))
+        extra_ops = []
+        if traced_idx:
+            with tracing.stage("group_index_put"):
+                extra_ops = [put_global(self.mesh, t, P()) for t in traced_idx]
+        if plane_stack is not None:
+            stacks.append(plane_stack)
         mask = self._mask_words(shards, canonical)
         extra_specs = (P(),) * len(extra_ops)
 
@@ -4765,7 +4818,8 @@ class MeshEngine:
                 prog = self._lower_filter(index, filter_call, lw)
                 self._note_fused_dispatch()
                 drain = self._note_group(
-                    index, fields, row_lists, filter_call, groups
+                    index, fields, row_lists, filter_call, groups,
+                    aggregate, cells,
                 )
             with tracing.stage("dispatch", **drain):
                 return kernels.group_tree(
@@ -4774,6 +4828,7 @@ class MeshEngine:
                     extra_specs + tuple(lw.specs),
                     tuple(statics),
                     self._group_pallas,
+                    pspec,
                     mask,
                     *[st.matrix for st in stacks],
                     *extra_ops,
@@ -4789,6 +4844,8 @@ class MeshEngine:
                 "filter": None if filter_call is None else str(filter_call),
                 "shards": list(shards),
                 "canon": [int(x) for x in canonical],
+                "aggregate": aggregate,
+                "traced": None if traced is None else [bool(t) for t in traced],
             },
             dispatch,
             broadcast,
@@ -4801,6 +4858,8 @@ class MeshEngine:
         row_lists: List[List[int]],
         filter_call: Optional[Call],
         shards: List[int],
+        aggregate: Optional[str] = None,
+        traced: Optional[Sequence[bool]] = None,
     ):
         """GroupBy over any number of Rows children: every group
         combination counted in ONE sharded dispatch — row gathers and the
@@ -4808,7 +4867,10 @@ class MeshEngine:
         GroupBy+Count shard reduce).  Returns int32[K1, ..., Kn] counts
         in row-id order, over the requested shard subset only, or None
         where ``group_counts_async`` declines."""
-        dev = self.group_counts_async(index, fields, row_lists, filter_call, shards)
+        dev = self.group_counts_async(
+            index, fields, row_lists, filter_call, shards,
+            aggregate=aggregate, traced=traced,
+        )
         if dev is None:
             return None
         return np.asarray(self._fetch(dev))

@@ -755,7 +755,10 @@ def build(engine, entries: List[tuple]) -> FusedPlan:
                 fields = list(spec.get("fields") or ())
                 row_lists = [list(r) for r in spec.get("rows") or ()]
                 filter_call = spec.get("filter")
-                if not fields:
+                if not fields or spec.get("aggregate"):
+                    # The edge emits a count tensor only: an aggregated
+                    # GroupBy runs its solo program (the batcher never
+                    # fuses one); handed one directly, decline.
                     routes[i] = ("const", DECLINED)
                     continue
                 combos = 1

@@ -167,6 +167,25 @@ def gather_rows(mat, idxs):
     return jnp.take(mat, idxs, axis=0)
 
 
+# Rows a traced axis of a GroupBy copies one dynamic slice each; a longer
+# one is one ``jnp.take`` (see ``slice_rows``).
+SLICE_ROWS_MAX = 64
+
+
+def slice_rows(mat, idxs):
+    """``gather_rows`` for a traced int32[K] of a GroupBy axis that a
+    ``Rows`` child cut: K dynamic slices (K is static), each a copy of
+    one [S, W] row.  ``jnp.take`` on a [250, S, W] stack compiles, for a
+    v5e, to a copy of the whole stack first (2.1 GB of scratch at 64
+    shards for 10 rows of it: tests/test_tpu_compile.py)."""
+    if idxs.shape[0] > SLICE_ROWS_MAX:
+        return jnp.take(mat, idxs, axis=0)
+    return jnp.stack([
+        jax.lax.dynamic_index_in_dim(mat, idxs[k], 0, keepdims=False)
+        for k in range(idxs.shape[0])
+    ])
+
+
 def replicate_shards(x, n_dev, axis=0):
     """[.., S_local, ..] -> replicated [.., S_total, ..]: scatter the
     local block at this device's offset and psum.  Equivalent to a tiled
@@ -721,6 +740,18 @@ def fused_tree(mesh, fspec, specs, *operands):
 # last field.  Neither unrolls anything per combination (the Pallas body
 # unrolls its inner loop over at most GROUP_UNROLL_WHOLE rows of the last
 # field), so the trace and the compile time do not grow with prod(K).
+#
+# With a measure (``GroupBy(..., aggregate=Sum(field=v))``) the BSI view's
+# planes are one more, innermost axis of the tensor: every combination's
+# mask is scored against each of v's ``depth`` value planes under the
+# not-null plane, against the not-null plane, and alone:
+#   cells[k1, ..., kn, b]         = popcount(mask & notnull & plane_b)
+#   cells[k1, ..., kn, depth]     = popcount(mask & notnull)
+#   cells[k1, ..., kn, depth + 1] = popcount(mask)          (the count)
+# so a group's sum is Σ_b 2^b · cells[..., b] (+ base · cells[..., depth]),
+# assembled on the host in integers.  A cell is an int32 popcount over one
+# device's columns, psum'ed over the mesh: exact while an index holds
+# fewer than 2^31 columns (2,048 shards).
 
 # Pallas body: words of one column tile (lanes), shards of one tile
 # (sublanes).  The tile's blocks — filter + every group row — are double
@@ -766,20 +797,30 @@ def _prefix_rows(p, dims):
     return ks[::-1]
 
 
-def _group_counts_xla(f, rows):
+def _group_counts_xla(f, rows, planes=None):
     """Per-device GroupBy counts, plain XLA: f uint32[S, W], rows a list
     of uint32[Ki, S, W] -> int32[prod(K)] (row-major).  One loop step a
     prefix: the prefix mask, then a broadcast popcount-reduce over the
     last field's rows.  Every step re-reads the last field's planes, so
     this is the body for backends without the Pallas one (the CPU) and
-    for shapes it declines, not the fast path on a TPU."""
-    pre_dims = tuple(r.shape[0] for r in rows[:-1])
+    for shapes it declines, not the fast path on a TPU.  With ``planes``
+    (a measure's uint32[depth + 1, S, W]) every field is part of the
+    prefix and a step scores the measure's planes: int32[prod(K) *
+    (depth + 2)], the cells of the header comment."""
+    pre_rows = rows if planes is not None else rows[:-1]
+    pre_dims = tuple(r.shape[0] for r in pre_rows)
     last = rows[-1]
 
     def one(p):
         pre = f
-        for r, k in zip(rows[:-1], _prefix_rows(p, pre_dims)):
+        for r, k in zip(pre_rows, _prefix_rows(p, pre_dims)):
             pre = pre & jax.lax.dynamic_index_in_dim(r, k, 0, keepdims=False)
+        if planes is not None:
+            have = pre & planes[-1]
+            return jnp.concatenate([
+                jnp.sum(_pc(planes[:-1] & have[None]), axis=(1, 2)),
+                jnp.stack([jnp.sum(_pc(have)), jnp.sum(_pc(pre))]),
+            ])
         return jnp.sum(_pc(last & pre[None]), axis=(1, 2))
 
     n_pre = 1
@@ -790,9 +831,11 @@ def _group_counts_xla(f, rows):
     return jax.lax.map(one, jnp.arange(n_pre, dtype=jnp.int32)).reshape(-1)
 
 
-def _group_counts_pallas(f, rows, tile_words, interpret=False):
+def _group_counts_pallas(f, rows, tile_words, interpret=False, planes=None):
     """Per-device GroupBy counts as ONE Pallas kernel: same contract as
-    ``_group_counts_xla``.  Grid = (passes, shard tiles, word tiles); a
+    ``_group_counts_xla`` (with ``planes``, the measure's planes take the
+    last field's place in the inner loop: a combination's mask stays in
+    registers while its depth + 2 cells are scored, unrolled whole).  Grid = (passes, shard tiles, word tiles); a
     step holds the tile of the filter and of EVERY group row in VMEM, so
     each plane leaves HBM once a pass, and the kernel's work is the
     prod(K) AND + popcount passes over the tile on the vector unit:
@@ -804,6 +847,10 @@ def _group_counts_pallas(f, rows, tile_words, interpret=False):
     from jax.experimental.pallas import tpu as pltpu
 
     dims = tuple(r.shape[0] for r in rows)
+    if planes is not None:
+        depth = planes.shape[0] - 1
+        dims += (depth + 2,)
+        rows = list(rows) + [planes]
     pre_dims, k_last = dims[:-1], dims[-1]
     n_pre = 1
     for d in pre_dims:
@@ -834,12 +881,23 @@ def _group_counts_pallas(f, rows, tile_words, interpret=False):
                 pre = pre & ref[k]
             base = pl_i * k_last
 
-            def score(k):
-                hits = _pc(pre & last_ref[k])
+            def add(k, mask):
+                hits = _pc(mask)
                 part = hits[:, 0:128]
                 for j in range(1, lanes):
                     part = part + hits[:, j * 128:(j + 1) * 128]
                 acc_ref[base + k] += part
+
+            if planes is not None:
+                have = pre & last_ref[depth]
+                for b in range(depth):
+                    add(b, have & last_ref[b])
+                add(depth, have)
+                add(depth + 1, pre)
+                return carry
+
+            def score(k):
+                add(k, pre & last_ref[k])
 
             def score_many(i, c):
                 for u in range(unroll):
@@ -861,7 +919,7 @@ def _group_counts_pallas(f, rows, tile_words, interpret=False):
         kernel,
         grid=(passes, S // ts, W // tw),
         in_specs=[pl.BlockSpec((ts, tw), lambda g, i, j: (i, j))]
-        + [tile(k) for k in dims],
+        + [tile(r.shape[0]) for r in rows],
         out_specs=pl.BlockSpec(
             (pre_block * k_last, ts, 128), lambda g, i, j: (g, 0, 0)
         ),
@@ -877,12 +935,20 @@ def _group_counts_pallas(f, rows, tile_words, interpret=False):
     return jnp.sum(out, axis=(1, 2))[: n_pre * k_last]
 
 
-def group_counts_local(f, rows, pallas):
+def group_counts_local(f, rows, pallas, planes=None):
     """The per-device GroupBy body both callers share (``group_tree``
     and the fused program's ``group`` edge): the Pallas kernel where the
     backend has it (``pallas``) and the local block is whole tiles, the
-    XLA loop otherwise."""
+    XLA loop otherwise.  int32[prod(K)], or with a measure's ``planes``
+    int32[prod(K) * (depth + 2)] (the header comment's cells; the
+    planes are the inner loop, so the fields keep their order)."""
     dims = tuple(r.shape[0] for r in rows)
+    if planes is not None:
+        f = jnp.broadcast_to(f, planes.shape[1:])
+        tw = group_tile_words(dims + (planes.shape[0] + 1,)) if pallas else 0
+        if tw and f.shape[0] % GROUP_TILE_SHARDS == 0 and f.shape[1] % tw == 0:
+            return _group_counts_pallas(f, rows, tw, planes=planes)
+        return _group_counts_xla(f, rows, planes)
     # The widest field goes last: the inner loop is over its rows, the
     # outer one over the product of the others (taxi query 4's nest as
     # 51 x 10 x 7 ran 26 ms on a v5e against 17 as 10 x 7 x 51).
@@ -902,8 +968,8 @@ def group_counts_local(f, rows, pallas):
     return counts.reshape([dims[i] for i in order]).transpose(back).reshape(-1)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def group_tree(mesh, prog, specs, idx_specs, pallas, mask, *operands):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def group_tree(mesh, prog, specs, idx_specs, pallas, pspec, mask, *operands):
     """N-field GroupBy in ONE dispatch: every (K1 x K2 x ... x Kn) group
     combination counted (executeGroupByShard's nested iterator,
     executor.go:1056/2726-2890, as one count tensor) + one psum ->
@@ -913,26 +979,42 @@ def group_tree(mesh, prog, specs, idx_specs, pallas, mask, *operands):
     gather-free index tuple, or None meaning the field's row indices
     arrive as a traced int32[Ki] operand (client-controlled subsets must
     not become compile keys).  The first ``n`` operands after ``mask``
-    are the field stacks, then the traced index vectors for the None
-    slots, then the filter-tree operands.  ``pallas`` selects the TPU
-    body (the engine sets it from the backend).
+    are the field stacks, then (``pspec`` not None) the measure's BSI
+    stack, then the traced index vectors for the None slots, then the
+    filter-tree operands.  ``pallas`` selects the TPU body (the engine
+    sets it from the backend).
 
-    The program is one per (filter structure, field widths): nothing is
-    unrolled per combination (``group_counts_local``), so there is no
-    cap on prod(K) here; the engine bounds the count tensor it reads
-    back (MeshEngine.MAX_GROUPS)."""
+    ``pspec`` is None, or the static plane layout (``gather_planes``) of
+    the measure of ``aggregate=Sum(field=v)``: the result is then
+    int32[K1, ..., Kn, depth + 2], a combination's popcounts under each
+    value plane, under the not-null plane, and alone (its count) — the
+    host assembles Σ 2^b · cells[..., b] in integers.
+
+    The program is one per (filter structure, field widths, measure
+    depth): nothing is unrolled per combination
+    (``group_counts_local``), so there is no cap on prod(K) here; the
+    engine bounds the tensor it reads back (MeshEngine.MAX_GROUPS)."""
     n = len(idx_specs)
+    n_mats = n + (pspec is not None)
 
     def body(m, *ops):
         mats = ops[:n]
-        rest = list(ops[n:])
+        rest = list(ops[n_mats:])
         idxs = [
             spec if spec is not None else rest.pop(0) for spec in idx_specs
         ]
         f = _filter(prog, m, tuple(rest))
-        rows = [gather_rows(mats[i], idxs[i]) for i in range(n)]  # [Ki, S, W]
+        rows = [
+            gather_rows(mats[i], idxs[i]) if idx_specs[i] is not None
+            else slice_rows(mats[i], idxs[i])
+            for i in range(n)
+        ]  # [Ki, S, W]
         dims = tuple(r.shape[0] for r in rows)
-        counts = group_counts_local(f, rows, pallas).reshape(dims)
+        planes = None
+        if pspec is not None:
+            planes = gather_planes(ops[n], pspec)
+            dims += (planes.shape[0] + 1,)
+        counts = group_counts_local(f, rows, pallas, planes).reshape(dims)
         return jax.lax.psum(counts, SHARD_AXIS)
 
     # check_vma off with the Pallas body: pallas_call's output carries no
@@ -941,7 +1023,7 @@ def group_tree(mesh, prog, specs, idx_specs, pallas, mask, *operands):
     return shard_map(
         body,
         mesh=mesh,
-        in_specs=(P(SHARD_AXIS),) + (P(None, SHARD_AXIS),) * n + specs,
+        in_specs=(P(SHARD_AXIS),) + (P(None, SHARD_AXIS),) * n_mats + specs,
         out_specs=P(),
         check_vma=not pallas,
     )(mask, *operands)

@@ -405,6 +405,10 @@ METRIC_ENGINE_DRAIN_PLANE_BYTES = "pilosa_engine_drain_plane_bytes_total"
 #   pilosa_engine_group_combos_total             GroupBy combinations the
 #       device evaluated (the count tensor's size, every dispatch)
 METRIC_ENGINE_GROUP_COMBOS = "pilosa_engine_group_combos_total"
+#   pilosa_engine_group_sum_passes_total         popcount passes of aggregated
+#       GroupBys (aggregate=Sum(field=v)): combinations x (v's depth + 2),
+#       every dispatch
+METRIC_ENGINE_GROUP_SUM_PASSES = "pilosa_engine_group_sum_passes_total"
 #   pilosa_executor_group_results_total{form}    GroupBy results by the form
 #       they left the executor in: form="columns" where the device path
 #       handed out a GroupColumns, form="objects" where one was turned into
@@ -780,6 +784,10 @@ REGISTRY.counter(
 REGISTRY.counter(
     METRIC_ENGINE_GROUP_COMBOS,
     help="GroupBy combinations evaluated on the device",
+)
+REGISTRY.counter(
+    METRIC_ENGINE_GROUP_SUM_PASSES,
+    help="Popcount passes of aggregated GroupBys: combinations x (depth + 2)",
 )
 for _form in GROUP_RESULT_FORMS:
     REGISTRY.counter(

@@ -497,14 +497,20 @@ def test_merge_with_a_remote_partial_answers_as_before(served, data, monkeypatch
 
 
 def test_host_iterator_counts_as_objects_and_equals_the_columns(served, data):
-    """A child the device path declines (``limit`` on a Rows) is the
-    host iterator's: a list, counted as objects; for plain children its
+    """A child with ``limit`` is the device path's since PR 36 (columns
+    over axes made for the request); what the host iterator answers (an
+    executor without an engine) is a list, counted as objects, and its
     list is the device path's columns, group for group."""
     ex, _ = served
     before = _forms()
-    res = ex.execute("i", "GroupBy(Rows(field=pc, limit=3), Rows(field=yr))").results[0]
-    assert type(res) is list and len(res) == 21
-    assert _forms() == (before[0], before[1] + 1)
+    q = "GroupBy(Rows(field=pc, limit=3), Rows(field=yr))"
+    res = ex.execute("i", q).results[0]
+    assert type(res) is GroupColumns and len(res) == 21 and not res.axes.kept
+    assert _forms() == (before[0] + 1, before[1])
+    listed = Executor(data[0]).execute("i", q).results[0]
+    assert type(listed) is list and _forms() == (before[0] + 1, before[1] + 1)
+    assert res == listed
+    before = _forms()
     host = Executor(data[0]).execute("i", f"GroupBy({ROWS}, limit=300, offset=7)").results[0]
     cols = ex.execute("i", f"GroupBy({ROWS}, limit=300, offset=7)").results[0]
     assert type(host) is list and type(cols) is GroupColumns and len(cols) == 293

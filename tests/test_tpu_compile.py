@@ -40,7 +40,7 @@ ENTRY_POINTS = {
     (kernels, "count_tree"): 3, (kernels, "count_batch_tree"): 3,
     (kernels, "sum_tree"): 4, (kernels, "minmax_tree"): 5,
     (kernels, "topn_full_tree"): 5, (kernels, "topn_slab_tree"): 6,
-    (kernels, "group_tree"): 5, (kernels, "fused_tree"): 3,
+    (kernels, "group_tree"): 6, (kernels, "fused_tree"): 3,
     (sparse, "count_tree_blocks"): 2,
 }
 
@@ -176,7 +176,7 @@ def test_smoke_programs_compile_for_v5e(topology, recorded, n_dev):
         if name == "group_tree":
             # Likewise the GroupBy program's Pallas body (static 4).
             temps["group_tree_pallas", static] = _temp_bytes(
-                fn, mesh, (*static[:3], True), arrays)
+                fn, mesh, (*static[:3], True, *static[4:]), arrays)
         if name == "fused_tree":
             # ... and the fused program with its group edge on that body.
             slots, counts, aggs = static[0]
@@ -244,3 +244,33 @@ def test_scatter_jits_update_in_place_on_v5e(topology, n_dev):
         stack_bytes = 8 * FULL_SHARDS // n_dev * ROW_BYTES
         assert ma.alias_size_in_bytes >= stack_bytes, (name, ma.alias_size_in_bytes)
         assert ma.temp_size_in_bytes < ROW_BYTES * 16, (name, ma.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("dims", [(6, 10, 10), (6, 5, 5)], ids=str)
+def test_aggregated_group_by_compiles_for_v5e_at_flight_3s_shapes(topology, n_dev, dims):
+    """``group_tree`` with a measure (aggregate=Sum over a 24-bit field:
+    26 popcount passes a combination) on its Pallas body, at
+    ssb.flight3_stream's shapes: 64 shards, three traced axes of 6 x 10
+    x 10 or 6 x 5 x 5 rows out of 7-, 250- and 25-row stacks.  Mosaic
+    must take it, and the program's scratch is the rows its traced axes
+    name, sliced and stacked (twice 26 of them), plus the lane
+    accumulators: ``jnp.take`` on the 250-row stacks held a copy of a
+    whole stack, 2.1 GB on one device."""
+    mesh = Mesh(np.asarray(topology.devices[:n_dev]), (SHARD_AXIS,))
+    shards, depth = 64, 24
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    stacks = [sds((k, shards, WORDS), jnp.uint32, P(None, SHARD_AXIS))
+              for k in (7, 250 if dims[1] == 10 else 25, 250 if dims[1] == 10 else 25)]
+    planes = sds((depth + 1, shards, WORDS), jnp.uint32, P(None, SHARD_AXIS))
+    mask = sds((shards, 1), jnp.uint32, P(SHARD_AXIS))
+    compiled = kernels.group_tree.lower(
+        mesh, ("ones",), (P(),) * 3, (None,) * 3, True, ("slice", 0, depth + 1),
+        mask, *stacks, planes, *[sds((k,), jnp.int32) for k in dims]).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    row_block = shards // n_dev * ROW_BYTES
+    accumulators = kernels.GROUP_ACC_GROUPS * 8 * 128 * 4 * 4  # four passes' lanes, summed outside
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * sum(dims) * row_block + accumulators
