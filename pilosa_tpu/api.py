@@ -1655,12 +1655,13 @@ class API:
             )
             return None if res is None else res[0]
         if kind == "group":
-            return eng.group_counts_async(
+            res = eng.group_counts_async(
                 index, payload["fields"], payload["rows"], call_of("filter"),
                 shards, broadcast=False,
                 aggregate=payload.get("aggregate"),
                 traced=payload.get("traced"),
             )
+            return None if res is None else res[0]
         raise ApiError(f"unknown collective kind: {kind}")
 
     def translate_keys(self, index: str, field: str, keys: List[str]) -> List[int]:

@@ -50,6 +50,7 @@ from ..util import tracing
 from ..util.stats import (
     COMPILE_PHASES,
     ENGINE_CACHES,
+    GROUP_PREFIX_STATES,
     METRIC_DEVICE_BYTES_SKIPPED,
     METRIC_ENGINE_CACHE_HITS,
     METRIC_ENGINE_CACHE_MISSES,
@@ -69,6 +70,7 @@ from ..util.stats import (
     METRIC_ENGINE_FUSED_PROGRAMS,
     METRIC_ENGINE_FUSED_QUERIES,
     METRIC_ENGINE_GROUP_COMBOS,
+    METRIC_ENGINE_GROUP_PREFIX_STEPS,
     METRIC_ENGINE_GROUP_SUM_PASSES,
     METRIC_ENGINE_PROMOTIONS,
     METRIC_ENGINE_REBUILDS,
@@ -983,6 +985,10 @@ class MeshEngine:
         )
         self._group_combos_counter = REGISTRY.counter(
             METRIC_ENGINE_GROUP_COMBOS
+        )
+        self._group_prefix_steps_counters = tuple(
+            REGISTRY.counter(METRIC_ENGINE_GROUP_PREFIX_STEPS, state=state)
+            for state in GROUP_PREFIX_STATES
         )
         # (op, path) -> the four drain-record counter handles.
         self._drain_counters: Dict[tuple, tuple] = {}
@@ -3739,14 +3745,14 @@ class MeshEngine:
                 host, cands, n_out
             )
         if kind == "group":
-            dev = self.group_counts_async(
+            res = self.group_counts_async(
                 index, spec["fields"], spec["rows"], spec.get("filter"),
                 shards, aggregate=spec.get("aggregate"),
                 traced=spec.get("traced"),
             )
-            if dev is None:
+            if res is None:
                 return None, fusion_mod._Const(fusion_mod.DECLINED)
-            return dev, lambda host: np.asarray(host)
+            return res[0], functools.partial(self.group_host, res[1])
         raise ValueError(f"unknown solo op kind: {kind!r}")
 
     def probe_fused_item(self, index: str, spec: dict, shards):
@@ -4744,8 +4750,9 @@ class MeshEngine:
         aggregate: Optional[str] = None,
         traced: Optional[Sequence[bool]] = None,
     ):
-        """GroupBy dispatch (kernels.group_tree) with the
-        int32[K1, ..., Kn] count tensor left on device, or, with
+        """GroupBy dispatch (kernels.group_tree): ``(dev, dims)``, its
+        array left on device and the tensor's shape (``group_host``
+        resolves the readback): the int32[K1, ..., Kn] count tensor, or, with
         ``aggregate`` (an int field: ``aggregate=Sum(field=...)``),
         the int32[K1, ..., Kn, depth + 2] tensor of every group's
         popcounts under the measure's value planes, under its not-null
@@ -4753,7 +4760,9 @@ class MeshEngine:
         ``traced[i]`` says that field i's row list changes from request
         to request (a ``Rows`` child with ``previous`` / ``limit`` /
         ``column``): its indices then ride a traced operand whatever
-        they are, so that the program is one per list LENGTH.  Returns
+        they are, so that the program is one per list LENGTH.  The
+        array is the tensor, flat, and after it the two counts of
+        prefix steps the program skipped and scored.  Returns
         None when the device path doesn't apply (no shards, peerless
         multi-process mesh, a missing stack, or a tensor over
         MAX_GROUPS)."""
@@ -4835,7 +4844,10 @@ class MeshEngine:
                     *lw.operands,
                 )
 
-        return self._collective(
+        dims = tuple(len(rows) for rows in row_lists)
+        if aggregate is not None:
+            dims += (depth + 2,)
+        dev = self._collective(
             "group",
             {
                 "index": index,
@@ -4850,6 +4862,7 @@ class MeshEngine:
             dispatch,
             broadcast,
         )
+        return dev, dims
 
     def group_counts(
         self,
@@ -4867,13 +4880,25 @@ class MeshEngine:
         GroupBy+Count shard reduce).  Returns int32[K1, ..., Kn] counts
         in row-id order, over the requested shard subset only, or None
         where ``group_counts_async`` declines."""
-        dev = self.group_counts_async(
+        res = self.group_counts_async(
             index, fields, row_lists, filter_call, shards,
             aggregate=aggregate, traced=traced,
         )
-        if dev is None:
+        if res is None:
             return None
-        return np.asarray(self._fetch(dev))
+        dev, dims = res
+        return self.group_host(dims, self._fetch(dev))
+
+    def group_host(self, dims, host):
+        """``group_counts_async``'s array read back -> the tensor, its
+        prefix steps added to
+        ``pilosa_engine_group_prefix_steps_total{state}``: whatever
+        follows (the memo, repair.py, the executor) sees the tensor
+        alone."""
+        counts, steps = kernels.split_group_steps(np.asarray(host), dims)
+        for counter, n in zip(self._group_prefix_steps_counters, steps):
+            counter.inc(n)
+        return counts
 
     # -- lifecycle / telemetry ----------------------------------------------
 

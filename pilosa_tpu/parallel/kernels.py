@@ -711,7 +711,7 @@ def fused_tree(mesh, fspec, specs, *operands):
                 for i_pm, gspec in zip(i_mats, gidx):
                     gix = gspec if isinstance(gspec, tuple) else ops[gspec]
                     grows.append(gather_rows(ops[i_pm], gix))
-                gcounts = group_counts_local(f, grows, pallas)
+                gcounts, _ = group_counts_local(f, grows, pallas)
                 outs.append(jax.lax.psum(gcounts, SHARD_AXIS))
             else:
                 raise ValueError(f"bad fused edge {kind}")
@@ -735,11 +735,32 @@ def fused_tree(mesh, fspec, specs, *operands):
 #
 # counts[k1, ..., kn] = popcount(filter & r1[k1] & ... & rn[kn]) over every
 # column.  Both bodies below evaluate the nest BY PREFIX: the product of all
-# fields but the last is one flat loop (its row indices come from div/mod of
-# the loop counter), each prefix mask is then scored against every row of the
-# last field.  Neither unrolls anything per combination (the Pallas body
-# unrolls its inner loop over at most GROUP_UNROLL_WHOLE rows of the last
-# field), so the trace and the compile time do not grow with prod(K).
+# fields but the last is walked (the XLA body: one flat loop, its row indices
+# from div/mod of the counter; the Pallas body: a loop a prefix field, nested),
+# each prefix mask is then scored against every row of the last field.
+# Neither unrolls anything per combination (the Pallas body unrolls its inner
+# loop over at most GROUP_UNROLL_WHOLE rows of the last field), so the trace
+# and the compile time do not grow with prod(K).
+#
+# What the Pallas body does NOT score: a prefix one of whose rows has no bit
+# under the filter anywhere in the column tile.  Its mask is all-zero there,
+# so its passes could only add zeros to accumulators that were zeroed: the
+# answer is exact whatever the field's type (a set field may hold several
+# rows a column, so the filter's text says nothing; the planes do).  SSB's
+# Q3.3 / Q3.4, and every drill-down report, filter on the attributes they
+# group by: 576-596 of their 600 prefixes are empty in every tile.  A grid
+# step first marks one liveness bit a row of every prefix field
+# (``any(f & row)``: sum(Ki) AND + OR passes on the vector unit, the bits
+# OR-ed into one int32 a 32 rows, ONE roll tree and reduce to a scalar a
+# word, kept in SMEM), then walks the nest under ``pl.when(bit)`` at every
+# level, so a dead outer row skips its whole subtree on one scalar test.
+# The test sits outside the scored loop's dependency path on purpose: a
+# vector -> scalar reduce costs ~240 cycles on a v5e, as much as scoring a
+# prefix against 26 planes, so neither the prefix mask itself is tested
+# before its branch nor the last field's rows (count mode's inner loop
+# stays branch-free); PERF.md section 6, PR 37, has every form timed.  The
+# kernel counts the prefix steps it skipped and scored; they leave
+# ``group_tree`` appended to the tensor.  The XLA body tests nothing.
 #
 # With a measure (``GroupBy(..., aggregate=Sum(field=v))``) the BSI view's
 # planes are one more, innermost axis of the tensor: every combination's
@@ -832,8 +853,9 @@ def _group_counts_xla(f, rows, planes=None):
 
 
 def _group_counts_pallas(f, rows, tile_words, interpret=False, planes=None):
-    """Per-device GroupBy counts as ONE Pallas kernel: same contract as
-    ``_group_counts_xla`` (with ``planes``, the measure's planes take the
+    """Per-device GroupBy counts as ONE Pallas kernel: ``_group_counts_xla``'s
+    tensor, and beside it int32[2], the prefix steps skipped and scored
+    (with ``planes``, the measure's planes take the
     last field's place in the inner loop: a combination's mask stays in
     registers while its depth + 2 cells are scored, unrolled whole).  Grid = (passes, shard tiles, word tiles); a
     step holds the tile of the filter and of EVERY group row in VMEM, so
@@ -842,7 +864,10 @@ def _group_counts_pallas(f, rows, tile_words, interpret=False, planes=None):
     per prefix, its mask stays in vector registers while the last
     field's rows stream past it from VMEM, each adding a lane-wise
     partial into its group's (8, 128) accumulator.  The accumulators are
-    the output block, resident across a pass; lanes are summed outside."""
+    the output block, resident across a pass; lanes are summed outside.
+    A prefix with a row that is dead in the tile is skipped (the header
+    comment): a step is one (grid step, prefix), and the two counts are
+    summed over the grid in an SMEM output."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -863,30 +888,89 @@ def _group_counts_pallas(f, rows, tile_words, interpret=False, planes=None):
     pre_block = max(1, min(n_pre, GROUP_ACC_GROUPS // k_last))
     passes = -(-n_pre // pre_block)
     lanes = tw // 128
+    # Row k of prefix field i is bit offsets[i] + k of the liveness words;
+    # a step of it is strides[i] prefixes of the row-major nest.
+    offsets = [sum(pre_dims[:i]) for i in range(len(pre_dims))]
+    strides = [1] * len(pre_dims)
+    for i in range(len(pre_dims) - 2, -1, -1):
+        strides[i] = strides[i + 1] * pre_dims[i + 1]
+    n_words = -(-sum(pre_dims) // 32)
+
+    def fold(x, op):
+        """[ts, tw] -> [ts, 128]: the tile's 128-lane chunks under op."""
+        part = x[:, 0:128]
+        for j in range(1, lanes):
+            part = op(part, x[:, j * 128:(j + 1) * 128])
+        return part
 
     def kernel(f_ref, *refs):
-        row_refs, acc_ref = refs[:-1], refs[-1]
+        row_refs, acc_ref, steps_ref, live_ref, scored_ref = refs[:-4], *refs[-4:]
         first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
 
         @pl.when(first)
         def _zero():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
+        @pl.when(first & (pl.program_id(0) == 0))
+        def _zero_steps():
+            steps_ref[0, 0] = jnp.int32(0)
+            steps_ref[0, 1] = jnp.int32(0)
+
+        # This pass's prefixes; the last pass may hold fewer than a block.
         p0 = pl.program_id(0) * pre_block
+        p1 = jnp.minimum(p0 + pre_block, n_pre)
         last_ref = row_refs[-1]
 
-        def prefix(pl_i, carry):
-            pre = f_ref[...]
-            for ref, k in zip(row_refs[:-1], _prefix_rows(p0 + pl_i, pre_dims)):
-                pre = pre & ref[k]
-            base = pl_i * k_last
+        # The liveness words of this tile.  A row's test is 16 ANDs and an
+        # OR tree down to one vreg; the rows' bits are OR-ed together on
+        # the vector unit, so a word costs ONE roll tree and reduce to a
+        # scalar (~240 cycles on a v5e), not one a row.
+        words = []
+        for w in range(n_words):
+            terms = []
+            for ref, d, off in zip(row_refs[:-1], pre_dims, offsets):
+                lo, hi = max(off, 32 * w), min(off + d, 32 * w + 32)
+                if lo >= hi:
+                    continue
 
+                def mark(k, ref=ref, off=off):
+                    part = fold(f_ref[...] & ref[k], jnp.bitwise_or)
+                    bit = jnp.left_shift(jnp.int32(1), off + k - 32 * w)
+                    return jnp.where(part != 0, bit, 0)
+
+                if sum(pre_dims) <= GROUP_UNROLL_WHOLE:
+                    # few rows in all: unrolled, so that their chains of
+                    # ANDs and ORs overlap (a loop waits out each one)
+                    terms += [mark(k) for k in range(lo - off, hi - off)]
+                else:
+                    terms.append(jax.lax.fori_loop(
+                        lo - off, hi - off, lambda k, bits: bits | mark(k),
+                        jnp.zeros((ts, 128), jnp.int32)))
+            while len(terms) > 1:
+                terms = [x | y for x, y in zip(terms[::2], terms[1::2])] + terms[len(terms) & ~1:]
+            bits = terms[0]
+            for axis, size in ((1, 128), (0, ts)):
+                shift = size // 2
+                while shift:
+                    bits = bits | pltpu.roll(bits, shift, axis)
+                    shift //= 2
+            words.append(jnp.max(bits[0:1, :]))
+            live_ref[w] = words[w]
+
+        def live_test(i):
+            """k -> whether row k of prefix field i is live in this tile:
+            a field whose bits lie in one word tests that scalar, a wider
+            one reads its word from SMEM."""
+            first, last = offsets[i] >> 5, (offsets[i] + pre_dims[i] - 1) >> 5
+            if first == last:
+                return lambda k: ((words[first] >> ((offsets[i] + k) & 31)) & 1) != 0
+            return lambda k: ((live_ref[(offsets[i] + k) >> 5] >> ((offsets[i] + k) & 31)) & 1) != 0
+
+        def score(pre, base):
+            """The prefix mask against the last field's rows, or the
+            measure's planes: cells base .. base + k_last of this pass."""
             def add(k, mask):
-                hits = _pc(mask)
-                part = hits[:, 0:128]
-                for j in range(1, lanes):
-                    part = part + hits[:, j * 128:(j + 1) * 128]
-                acc_ref[base + k] += part
+                acc_ref[base + k] += fold(_pc(mask), jnp.add)
 
             if planes is not None:
                 have = pre & last_ref[depth]
@@ -894,61 +978,96 @@ def _group_counts_pallas(f, rows, tile_words, interpret=False, planes=None):
                     add(b, have & last_ref[b])
                 add(depth, have)
                 add(depth + 1, pre)
-                return carry
+                return
 
-            def score(k):
+            def score_one(k):
                 add(k, pre & last_ref[k])
 
             def score_many(i, c):
                 for u in range(unroll):
-                    score(i * unroll + u)
+                    score_one(i * unroll + u)
                 return c
 
             jax.lax.fori_loop(0, k_last // unroll, score_many, 0)
             for k in range(k_last - k_last % unroll, k_last):
-                score(k)
-            return carry
+                score_one(k)
 
-        # The last pass may hold fewer prefixes than a block.
-        jax.lax.fori_loop(0, jnp.minimum(pre_block, n_pre - p0), prefix, 0)
+        scored_ref[0] = jnp.int32(0)
+
+        def walk(i, pre, p):
+            """The nest below a node: prefix field i onward, ``pre`` the
+            mask so far, ``p`` the node's first prefix.  One loop a field,
+            cut to this pass's [p0, p1); no div/mod a prefix, and a dead
+            row skips its whole subtree on one scalar test."""
+            if i == len(pre_dims):
+                score(pre, (p - p0) * k_last)
+                scored_ref[0] += 1
+                return
+            stride, live = strides[i], live_test(i)
+            k_lo = jnp.maximum(p0 - p, 0) // stride
+            k_hi = jnp.minimum((p1 - 1 - p) // stride + 1, pre_dims[i])
+
+            def child(k, c):
+                @pl.when(live(k))
+                def _():
+                    walk(i + 1, pre & row_refs[i][k], p + k * stride)
+
+                return c
+
+            jax.lax.fori_loop(k_lo, k_hi, child, 0)
+
+        walk(0, f_ref[...], jnp.int32(0))
+        scored = scored_ref[0]
+        steps_ref[0, 0] += p1 - p0 - scored
+        steps_ref[0, 1] += scored
 
     def tile(k):
         return pl.BlockSpec((k, ts, tw), lambda g, i, j: (0, i, j))
 
-    out = pl.pallas_call(
+    out, steps = pl.pallas_call(
         kernel,
         grid=(passes, S // ts, W // tw),
         in_specs=[pl.BlockSpec((ts, tw), lambda g, i, j: (i, j))]
         + [tile(r.shape[0]) for r in rows],
-        out_specs=pl.BlockSpec(
-            (pre_block * k_last, ts, 128), lambda g, i, j: (g, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (passes * pre_block * k_last, ts, 128), jnp.int32
-        ),
+        out_specs=[
+            pl.BlockSpec(
+                (pre_block * k_last, ts, 128), lambda g, i, j: (g, 0, 0)
+            ),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(
+                (passes * pre_block * k_last, ts, 128), jnp.int32
+            ),
+            jax.ShapeDtypeStruct((1, 2), jnp.int32),
+        ],
+        scratch_shapes=[pltpu.SMEM((max(1, n_words),), jnp.int32), pltpu.SMEM((1,), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3,
             vmem_limit_bytes=GROUP_VMEM_LIMIT,
         ),
         interpret=interpret,
     )(f, *rows)
-    return jnp.sum(out, axis=(1, 2))[: n_pre * k_last]
+    return jnp.sum(out, axis=(1, 2))[: n_pre * k_last], steps[0]
 
 
 def group_counts_local(f, rows, pallas, planes=None):
     """The per-device GroupBy body both callers share (``group_tree``
     and the fused program's ``group`` edge): the Pallas kernel where the
     backend has it (``pallas``) and the local block is whole tiles, the
-    XLA loop otherwise.  int32[prod(K)], or with a measure's ``planes``
-    int32[prod(K) * (depth + 2)] (the header comment's cells; the
-    planes are the inner loop, so the fields keep their order)."""
+    XLA loop otherwise.  ``(counts, steps)``: int32[prod(K)], or with a
+    measure's ``planes`` int32[prod(K) * (depth + 2)] (the header
+    comment's cells; the planes are the inner loop, so the fields keep
+    their order), and int32[2], the prefix steps the Pallas body
+    skipped and scored (zeros from the XLA loop, which tests nothing)."""
     dims = tuple(r.shape[0] for r in rows)
+    no_steps = jnp.zeros(2, jnp.int32)
     if planes is not None:
         f = jnp.broadcast_to(f, planes.shape[1:])
         tw = group_tile_words(dims + (planes.shape[0] + 1,)) if pallas else 0
         if tw and f.shape[0] % GROUP_TILE_SHARDS == 0 and f.shape[1] % tw == 0:
             return _group_counts_pallas(f, rows, tw, planes=planes)
-        return _group_counts_xla(f, rows, planes)
+        return _group_counts_xla(f, rows, planes), no_steps
     # The widest field goes last: the inner loop is over its rows, the
     # outer one over the product of the others (taxi query 4's nest as
     # 51 x 10 x 7 ran 26 ms on a v5e against 17 as 10 x 7 x 51).
@@ -959,13 +1078,20 @@ def group_counts_local(f, rows, pallas, planes=None):
     f = jnp.broadcast_to(f, rows[0].shape[1:])
     tw = group_tile_words(tuple(dims[i] for i in order)) if pallas else 0
     if tw and f.shape[0] % GROUP_TILE_SHARDS == 0 and f.shape[1] % tw == 0:
-        counts = _group_counts_pallas(f, rows, tw)
+        counts, steps = _group_counts_pallas(f, rows, tw)
     else:
-        counts = _group_counts_xla(f, rows)
+        counts, steps = _group_counts_xla(f, rows), no_steps
     if order == list(range(n)):
-        return counts
+        return counts, steps
     back = sorted(range(n), key=order.__getitem__)
-    return counts.reshape([dims[i] for i in order]).transpose(back).reshape(-1)
+    counts = counts.reshape([dims[i] for i in order]).transpose(back)
+    return counts.reshape(-1), steps
+
+
+def split_group_steps(host, dims):
+    """``group_tree``'s array on the host -> (the tensor shaped
+    ``dims``, its (skipped, scored) prefix steps)."""
+    return host[:-2].reshape(dims), (int(host[-2]), int(host[-1]))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
@@ -973,7 +1099,11 @@ def group_tree(mesh, prog, specs, idx_specs, pallas, pspec, mask, *operands):
     """N-field GroupBy in ONE dispatch: every (K1 x K2 x ... x Kn) group
     combination counted (executeGroupByShard's nested iterator,
     executor.go:1056/2726-2890, as one count tensor) + one psum ->
-    int32[K1, ..., Kn], replicated.
+    int32[K1 * ... * Kn + 2], replicated: the count tensor, row-major,
+    and after it the prefix steps the Pallas body skipped and scored
+    over every tile, pass and device (``_group_counts_pallas``; zeros
+    from the XLA body) -- ONE array, so that the steps ride the
+    tensor's readback (``split_group_steps`` parts them on the host).
 
     ``idx_specs`` is a static tuple with one slot per field: a
     gather-free index tuple, or None meaning the field's row indices
@@ -985,7 +1115,7 @@ def group_tree(mesh, prog, specs, idx_specs, pallas, pspec, mask, *operands):
     sets it from the backend).
 
     ``pspec`` is None, or the static plane layout (``gather_planes``) of
-    the measure of ``aggregate=Sum(field=v)``: the result is then
+    the measure of ``aggregate=Sum(field=v)``: the tensor is then
     int32[K1, ..., Kn, depth + 2], a combination's popcounts under each
     value plane, under the not-null plane, and alone (its count) — the
     host assembles Σ 2^b · cells[..., b] in integers.
@@ -1014,8 +1144,8 @@ def group_tree(mesh, prog, specs, idx_specs, pallas, pspec, mask, *operands):
         if pspec is not None:
             planes = gather_planes(ops[n], pspec)
             dims += (planes.shape[0] + 1,)
-        counts = group_counts_local(f, rows, pallas, planes).reshape(dims)
-        return jax.lax.psum(counts, SHARD_AXIS)
+        counts, steps = group_counts_local(f, rows, pallas, planes)
+        return jax.lax.psum(jnp.concatenate([counts, steps]), SHARD_AXIS)
 
     # check_vma off with the Pallas body: pallas_call's output carries no
     # varying-axes type (sparse.count_tree_blocks_pallas); the psum makes

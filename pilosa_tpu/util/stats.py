@@ -409,6 +409,13 @@ METRIC_ENGINE_GROUP_COMBOS = "pilosa_engine_group_combos_total"
 #       GroupBys (aggregate=Sum(field=v)): combinations x (v's depth + 2),
 #       every dispatch
 METRIC_ENGINE_GROUP_SUM_PASSES = "pilosa_engine_group_sum_passes_total"
+#   pilosa_engine_group_prefix_steps_total{state}  prefix steps of the
+#       GroupBy program's Pallas body, a (tile, prefix) each, read back
+#       beside the tensor: state="skipped" where a row of the prefix has
+#       no bit under the filter in the tile, state="scored" the rest;
+#       the XLA body tests nothing and counts nothing
+METRIC_ENGINE_GROUP_PREFIX_STEPS = "pilosa_engine_group_prefix_steps_total"
+GROUP_PREFIX_STATES = ("skipped", "scored")
 #   pilosa_executor_group_results_total{form}    GroupBy results by the form
 #       they left the executor in: form="columns" where the device path
 #       handed out a GroupColumns, form="objects" where one was turned into
@@ -789,6 +796,12 @@ REGISTRY.counter(
     METRIC_ENGINE_GROUP_SUM_PASSES,
     help="Popcount passes of aggregated GroupBys: combinations x (depth + 2)",
 )
+for _state in GROUP_PREFIX_STATES:
+    REGISTRY.counter(
+        METRIC_ENGINE_GROUP_PREFIX_STEPS,
+        help="Prefix steps of the GroupBy program's Pallas body, by outcome",
+        state=_state,
+    )
 for _form in GROUP_RESULT_FORMS:
     REGISTRY.counter(
         METRIC_EXECUTOR_GROUP_RESULTS,

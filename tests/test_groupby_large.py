@@ -7,6 +7,7 @@ numpy: per-column row membership, then counts by explicit loops over the
 combinations; it never sees a bitmap.  Every case asserts the device
 program answered (a plan op with a device path, no host_fallback)."""
 
+import functools
 import gc
 import json
 import time
@@ -30,6 +31,7 @@ from pilosa_tpu.net import wire
 from pilosa_tpu.ops import SHARD_WIDTH
 from pilosa_tpu.parallel import MeshEngine, kernels, make_mesh
 from pilosa_tpu.util import plans
+from pilosa_tpu.pql import parse
 from pilosa_tpu.util.stats import METRIC_EXECUTOR_GROUP_RESULTS, REGISTRY
 
 SHARDS = 3
@@ -149,9 +151,12 @@ def _lowered(dims, shards=8, words=256):
 @pytest.mark.parametrize("body", ["xla", "pallas"])
 def test_trace_does_not_grow_with_the_combination_count(body):
     """The jaxpr of 2 x 2 x 51 = 204 combinations and of 10 x 7 x 51 =
-    3,570 have the same number of equations, in both bodies: nothing is
-    unrolled per combination (the Pallas body unrolls its inner loop over
-    the last field's rows, at most GROUP_UNROLL_WHOLE of them)."""
+    3,570 have the same number of equations in the XLA body, and in the
+    Pallas body differ by the liveness test of each further prefix ROW
+    (13 of them, the same few equations each; up to GROUP_UNROLL_WHOLE
+    rows are unrolled): nothing is unrolled per combination (the Pallas
+    body unrolls its inner loop over the last field's rows, at most
+    GROUP_UNROLL_WHOLE of them)."""
     import jax
 
     def size(dims):
@@ -161,10 +166,14 @@ def test_trace_does_not_grow_with_the_combination_count(body):
         else:
             fn = lambda f, *r: kernels._group_counts_pallas(  # noqa: E731
                 f, list(r), 128, interpret=True)
-        return len(str(jax.make_jaxpr(fn)(f, *rows)).splitlines())
+        return str(jax.make_jaxpr(fn)(f, *rows)).count(" = ")  # equations, however the printer wraps them
 
-    assert size((10, 7, 51)) == size((2, 2, 51))
-    assert size((4, 5, 200)) == size((2, 2, 200))  # a last field past the whole unroll
+    a_row = 0 if body == "xla" else size((3, 2, 51)) - size((2, 2, 51))
+    assert a_row < 10
+    assert size((10, 7, 51)) == size((2, 2, 51)) + 13 * a_row
+    assert size((4, 5, 200)) == size((2, 2, 200)) + 5 * a_row  # a last field past the whole unroll
+    if body == "pallas":  # past GROUP_UNROLL_WHOLE prefix rows the tests are loops, one a 32-bit word of a field
+        assert size((33, 63, 5)) == size((34, 62, 5))
 
 
 @pytest.mark.parametrize("dims,acc_groups", [
@@ -181,8 +190,146 @@ def test_pallas_body_is_the_xla_body(monkeypatch, dims, acc_groups):
     want = np.asarray(kernels._group_counts_xla(f, rows))
     assert want.sum() > 0
     for tile_words in (128, 256):
-        got = kernels._group_counts_pallas(f, rows, tile_words, interpret=True)
+        got, _ = kernels._group_counts_pallas(f, rows, tile_words, interpret=True)
         assert (np.asarray(got) == want).all()
+
+
+def _skip_planes(filt, measure):
+    """(f, rows, planes) over [16, 512] words, four [8, 256] tiles:
+    three fields of 4, 3 and 5 rows (``wide_fields``: 40, 30 and 5, so
+    that the liveness bits fill several words and a field lies across
+    two), a column in one row of each (and a tenth of them in a
+    second), and the filter named by ``filt``."""
+    rng = np.random.default_rng(37)
+    S, W, dims = 16, 512, (40, 30, 5) if filt == "wide_fields" else (4, 3, 5)
+    rows = []
+    for k in dims:
+        cat = rng.integers(0, k, (S, W * 32))
+        also = np.where(rng.random((S, W * 32)) < 0.1, rng.integers(0, k, (S, W * 32)), cat)
+        rows.append(np.stack([
+            np.packbits((cat == i) | (also == i), axis=1, bitorder="little").view(np.uint32)
+            for i in range(k)]))
+    f = rng.integers(0, 2**32, (S, W), dtype=np.uint32)
+    if filt == "empty":
+        f[:] = 0
+    elif filt in ("two_rows", "wide_fields"):  # what SSB's Q3.3 does: a where on the grouped attributes
+        for r in rows[:3 if measure else 2]:  # ... on columns that hold no other row of the field
+            f &= (r[0] | r[2]) & ~np.bitwise_or.reduce(np.delete(r, [0, 2], axis=0))
+    elif filt == "some_tiles":  # two of the four tiles hold no column of the filter
+        f[:8, :256] = 0
+        f[8:, 256:] = 0
+    planes = rng.integers(0, 2**32, (4, S, W), dtype=np.uint32) if measure else None
+    return f, rows, planes
+
+
+def _reckoned_steps(f, pre_rows, tile_words):
+    """(skipped, scored) by the rule of the Pallas body, in numpy: a
+    prefix is scored in a tile where every one of its rows has a bit
+    under the filter there."""
+    S, W = f.shape
+    skipped = scored = 0
+    for i in range(0, S, kernels.GROUP_TILE_SHARDS):
+        for j in range(0, W, tile_words):
+            t = np.s_[i:i + kernels.GROUP_TILE_SHARDS, j:j + tile_words]
+            live = [[bool((f[t] & r[k][t]).any()) for k in range(r.shape[0])] for r in pre_rows]
+            for combo in np.ndindex(*[r.shape[0] for r in pre_rows]):
+                if all(live[n][k] for n, k in enumerate(combo)):
+                    scored += 1
+                else:
+                    skipped += 1
+    return skipped, scored
+
+
+@pytest.mark.parametrize("filt", ["all_live", "empty", "two_rows", "some_tiles", "wide_fields"])
+@pytest.mark.parametrize("measure", [False, True], ids=["count", "measure"])
+def test_pallas_body_skips_a_prefix_with_a_dead_row_and_says_so(monkeypatch, measure, filt):
+    """A prefix one of whose rows has no bit under the filter in the
+    tile is not scored: the tensor stays the XLA body's, and the two
+    step counts are what numpy reckons from the tiles, over four tiles
+    and several accumulator passes."""
+    monkeypatch.setattr(kernels, "GROUP_ACC_GROUPS", 25)  # 12 x 5 in 3 passes; 60 x 5 in 12
+    f, rows, planes = _skip_planes(filt, measure)
+    want = np.asarray(kernels._group_counts_xla(f, rows, planes))
+    got, steps = kernels._group_counts_pallas(f, rows, 256, interpret=True, planes=planes)
+    assert (np.asarray(got) == want).all()
+    assert (want.sum() > 0) == (filt != "empty")
+    skipped, scored = _reckoned_steps(f, rows if measure else rows[:-1], 256)
+    assert tuple(np.asarray(steps)) == (skipped, scored)
+    n_pre = int(np.prod([r.shape[0] for r in (rows if measure else rows[:-1])]))
+    assert skipped + scored == 4 * n_pre
+    if filt == "all_live":
+        assert skipped == 0
+    elif filt == "empty":
+        assert scored == 0
+    elif filt in ("two_rows", "wide_fields"):
+        assert scored == 4 * (8 if measure else 4)
+    else:
+        assert (skipped, scored) == (2 * n_pre, 2 * n_pre)
+    # the XLA loop tests nothing and counts nothing
+    assert tuple(np.asarray(kernels.group_counts_local(f, rows, False, planes)[1])) == (0, 0)
+
+
+def test_prefix_steps_reach_their_counter_and_nothing_else_sees_them(monkeypatch):
+    """The Pallas body (interpret mode) under the engine: the skipped
+    and scored steps of a GroupBy ride its one readback into
+    ``pilosa_engine_group_prefix_steps_total``; the executor, the result
+    memo and the fused ``group`` edge see the count tensor alone."""
+    monkeypatch.setattr(kernels, "_group_counts_pallas",
+                        functools.partial(kernels._group_counts_pallas, interpret=True))
+    rng = np.random.default_rng(7)
+    h = Holder()
+    h.open()
+    idx = h.create_index("k")
+    n_shards, tile_bits = 8, 2048 * 32
+    # columns in the first five of a shard's sixteen tiles only; field b's row 2 in the first alone
+    cols = np.concatenate([s * SHARD_WIDTH + rng.choice(5 * tile_bits, 300, replace=False)
+                           for s in range(n_shards)])
+    a, b = rng.integers(0, 4, len(cols)), rng.integers(0, 2, len(cols))
+    b[(cols % SHARD_WIDTH < tile_bits) & (rng.random(len(cols)) < 0.5)] = 2
+    c = rng.integers(0, 2, len(cols))
+    for name, vals in (("a", a), ("b", b), ("c", c)):
+        idx.create_field(name).import_bulk(vals.tolist(), cols.tolist())
+    eng = MeshEngine(h, make_mesh(1))
+    eng._group_pallas = True  # what a TPU backend sets
+    ex = Executor(h, mesh_engine=eng)
+    q = "GroupBy(Rows(field=a), Rows(field=b), filter=Row(c=1))"
+    # a (4 rows) is the inner loop, b's rows the prefixes: 16 tiles x 3
+    tile = (cols % SHARD_WIDTH) // tile_bits
+    scored = sum(bool(((tile == t) & (b == k) & (c == 1)).any()) for t in range(16) for k in range(3))
+    assert scored == 2 * 5 + 1
+
+    def steps():
+        return tuple(cn.get() for cn in eng._group_prefix_steps_counters)
+
+    want = [((ra, rb), int(((a == ra) & (b == rb) & (c == 1)).sum())) for ra in range(4) for rb in range(3)]
+    before = steps()
+    got = [(tuple(fr.row_id for fr in gc.group), gc.count) for gc in ex.execute("k", q).results[0]]
+    assert got == [w for w in want if w[1]]
+    assert steps() == (before[0] + 48 - scored, before[1] + scored)
+    # the same text again is the memo's: no dispatch, no steps, the same groups
+    hits = eng.cache_stats["memo_groupby"][0]
+    again = [(tuple(fr.row_id for fr in gc.group), gc.count) for gc in ex.execute("k", q).results[0]]
+    assert again == got and eng.cache_stats["memo_groupby"][0] == hits + 1
+    assert steps() == (before[0] + 48 - scored, before[1] + scored)
+    # group_counts_async leaves ONE array on the device, the tensor and then the two counts;
+    # group_host resolves its readback to the tensor
+    flt = parse("Row(c=1)").calls[0]
+    shards = list(range(n_shards))
+    dev, dims = eng.group_counts_async("k", ["a", "b"], [[0, 1, 2, 3], [0, 1, 2]], flt, shards)
+    assert dev.shape == (4 * 3 + 2,) and dims == (4, 3)
+    assert tuple(np.asarray(dev)[-2:]) == (48 - scored, scored)
+    solo = eng.group_counts("k", ["a", "b"], [[0, 1, 2, 3], [0, 1, 2]], flt, shards)
+    assert isinstance(solo, np.ndarray) and solo.tolist() == [[w[1] for w in want[i * 3:i * 3 + 3]] for i in range(4)]
+    # a fused drain's group edge hands out the tensor alone, and counts no step
+    before = steps()
+    fused = eng.fused_drain([
+        ("k", {"kind": "count", "call": parse("Intersect(Row(a=1), Row(c=1))").calls[0]}, shards),
+        ("k", {"kind": "group", "fields": ["a", "b"], "rows": [[0, 1, 2, 3], [0, 1, 2]], "filter": flt}, shards),
+    ])
+    assert fused[0] == int(((a == 1) & (c == 1)).sum())
+    assert np.array_equal(np.asarray(fused[1]), solo) and steps() == before
+    eng.close()
+    h.close()
 
 
 def test_compile_time_is_bounded_at_3570_combinations(served):
@@ -544,5 +691,5 @@ def test_the_widest_field_is_scored_last_and_the_tensor_keeps_its_order(dims):
     f = rng.integers(0, 2**32, (2, 128), dtype=np.uint32)
     rows = [rng.integers(0, 2**32, (k, 2, 128), dtype=np.uint32) for k in dims]
     want = np.asarray(kernels._group_counts_xla(f, rows))
-    got = np.asarray(kernels.group_counts_local(f, rows, False))
+    got = np.asarray(kernels.group_counts_local(f, rows, False)[0])
     assert (got == want).all() and want.sum() > 0

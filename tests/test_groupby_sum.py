@@ -389,8 +389,8 @@ def test_both_bodies_count_every_plane_of_every_combination(dims, depth, acc, mo
         have = m & planes[depth]
         want[combo] = [_pc(have & planes[b]) for b in range(depth)] + [_pc(have), _pc(m)]
     for got in (kernels._group_counts_xla(f, rows, planes),
-                kernels._group_counts_pallas(f, rows, 128, interpret=True, planes=planes),
-                kernels.group_counts_local(f, rows, False, planes)):
+                kernels._group_counts_pallas(f, rows, 128, interpret=True, planes=planes)[0],
+                kernels.group_counts_local(f, rows, False, planes)[0]):
         assert np.array_equal(np.asarray(got).reshape(want.shape), want)
 
 

@@ -331,7 +331,7 @@ def test_executor_mesh_group_by(holder, mesh):
         lambda *x, **k: devs.append(orig(*x, **k)) or devs[-1])
     with engine.repairs.suspended():
         assert fused.execute("i", q).results == plain.execute("i", q).results
-    assert len(devs) == 1 and devs[0].shape == (5, 3, 2)  # the device tensor
+    assert len(devs) == 1 and devs[0][0].shape == (32,) and devs[0][1] == (5, 3, 2)  # the device array: the tensor, then two step counts
 
 
 def test_mesh_time_range(holder, mesh):

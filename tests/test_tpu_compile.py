@@ -274,3 +274,32 @@ def test_aggregated_group_by_compiles_for_v5e_at_flight_3s_shapes(topology, n_de
     row_block = shards // n_dev * ROW_BYTES
     accumulators = kernels.GROUP_ACC_GROUPS * 8 * 128 * 4 * 4  # four passes' lanes, summed outside
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * sum(dims) * row_block + accumulators
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("shards,dims", [(256, (10, 7, 51)), (64, (40, 30, 5))], ids=["query4", "wide_prefix_fields"])
+def test_group_by_compiles_for_v5e_at_taxi_query_4s_nest(topology, n_dev, shards, dims):
+    """``group_tree`` on its Pallas body at taxi.q4_stream's shape: 256
+    shards, whole static axes of 10 x 7 x 51 rows (3,570 combinations
+    in one accumulator pass), the widest scored innermost.  Mosaic must
+    take the liveness bits (a roll tree and one reduce to a scalar a
+    grid step, SMEM scratch) and the nested walk under ``pl.when``; the
+    program holds no copy of a stack: its scratch is the lane
+    accumulators, summed outside the kernel, and the two step counts.
+    And with prefix fields of 40 and 30 rows: past GROUP_UNROLL_WHOLE
+    rows the liveness tests are loops, their bits fill three words, and
+    a field that lies across two reads its word from SMEM by a traced
+    index."""
+    mesh = Mesh(np.asarray(topology.devices[:n_dev]), (SHARD_AXIS,))
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    stacks = [sds((k, shards, WORDS), jnp.uint32, P(None, SHARD_AXIS)) for k in dims]
+    mask = sds((shards, 1), jnp.uint32, P(SHARD_AXIS))
+    compiled = kernels.group_tree.lower(
+        mesh, ("ones",), (), tuple(tuple(range(k)) for k in dims), True, None,
+        mask, *stacks).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    accumulators = int(np.prod(dims)) * 8 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * accumulators + shards // n_dev * ROW_BYTES
