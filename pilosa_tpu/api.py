@@ -310,19 +310,24 @@ class API:
         with self.tracer.start_span(
             "api.Query", parent=parent, index=req.index, remote=req.remote
         ) as span, plans.attach(plan):
-            if req.clock is not None:
-                req.clock.span = span
+            clock = req.clock
+            if clock is not None:
+                clock.span, clock.plan = span, plan
+                clock.executing()  # the prologue stage ends
             resp = self.executor.execute(req.index, req.query, req.shards, opt)
-        elapsed = time.monotonic() - start
-        trace_id = span.trace_id if span is not None else None
-        self._h_query_sync.observe(elapsed, exemplar=trace_id)
-        if plan is not None:
-            plan.finish(elapsed, trace_id=trace_id)
-            plans.record(plan)
-            if plan.profile:
-                resp.plan = plan.to_dict()
-        if span is not None:
-            resp.trace_id = span.trace_id
+            if clock is not None:
+                clock.executed()  # the epilogue stage starts
+        with tracing.mark("epilogue"):
+            elapsed = time.monotonic() - start
+            trace_id = span.trace_id if span is not None else None
+            self._h_query_sync.observe(elapsed, exemplar=trace_id)
+            if plan is not None:
+                plan.finish(elapsed, trace_id=trace_id)
+                plans.record(plan)
+                if plan.profile:
+                    resp.plan = plan.to_dict()
+            if span is not None:
+                resp.trace_id = span.trace_id
         # Long-query logging (api.go:1021, server LongQueryTime).
         if self.long_query_time and elapsed > self.long_query_time:
             self.logger.printf(
@@ -387,9 +392,13 @@ class API:
         if plan is not None:
             plan.pipelined = True
         with tracing.attach(span), plans.attach(plan):
+            if req.clock is not None:
+                req.clock.executing()  # the prologue stage ends
             fut = self.executor.execute_async(
                 req.index, req.query, req.shards, opt
             )
+            if req.clock is not None:
+                req.clock.executed()
         if fut is None:
             # Declined (sync fallback): discard the provisional span —
             # left attached it would sit unfinished in a live parent's
@@ -403,7 +412,7 @@ class API:
         fut.trace_span = span
         fut.query_plan = plan
         if req.clock is not None:
-            req.clock.span = span
+            req.clock.span, req.clock.plan = span, plan
 
         def _finish(_f):
             elapsed = time.monotonic() - start

@@ -602,6 +602,14 @@ class _QueryFuture:
     def done(self) -> bool:
         return self._event.is_set()
 
+    @property
+    def t_decoded(self) -> Optional[float]:
+        """When the last drain that answered an item of this query had
+        decoded it (None where no drain did): the start of the request's
+        ``complete_wait`` (util/tracing.RequestClock)."""
+        times = [it.t_decoded for _k, it in self._items if it.t_decoded is not None]
+        return max(times) if times else None
+
     def add_done_callback(self, fn):
         """Run ``fn(self)`` on resolution — immediately when already
         resolved AND fully drained; if the resolver is still draining

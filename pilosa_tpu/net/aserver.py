@@ -228,6 +228,7 @@ class _Reactor(threading.Thread):
         self._stopping = False
         self._last_sweep = time.monotonic()
         self._t_select = self._last_sweep  # when the last select returned
+        self._writing = 0  # connections whose socket would not take all of a reply
         self._tid: Optional[int] = None
         # (sock, callback) pairs registered before start(): extra
         # readable fds the loop watches alongside its connections —
@@ -296,9 +297,15 @@ class _Reactor(threading.Thread):
             self.sel.register(s, selectors.EVENT_READ, ("ext", cb))
         try:
             while not self._stopping:
-                events = self.sel.select(
-                    timeout=0.0 if self._pending else 0.5
-                )
+                # Asleep with open=0 the reactor waits for the client
+                # and the socket, as it does while a socket has yet to
+                # take the rest of a reply (writing>0); else, with
+                # open>0, for a thread of the server.
+                with tracing.mark("select_wait", open=tracing.OCCUPIED.depth,
+                                  writing=self._writing):
+                    events = self.sel.select(
+                        timeout=0.0 if self._pending else 0.5
+                    )
                 self._t_select = time.monotonic()
                 self._signaled = False
                 while self._pending:
@@ -435,7 +442,9 @@ class _Reactor(threading.Thread):
             mask |= selectors.EVENT_READ
         if write:
             mask |= selectors.EVENT_WRITE
-        conn.want_write = write
+        if write != conn.want_write:
+            self._writing += 1 if write else -1
+            conn.want_write = write
         try:
             if mask == 0:
                 if conn.registered:
@@ -457,6 +466,10 @@ class _Reactor(threading.Thread):
             return
         if conn.t_first is None:
             conn.t_first = self._t_select
+        with tracing.mark("read"):
+            self._read(conn)
+
+    def _read(self, conn: _Conn):
         got_any = False
         while True:
             try:
@@ -609,7 +622,7 @@ class _Reactor(threading.Thread):
         slot = conn.next_slot
         conn.next_slot += 1
         conn.inflight += 1
-        conn.last_progress = time.monotonic()
+        t_dispatch = conn.last_progress = time.monotonic()
         if conn.inflight >= MAX_PENDING:
             conn.paused = True
         keep_alive = version == "HTTP/1.1"
@@ -671,10 +684,13 @@ class _Reactor(threading.Thread):
         released = []
         # A query request carries its clock to the handler and back:
         # first byte in (the select that delivered it) -> last byte out
-        # (_flush), pilosa_http_request_seconds.
+        # (_flush), pilosa_http_request_seconds.  Its read stage ends
+        # where this method was entered.
         clock = None
         if t_first is not None and method == "POST" and path.endswith("/query"):
-            clock = headers[tracing.CLOCK] = tracing.RequestClock(t_first)
+            clock = headers[tracing.CLOCK] = tracing.RequestClock(
+                t_first, t_dispatch
+            )
 
         def release_once():
             if admission is not None and not released:
@@ -697,7 +713,10 @@ class _Reactor(threading.Thread):
         result = None
         if fast is not None:
             try:
-                result = fast(method, path, query, body, headers)
+                # The inline route's handoff; on the pool route, the
+                # attempt the handler declines.
+                with tracing.mark("handoff", route="inline"):
+                    result = fast(method, path, query, body, headers)
             except Exception as e:  # noqa: BLE001
                 from .server import error_response
 
@@ -709,8 +728,14 @@ class _Reactor(threading.Thread):
             return
         # Blocking path: the full route table on the worker pool.
         srv._c_req_pool.inc()
+        if clock is not None:
+            clock.route = "pool"
 
         def job():
+            # The job's wait in the pool's queue ends here, on the pool
+            # thread: the handoff stage's other end is the handler's entry.
+            if clock is not None:
+                clock.pooled()
             try:
                 res = handler.handle(method, path, query, body, headers)
             except Exception as e:  # noqa: BLE001
@@ -732,6 +757,8 @@ class _Reactor(threading.Thread):
                 threading.Thread(target=job, daemon=True).start()
                 return
             release_once()
+            if clock is not None:
+                clock.abandon()  # shed: no query request is held
             if admission is not None:
                 status, reason = admission.shed_queue_full()
                 plans_mod.LEDGER.note_shed(tenant)
@@ -837,6 +864,19 @@ class _Reactor(threading.Thread):
         and flush everything now in order.  ``clock`` (a query's
         tracing.RequestClock) is finished when the last byte of ``raw``
         has been handed to the socket."""
+        if clock is not None:
+            if conn.closed:
+                clock.abandon()
+                return
+            woke_us = clock.completing()  # respond_wake ends, write starts
+            if tracing.capturing:
+                # The wake-up no thread performs, written on what it ends in.
+                with tracing.mark("write", waited="respond_wake", waited_us=woke_us):
+                    self._place(conn, slot, raw, clock)
+                return
+        self._place(conn, slot, raw, clock)
+
+    def _place(self, conn: _Conn, slot: int, raw: bytes, clock):
         if conn.closed:
             return
         conn.ready[slot] = (raw, clock)
@@ -861,26 +901,10 @@ class _Reactor(threading.Thread):
     def _flush(self, conn: _Conn):
         if conn.closed:
             return
-        while conn.out:
-            buf, clock = conn.out[0]
-            try:
-                n = conn.sock.send(buf)
-            except (BlockingIOError, InterruptedError):
-                break
-            except ssl_mod.SSLWantWriteError:
-                break
-            except ssl_mod.SSLWantReadError:
-                break
-            except (BrokenPipeError, ConnectionResetError, OSError):
-                self._close(conn)
-                return
-            if n == len(buf):
-                conn.out.popleft()
-                if clock is not None:
-                    clock.finish()
-            else:
-                conn.out[0] = (buf[n:] if n else buf, clock)
-                break
+        if conn.out:
+            with tracing.mark("write"):
+                if self._send(conn):
+                    return
         want_write = bool(conn.out)
         if (
             not want_write
@@ -897,6 +921,31 @@ class _Reactor(threading.Thread):
             read=not conn.stop_reading and not conn.paused,
             write=want_write,
         )
+
+    def _send(self, conn: _Conn) -> bool:
+        """Hand ``conn.out`` to the socket for as long as it takes it;
+        True when the connection broke (and is closed)."""
+        while conn.out:
+            buf, clock = conn.out[0]
+            try:
+                n = conn.sock.send(buf)
+            except (BlockingIOError, InterruptedError):
+                break
+            except ssl_mod.SSLWantWriteError:
+                break
+            except ssl_mod.SSLWantReadError:
+                break
+            except (BrokenPipeError, ConnectionResetError, OSError):
+                self._close(conn)
+                return True
+            if n == len(buf):
+                conn.out.popleft()
+                if clock is not None:
+                    clock.finish()
+            else:
+                conn.out[0] = (buf[n:] if n else buf, clock)
+                break
+        return False
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -936,6 +985,11 @@ class _Reactor(threading.Thread):
         if conn.closed:
             return
         conn.closed = True
+        if conn.want_write:
+            self._writing -= 1
+        for _raw, clock in (*conn.ready.values(), *conn.out):
+            if clock is not None:
+                clock.abandon()  # the reply never left: no longer occupied
         conn.ready.clear()
         conn.out.clear()
         try:

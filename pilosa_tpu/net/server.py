@@ -570,28 +570,30 @@ class Handler:
         d = DeferredResponse()
 
         def _done(f):
-            if req.clock is not None:
-                req.clock.result_ready()  # the respond stage starts
-            try:
-                resp = f.result(0)
-                span = getattr(f, "trace_span", None)
-                trace_id = span.trace_id if span is not None else None
-                plan = getattr(f, "query_plan", None) if req.profile else None
-                payload = (
-                    count_response_bytes(resp, trace_id)
-                    if plan is None else None  # profiled: full encoder
-                )
-                if payload is None:
-                    out = response_to_json(resp)
-                    if trace_id is not None:
-                        out["traceID"] = trace_id
-                    if plan is not None:
-                        out["plan"] = plan.to_dict()
-                    payload = json.dumps(out).encode()
-                d.resolve(200, "application/json", payload)
-            except Exception as e:  # noqa: BLE001
-                status, payload = error_response(e)
-                d.resolve(status, "application/json", payload)
+            # result_ready, and the reply's encoding as the encode stage
+            # of this request's respond (complete_wait ends here: the
+            # drain's decode -> this callback's turn on the collect worker).
+            with tracing.encoding(req.clock, getattr(f, "t_decoded", None)):
+                try:
+                    resp = f.result(0)
+                    span = getattr(f, "trace_span", None)
+                    trace_id = span.trace_id if span is not None else None
+                    plan = getattr(f, "query_plan", None) if req.profile else None
+                    payload = (
+                        count_response_bytes(resp, trace_id)
+                        if plan is None else None  # profiled: full encoder
+                    )
+                    if payload is None:
+                        out = response_to_json(resp)
+                        if trace_id is not None:
+                            out["traceID"] = trace_id
+                        if plan is not None:
+                            out["plan"] = plan.to_dict()
+                        payload = json.dumps(out).encode()
+                    status = 200
+                except Exception as e:  # noqa: BLE001
+                    status, payload = error_response(e)
+            d.resolve(status, "application/json", payload)
 
         fut.add_done_callback(_done)
         return d
@@ -646,24 +648,25 @@ class Handler:
         if d is not None:
             return d
         resp = self.api.query(req)
-        if req.clock is not None:
-            req.clock.result_ready()  # the respond stage starts
-        if getattr(resp, "plan", None) is None:
-            # Fast JSON encode for int and TopN (id, count) results —
-            # byte-identical to the generic walk (net/wire.py).  The
-            # classic dashboard TopN payload previously always paid the
-            # per-pair dict build + json.dumps dispatch chain here.
-            payload = count_response_bytes(
-                resp, getattr(resp, "trace_id", None)
-            )
-            if payload is not None:
-                return 200, "application/json", payload
-        out = response_to_json(resp)
-        if getattr(resp, "trace_id", None):
-            out["traceID"] = resp.trace_id
-        if getattr(resp, "plan", None) is not None:
-            out["plan"] = resp.plan
-        return out
+        # result_ready, and the reply's encoding as the encode stage of
+        # this request's respond.
+        with tracing.encoding(req.clock):
+            if getattr(resp, "plan", None) is None:
+                # Fast JSON encode for int and TopN (id, count) results —
+                # byte-identical to the generic walk (net/wire.py).  The
+                # classic dashboard TopN payload previously always paid the
+                # per-pair dict build + json.dumps dispatch chain here.
+                payload = count_response_bytes(
+                    resp, getattr(resp, "trace_id", None)
+                )
+                if payload is not None:
+                    return 200, "application/json", payload
+            out = response_to_json(resp)
+            if getattr(resp, "trace_id", None):
+                out["traceID"] = resp.trace_id
+            if getattr(resp, "plan", None) is not None:
+                out["plan"] = resp.plan
+            return 200, "application/json", json.dumps(out).encode()
 
     # -- continuous queries (docs/incremental.md) --------------------------
 
@@ -793,9 +796,13 @@ class Handler:
         # at pull time too (docs/observability.md): the query hot path
         # only touches the ledger's own lock.
         plans_mod.LEDGER.refresh_series()
-        # The stage clock's two window denominators, current to the
-        # scrape: the open part of the in-flight union, and the uptime.
+        # The stage clock's series, current to the scrape: the finished
+        # requests' HTTP stages, the open parts of the in-flight and
+        # occupied unions, the collector's pending pauses, the uptime.
+        tracing.settle()
         tracing.INFLIGHT.flush()
+        tracing.OCCUPIED.flush()
+        tracing.GC.flush()
         REGISTRY.set_gauge(
             METRIC_UPTIME, time.monotonic() - _START_MONOTONIC
         )
@@ -979,6 +986,7 @@ class Handler:
             limit = int(q.get("limit", ["64"])[0])
         except ValueError:
             raise ValueError("limit must be an integer")
+        tracing.settle()  # the finished requests' HTTP stages
         return plans_mod.STORE.to_doc(
             op=q.get("op", [None])[0],
             limit=limit,
@@ -992,6 +1000,7 @@ class Handler:
         tracer = getattr(self.api, "tracer", None)
         if tracer is None or not hasattr(tracer, "traces"):
             return {"recent": [], "slow": []}
+        tracing.settle()  # the finished requests' HTTP stages
         return tracer.traces()
 
     def _debug_faults_get(self, q, b, **kw):
@@ -1596,15 +1605,22 @@ class _ResponseSequencer:
     def complete(self, slot: int, raw: bytes, clock=None):
         """``clock`` (a query's tracing.RequestClock) is finished when
         ``raw`` has been handed to the socket."""
+        if clock is not None:
+            clock.completing()  # the write stage starts
         with self._cond:
+            if self.dead:
+                if clock is not None:
+                    clock.abandon()
+                return
             self._ready[slot] = (raw, clock)
             while not self.dead and self._next_write in self._ready:
                 buf, written = self._ready.pop(self._next_write)
                 try:
                     self._wfile.write(buf)
                 except Exception:  # noqa: BLE001 — client went away
-                    self.dead = True
-                    self._ready.clear()
+                    self._kill()
+                    if written is not None:
+                        written.abandon()
                     break
                 if written is not None:
                     written.finish()
@@ -1623,10 +1639,18 @@ class _ResponseSequencer:
                 self._cond.wait(min(remaining, 1.0))
             return self._next_write >= self._next_slot
 
+    def _kill(self):
+        """Dead, and the backlog dropped (its requests' clocks with it);
+        called with the lock held."""
+        self.dead = True
+        for _raw, clock in self._ready.values():
+            if clock is not None:
+                clock.abandon()
+        self._ready.clear()
+
     def kill(self):
         with self._cond:
-            self.dead = True
-            self._ready.clear()
+            self._kill()
             self._cond.notify_all()
 
 
@@ -1917,6 +1941,18 @@ def serve(
         # (api.readiness folds these reasons in).
         api.process_server = srv
     srv.RequestHandlerClass.handler = handler
+    # The collector's clock runs for as long as this server serves:
+    # from here to its server_close (never at import).
+    tracing.GC.install()
+    close, held = srv.server_close, [True]
+
+    def server_close():
+        if held:
+            held.clear()
+            tracing.GC.uninstall()
+        close()
+
+    srv.server_close = server_close
     thread = threading.Thread(target=srv.serve_forever, daemon=True)
     thread.start()
     return srv, thread

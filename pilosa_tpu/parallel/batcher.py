@@ -98,6 +98,7 @@ class _Item:
         "result",
         "error",
         "t_submit",
+        "t_decoded",
         "span",
         "plan",
         "memo_note",
@@ -122,6 +123,10 @@ class _Item:
         self.result: Optional[int] = None
         self.error: Optional[BaseException] = None
         self.t_submit = time.monotonic()
+        # When the decode stage of the drain that answered this item
+        # ended (None on the direct path, a memo hit or an error): where
+        # its request's complete_wait starts.
+        self.t_decoded: Optional[float] = None
         self.span = tracing.current_span()
         # The submitter's query plan, captured exactly like the span:
         # stage workers stamp decisions and timings onto it across the
@@ -633,7 +638,8 @@ class CountBatcher:
                     wake = now + self.QUIET_MIN
             if now >= last + quiet:
                 return "quiet"
-            self._cond.wait(min(last + quiet, wake) - now)
+            with tracing.mark("accum_wait", queued=n):
+                self._cond.wait(min(last + quiet, wake) - now)
 
     def _plan_drain(self, batch):
         """The whole-program planning stage between accumulate and
@@ -783,9 +789,10 @@ class CountBatcher:
             self.pipeline.gauge_max("max_batch_occupancy", len(items))
             if len(items) >= 2:
                 # Cross-request coalescing evidence: how many batches
-                # actually fused, and how many answers rode them.
+                # actually fused (the engine's own
+                # pilosa_engine_fused_program_*_total series count the
+                # programs and the queries that rode them).
                 self.pipeline.incr("fused_batches")
-                self.pipeline.incr("fused_queries", len(items))
                 self._last_fused = time.monotonic()
                 # Process mode: a fused batch whose riders arrived via
                 # DIFFERENT worker processes proves the cross-process
@@ -793,15 +800,6 @@ class CountBatcher:
                 origins = {it.origin for it in items if it.origin}
                 if len(origins) >= 2:
                     self.pipeline.incr("cross_worker_fused_batches")
-                    self.pipeline.gauge_max(
-                        "fused_worker_origins_max", len(origins)
-                    )
-            if gkind == "fused":
-                # Heterogeneous whole-program evidence (docs/fusion.md):
-                # this drain lowered to ONE device program across op
-                # kinds.
-                self.pipeline.incr("fused_program_batches")
-                self.pipeline.incr("fused_program_queries", len(items))
             # In flight from the jitted call's return to the collect
             # worker's device_get (pilosa_engine_device_inflight_seconds_total).
             tracing.INFLIGHT.begin(lowering.t1)
@@ -989,12 +987,17 @@ class CountBatcher:
                     tracing.waited(
                         "collect_wait", None, t_dispatched, t_taken, None
                     )
+                    # The device has its work: the time to record what
+                    # the last drain's requests left for a thread that
+                    # waits anyway.
+                    tracing.settle()
                     with tracing.stage("device_get"):
                         if decoders is None:
                             out = np.asarray(jax.device_get(dev))
                         else:
                             out = jax.device_get(dev)
-                with tracing.stage("decode", path, items):
+                decode = tracing.stage("decode", path, items)
+                with decode:
                     for i, it in enumerate(items):
                         it.result = (
                             int(out[i]) if decoders is None
@@ -1025,6 +1028,7 @@ class CountBatcher:
                 window = readback.t1 - t_dispatched
                 total_w = sum(weights) if weights else 0.0
                 for i, it in enumerate(items):
+                    it.t_decoded = decode.t1
                     if it.plan is not None:
                         it.plan.note_device_seconds(
                             window * weights[i] / total_w
@@ -1040,8 +1044,14 @@ class CountBatcher:
                     self._live -= 1
                 self.pipeline.add_delta("inflight", -1)
                 self._inflight.release()
-                for it in items:
-                    it._resolve()
+                # The riders' completion callbacks, one after another on
+                # this worker (the HTTP layer encodes each reply here):
+                # once a drain, and on no rider, whose reply may have
+                # left before the loop ends.  A rider's own wait for its
+                # turn is its request's complete_wait.
+                with tracing.stage("complete", path, (), batch=len(items)):
+                    for it in items:
+                        it._resolve()
 
     # -- signatures / telemetry ---------------------------------------------
 

@@ -1137,6 +1137,9 @@ class MeshEngine:
         ``since`` (when the first jitted call returned)."""
         tracing.INFLIGHT.begin(since)
         try:
+            # The device has its work: the time to record what earlier
+            # requests left for a thread that waits anyway.
+            tracing.settle()
             with tracing.stage("device_get"):
                 return jax.device_get(dev)
         finally:

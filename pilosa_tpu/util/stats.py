@@ -424,6 +424,16 @@ METRIC_EXECUTOR_GROUP_RESULTS = "pilosa_executor_group_results_total"
 GROUP_RESULT_FORMS = ("columns", "objects")
 METRIC_ENGINE_DEVICE_INFLIGHT = "pilosa_engine_device_inflight_seconds_total"
 METRIC_UPTIME = "pilosa_uptime_seconds"
+#   pilosa_http_occupied_seconds_total   seconds in which the server held a
+#       query request at all: the union of [first byte in, last byte out]
+#       over all requests (util/tracing.OCCUPIED); over the uptime it stands
+#       above the in-flight share, and 100 % less it is the client's
+#       turnaround and the socket on the server's clock
+#   pilosa_gc_pause_seconds{generation}  pauses of the Python collector
+#       (util/tracing.GC: a gc.callbacks hook for as long as a server serves)
+METRIC_HTTP_OCCUPIED = "pilosa_http_occupied_seconds_total"
+METRIC_GC_PAUSE = "pilosa_gc_pause_seconds"
+GC_GENERATIONS = (0, 1, 2)
 METRIC_FRAGMENT_OP = "pilosa_fragment_op_seconds"
 #   pilosa_engine_cache_hits_total{cache=...}   engine cache hits
 #   pilosa_engine_cache_misses_total{cache=...} engine cache misses

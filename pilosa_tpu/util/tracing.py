@@ -25,17 +25,21 @@ trace's clock, the TPU equivalent of the reference's Jaeger adapter.
 
 from __future__ import annotations
 
+import gc
 import os
 import random
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional
 
 from . import plans as plans_mod
 from .stats import (
+    GC_GENERATIONS,
     METRIC_ENGINE_DEVICE_INFLIGHT,
+    METRIC_GC_PAUSE,
+    METRIC_HTTP_OCCUPIED,
     METRIC_HTTP_REQUEST,
     METRIC_PIPELINE_STAGE,
     METRIC_QUERY_STAGE,
@@ -365,6 +369,22 @@ def _annotation(name: str, path: Optional[str], tags: dict):
     return TraceAnnotation("pilosa." + name, path=path or "", **tags)
 
 
+_NOTHING = nullcontext()
+
+
+def mark(name: str, **tags):
+    """``with tracing.mark(name, **tags):`` around a moment that is no
+    stage of its own: a thread's sleep (``accum_wait``, ``select_wait``)
+    or the part of a timestamp-form stage that a thread does work in
+    (``read``, ``handoff``, ``write``).  A live ``pilosa.<name>``
+    annotation while a capture runs, so that an idle gap can be put
+    down to it (scripts/trace_gaps.py); nothing at all otherwise, and
+    never a histogram."""
+    if capturing:
+        return _annotation(name, None, tags)
+    return _NOTHING
+
+
 def name_thread(name: Optional[str] = None):
     """Give the calling OS thread a name (the Python thread's, cut to
     the kernel's 15 characters): the profiler names a host line after
@@ -413,14 +433,20 @@ class stage:
     the executor spans' own — so the stages inside it ride the thread's
     span again.  A block left by an exception records nothing.  A
     slotted class, not a @contextmanager: this sits on the per-query
-    hot path (cf. plans.attach)."""
+    hot path (cf. plans.attach).
+
+    ``under`` names the enclosing stage outright, whatever stage the
+    thread is inside: ``RequestClock``'s ``encode`` runs on the thread
+    that finished the request and belongs to that request's ``respond``,
+    which no thread is inside."""
 
     __slots__ = ("name", "path", "riders", "tags", "t0", "t1", "inner",
                  "hole_s", "self_time", "shared", "observed", "_outer",
-                 "_ann")
+                 "_prev", "_ann")
 
     def __init__(self, name: str, path: Optional[str] = None, riders=None,
-                 t0: Optional[float] = None, self_time: bool = False, **tags):
+                 t0: Optional[float] = None, self_time: bool = False,
+                 under: Optional["stage"] = None, **tags):
         self.name = name
         self.path = path
         self.riders = riders
@@ -431,6 +457,7 @@ class stage:
         self.hole_s = 0.0
         self.self_time = self_time
         self.shared = False  # rides (and nests) with the enclosing stage
+        self._outer = under
         self._ann = None
 
     def _adopt(self, outer: Optional["stage"]):
@@ -444,7 +471,10 @@ class stage:
                 self.riders = (Ambient(),)
 
     def __enter__(self):
-        outer = self._outer = getattr(_LOCAL, "stage", None)
+        prev = self._prev = getattr(_LOCAL, "stage", None)
+        outer = self._outer
+        if outer is None:
+            outer = self._outer = prev
         self._adopt(outer)
         _LOCAL.stage = self
         if capturing:
@@ -458,9 +488,13 @@ class stage:
         self.t1 = time.monotonic()
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
-        _LOCAL.stage = self._outer
+        _LOCAL.stage = self._prev
         if exc_type is None:
             self._done()
+        # A finished stage lives on in rings (spans, plans): it keeps no
+        # way back to what enclosed it, or every tree would be a cycle
+        # that only the collector's full pass frees.
+        self._outer = self._prev = None
         return False
 
     def _done(self):
@@ -498,6 +532,7 @@ def waited(name: str, path: Optional[str], t0: float, t1: float, riders):
     st._outer = getattr(_LOCAL, "stage", None)
     st._adopt(st._outer)
     st._done()
+    st._outer = None  # as a stage that has exited
 
 
 def hole(t0: float, t1: float):
@@ -596,45 +631,282 @@ def _observe(st: "stage", path: str, exemplar: Optional[str]) -> str:
     return path
 
 
+_NO_TAGS: dict = {}
+
+
+class _Record:
+    """A stage known by its two timestamps, as ``RequestClock._record``
+    records it: what ``Span.children``, ``QueryPlan.stages`` and the
+    closure read of a finished stage tree, and nothing else (a request
+    makes eight of them)."""
+
+    __slots__ = ("name", "t0", "t1", "observed", "tags", "inner", "shared")
+
+    def __init__(self, name: str, t0: float, t1: float, tags: dict = _NO_TAGS,
+                 inner: Optional[list] = None, shared: bool = False):
+        self.name = name
+        self.t0 = t0
+        self.t1 = t1
+        self.observed = t1 - t0
+        self.tags = tags
+        self.inner = inner
+        self.shared = shared
+
+    _to_span = stage._to_span
+
+
+def _covered(intervals, lo: float, hi: float) -> float:
+    """Seconds of [lo, hi] inside the union of ``intervals``."""
+    total, end = 0.0, lo
+    for a, b in sorted(intervals):
+        if b > hi:
+            b = hi
+        if a < end:
+            a = end
+        if b > a:
+            total += b - a
+            end = b
+    return total
+
+
+def _top_level(span: "Span"):
+    """The stage intervals recorded on ``span``: its staged trees and,
+    where somebody has walked it already, its ``pipeline.*`` children."""
+    for st in list(span._staged):
+        yield st.t0, st.t1
+    for c in span._children:
+        if c.name.startswith("pipeline.") and c.duration is not None:
+            yield c.start, c.start + c.duration
+
+
 class RequestClock:
     """One query request on the HTTP layer's clock: made at the first
     byte, handed to the handler (``headers[CLOCK]``) and the API
     (``QueryRequest.clock``), finished when the last byte of the reply
-    has been handed to the socket.  ``finish`` observes
-    ``pilosa_http_request_seconds`` and the two stages only the HTTP
-    layer sees — ``http_read`` (first byte -> handler entered) and
-    ``respond`` (result -> last byte) — under the path the request's
-    root span was stamped with."""
+    has been handed to the socket.  It is a stage rider (``span``,
+    ``plan``: the API sets both).  ``finish``, on the writer's thread,
+    observes ``pilosa_http_request_seconds`` and stops the clock;
+    ``settle`` then records, under the path the request's root span was
+    stamped with, the stages only the HTTP layer sees:
 
-    __slots__ = ("t0", "t_handler", "t_result", "span")
+    ``http_read``      first byte -> handler entered for the last time
+      ``read``         ... -> the reactor's ``_dispatch`` entered
+      ``handoff``      ... -> handler entered (``route=inline|pool``)
+    ``prologue``       handler entered -> the executor entered
+    ``epilogue``       the executor returned -> ``result_ready``, where no
+                       drain answered (else that is ``complete_wait``)
+    ``complete_wait``  the drain's ``decode`` ended -> ``result_ready``
+    ``respond``        ``result_ready`` -> last byte handed to the socket
+      ``encode``       the payload's encoding (``encoding()``: a real stage)
+      ``respond_wake`` ... -> the reactor's ``_complete`` entered
+      ``write``        ... -> ``finish``
 
-    def __init__(self, t0: float):
+    and ``unstaged``: the request's seconds less the union of every
+    top-level stage recorded for it.  The threaded server has no
+    reactor: no ``read`` / ``handoff`` / ``respond_wake`` there.  While
+    the clock is open the server is occupied (``OCCUPIED``)."""
+
+    __slots__ = ("t0", "t_dispatch", "route", "t_handler", "t_exec",
+                 "t_executed", "t_decoded", "t_result", "t_complete", "t_done",
+                 "span", "plan", "inner", "_mark", "_open")
+
+    # As the stage that encloses ``encode`` (``stage(under=clock)``):
+    # the path is the request's, known when the stages are recorded;
+    # nothing rides yet.
+    path = None
+    riders = ()
+    self_time = False
+
+    def __init__(self, t0: float, t_dispatch: Optional[float] = None):
         self.t0 = t0
+        self.t_dispatch = t_dispatch
+        self.route = "inline"
         self.t_handler = None
+        self.t_exec = None
+        self.t_executed = None
+        self.t_decoded = None
         self.t_result = None
+        self.t_complete = None
+        self.t_done = None
         self.span = None
+        self.plan = None
+        self.inner = None  # [the finished encode stage]
+        self._mark = None
+        self._open = True
+        OCCUPIED.begin(t0)
+
+    def _note(self, name: Optional[str] = None, **tags):
+        """While a capture runs: leave the mark this request's thread
+        is inside and enter ``name`` (the parts of ``handoff`` and
+        ``prologue`` that a thread works in begin and end in different
+        functions, so the clock carries the annotation between them)."""
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+            self._mark = None
+        if name is not None and capturing:
+            self._mark = mark(name, **tags)
+            self._mark.__enter__()
+
+    def pooled(self):
+        """A pool thread has taken the request (the pool route).  What
+        went before since ``_dispatch`` was entered (the declined
+        attempt on the reactor, then the pool's queue) is written on the
+        mark of this thread's own part of ``handoff`` as ``waited_us``:
+        an annotation cannot be written after the fact."""
+        if capturing:
+            self._note("handoff", route="pool", waited="handoff",
+                       waited_us=int((time.monotonic() - self.t_dispatch) * 1e6))
 
     def handler_entered(self):
         # The last entry counts: a request the reactor's deferred
         # attempt declines enters the handler again on a pool thread.
         self.t_handler = time.monotonic()
+        if capturing or self._mark is not None:
+            self._note("prologue")
 
-    def result_ready(self):
+    def executing(self):
+        """The executor is entered next (the last time counts, as for
+        the handler): ``prologue`` ends."""
+        if self._mark is not None:
+            self._note()
+        self.t_exec = time.monotonic()
+
+    def executed(self):
+        """The executor has returned (the last time counts): what the
+        API does with the answer before the handler has it (the span's
+        and the plan's finish, the plan's record) is ``epilogue``."""
+        self.t_executed = time.monotonic()
+
+    def result_ready(self, t_decoded: Optional[float] = None):
+        """The handler has the result; ``t_decoded`` is when the
+        drain's ``decode`` ended, where a drain answered."""
         self.t_result = time.monotonic()
+        self.t_decoded = t_decoded
+
+    def encoding(self) -> "stage":
+        """``with clock.encoding():`` around the payload's encoding, on
+        the thread that has the result (after ``result_ready``): a stage
+        of its own, kept to be recorded under ``respond``."""
+        return stage("encode", t0=self.t_result, under=self)
+
+    def completing(self) -> int:
+        """The writer has the rendered reply (the reactor's
+        ``_complete``, the threaded sequencer's ``complete``); the
+        microseconds it took to come here from the encoding's end."""
+        now = self.t_complete = time.monotonic()
+        since = self.inner[0].t1 if self.inner else self.t_result
+        return int((now - since) * 1e6) if since is not None else 0
+
+    def abandon(self):
+        """The connection went before the reply: nothing is observed."""
+        if self._open:
+            self._open = False
+            OCCUPIED.end()
+        if self._mark is not None:
+            self._note()
 
     def finish(self):
-        now = time.monotonic()
-        _HTTP_HIST.observe(now - self.t0)
-        if self.t_handler is None:
+        """The last byte has been handed to the socket.  On the writer's
+        thread (the reactor: the next request waits behind it) only the
+        clock is stopped; the stages are recorded by ``settle``."""
+        if not self._open:
             return
+        self._open = False
+        if self._mark is not None:  # a handler left by an error
+            self._note()
+        now = self.t_done = time.monotonic()
+        OCCUPIED.end(now)
+        _HTTP_HIST.observe(now - self.t0)
+        if self.t_handler is not None:
+            _FINISHED.append(self)
+            if len(_FINISHED) >= SETTLE_AT:
+                settle()
+
+    def _record(self):
+        """The stages and the closure of a finished request (``settle``)."""
+        t0, now, t_handler = self.t0, self.t_done, self.t_handler
         span = self.span
         path = span.tags.get("path", "host") if span is not None else "host"
-        _stage_hist(path, "http_read").observe(self.t_handler - self.t0)
-        if self.t_result is not None:
-            _stage_hist(path, "respond").observe(now - self.t_result)
-        if span is not None:
-            span.tags["http_read_ms"] = round((self.t_handler - self.t0) * 1e3, 3)
-            span.tags["http_ms"] = round((now - self.t0) * 1e3, 3)
+        reactor = self.t_dispatch is not None
+        trees = [_Record("http_read", t0, t_handler, inner=[
+            _Record("read", t0, self.t_dispatch, shared=True),
+            _Record("handoff", self.t_dispatch, t_handler,
+                    {"route": self.route}, shared=True),
+        ] if reactor else None)]
+        if self.t_exec is not None and self.t_exec >= t_handler:
+            trees.append(_Record("prologue", t_handler, self.t_exec))
+        t_result = self.t_result
+        if t_result is not None:
+            if self.t_decoded is not None:
+                trees.append(_Record("complete_wait", self.t_decoded, t_result))
+            elif self.t_executed is not None and self.t_executed >= t_handler:
+                trees.append(_Record("epilogue", self.t_executed, t_result))
+            parts, t = [], t_result
+            if self.inner:
+                encode = self.inner[0]
+                encode.observed = encode.t1 - encode.t0
+                parts.append(encode)
+                t = encode.t1
+            if self.t_complete is not None:
+                if reactor:  # the reply crosses to the reactor's thread
+                    parts.append(_Record("respond_wake", t, self.t_complete, shared=True))
+                    t = self.t_complete
+                parts.append(_Record("write", t, now, shared=True))
+            trees.append(_Record("respond", t_result, now, inner=parts))
+        plan = self.plan
+        for tree in trees:
+            _stage_hist(path, tree.name).observe(tree.observed)
+            for part in tree.inner or ():
+                _stage_hist(path, part.name).observe(part.observed)
+            if span is not None:
+                span._staged.append(tree)
+            if plan is not None:
+                plan._stage_trees.append(tree)
+        if span is None:
+            return
+        span.tags["http_read_ms"] = round((t_handler - t0) * 1e3, 3)
+        span.tags["http_ms"] = round((now - t0) * 1e3, 3)
+        # The closure: what no stage recorded for this request holds.
+        if plan is not None:
+            intervals = [(st.t0, st.t1) for st in plan._stage_trees]
+        else:
+            intervals = _top_level(span)
+        unstaged = max(0.0, now - t0 - _covered(intervals, t0, now))
+        _stage_hist(path, "unstaged").observe(unstaged)
+        span.tags["unstaged_ms"] = round(unstaged * 1e3, 3)
+
+
+# Finished clocks whose stages are yet to be recorded, and how many may
+# wait before the finishing thread records them itself (a server that
+# never waits for the device: memo hits only).
+_FINISHED: "deque[RequestClock]" = deque()
+SETTLE_AT = 64
+
+
+def settle():
+    """Record the HTTP layer's stages of every request that has finished
+    since the last call.  Called where a thread is about to wait for
+    the device anyway (the collect worker's and the direct path's
+    ``device_get``), so that the records (eight objects, eleven
+    observations and a union a request) cost no request its place on
+    the reactor; and wherever they are read (a scrape, ``/debug/traces``,
+    ``/debug/plans``).  Any thread may call it; a clock is recorded
+    once."""
+    while _FINISHED:
+        try:
+            clock = _FINISHED.popleft()
+        except IndexError:  # another thread took the last
+            return
+        clock._record()
+
+
+def encoding(clock: Optional[RequestClock], t_decoded: Optional[float] = None):
+    """``with tracing.encoding(req.clock):`` — ``result_ready`` and the
+    ``encode`` stage of a request that has a clock; nothing without."""
+    if clock is None:
+        return _NOTHING
+    clock.result_ready(t_decoded)
+    return clock.encoding()
 
 
 # The key under which the HTTP servers hand a request's clock to the
@@ -643,12 +915,13 @@ CLOCK = ":clock"
 
 
 class Inflight:
-    """Seconds in which the host had given the device anything at all:
-    the union, over all query dispatches, of [jitted call returned, its
-    device_get returned].  A depth counter and the time the depth left
-    zero; the union's closed part goes to
-    ``pilosa_engine_device_inflight_seconds_total`` whenever the depth
-    returns to zero (and at scrape time, ``flush``)."""
+    """Seconds in which a depth counter stood above zero: the union of
+    the intervals [``begin``, ``end``].  ``INFLIGHT`` is the host having
+    given the device anything at all (over all query dispatches, [jitted
+    call returned, its device_get returned]); ``OCCUPIED`` the server
+    holding a query request at all (a ``RequestClock`` made and not
+    finished).  The union's closed part goes to the counter whenever the
+    depth returns to zero (and at scrape time, ``flush``)."""
 
     def __init__(self, counter=None):
         self._lock = threading.Lock()
@@ -656,10 +929,18 @@ class Inflight:
         self._since = 0.0
         self._counter = counter or REGISTRY.counter(METRIC_ENGINE_DEVICE_INFLIGHT)
 
+    @property
+    def depth(self) -> int:
+        return self._depth
+
     def begin(self, now: Optional[float] = None):
         with self._lock:
             if self._depth == 0:
-                self._since = time.monotonic() if now is None else now
+                # Not before the last interval's end: a ``now`` read
+                # earlier than this call may lie inside it.
+                now = time.monotonic() if now is None else now
+                if now > self._since:
+                    self._since = now
             self._depth += 1
 
     def end(self, now: Optional[float] = None):
@@ -667,15 +948,107 @@ class Inflight:
             self._depth -= 1
             if self._depth == 0:
                 now = time.monotonic() if now is None else now
-                self._counter.inc(max(0.0, now - self._since))
+                if now > self._since:
+                    self._counter.inc(now - self._since)
+                    self._since = now
 
     def flush(self):
         """Count the open interval up to now (a scrape mid-drain)."""
         with self._lock:
             if self._depth > 0:
                 now = time.monotonic()
-                self._counter.inc(max(0.0, now - self._since))
-                self._since = now
+                if now > self._since:
+                    self._counter.inc(now - self._since)
+                    self._since = now
 
 
 INFLIGHT = Inflight()
+OCCUPIED = Inflight(REGISTRY.counter(
+    METRIC_HTTP_OCCUPIED,
+    help="Seconds in which the server held a query request at all",
+))
+
+
+class GcClock:
+    """The collector's pauses: one ``gc.callbacks`` hook, installed by a
+    serving process for as long as it serves (``install`` /
+    ``uninstall``, counted: two servers of one process share it).  A
+    pass costs the hook two clock reads and a list append; ``flush``
+    (scrape time) observes what is pending into
+    ``pilosa_gc_pause_seconds{generation}``.  A pass of generation 2
+    adds its ``gc_ms`` to the tags of the stage the allocating thread
+    is inside, so the slow ring says which request paid; while a
+    capture runs a pass of generation >= 1 is a ``pilosa.gc``
+    annotation, the innermost stage of whatever it interrupts (the pass
+    runs on the allocating thread, and no second pass starts inside it:
+    one slot is enough)."""
+
+    PENDING_MAX = 4096
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._refs = 0
+        self._t = 0.0
+        self._ann = None
+        self._pending: list = []
+        self._hists: Dict[int, object] = {}
+
+    def install(self):
+        with self._lock:
+            self._refs += 1
+            if self._refs == 1:
+                for g in GC_GENERATIONS:
+                    self._hists[g] = REGISTRY.histogram(
+                        METRIC_GC_PAUSE,
+                        help="Pauses of the Python collector, by generation (seconds)",
+                        generation=str(g),
+                    )
+                gc.callbacks.append(self._on_gc)
+
+    def uninstall(self):
+        with self._lock:
+            self._refs -= 1
+            if self._refs == 0:
+                try:
+                    gc.callbacks.remove(self._on_gc)
+                except ValueError:
+                    pass
+        self.flush()
+
+    def _on_gc(self, phase: str, info: dict):
+        if phase == "start":
+            if capturing and info["generation"]:
+                self._ann = _annotation("gc", None, {"generation": info["generation"]})
+                self._ann.__enter__()
+            self._t = time.monotonic()
+            return
+        seconds = time.monotonic() - self._t
+        generation = info["generation"]
+        pending = self._pending
+        pending.append((generation, seconds))
+        if generation:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            if generation == 2:
+                st = getattr(_LOCAL, "stage", None)
+                if st is not None:
+                    st.tags["gc_ms"] = round(
+                        st.tags.get("gc_ms", 0.0) + seconds * 1e3, 3)
+            if len(pending) > self.PENDING_MAX:
+                self.flush()
+
+    def flush(self):
+        """Observe the pending pauses (appends race this only at the
+        list's end, which the cut leaves alone)."""
+        pending = self._pending
+        n = len(pending)
+        done = pending[:n]
+        del pending[:n]
+        for generation, seconds in done:
+            h = self._hists.get(generation)
+            if h is not None:
+                h.observe(seconds)
+
+
+GC = GcClock()

@@ -8,6 +8,7 @@ round-6 satellite fixes: _signature literal-only masking, resize
 membership-before-NORMAL ordering, and join/leave queued during an
 active resize job."""
 
+import contextlib
 import json
 import socket
 import threading
@@ -210,6 +211,7 @@ class _VirtualWindow:
 
         class _Tracing:
             stage = _Stage
+            mark = staticmethod(lambda name, **tags: contextlib.nullcontext())
             current_span = staticmethod(lambda: None)
             name_thread = staticmethod(lambda name=None: None)
 
