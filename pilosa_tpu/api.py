@@ -95,6 +95,11 @@ class QueryRequest:
         # byte out); the API hands it the request's root span so its
         # http_read/respond stages carry the path the query took.
         self.clock = clock
+        # The text's parse (pql.Query), made once: by the deferred
+        # attempt (query_async), and handed to the sync path with the
+        # attempt's span and plan, ``declined``, where it was declined.
+        self.parsed = None
+        self.declined = None
 
 
 class ImportRequest:
@@ -297,24 +302,38 @@ class API:
             freshness_ms=getattr(req, "freshness_ms", None),
         )
         start = time.monotonic()
-        parent = getattr(req, "trace_context", None)
-        # Per-query plan record (util/plans.py): decisions stamp onto it
-        # from the executor/engine/batcher while the span carries the
-        # timing tree.  Remote replays are excluded — the initiator's
-        # plan already attributes the whole query, and a replay plan
-        # would double-charge the tenant ledger.
-        plan = None if req.remote else plans.begin(
-            req.index, req.query, tenant=getattr(req, "tenant", "default"),
-            profile=getattr(req, "profile", False),
-        )
-        with self.tracer.start_span(
-            "api.Query", parent=parent, index=req.index, remote=req.remote
-        ) as span, plans.attach(plan):
+        declined = getattr(req, "declined", None)
+        if declined is not None:
+            # The deferred attempt's span and plan (query_async): the
+            # request's own, begun before its text was parsed.
+            span, plan = declined
+            if span is not None:
+                span.tags["remote"] = req.remote
+            scope = self.tracer.resume(span)
+        else:
+            # Per-query plan record (util/plans.py): decisions stamp onto
+            # it from the executor/engine/batcher while the span carries
+            # the timing tree.  Remote replays are excluded — the
+            # initiator's plan already attributes the whole query, and a
+            # replay plan would double-charge the tenant ledger.
+            plan = None if req.remote else plans.begin(
+                req.index, req.query, tenant=getattr(req, "tenant", "default"),
+                profile=getattr(req, "profile", False),
+            )
+            scope = self.tracer.start_span(
+                "api.Query", parent=getattr(req, "trace_context", None),
+                index=req.index, remote=req.remote,
+            )
+        parsed = getattr(req, "parsed", None)
+        with scope as span, plans.attach(plan):
             clock = req.clock
             if clock is not None:
                 clock.span, clock.plan = span, plan
                 clock.executing()  # the prologue stage ends
-            resp = self.executor.execute(req.index, req.query, req.shards, opt)
+            resp = self.executor.execute(
+                req.index, req.query if parsed is None else parsed,
+                req.shards, opt,
+            )
             if clock is not None:
                 clock.executed()  # the epilogue stage starts
         with tracing.mark("epilogue"):
@@ -365,9 +384,14 @@ class API:
         """Deferred query: returns a future (result/add_done_callback ->
         QueryResponse) when the executor can pipeline the request
         (all-Count queries through the batch pipeline), else None — the
-        caller falls back to the synchronous ``query``.  The HTTP layer
-        uses this to resolve responses from completion callbacks instead
-        of holding a handler thread per in-flight query."""
+        caller falls back to the synchronous ``query``, which takes over
+        the attempt's parse, span and plan (``req.parsed``,
+        ``req.declined``): a request is offered here once and its text
+        parsed once.  The HTTP layer uses this to resolve responses from
+        completion callbacks instead of holding a handler thread per
+        in-flight query."""
+        if getattr(req, "declined", None) is not None:
+            return None
         opt = ExecOptions(
             remote=req.remote,
             exclude_row_attrs=req.exclude_row_attrs,
@@ -394,20 +418,21 @@ class API:
         with tracing.attach(span), plans.attach(plan):
             if req.clock is not None:
                 req.clock.executing()  # the prologue stage ends
-            fut = self.executor.execute_async(
+            fut, parsed = self.executor.execute_async(
                 req.index, req.query, req.shards, opt
             )
             if req.clock is not None:
                 req.clock.executed()
+        if parsed is not None:
+            req.parsed = parsed
         if fut is None:
-            # Declined (sync fallback): discard the provisional span —
-            # left attached it would sit unfinished in a live parent's
-            # tree, and query() roots its own span for the retry.
-            if span is not None and span.parent is not None:
-                try:
-                    span.parent.children.remove(span)
-                except ValueError:
-                    pass
+            # Declined: the sync path (query) takes the span and the
+            # plan over, with the parse, on this thread or another.
+            if span is not None:
+                span.tags.pop("pipelined", None)
+            if plan is not None:
+                plan.pipelined = False
+            req.declined = (span, plan)
             return None
         fut.trace_span = span
         fut.query_plan = plan

@@ -701,21 +701,26 @@ class Executor:
 
     def _parse_cached(self, s: str) -> Query:
         """PQL text -> calls: the ``parse`` stage, parse-cache hits
-        included and tagged."""
+        included and tagged.  The plan the text runs under takes the
+        sequence miner's signature from the calls here, before any
+        in-place key translation (``QueryPlan.sign``)."""
         with tracing_mod.stage("parse", cache="hit") as st:
             with self._parse_lock:
                 q = self._parse_cache.get(s)
                 if q is not None:
                     self._parse_cache.move_to_end(s)
-                    return q
-            st.tags["cache"] = "miss"
-            q = pql.parse(s)
-            if all(_call_cacheable(c) for c in q.calls):
-                with self._parse_lock:
-                    self._parse_cache[s] = q
-                    while len(self._parse_cache) > self._PARSE_CACHE_MAX:
-                        self._parse_cache.popitem(last=False)
-            return q
+            if q is None:
+                st.tags["cache"] = "miss"
+                q = pql.parse(s)
+                if all(_call_cacheable(c) for c in q.calls):
+                    with self._parse_lock:
+                        self._parse_cache[s] = q
+                        while len(self._parse_cache) > self._PARSE_CACHE_MAX:
+                            self._parse_cache.popitem(last=False)
+        plan = plans_mod.current_plan()
+        if plan is not None:
+            plan.sign(s, q)
+        return q
 
     # -- entry point (executor.go Execute :84) -----------------------------
 
@@ -726,6 +731,9 @@ class Executor:
         shards: Optional[List[int]] = None,
         opt: Optional[ExecOptions] = None,
     ) -> QueryResponse:
+        """``query`` is the PQL text, or its parse (a ``pql.Query``)
+        where the caller has it: a request the deferred path declined
+        (``execute_async``) is not parsed again."""
         # The ``plan`` stage is the executor's own host time: this call
         # minus the stages inside it (parse, and whatever lane each call
         # takes through the batcher).
@@ -737,12 +745,7 @@ class Executor:
         # answers from maintained row cardinalities without touching the
         # dispatch stack (reference analogue: summing roaring container
         # ``n`` fields instead of materializing the row).
-        if (
-            opt is None
-            and self.cluster is None
-            and self.translator is None
-            and isinstance(query, str)
-        ):
+        if opt is None and self.cluster is None and self.translator is None:
             resp, parsed = self._execute_fast_count(index, query, shards)
             if resp is not None:
                 return resp
@@ -758,53 +761,62 @@ class Executor:
         either answered from the O(1) cardinality lane or queued into
         the engine's bounded batch pipeline, and a future
         (result/add_done_callback) is returned WITHOUT waiting for the
-        device.  Returns None when the query isn't eligible — the
-        caller runs the synchronous ``execute`` path.  This is the seam
-        the HTTP layer uses to stop parking a handler thread per
-        in-flight query: completion callbacks resolve pending responses
-        when the fused batch's readback lands."""
+        device.  This is the seam the HTTP layer uses to stop parking a
+        handler thread per in-flight query: completion callbacks resolve
+        pending responses when the fused batch's readback lands.
+
+        Returns ``(future, parsed)``.  The future is None when the query
+        isn't eligible — the caller runs the synchronous ``execute``
+        path, and hands it ``parsed``, the parse of a text ``query``
+        (None where the attempt declined before parsing, or the text
+        does not parse), so that the text is not parsed again."""
         with tracing_mod.stage("plan", self_time=True) as st:
-            fut = self._execute_async(index, query, shards, opt)
+            fut, parsed = self._execute_async(index, query, shards, opt)
             if fut is not None:
                 st.path = "deferred"
-        return fut
+        return fut, parsed
 
     def _execute_async(self, index, query, shards, opt):
         eng = self.mesh_engine
         if eng is None or eng._peerless_multiproc:
-            return None
+            return None, None
         if opt is not None and (opt.remote or opt.column_attrs):
-            return None
+            return None, None
         try:
             if isinstance(query, str):
                 query = self._parse_cached(query)
         except Exception:  # noqa: BLE001 — sync path surfaces the error
-            return None
+            return None, None
         calls = query.calls
         if not calls or any(
             c.name != "Count" or len(c.children) != 1 for c in calls
         ):
-            return None
+            return None, query
         idx = self.holder.index(index)
         if idx is None:
-            return None  # sync path raises IndexNotFoundError
+            return None, query  # sync path raises IndexNotFoundError
         opt = opt or ExecOptions()
         try:
             if not opt.remote and self.translator is not None:
-                # In-place key->id rewrite, same as the sync prologue
-                # (idempotent: a later sync fallback re-translates ints
-                # as no-ops).  translate_results is safely skipped:
-                # Count results are plain ints, never key-translated.
+                # The key->id rewrite of the sync prologue, on copies
+                # wherever it can change a call (a string or bool
+                # argument): a declined attempt hands the sync path the
+                # request's parse as parsed, and the future's fallback
+                # re-executes it so.  translate_results is safely
+                # skipped: Count results are plain ints, never
+                # key-translated.
+                if not all(_call_cacheable(c) for c in calls):
+                    calls = [c.clone() for c in calls]
                 self.translator.translate_calls(index, idx, calls)
             if not shards:
                 shards = self._default_shards(index) or [0]
             if self.cluster is not None:
                 local = set(self._local_shards(index, shards, opt.remote))
                 if any(s not in local for s in shards):
-                    return None  # remote shards: the sync mapper splits
+                    return None, query  # remote shards: the sync mapper splits
             children = [c.children[0] for c in calls]
             if not all(eng.lowerable(ch) for ch in children):
-                return None
+                return None, query
             # Two passes: probe every fast-lane answer FIRST, so a late
             # surprise in this (fallible, host-side) pass aborts to the
             # sync path with ZERO batcher items enqueued — bailing after
@@ -816,14 +828,14 @@ class Executor:
                     index, ch, shards, opt.remote
                 )
         except Exception:  # noqa: BLE001 — any surprise: sync path decides
-            return None
+            return None, query
         items = [
             (k, eng.batched_count_async(index, ch, shards))
             for k, ch in enumerate(children)
             if slots[k] is None
         ]
         self.stats.count("Count", len(calls), tags=[f"index:{index}"])
-        return _QueryFuture(self, index, query, shards, opt, slots, items)
+        return _QueryFuture(self, index, query, shards, opt, slots, items), query
 
     def memo_counts(self, index, query: str):
         """Serving-boundary memo lane: the list of counts when EVERY
@@ -872,38 +884,45 @@ class Executor:
             eng._cache_hit("result_memo")
         return out
 
+    @staticmethod
+    def _fast_count_row(parsed):
+        """The Row of a bare ``Count(Row(f=n))``, else False: the O(1)
+        lane's structural eligibility, static per query text (field
+        shape and type stay dynamic, checked per execution by
+        _count_from_cardinalities)."""
+        calls = parsed.calls
+        if len(calls) == 1 and calls[0].name == "Count" and len(calls[0].children) == 1:
+            ch = calls[0].children[0]
+            if ch.name == "Row" and not ch.children and len(ch.args) == 1:
+                (row_val,) = ch.args.values()
+                if isinstance(row_val, int) and not isinstance(row_val, bool):
+                    return ch
+        return False
+
     def _execute_fast_count(self, index, query, shards):
         """O(1)-lane probe: returns (response, parsed).  ``response`` is
         set when the lane answered; otherwise ``parsed`` (when available)
-        lets the caller skip re-parsing.  Eligibility and counting both
-        live in _count_from_cardinalities — one implementation for the
-        prepared lane and the generic Count path."""
-        key = (index, query)
-        plan = self._fast_plans.get(key)
+        lets the caller skip re-parsing.  ``query`` is the text (its
+        eligibility is kept by text) or its parse.  Eligibility and
+        counting both live in _count_from_cardinalities — one
+        implementation for the prepared lane and the generic Count
+        path."""
         parsed = None
-        if plan is None:
-            try:
-                parsed = self._parse_cached(query)
-            except Exception:
-                return None, None  # outer path surfaces the parse error
-            plan = False
-            if (
-                len(parsed.calls) == 1
-                and parsed.calls[0].name == "Count"
-                and len(parsed.calls[0].children) == 1
-            ):
-                ch = parsed.calls[0].children[0]
-                # Structural eligibility is static per query text; field
-                # shape/type stays dynamic (checked per execution by
-                # _count_from_cardinalities).
-                if ch.name == "Row" and not ch.children and len(ch.args) == 1:
-                    (row_val,) = ch.args.values()
-                    if isinstance(row_val, int) and not isinstance(row_val, bool):
-                        plan = ch
-            with self._parse_lock:
-                self._fast_plans[key] = plan
-                while len(self._fast_plans) > self._PARSE_CACHE_MAX:
-                    self._fast_plans.popitem(last=False)
+        if not isinstance(query, str):
+            plan = self._fast_count_row(query)
+        else:
+            key = (index, query)
+            plan = self._fast_plans.get(key)
+            if plan is None:
+                try:
+                    parsed = self._parse_cached(query)
+                except Exception:
+                    return None, None  # outer path surfaces the parse error
+                plan = self._fast_count_row(parsed)
+                with self._parse_lock:
+                    self._fast_plans[key] = plan
+                    while len(self._fast_plans) > self._PARSE_CACHE_MAX:
+                        self._fast_plans.popitem(last=False)
         if plan is False:
             return None, parsed
         if not shards:  # same default as _execute: every available shard

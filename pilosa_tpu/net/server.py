@@ -45,6 +45,11 @@ DEFAULT_BACKEND = "async"
 # Process start reference for /healthz uptime.
 _START_MONOTONIC = time.monotonic()
 
+# The headers' slot (beside tracing.CLOCK) in which the reactor hands the
+# worker pool a query request its deferred attempt declined: parsed,
+# with the attempt's span and plan (Handler.handle_async, _post_query).
+DECLINED = ":declined"
+
 # Per-node scrape failure marker in the federated /cluster/metrics
 # exposition (NOT registered in the process REGISTRY: it describes the
 # federation attempt, not this node).
@@ -640,13 +645,23 @@ class Handler:
         if isinstance(result, DeferredResponse):
             result.on_ready(lambda status, ctype, payload: (
                 self._err_counter.inc() if status >= 500 else None))
+        else:
+            # Declined: the pool's _post_query takes the request over,
+            # parsed, with the attempt's span and plan.
+            headers[DECLINED] = req
         return result
 
     def _post_query(self, q, b, *, index, **kw):
-        req = self._query_request(index, q, b, kw.get("_headers", {}))
-        d = self._defer_query(req)
-        if d is not None:
-            return d
+        headers = kw.get("_headers", {})
+        req = headers.get(DECLINED)
+        if req is not None:
+            if req.clock is not None:
+                req.clock.handler_entered()
+        else:
+            req = self._query_request(index, q, b, headers)
+            d = self._defer_query(req)
+            if d is not None:
+                return d
         resp = self.api.query(req)
         # result_ready, and the reply's encoding as the encode stage of
         # this request's respond.

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import re
 
+from ..util.stats import METRIC_PQL_PARSES, REGISTRY
 from .ast import ASSIGN, BETWEEN, EQ, GT, GTE, LT, LTE, NEQ, Call, Condition, Query
 
 _IDENT_RE = re.compile(r"[A-Za-z][A-Za-z0-9]*")
@@ -454,6 +455,14 @@ class _Parser:
         self.error("expected value")
 
 
+# Every parse in the process, whoever asks: a served request's text is
+# parsed once, and the parse travels with the request.
+_PARSES = REGISTRY.counter(
+    METRIC_PQL_PARSES, help="PQL texts parsed (pql.parse calls)"
+)
+
+
 def parse(src: str) -> Query:
     """Parse a PQL query string into a Query AST."""
+    _PARSES.inc()
     return _Parser(src).parse()

@@ -275,9 +275,13 @@ class HeatRecorder:
                     self._touch_locked(tick, t, share)
                     all_touches.append(t)
         # Sequence + advisor feeds run OUTSIDE the table lock (the
-        # miner and advisor have their own locks; signature() parses).
+        # miner and advisor have their own locks).  The plan carries
+        # its signature where the executor parsed the request's text
+        # (QueryPlan.sign); else signature() parses the recorded text.
         try:
-            sig = plan_miner.signature(index, query)
+            sig = getattr(plan, "signature", None) or plan_miner.signature(
+                index, query
+            )
             plan_miner.MINER.observe(sig, float(plan.start_wall))
         except Exception:  # noqa: BLE001 — telemetry never fails a query
             sig = None

@@ -41,15 +41,22 @@ def plan_masks(query_text: str) -> List[str]:
     """Every mask-subtree text a recorded query evaluates (one entry
     per OCCURRENCE — repeats across the query's own calls count).
     Unparseable / truncated plan texts yield []."""
-    from ..parallel.fusion import subtree_texts
     from ..pql import parser as pql_parser
 
     try:
         q = pql_parser.parse(query_text)
     except Exception:  # noqa: BLE001 — recorded text may be truncated
         return []
+    return query_masks(q)
+
+
+def query_masks(query) -> List[str]:
+    """``plan_masks`` of a query already parsed (``pql.Query``), before
+    any in-place key translation."""
+    from ..parallel.fusion import subtree_texts
+
     out: List[str] = []
-    for call in q.calls:
+    for call in query.calls:
         for tree in _mask_trees(call):
             # Every subtree is a potential shared mask: the fused
             # planner hash-conses at all levels, so mine at all levels.
@@ -170,12 +177,15 @@ _SIG_CACHE_MAX = 512
 _SIG_LOCK = threading.Lock()
 
 
-def signature(index: str, query_text: str) -> str:
+def signature(index: str, query_text: str, query=None) -> str:
     """Canonical signature of a recorded query: its index plus the
     sorted mask-subtree texts (the same ``fusion.subtree_texts``
     canonicalization the fused planner hash-conses by), so predictions
     name real mask slots.  Unparseable texts fall back to the raw query
-    string — still a stable key for repeats.
+    string — still a stable key for repeats.  ``query`` is the text's
+    own parse where the caller has it (the request's, before any
+    translation): the masks are taken from it and the text is not
+    parsed again.
 
     The cache hit is LOCK-FREE (a single dict get is atomic under the
     GIL) and eviction is insertion-order FIFO rather than LRU — this
@@ -185,7 +195,7 @@ def signature(index: str, query_text: str) -> str:
     hit = _SIG_CACHE.get(key)
     if hit is not None:
         return hit
-    masks = plan_masks(query_text)
+    masks = query_masks(query) if query is not None else plan_masks(query_text)
     sig = f"{index}|" + (";".join(masks) if masks else query_text)
     with _SIG_LOCK:
         _SIG_CACHE[key] = sig

@@ -48,6 +48,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from . import plan_miner
 from .stats import (
     METRIC_CACHE_ENTRIES,
     METRIC_CACHE_RECALC,
@@ -162,6 +163,7 @@ class QueryPlan:
         "fanouts",
         "annotations",
         "pipelined",
+        "signature",
     )
 
     def __init__(self, index: str, query: str, tenant: str = "default",
@@ -185,6 +187,24 @@ class QueryPlan:
         self.fanouts: List[tuple] = []
         self.annotations: List[str] = []
         self.pipelined = False
+        # The sequence miner's key, where ``sign`` had the request's parse.
+        self.signature: Optional[str] = None
+
+    def sign(self, text: str, query):
+        """Take the sequence miner's signature (``plan_miner.signature``)
+        from ``query``, the parse of ``text``, where the executor has
+        just made it: before any in-place key translation, and so that
+        the heat observer has no text to parse again.  Only the plan's
+        own text, uncut, is signed here (a text past the 512-character
+        cut is not the plan's ``query``: the observer signs it from the
+        cut text, as it always did), and no internal index (the
+        observer passes those over)."""
+        if (
+            self.signature is None
+            and (text is self.query or text == self.query)
+            and not self.index.startswith("_")
+        ):
+            self.signature = plan_miner.signature(self.index, text, query)
 
     # -- stamping (hot path: appends only) ---------------------------------
 

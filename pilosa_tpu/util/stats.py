@@ -422,6 +422,9 @@ GROUP_PREFIX_STATES = ("skipped", "scored")
 #       GroupCount objects (once a result) or the host iterator answered
 METRIC_EXECUTOR_GROUP_RESULTS = "pilosa_executor_group_results_total"
 GROUP_RESULT_FORMS = ("columns", "objects")
+#   pilosa_pql_parses_total                      pql.parse calls in the process
+#       (pql/parser.py): a served request's text is parsed once
+METRIC_PQL_PARSES = "pilosa_pql_parses_total"
 METRIC_ENGINE_DEVICE_INFLIGHT = "pilosa_engine_device_inflight_seconds_total"
 METRIC_UPTIME = "pilosa_uptime_seconds"
 #   pilosa_http_occupied_seconds_total   seconds in which the server held a

@@ -286,6 +286,20 @@ class Tracer:
             parent._children.append(span)
         return span
 
+    @contextmanager
+    def resume(self, span: Optional[Span]):
+        """``start_span``'s block for a span ``begin`` started earlier,
+        on this thread or another: the thread's current span for the
+        block, finished at its end."""
+        prev = getattr(_LOCAL, "current", None)
+        _LOCAL.current = span if span is not None else prev
+        try:
+            yield span
+        finally:
+            if span is not None:
+                span.finish()
+            _LOCAL.current = prev
+
     def _record_finished(self, span: Span):
         if not self.keep_finished:
             return

@@ -495,7 +495,7 @@ def test_execute_async_matches_sync(holder, mesh):
         "Count(Union(Row(f=10), Row(f=11)))"
     )
     want = ex.execute("i", multi).results
-    fut = ex.execute_async("i", multi)
+    fut, _ = ex.execute_async("i", multi)
     assert fut is not None
     assert fut.result(60).results == want
 
@@ -503,11 +503,11 @@ def test_execute_async_matches_sync(holder, mesh):
 def test_execute_async_declines_non_count(holder, mesh):
     eng = MeshEngine(holder, mesh)
     ex = Executor(holder, mesh_engine=eng)
-    assert ex.execute_async("i", "TopN(f, n=2)") is None
-    assert ex.execute_async("i", "Set(1, f=10)") is None
-    assert ex.execute_async("i", "Count(Row(f=10))Set(1, f=10)") is None
+    assert ex.execute_async("i", "TopN(f, n=2)")[0] is None
+    assert ex.execute_async("i", "Set(1, f=10)")[0] is None
+    assert ex.execute_async("i", "Count(Row(f=10))Set(1, f=10)")[0] is None
     plain = Executor(holder)  # no mesh engine: nothing to pipeline
-    assert plain.execute_async("i", "Count(Row(f=10))") is None
+    assert plain.execute_async("i", "Count(Row(f=10))")[0] is None
 
 
 def test_execute_async_error_converges_to_sync(holder, mesh):
@@ -522,7 +522,7 @@ def test_execute_async_error_converges_to_sync(holder, mesh):
         sync_err = None
     except Exception as e:  # noqa: BLE001
         sync_err = type(e)
-    fut = ex.execute_async("i", q)
+    fut, _ = ex.execute_async("i", q)
     assert fut is not None
     if sync_err is None:
         fut.result(60)
@@ -536,7 +536,7 @@ def test_execute_async_callback_fires(holder, mesh):
     ex = Executor(holder, mesh_engine=eng)
     fired = threading.Event()
     out = []
-    fut = ex.execute_async("i", "Count(Row(f=10))")
+    fut, _ = ex.execute_async("i", "Count(Row(f=10))")
     fut.add_done_callback(lambda f: (out.append(f.result(0).results), fired.set()))
     assert fired.wait(60)
     assert out[0] == ex.execute("i", "Count(Row(f=10))").results
@@ -555,7 +555,7 @@ def test_mixed_read_write_stream_stays_correct(holder, mesh):
     eng = MeshEngine(holder, mesh)
     ex = Executor(holder, mesh_engine=eng)
     q = "Count(Union(Row(f=10), Row(f=11)))"
-    base = ex.execute_async("i", q).result(60).results[0]
+    base = ex.execute_async("i", q)[0].result(60).results[0]
 
     stop = threading.Event()
     errors, seen = [], []
@@ -576,7 +576,7 @@ def test_mixed_read_write_stream_stays_correct(holder, mesh):
     def reader():
         try:
             while not stop.is_set():
-                fut = ex.execute_async("i", q)
+                fut, _ = ex.execute_async("i", q)
                 assert fut is not None
                 seen.append(fut.result(60).results[0])
         except Exception as e:  # noqa: BLE001
@@ -597,7 +597,7 @@ def test_mixed_read_write_stream_stays_correct(holder, mesh):
         assert b >= a, (a, b)
     plain = Executor(holder)
     assert (
-        ex.execute_async("i", q).result(60).results
+        ex.execute_async("i", q)[0].result(60).results
         == plain.execute("i", q).results
     )
 

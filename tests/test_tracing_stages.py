@@ -514,6 +514,7 @@ def test_a_groupby_lands_on_the_direct_path_with_a_drain_record(served):
 
 # -- the HTTP layer's stages and their closure -------------------------------
 
+PATHS = ("deferred", "direct", "solo", "fused", "host")
 REACTOR_ONLY = ("read", "handoff", "respond_wake")
 EVERY_REQUEST = ("http_read", "prologue", "encode", "write", "respond", "unstaged")
 
@@ -556,6 +557,12 @@ def _clock_of(clocks, trace_id, timeout=15):
     raise AssertionError(f"the clock of trace {trace_id} never finished")
 
 
+def _across_paths(stage):
+    """(count, sum) of one stage over every path."""
+    return tuple(map(sum, zip(*(_hist(METRIC_QUERY_STAGE, path=p, stage=stage)
+                                for p in PATHS))))
+
+
 def _union(intervals):
     total, end = 0.0, float("-inf")
     for a, b in sorted(intervals):
@@ -583,7 +590,9 @@ def test_the_stages_close_over_the_request(served, threaded, clocks, backend, ki
     """Σ top-level stages + unstaged = the request's
     pilosa_http_request_seconds observation; read + handoff = http_read;
     encode + respond_wake + write = respond; each observed once under
-    the request's path, the reactor's three only behind the reactor."""
+    the request's path, the reactor's three only behind the reactor.
+    The text is parsed once, and the executor entered once more where
+    the deferred path declined it (its one attempt), on either server."""
     eng, api, uri = served
     uri = uri if backend == "async" else threaded
     text, path, drained = REQUESTS[kind]
@@ -596,10 +605,21 @@ def test_the_stages_close_over_the_request(served, threaded, clocks, backend, ki
         doc = _post(uri, body)
         _clock_of(clocks, doc["traceID"])
     before = _stage_deltas(path, names)
+    entered = [_across_paths(n)[0] for n in ("parse", "plan")]
     doc = _post(uri, body)
     clock = _clock_of(clocks, doc["traceID"])
     assert clock.span.tags.get("path", "host") == path
+    if drained:
+        # ``complete`` is the drain's, recorded when its callback loop
+        # ends: the reply it holds may reach the client first.
+        deadline = time.monotonic() + 15
+        while (_hist(METRIC_QUERY_STAGE, path=path, stage="complete")[0]
+               == before["complete"][0] and time.monotonic() < deadline):
+            time.sleep(0.01)
     moved = _stage_deltas(path, names, before)
+    declined = path not in ("deferred", "host")
+    assert [_across_paths(n)[0] - b for n, b in zip(("parse", "plan"), entered)] == [
+        1, 1 + declined]
     http_n, http_s = moved.pop("http")
     assert http_n == 1
     want = {n: 1 for n in EVERY_REQUEST}
@@ -736,17 +756,18 @@ def test_a_forced_pass_inside_a_request_is_counted_and_tagged(
         return parse(text)
 
     monkeypatch.setattr(pql, "parse", collecting)
-    tracing.GC.flush()
     h = REGISTRY.get_histogram(METRIC_GC_PAUSE, generation=str(generation))
-    before = h.count
-    gc.disable()  # no pass of its own making in between
+    gc.disable()  # no pass of its own making between the two readings
     try:
+        tracing.GC.flush()
+        before = h.count
         doc = _post(uri, f"Count(Intersect(Row(f=1{generation}), Row(f=13), Row(f=10)))".encode())
+        root = _finished(api.tracer, doc["traceID"])
+        tracing.GC.flush()
+        after = h.count
     finally:
         gc.enable()
-    root = _finished(api.tracer, doc["traceID"])
-    tracing.GC.flush()
-    assert h.count == before + 1
+    assert after == before + 1
     (parsed,) = [s for s in _walk(root) if s.name == "pipeline.parse"]
     assert parsed.tags["cache"] == "miss"
     assert ("gc_ms" in parsed.tags) == (generation == 2)
