@@ -77,6 +77,27 @@ SHARD_WIDTH = ops.SHARD_WIDTH
 WORDS64 = bitops.WORDS64
 
 HASH_BLOCK_SIZE = 100  # rows per anti-entropy checksum block
+
+NARROW_BITS = 4096  # a narrow row's width is a whole number of these
+
+
+def narrow_words(max_column: int) -> int:
+    """uint32 words a row of a narrow matrix holds when the largest
+    in-shard column present is ``max_column``: rounded up to 4,096 bits
+    (128 words, one lane-width of words)."""
+    return (max_column // NARROW_BITS + 1) * (NARROW_BITS // 32)
+
+
+def narrow_counts(rows: np.ndarray, q: np.ndarray) -> np.ndarray:
+    """int64[R]: popcount(row & q) of every row of uint32[R, W], 65,536
+    rows at a time (the AND of 1.7 M rows at once would be a 0.9 GB
+    temporary)."""
+    out = np.empty(rows.shape[0], dtype=np.int64)
+    for i in range(0, rows.shape[0], 1 << 16):
+        out[i:i + (1 << 16)] = np.bitwise_count(rows[i:i + (1 << 16)] & q).sum(axis=1)
+    return out
+
+
 DEFAULT_MAX_OP_N = 2000
 
 # -- ingest ack/durability policy ([storage] ack, docs/durability.md) -------
@@ -263,6 +284,7 @@ class Fragment:
         self._dev_version = -1
         self._dev_matrix = None
         self._dev_index: Dict[int, int] = {}
+        self._narrow = None  # (version, narrow_matrix()) at that version
         # Mutation log as {row_id: last_touched_version}: the mesh
         # engine replays dirty rows to scatter-update its resident HBM
         # stacks instead of re-uploading whole views per write (the
@@ -358,15 +380,19 @@ class Fragment:
         self._mut_floor = self._version  # load is unattributed: no sync past it
 
     def positions(self) -> np.ndarray:
-        """All storage positions, sorted (for snapshot serialization)."""
-        chunks = []
-        for r in self._store.row_ids():
-            pos = self._store.positions(r)
-            if pos.size:
-                chunks.append(pos.astype(np.uint64) + np.uint64(r * SHARD_WIDTH))
-        if not chunks:
+        """All storage positions, sorted (for snapshot serialization): the
+        rows' in-row arrays joined once and offset by their rows in one
+        pass (a fragment of 1.7 M fingerprint rows is snapshotted after
+        every import body)."""
+        ids = self._store.row_ids()
+        if not ids:
             return np.empty(0, dtype=np.uint64)
-        return np.concatenate(chunks)
+        chunks = [self._store.positions(r) for r in ids]
+        lens = np.fromiter((a.size for a in chunks), dtype=np.int64, count=len(ids))
+        rows = np.asarray(ids, dtype=np.uint64) << np.uint64(ops.SHARD_WIDTH_EXP)
+        out = np.concatenate(chunks, dtype=np.uint64)
+        out += np.repeat(rows, lens)
+        return out
 
     @_locked
     def snapshot(self):
@@ -937,6 +963,15 @@ class Fragment:
 
     # -- device mirror -----------------------------------------------------
 
+    # A dense mirror past this many bytes is never built: a field of
+    # millions of narrow rows (1.7 M fingerprints x 128 KiB would be
+    # 222 GB) uploads the rows it is asked for one at a time, and its
+    # TopN scores on ``narrow_matrix``.
+    DENSE_MIRROR_MAX_BYTES = 1 << 30
+
+    def _mirror_fits(self) -> bool:
+        return len(self._store) * bitops.WORDS * 4 <= self.DENSE_MIRROR_MAX_BYTES
+
     @_locked
     def _sync_device(self):
         import jax.numpy as jnp
@@ -953,24 +988,48 @@ class Fragment:
         self._dev_matrix = jnp.asarray(mat)
         self._dev_version = self._version
 
-    def device_matrix(self):
-        """uint32[n_rows, WORDS] device matrix + row index map."""
-        self._sync_device()
-        return self._dev_matrix, self._dev_index
-
     def device_row(self, row_id: int):
+        import jax.numpy as jnp
+
+        if not self._mirror_fits():
+            with self._mu:
+                return jnp.asarray(self._store.words_u32(row_id))
         self._sync_device()
         idx = self._dev_index.get(row_id)
         if idx is None:
-            import jax.numpy as jnp
-
             return jnp.zeros(bitops.WORDS, dtype=jnp.uint32)
         return self._dev_matrix[idx]
+
+    @_locked
+    def narrow_matrix(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(ids int64[R], words uint32[R, W]): every row that holds a
+        bit, in id order, W words wide, W the fragment's largest column
+        rounded up to 4,096 bits (``narrow_words``).  The host form of
+        the engine's narrow stack and of ``top``'s scoring: one pass over
+        the store's position arrays, the words OR-ed together a run of
+        equal (row, word) keys at a time; kept until the next write."""
+        if self._narrow is not None and self._narrow[0] == self._version:
+            return self._narrow[1]
+        ids = np.asarray(self._store.row_ids(), dtype=np.int64)
+        chunks = [self._store.positions(r) for r in ids.tolist()]
+        lens = np.fromiter((a.size for a in chunks), dtype=np.int64, count=ids.size)
+        pos = np.concatenate(chunks) if chunks else np.empty(0, dtype=np.uint32)
+        w = narrow_words(int(pos.max()) if pos.size else 0)
+        mat = np.zeros((ids.size, w), dtype=np.uint32)
+        if pos.size:
+            key = np.repeat(np.arange(ids.size, dtype=np.int64) * w, lens) + (pos >> 5)
+            starts = np.flatnonzero(np.r_[True, key[1:] != key[:-1]])
+            bits = np.left_shift(np.uint32(1), (pos & 31).astype(np.uint32))
+            mat.reshape(-1)[key[starts]] = np.bitwise_or.reduceat(bits, starts)
+        self._narrow = (self._version, (ids, mat))
+        return ids, mat
 
     def device_planes(self, bit_depth: int):
         """uint32[bit_depth+1, WORDS] BSI plane matrix (rows 0..bit_depth)."""
         import jax.numpy as jnp
 
+        if not self._mirror_fits():
+            return jnp.stack([self.device_row(r) for r in range(bit_depth + 1)])
         self._sync_device()
         idxs = [self._dev_index.get(r) for r in range(bit_depth + 1)]
         if None not in idxs and idxs == list(range(idxs[0], idxs[0] + bit_depth + 1)):
@@ -1558,7 +1617,7 @@ class Fragment:
         min-heap, threshold early-exits, attribute filter, and Tanimoto
         window — except the per-candidate Src intersection counts (the
         reference's hot loop :1089,:1133) are computed for ALL candidates in
-        one batched device popcount kernel up front."""
+        one vectorised pass over ``narrow_matrix`` up front."""
         import heapq
         import math
 
@@ -1580,24 +1639,22 @@ class Fragment:
             min_tan = src_count * tanimoto_threshold / 100.0
             max_tan = src_count * 100.0 / tanimoto_threshold
 
-        # Batched device scoring of every candidate against src (callers
-        # that batch ACROSS shards pass src_counts precomputed).
+        # Every candidate scored against src at once (callers that batch
+        # ACROSS shards pass src_counts precomputed).
         if src_counts is None:
             src_counts = {}
-            if src is not None:
-                seg = src.segment(self.shard)
-                _, idx = self.device_matrix()
-                present = [r for r, _ in pairs if r in idx]
-                if seg is not None and present:
-                    import jax.numpy as jnp
-
-                    sel = self._dev_matrix[
-                        np.array([idx[r] for r in present], dtype=np.int32)
-                    ]
-                    counts = np.asarray(
-                        bitops.popcount_and_rows(sel, jnp.asarray(seg))
-                    )
-                    src_counts = dict(zip(present, counts.tolist()))
+            seg = src.segment(self.shard) if src is not None else None
+            if seg is not None and pairs:
+                ids, mat = self.narrow_matrix()
+                # src's bits past the narrow width meet no row's.
+                scores = narrow_counts(mat, np.asarray(seg, np.uint32)[: mat.shape[1]])
+                cand = np.fromiter((r for r, _ in pairs), np.int64, len(pairs))
+                at = np.searchsorted(ids, cand)
+                present = at < ids.size
+                present[present] = ids[at[present]] == cand[present]
+                src_counts = dict(zip(
+                    cand[present].tolist(), scores[at[present]].tolist()
+                ))
 
         # heap of (count, id): smallest count on top (pairHeap is a min-heap).
         heap: List[Tuple[int, int]] = []

@@ -33,9 +33,11 @@ import numpy as np
 from ..util.stats import (
     GROUP_RESULT_FORMS,
     METRIC_EXECUTOR_GROUP_RESULTS,
+    METRIC_EXECUTOR_TOPN_DECLINES,
     METRIC_QUERY_OP,
     METRIC_REPLICA_READS,
     REGISTRY,
+    TANIMOTO_DECLINES,
 )
 
 # Per-op histogram handles, cached so the dispatch path never takes the
@@ -61,9 +63,15 @@ _GROUP_RESULTS = {
     form: REGISTRY.counter(METRIC_EXECUTOR_GROUP_RESULTS, form=form)
     for form in GROUP_RESULT_FORMS
 }
+# Tanimoto TopNs left to the host walk, by reason (_mesh_topn_tanimoto).
+_TOPN_DECLINES = {
+    reason: REGISTRY.counter(METRIC_EXECUTOR_TOPN_DECLINES, reason=reason)
+    for reason in TANIMOTO_DECLINES
+}
 
 from .. import ops, pql
-from ..parallel.errors import PeerlessMeshError
+from ..parallel import kernels as kernels_mod
+from ..parallel.errors import NarrowDecline, PeerlessMeshError
 from ..util import plans as plans_mod
 from ..util import tracing as tracing_mod
 from ..core.field import FIELD_TYPE_BOOL, FIELD_TYPE_INT, FIELD_TYPE_MUTEX, FIELD_TYPE_SET, FIELD_TYPE_TIME
@@ -2177,17 +2185,18 @@ class Executor:
         candidate scan + exact recount, executor.go :694-733) collapse
         into one device program with one tiny readback — exact totals
         for every cache candidate, gated and trimmed on device.  Applies
-        when every requested shard is local and no attribute/Tanimoto
-        filter needs host candidate metadata; otherwise returns None and
+        when every requested shard is local and no attribute filter
+        needs host candidate metadata (a Tanimoto TopN takes its own
+        lane, ``_mesh_topn_tanimoto``); otherwise returns None and
         the two-phase composition path runs.  Remote (re-entrant) calls
         also fall through: peers must return untrimmed phase pairs for
         the coordinator's merge."""
         if self.mesh_engine is None or opt.remote:
             return None
-        if c.args.get("attrName") or c.args.get("attrValues"):
-            return None
         tanimoto, _ = c.uint_arg("tanimotoThreshold")
         if tanimoto > 0:
+            return self._mesh_topn_tanimoto(index, c, shards, tanimoto)
+        if c.args.get("attrName") or c.args.get("attrValues"):
             return None
         if len(c.children) > 1:
             raise Error("TopN() can only have one input bitmap")
@@ -2248,6 +2257,81 @@ class Executor:
             # cannot leak into the next query's plan on this thread.
             plans_mod.take_dispatch_note()
             return None
+
+    def _mesh_topn_tanimoto(self, index, c: Call, shards, tanimoto: int):
+        """``TopN(f, Row(f=id), tanimotoThreshold=t)`` with no ``n`` as ONE
+        device program on the narrow form of f, both reference phases
+        (executor.go :694-733) in one readback
+        (``MeshEngine.tanimoto_async``, through the batcher's direct
+        path), the slab decoded here (the ``topn_decode`` stage).
+        Returns the sorted pairs, or None where the host walk answers,
+        counted by reason in ``pilosa_executor_topn_declines_total``:
+        ``n`` (with n > 0 the reference walk stops re-checking Tanimoto
+        once its heap is full, fragment.go top, so its answer depends on
+        the walk's order), ``attr``, ``ids``, ``src`` (the child is not one
+        Row of f), the engine's ``shards`` / ``cache`` / ``budget``, and
+        ``overflow`` (more hits than the slab holds)."""
+        if tanimoto > 100:
+            raise Error("Tanimoto Threshold is from 1 to 100 only")
+        eng = self.mesh_engine
+        if getattr(eng, "multiproc", False):
+            return None
+        if len(c.children) > 1:
+            raise Error("TopN() can only have one input bitmap")
+        seq = frag_mod.WRITE_SEQ.v  # first (see _aggregate_flight)
+        local = set(self._local_shards(index, shards))
+        if any(s not in local for s in shards):
+            return None
+        field_name = c.args.get("_field") or DEFAULT_FIELD
+        n, _ = c.uint_arg("n")
+        row_ids, _ = c.uint_slice_arg("ids")
+        src = c.children[0] if c.children else None
+        if n:
+            return self._tanimoto_declined("n")
+        if c.args.get("attrName") or c.args.get("attrValues"):
+            return self._tanimoto_declined("attr")
+        if row_ids:
+            return self._tanimoto_declined("ids")
+        if (
+            src is None
+            or src.name != "Row"
+            or src.children
+            or set(src.args) != {field_name}
+            or isinstance(src.args[field_name], bool)
+            or not isinstance(src.args[field_name], int)
+        ):
+            return self._tanimoto_declined("src")
+        try:
+            slab, ids = self._sflight.do(
+                ("topn", seq, index, str(c), tuple(sorted(local))),
+                lambda: eng.batched_tanimoto(
+                    index, field_name, src, src.args[field_name], tanimoto,
+                    shards,
+                ),
+            )
+        except NarrowDecline as e:
+            plans_mod.take_dispatch_note()
+            return self._tanimoto_declined(e.reason)
+        except (ValueError, PeerlessMeshError):
+            plans_mod.take_dispatch_note()
+            return None
+        with tracing_mod.stage("topn_decode"):
+            pairs = [] if slab is None else kernels_mod.tanimoto_pairs(slab, ids)
+        p = plans_mod.current_plan()
+        if p is not None:
+            p.amend_op("TopN", hits=0 if slab is None else int(slab[0]),
+                       overflow=pairs is None)
+        if pairs is None:
+            return self._tanimoto_declined("overflow")
+        return pairs
+
+    @staticmethod
+    def _tanimoto_declined(reason: str):
+        _TOPN_DECLINES[reason].inc()
+        p = plans_mod.current_plan()
+        if p is not None:
+            p.note_op(op="TopN", path="host_walk", declined=reason)
+        return None
 
     def _execute_topn_shards(self, index, c, shards, opt):
         def map_fn(shard):

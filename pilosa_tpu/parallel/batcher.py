@@ -671,10 +671,13 @@ class CountBatcher:
             aggs = [it for it in items if it.kind != "count"]
             counts = [it for it in items if it.kind == "count"]
             # An aggregated GroupBy keeps to its solo program: the fused
-            # "group" edge knows a count tensor only.
-            alone = [it for it in aggs if it.spec.get("aggregate")]
+            # "group" edge knows a count tensor only.  So does a Tanimoto
+            # TopN: its program reads a narrow field no edge knows.
+            alone = [it for it in aggs
+                     if it.spec.get("aggregate") or it.kind == "tanimoto"]
             groups.extend(("solo", index, [it]) for it in alone)
-            aggs = [it for it in aggs if not it.spec.get("aggregate")]
+            aggs = [it for it in aggs if not (it.spec.get("aggregate")
+                                              or it.kind == "tanimoto")]
             if aggs and fusion_ok:
                 from .fusion import item_texts, subtree_texts
 

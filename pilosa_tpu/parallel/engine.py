@@ -76,6 +76,9 @@ from ..util.stats import (
     METRIC_ENGINE_REBUILDS,
     METRIC_ENGINE_RESIDENT_BLOCK_FRACTION,
     METRIC_ENGINE_RESIDENT_BYTES,
+    METRIC_ENGINE_TOPN_HITS,
+    METRIC_ENGINE_TOPN_ROWS_SCORED,
+    METRIC_ENGINE_TOPN_SLAB_OVERFLOW,
     METRIC_INGEST_SYNC_CHUNKS,
     METRIC_MESH_DEVICES,
     METRIC_MESH_LOCAL_DEVICES,
@@ -293,6 +296,24 @@ class _TopNCandidates:
         self.dyn_idxs = dyn_idxs  # traced device vector otherwise
         self.dev_cnt = dev_cnt
         self.host_cnt = host_cnt
+
+
+class _NarrowStack:
+    """A narrow field on one device, for ``kernels.tanimoto_topn``:
+    ``matrix`` uint32[W, R_pad] word-major (``kernels``' Tanimoto
+    comment), ``counts`` int32[R_pad] the ranked cache's count of each
+    row (0 on padding), ``ids`` int64[R] the row ids in row order
+    (ascending), ``token`` the view's version token it was built at,
+    ``footprint`` the device bytes it holds."""
+
+    __slots__ = ("matrix", "counts", "ids", "token", "footprint")
+
+    def __init__(self, matrix, counts, ids, token, footprint):
+        self.matrix = matrix
+        self.counts = counts
+        self.ids = ids
+        self.token = token
+        self.footprint = footprint
 
 
 class _Lowering:
@@ -738,7 +759,7 @@ class _NotSparse(Exception):
 
 # Re-exported for back-compat; the classes live in errors.py so they
 # have an import-cycle-free home (see that module's docstring).
-from .errors import PeerlessMeshError, ResidencyMiss  # noqa: E402
+from .errors import NarrowDecline, PeerlessMeshError, ResidencyMiss  # noqa: E402
 
 
 class MeshEngine:
@@ -990,6 +1011,15 @@ class MeshEngine:
             REGISTRY.counter(METRIC_ENGINE_GROUP_PREFIX_STEPS, state=state)
             for state in GROUP_PREFIX_STATES
         )
+        self._topn_rows_scored_counter = REGISTRY.counter(
+            METRIC_ENGINE_TOPN_ROWS_SCORED
+        )
+        self._topn_hits_counter = REGISTRY.counter(METRIC_ENGINE_TOPN_HITS)
+        self._topn_overflow_counter = REGISTRY.counter(
+            METRIC_ENGINE_TOPN_SLAB_OVERFLOW
+        )
+        # (index, field) -> _NarrowStack (narrow_stack).
+        self._narrow: Dict[Tuple[str, str], _NarrowStack] = {}
         # (op, path) -> the four drain-record counter handles.
         self._drain_counters: Dict[tuple, tuple] = {}
         # Residency/compile introspection handles (resolved once).
@@ -3756,6 +3786,10 @@ class MeshEngine:
             if res is None:
                 return None, fusion_mod._Const(fusion_mod.DECLINED)
             return res[0], functools.partial(self.group_host, res[1])
+        if kind == "tanimoto":
+            return self.tanimoto_async(
+                index, spec["field"], spec["row"], spec["threshold"]
+            )
         raise ValueError(f"unknown solo op kind: {kind!r}")
 
     def probe_fused_item(self, index: str, spec: dict, shards):
@@ -3764,6 +3798,9 @@ class MeshEngine:
         own error if it has one (parallel to the batcher's per-Count
         lowering probe)."""
         kind = spec["kind"]
+        if kind == "tanimoto":
+            self.narrow_stack(index, spec["field"])
+            return
         if kind == "count":
             trees = [spec["call"]]
         elif kind in ("sum", "min", "max", "group"):
@@ -3858,6 +3895,20 @@ class MeshEngine:
             spec["traced"] = tuple(bool(t) for t in traced)
         out = self.batcher().submit_op(index, "group", spec, shards)
         return None if out is fusion_mod.DECLINED else out
+
+    def batched_tanimoto(self, index: str, field: str, src_call: Call,
+                         row_id: int, threshold: int, shards):
+        """``TopN(field, Row(field=row_id), tanimotoThreshold=threshold)``
+        through the batcher (its direct path for a lone caller, a solo
+        program in a drain): ``(slab, ids)`` as ``tanimoto_async``'s
+        decoder gives them.  Raises NarrowDecline where the lane does
+        not take the field."""
+        return self.batcher().submit_op(
+            index, "tanimoto",
+            {"kind": "tanimoto", "field": field, "src": src_call,
+             "row": int(row_id), "threshold": int(threshold)},
+            shards,
+        )
 
     def count_many(self, index: str, calls, shards_list) -> List[int]:
         """K Count(tree) queries in ONE fused dispatch + ONE readback
@@ -4733,6 +4784,109 @@ class MeshEngine:
             pairs = pairs[: int(n)]
         return pairs
 
+    # -- Tanimoto TopN over a narrow field ------------------------------------
+
+    def narrow_stack(self, index: str, field: str) -> _NarrowStack:
+        """``field``'s standard view in its narrow form (``_NarrowStack``):
+        every row of its one shard, ``Fragment.narrow_matrix``'s words
+        laid out word-major on the mesh's first device, beside the ranked
+        cache's count of each row.  Built on first use and again after a
+        write (the view's version token), its bytes charged to the
+        residency budget.  Raises NarrowDecline naming why the field has
+        none: ``shards``, ``cache``, ``budget`` (errors.NarrowDecline)."""
+        key = (index, field)
+        view = self.holder.view(index, field, VIEW_STANDARD)
+        token = (
+            self.holder.shard_epoch(index),
+            id(view),
+            -1 if view is None else view.version,
+        )
+        with self._stacks_lock:
+            st = self._narrow.get(key)
+        if st is not None and st.token == token:
+            return st
+        frags = [
+            f for s in self.canonical_shards(index)
+            if (f := self.holder.fragment(index, field, VIEW_STANDARD, s))
+            is not None
+        ]
+        if len(frags) != 1:
+            raise NarrowDecline("shards")
+        rank_columns = getattr(frags[0].cache, "rank_columns", None)
+        if rank_columns is None:
+            raise NarrowDecline("cache")
+        ids, rows = frags[0].narrow_matrix()
+        rank_ids, rank_counts = rank_columns()
+        order = np.argsort(rank_ids)
+        if not np.array_equal(rank_ids[order], ids):
+            raise NarrowDecline("cache")  # the walk ranks another row set
+        r_pad = kernels.narrow_rows_pad(ids.size)
+        counts = np.zeros(r_pad, dtype=np.int32)
+        counts[: ids.size] = rank_counts[order]
+        footprint = r_pad * (rows.shape[1] + 1) * 4
+        with self._dispatch_lock, self._stacks_lock:
+            old = self._narrow.pop(key, None)
+            if old is not None:
+                self._resident_bytes -= old.footprint
+            if not self._admissible(footprint):
+                raise NarrowDecline("budget")
+            self._evict_for(footprint)
+            dev = self.mesh.devices.flat[0]
+            st = _NarrowStack(
+                kernels.narrow_layout(jax.device_put(rows, dev), r_pad),
+                jax.device_put(counts, dev),
+                ids,
+                token,
+                footprint,
+            )
+            self._narrow[key] = st
+            self._resident_bytes += footprint
+            self.stack_rebuilds += 1
+            self._rebuilds_counter.inc()
+        return st
+
+    def tanimoto_async(self, index: str, field: str, row_id: int,
+                       threshold: int):
+        """``TopN(field, Row(field=row_id), tanimotoThreshold=threshold)``
+        dispatched as ONE program (``kernels.tanimoto_topn``) on the
+        field's narrow form, its result left on the device: ``(dev,
+        decoder)``, the decoder giving ``(slab, ids)``: the program's
+        int32 slab (None where the query row holds no bit: nothing can
+        match) and the row ids its positions index.  Counts the rows
+        scored at dispatch, the hits and a slab overflow at decode; the
+        drain record is ``op="TopN",path="tanimoto"``, its planes the
+        scan's bytes (the matrix and the count vector) in 128 KiB
+        units."""
+        st = self.narrow_stack(index, field)
+        ids = st.ids
+        q = int(np.searchsorted(ids, row_id))
+        if q >= ids.size or ids[q] != row_id:
+            return None, fusion_mod._Const((None, ids))
+        W = st.matrix.shape[0]
+
+        def dispatch():
+            with tracing.stage("lower"):
+                self._topn_rows_scored_counter.inc(ids.size)
+                planes = -(-ids.size * (W + 1) * 4 // self.PLANE_BYTES)
+                drain = self._note_drain(
+                    "TopN", "tanimoto", 1, 1, (planes, planes), (planes, planes)
+                )
+                plans_mod.note_dispatch(path="tanimoto", rows=int(ids.size))
+            with tracing.stage("dispatch", **drain):
+                return kernels.tanimoto_topn(
+                    st.matrix, st.counts, np.int32(q), np.int32(threshold)
+                )
+
+        def decode(host):
+            slab = np.asarray(host)
+            n = int(slab[0])
+            self._topn_hits_counter.inc(n)
+            if n > kernels.TANIMOTO_SLAB:
+                self._topn_overflow_counter.inc()
+            return slab, ids
+
+        return self._locked_dispatch(dispatch), decode
+
     # Bound on the TENSOR of one GroupBy that is read back, in int32
     # cells: the groups, times the measure's depth + 2 under
     # ``aggregate=Sum(...)``.  It is read back whole and walked by the
@@ -4956,6 +5110,7 @@ class MeshEngine:
                 self._canonical.clear()
                 self._topn_cands.clear()
                 self._topn_slab_cands.clear()
+                self._narrow.clear()
                 self._fused_plans.clear()
                 memo_entries = len(self.result_memo)
                 self.result_memo.clear()

@@ -30,3 +30,17 @@ class ResidencyMiss(PeerlessMeshError):
         super().__init__(msg)
         self.key = key
         self.resident_fraction = resident_fraction
+
+
+class NarrowDecline(ValueError):
+    """The device's Tanimoto TopN lane does not take this call
+    (``MeshEngine.tanimoto_async``): the host walk answers it.
+    ``reason`` names why, as ``pilosa_executor_topn_declines_total``
+    counts it: ``shards`` (the field's rows lie in more than one local
+    shard), ``cache`` (its ranked cache does not hold every row, so the
+    walk ranks a subset), ``budget`` (its narrow form does not fit the
+    device budget)."""
+
+    def __init__(self, reason: str):
+        super().__init__(f"Tanimoto TopN declined: {reason}")
+        self.reason = reason

@@ -60,6 +60,7 @@ OP_NAMES = {
     "topn": "TopN",
     "topnf": "TopN",
     "group": "GroupBy",
+    "tanimoto": "TopN",
 }
 
 

@@ -211,6 +211,14 @@ class QueryPlan:
     def note_op(self, **kw):
         self.ops.append(kw)
 
+    def amend_op(self, op: str, **kw):
+        """Add what is known only after a dispatch's readback to the last
+        record of ``op`` (the Tanimoto lane's hits and overflow)."""
+        for rec in reversed(self.ops):
+            if rec.get("op") == op:
+                rec.update(kw)
+                return
+
     def note_stage(self, stage: str, seconds: float):
         self._stage_events.append((stage, seconds))
 

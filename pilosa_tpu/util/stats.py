@@ -422,6 +422,19 @@ GROUP_PREFIX_STATES = ("skipped", "scored")
 #       GroupCount objects (once a result) or the host iterator answered
 METRIC_EXECUTOR_GROUP_RESULTS = "pilosa_executor_group_results_total"
 GROUP_RESULT_FORMS = ("columns", "objects")
+#   pilosa_engine_topn_rows_scored_total         rows the Tanimoto TopN program
+#       scored against its query row on the device (the narrow field's
+#       rows, every dispatch)
+METRIC_ENGINE_TOPN_ROWS_SCORED = "pilosa_engine_topn_rows_scored_total"
+#   pilosa_engine_topn_hits_total                rows its integer gate kept
+METRIC_ENGINE_TOPN_HITS = "pilosa_engine_topn_hits_total"
+#   pilosa_engine_topn_slab_overflow_total       Tanimoto TopNs whose hits
+#       outnumbered the slab read back: the host walk answered them
+METRIC_ENGINE_TOPN_SLAB_OVERFLOW = "pilosa_engine_topn_slab_overflow_total"
+#   pilosa_executor_topn_declines_total{reason}  Tanimoto TopNs the device
+#       lane left to the host walk, by why (TANIMOTO_DECLINES)
+METRIC_EXECUTOR_TOPN_DECLINES = "pilosa_executor_topn_declines_total"
+TANIMOTO_DECLINES = ("n", "attr", "ids", "src", "shards", "cache", "budget", "overflow")
 #   pilosa_pql_parses_total                      pql.parse calls in the process
 #       (pql/parser.py): a served request's text is parsed once
 METRIC_PQL_PARSES = "pilosa_pql_parses_total"
@@ -820,6 +833,24 @@ for _form in GROUP_RESULT_FORMS:
         METRIC_EXECUTOR_GROUP_RESULTS,
         help="GroupBy results by the form they left the executor in",
         form=_form,
+    )
+REGISTRY.counter(
+    METRIC_ENGINE_TOPN_ROWS_SCORED,
+    help="Rows the Tanimoto TopN program scored on the device",
+)
+REGISTRY.counter(
+    METRIC_ENGINE_TOPN_HITS,
+    help="Rows the Tanimoto TopN program's gate kept",
+)
+REGISTRY.counter(
+    METRIC_ENGINE_TOPN_SLAB_OVERFLOW,
+    help="Tanimoto TopNs whose hits outnumbered the slab (host walk answered)",
+)
+for _reason in TANIMOTO_DECLINES:
+    REGISTRY.counter(
+        METRIC_EXECUTOR_TOPN_DECLINES,
+        help="Tanimoto TopNs left to the host walk, by reason",
+        reason=_reason,
     )
 for _kind in REPAIR_KINDS:
     REGISTRY.counter(

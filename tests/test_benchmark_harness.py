@@ -1,6 +1,7 @@
 """The benchmark's own fast cases, collected by tier-1: what BENCHMARK.json
 and the files it names agree on (every cell loads, every configuration is
-self-consistent: ``benchmark/tests/test_harness.py``) and the end-of-run
+self-consistent: ``benchmark/tests/test_harness.py``; chembl-similarity's
+generator, reference and template: ``test_chembl_similarity.py``) and the end-of-run
 rule (no process outlives a run, however it ends:
 ``benchmark/tests/test_process_end.py``).  No JAX, a stand-in server,
 seconds each.  ``benchmark/tests/test_faults.py`` drives a real server for
@@ -22,6 +23,7 @@ def _cases(name):
 
 
 globals().update(_cases("test_harness"))
+globals().update(_cases("test_chembl_similarity"))
 globals().update(_cases("test_process_end"))
 
 _respawn = globals()["test_a_process_that_cannot_be_ended_fails_the_run_and_is_named"]

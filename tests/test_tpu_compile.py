@@ -303,3 +303,27 @@ def test_group_by_compiles_for_v5e_at_taxi_query_4s_nest(topology, n_dev, shards
     assert "tpu_custom_call" in compiled.as_text()
     accumulators = int(np.prod(dims)) * 8 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * accumulators + shards // n_dev * ROW_BYTES
+
+
+def test_tanimoto_program_compiles_for_v5e_at_the_chembl_shape(topology):
+    """``tanimoto_topn`` and ``narrow_layout`` at chem.similarity_stream's
+    shape: 1.7 M molecules x 128 words on one device.  The search holds
+    no copy of the 0.88 GB matrix (its scratch is a few vectors of row
+    length and the slab's lane-rows), and the layout no more than the
+    padded copy it transposes."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topology.devices[0])
+    rows, words = 1_700_000, 128
+    r_pad = kernels.narrow_rows_pad(rows)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    mat_bytes = words * r_pad * 4
+    search = kernels.tanimoto_topn.lower(
+        sds((words, r_pad), jnp.uint32), sds((r_pad,), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile()
+    assert search.memory_analysis().temp_size_in_bytes < mat_bytes // 64
+    layout = kernels.narrow_layout.lower(sds((rows, words), jnp.uint32), r_pad).compile()
+    assert layout.memory_analysis().temp_size_in_bytes <= mat_bytes
