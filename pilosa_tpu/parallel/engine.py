@@ -76,6 +76,7 @@ from ..util.stats import (
     METRIC_ENGINE_REBUILDS,
     METRIC_ENGINE_RESIDENT_BLOCK_FRACTION,
     METRIC_ENGINE_RESIDENT_BYTES,
+    METRIC_ENGINE_SUM_PROGRAMS,
     METRIC_ENGINE_TOPN_HITS,
     METRIC_ENGINE_TOPN_ROWS_SCORED,
     METRIC_ENGINE_TOPN_SLAB_OVERFLOW,
@@ -87,6 +88,7 @@ from ..util.stats import (
     METRIC_INGEST_SYNC_COALESCED,
     METRIC_INGEST_SYNC_DISPATCHES,
     REGISTRY,
+    SUM_BODIES,
 )
 from . import fusion as fusion_mod
 from . import repair as repair_mod
@@ -925,6 +927,8 @@ class MeshEngine:
         # Likewise the GroupBy program's Pallas body
         # (kernels.group_counts_local).
         self._group_pallas = self._sparse_pallas
+        # ... and the Sum program's (kernels.sum_counts_pallas).
+        self._sum_pallas = self._sparse_pallas
         self.sparse_dispatches = 0
         self.device_bytes_skipped = 0
         # Versioned result memo: fused Counts repeated against unchanged
@@ -1011,6 +1015,10 @@ class MeshEngine:
             REGISTRY.counter(METRIC_ENGINE_GROUP_PREFIX_STEPS, state=state)
             for state in GROUP_PREFIX_STATES
         )
+        self._sum_programs_counters = {
+            body: REGISTRY.counter(METRIC_ENGINE_SUM_PROGRAMS, body=body)
+            for body in SUM_BODIES
+        }
         self._topn_rows_scored_counter = REGISTRY.counter(
             METRIC_ENGINE_TOPN_ROWS_SCORED
         )
@@ -4191,12 +4199,15 @@ class MeshEngine:
                 self._note_fused_dispatch()
                 drain = self._note_aggregate("Sum", index, field_name,
                                              filter_call)
-            with tracing.stage("dispatch", **drain):
+                body = "pallas" if self._sum_pallas else "xla"
+                self._sum_programs_counters[body].inc()
+            with tracing.stage("dispatch", body=body, **drain):
                 return kernels.sum_tree(
                     self.mesh,
                     prog,
                     tuple(lw.specs),
                     self._plane_spec(stack, depth),
+                    self._sum_pallas,
                     mask,
                     stack.matrix,
                     *lw.operands,

@@ -23,12 +23,15 @@ slower on v5e (95 vs 705 GB/s effective).  Padding shards are zero.  ``mask`` is
 ``("ones",)`` means mask-only.
 
 These are plain-XLA programs: the batched Count program reads its
-planes at 89 % of a v5e's HBM roofline (PERF.md §5, taxi cell), and no
-hand-written kernel layer is kept for the dense sweep.  The one
-exception is GroupBy (``group_tree``), whose bound is the vector unit
-and not HBM: XLA re-reads the last field's planes for every prefix of
-the nest, a Pallas kernel holds a tile of every operand in VMEM and
-reads each plane once (PERF.md §6, PR 34).
+planes at 89 % of a v5e's HBM roofline (PERF.md §5, taxi cell).  Two
+exceptions run a Pallas kernel on a TPU.  GroupBy (``group_tree``),
+whose bound is the vector unit and not HBM: XLA re-reads the last
+field's planes for every prefix of the nest, the kernel holds a tile of
+every operand in VMEM and reads each plane once (PERF.md §6).
+And the BSI Sum (``sum_tree``): XLA copied the filter's row leaves out
+of their stacks and wrote the filter and the broadcast mask to HBM
+before its reduce, 50-72 % of the roofline at SSB flight 1's shapes;
+the kernel reads each plane once, at 88 % (PERF.md §6).
 """
 
 from __future__ import annotations
@@ -508,40 +511,296 @@ def topn_slab_tree(
     )(mask, cand_mat, cnt, thr, *operands)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def sum_tree(mesh, prog, specs, pspec, mask, plane_mat, *operands):
+def sum_counts_xla(f, p):
+    """Per-device BSI Sum counts, plain XLA: the filter row ``f``
+    (uint32[S, W], or [S, 1] broadcasting) against the planes ``p``
+    (uint32[depth + 1, S, W], the not-null plane last) -> int32[depth + 1]:
+    each value plane's popcount under not-null & f, then that of
+    not-null & f.  ONE variadic reduce over the D + 1 popcount operands:
+    the not-null plane (inside ``consider``) loads once per element and
+    is reused across every masked plane instead of re-read per plane.
+    The body the CPU runs, and the fused program's ``sum`` edge."""
+    consider = jnp.bitwise_and(p[-1], f)
+    depth = p.shape[0] - 1
+    ops_list = [_pc(p[i] & consider) for i in range(depth)]
+    ops_list.append(_pc(consider))
+    return jnp.stack(_sum_many(ops_list, (0, 1)))
+
+
+# The Pallas body of ``sum_tree``: one pass over the shard block.  At SSB
+# flight 1's shapes on a v5e (38-39 planes x 64 shards) it runs 0.441-0.454
+# ms a Sum, 88 % of the HBM roofline, where the XLA body ran 0.545-0.819:
+# XLA copied whole date stacks into VMEM to take one row, and wrote that
+# row, the broadcast shard mask and a zero row to HBM before the reduce
+# (PERF.md section 6 has every form timed).  Every
+# row-plane the call reads -- the filter's leaves, the measure's value
+# planes and its not-null plane -- is an input of its own, a tile of
+# SUM_TILE_SHARDS shards x tw words in VMEM, chosen by the BlockSpec's
+# index map (a static row, or a traced one from SMEM): nothing is sliced
+# or copied out of a stack first.  A grid step walks its tile a 128-word
+# lane chunk at a time: the chunk of each plane is loaded into registers
+# once, the filter tree evaluated on them (the BSI walks of ops/bsi.py,
+# their predicate bits scalars from SMEM), and D + 1 popcounts added into
+# (shards, 128) int32 accumulators carried through the loop.  The
+# accumulators leave as int32[D + 1, S, 128]; lanes, and the shards the
+# mask leaves out, are reduced outside, once.
+SUM_TILE_SHARDS = 8
+SUM_TILE_WORDS = 2048
+SUM_VMEM_LIMIT = 64 << 20
+# Row-plane inputs the body takes: each is a double-buffered tile of
+# SUM_TILE_SHARDS x SUM_TILE_WORDS words, and together they fill at most
+# half of SUM_VMEM_LIMIT.  A call with more (a filter over hundreds of
+# distinct rows) has its filter evaluated by XLA into one row first.
+SUM_MAX_INPUTS = SUM_VMEM_LIMIT // 2 // (2 * SUM_TILE_SHARDS * SUM_TILE_WORDS * 4)
+
+
+class _Chunks:
+    """A BSI leaf's planes as the walks of ops/bsi.py index them:
+    ``shape[0]`` planes, ``[i]`` the chunk of plane i."""
+
+    def __init__(self, chunks):
+        self.chunks = chunks
+        self.shape = (len(chunks),)
+
+    def __getitem__(self, i):
+        return self.chunks[i]
+
+
+# The walks unjitted: in the kernel they run on one chunk's registers.
+_WALKS = {
+    "eq": bsi_ops.range_eq.__wrapped__,
+    "neq": bsi_ops.range_neq.__wrapped__,
+    "lt": functools.partial(bsi_ops.range_lt.__wrapped__, allow_equality=False),
+    "lte": functools.partial(bsi_ops.range_lt.__wrapped__, allow_equality=True),
+    "gt": functools.partial(bsi_ops.range_gt.__wrapped__, allow_equality=False),
+    "gte": functools.partial(bsi_ops.range_gt.__wrapped__, allow_equality=True),
+}
+
+
+class _SumInputs:
+    """The Pallas body's operands, gathered from a lowered filter tree:
+    ``planes`` the (array, row) of each distinct row-plane input -- row
+    an int, or ``("s", k)``, a traced index at ``scalars[k]``, or None
+    for an [S, W] row --, ``scalars`` the int32 pieces of the SMEM
+    vector."""
+
+    def __init__(self):
+        self.planes, self.keys, self.scalars = [], {}, []
+        self.n_scalars = 0
+
+    def scalar(self, x):
+        """Offset of ``x`` (a traced int32 scalar or vector) in SMEM."""
+        x = jnp.reshape(x, (-1,)).astype(jnp.int32)
+        off = self.n_scalars
+        self.scalars.append(x)
+        self.n_scalars += x.shape[0]
+        return off
+
+    def plane(self, key, arr, row):
+        k = self.keys.get(key)
+        if k is None:
+            k = self.keys[key] = len(self.planes)
+            self.planes.append((arr, row))
+        return k
+
+    def leaf_planes(self, i_mat, mat, pspec):
+        """A BSI leaf's plane inputs, None where a gathered layout
+        misses a plane (-1): the kernel reads it as zeros, as
+        ``gather_planes`` does."""
+        if pspec[0] == "slice":
+            rows = range(pspec[1], pspec[1] + pspec[2])
+        else:
+            rows = pspec[1]
+        return tuple(None if r < 0 else self.plane((i_mat, r), mat, r)
+                     for r in rows)
+
+    def xla_row(self, prog, ops):
+        """``prog`` evaluated by XLA into an [S, W] row, one input."""
+        row = apply_prog(prog, ops)
+        return ("t", self.plane(("xla", len(self.planes)), row, None))
+
+    def tree(self, prog, ops):
+        """A lowered filter tree -> the kernel's tree over plane inputs:
+        ("t", k), ("range", kind, planes, off), ("between", planes,
+        off_lo, off_hi), or a set operation over those.  A leaf the body
+        does not take (a block-pool or maskable row, a zero stack) is
+        evaluated by XLA first, a row of its own: the choice follows the
+        tree's static structure."""
+        kind = prog[0]
+        if kind == "row":
+            # the index as apply_prog's dynamic_index_in_dim takes it
+            ref, mat = prog[2], ops[prog[1]]
+            key = (prog[1], ref)
+            if key not in self.keys:
+                idx = ops[0][ref[1]] if isinstance(ref, tuple) else ops[ref]
+                idx = jnp.clip(idx, 0, mat.shape[0] - 1)
+                self.plane(key, mat, ("s", self.scalar(idx)))
+            return ("t", self.keys[key])
+        if kind == "range":
+            planes = self.leaf_planes(prog[2], ops[prog[2]], prog[3])
+            return ("range", prog[1], planes, self.scalar(ops[prog[4]]))
+        if kind == "between":
+            planes = self.leaf_planes(prog[1], ops[prog[1]], prog[2])
+            return ("between", planes, self.scalar(ops[prog[3]]),
+                    self.scalar(ops[prog[4]]))
+        if kind in ("and", "or", "andnot", "xor"):
+            return (kind,) + tuple(self.tree(p, ops) for p in prog[1:])
+        return self.xla_row(prog, ops)
+
+
+def _sum_inputs(prog, ops, pm, pspec):
+    """The Pallas body's inputs for one call: (the ``_SumInputs``, the
+    measure's plane inputs, the kernel's filter tree or None).  A call
+    that would take more than SUM_MAX_INPUTS inputs has its whole filter
+    evaluated by XLA into one row instead."""
+    ins = _SumInputs()
+    measure = ins.leaf_planes("pm", pm, pspec)
+    if prog == ("ones",):
+        return ins, measure, None
+    tree = ins.tree(prog, ops)
+    if len(ins.planes) > SUM_MAX_INPUTS:
+        ins = _SumInputs()
+        measure = ins.leaf_planes("pm", pm, pspec)
+        tree = ins.xla_row(prog, ops)
+    return ins, measure, tree
+
+
+def _eval_chunk(node, ld, bits):
+    """The kernel's tree on one lane chunk: ``ld(k)`` loads input k's
+    chunk, ``bits(off, n)`` reads n predicate bits from SMEM."""
+    kind = node[0]
+    if kind == "t":
+        return ld(node[1])
+    if kind == "range":
+        _, rk, planes, off = node
+        chunks = _Chunks([ld(k) for k in planes])
+        return _WALKS[rk](chunks, bits(off, max(len(planes) - 1, 1)))
+    if kind == "between":
+        _, planes, lo, hi = node
+        n = max(len(planes) - 1, 1)
+        return bsi_ops.range_between.__wrapped__(
+            _Chunks([ld(k) for k in planes]), bits(lo, n), bits(hi, n))
+    subs = [_eval_chunk(s, ld, bits) for s in node[1:]]
+    out = subs[0]
+    for s in subs[1:]:
+        if kind == "or":
+            out = out | s
+        elif kind == "and":
+            out = out & s
+        elif kind == "andnot":
+            out = out & ~s
+        else:
+            out = out ^ s
+    return out
+
+
+def sum_counts_pallas(prog, mask, ops, pm, pspec, interpret=False):
+    """Per-device BSI Sum counts as ONE Pallas kernel: ``sum_counts_xla``'s
+    int32[depth + 1] of ``_filter(prog, mask, ops)`` against
+    ``gather_planes(pm, pspec)``, reading each row-plane once (the
+    header comment).  ``mask`` is the requested-shard uint32[S, 1], all
+    ones or zero a shard (``MeshEngine._mask_words``), so it is applied
+    to each shard's counts after the kernel.  A shard block that is not
+    a whole number of tiles ends in a partial one: its rows past the
+    block are never summed."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    ins, measure, tree = _sum_inputs(prog, ops, pm, pspec)
+    depth = len(measure) - 1
+    if measure[-1] is None:
+        # no not-null plane: no column is considered
+        return jnp.zeros(depth + 1, jnp.int32)
+    S, W = pm.shape[1], pm.shape[2]
+    ts = min(SUM_TILE_SHARDS, S)
+    tw = min(SUM_TILE_WORDS, W)
+    n_in = len(ins.planes)
+    scal = (jnp.concatenate(ins.scalars) if ins.scalars
+            else jnp.zeros(1, jnp.int32))
+
+    def kernel(s_ref, *refs):
+        tiles, acc_ref = refs[:n_in], refs[n_in]
+
+        @pl.when(pl.program_id(1) == 0)
+        def _zero():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def bits(off, n):
+            return [s_ref[off + i] for i in range(n)]
+
+        def one(c, accs):
+            sl = pl.ds(pl.multiple_of(c * 128, 128), 128)
+            loaded = {}
+
+            def ld(k):
+                if k is None:  # a missing plane
+                    return jnp.zeros((ts, 128), jnp.uint32)
+                if k not in loaded:
+                    loaded[k] = tiles[k][:, sl]
+                return loaded[k]
+
+            consider = ld(measure[-1])
+            if tree is not None:
+                consider = consider & _eval_chunk(tree, ld, bits)
+            outs = [a + _pc(ld(measure[b]) & consider)
+                    for b, a in enumerate(accs[:depth])]
+            return tuple(outs) + (accs[depth] + _pc(consider),)
+
+        zero = jnp.zeros((ts, 128), jnp.int32)
+        accs = jax.lax.fori_loop(0, tw // 128, one, (zero,) * (depth + 1))
+        for b in range(depth + 1):
+            acc_ref[b] += accs[b]
+
+    def spec(row):
+        if row is None:
+            return pl.BlockSpec((ts, tw), lambda i, j, s: (i, j))
+        if isinstance(row, tuple):
+            return pl.BlockSpec((None, ts, tw), lambda i, j, s: (s[row[1]], i, j))
+        return pl.BlockSpec((None, ts, tw), lambda i, j, s: (row, i, j))
+
+    acc = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(S, ts), W // tw),
+            in_specs=[spec(row) for _, row in ins.planes],
+            out_specs=pl.BlockSpec((depth + 1, ts, 128), lambda i, j, s: (0, i, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((depth + 1, S, 128), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=SUM_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+    )(scal, *[arr for arr, _ in ins.planes])
+    per_shard = jnp.sum(acc, axis=2)
+    return jnp.sum(jnp.where(mask[:, 0] != 0, per_shard, 0), axis=1)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def sum_tree(mesh, prog, specs, pspec, pallas, mask, plane_mat, *operands):
     """BSI Sum in ONE dispatch: plane slice + filter tree + weighted
     popcounts (fragment.go sum :716-742) -> (int32[D] plane counts,
     int32 considered), replicated.  The Σ 2^i·counts[i] assembly stays
-    host-side in arbitrary precision."""
+    host-side in arbitrary precision.  ``pallas`` (the engine sets it
+    from the backend) selects the TPU body, ``sum_counts_pallas``; else
+    the XLA one, ``sum_counts_xla``."""
 
     def body(m, pm, *ops):
-        f = _filter(prog, m, ops)
-        p = gather_planes(pm, pspec)
-        consider = jnp.bitwise_and(p[-1], f)
-        # ONE variadic reduce over D+1 popcount operands: the not-null
-        # plane (inside ``consider``) loads once per element and is
-        # reused across every masked plane instead of re-read per plane
-        # (the 553 GB/s vs 755 gap of the two-reduction form).
-        depth = p.shape[0] - 1
-        ops_list = [_pc(p[i] & consider) for i in range(depth)]
-        ops_list.append(_pc(consider))
-        outs = _sum_many(ops_list, (0, 1))
-        # depth 0 (a BSI group with max == min): no value planes, the
-        # total is count * base — jnp.stack([]) would raise.
-        counts = (
-            jnp.stack(outs[:depth]) if depth else jnp.zeros(0, jnp.int32)
-        )
-        return (
-            jax.lax.psum(counts, SHARD_AXIS),
-            jax.lax.psum(outs[depth], SHARD_AXIS),
-        )
+        if pallas:
+            out = sum_counts_pallas(prog, m, ops, pm, pspec)
+        else:
+            out = sum_counts_xla(_filter(prog, m, ops), gather_planes(pm, pspec))
+        out = jax.lax.psum(out, SHARD_AXIS)
+        return out[:-1], out[-1]
 
+    # check_vma off with the Pallas body, as in group_tree.
     return shard_map(
         body,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(None, SHARD_AXIS)) + specs,
         out_specs=(P(), P()),
+        check_vma=not pallas,
     )(mask, plane_mat, *operands)
 
 
@@ -652,20 +911,13 @@ def fused_tree(mesh, fspec, specs, *operands):
             kind = e[0]
             if kind == "sum":
                 _, slot, i_mask, i_pm, pspec = e
-                f = masked(slot, i_mask)
-                p = gather_planes(ops[i_pm], pspec)
-                consider = jnp.bitwise_and(p[-1], f)
-                depth = p.shape[0] - 1
-                ops_list = [_pc(p[i] & consider) for i in range(depth)]
-                ops_list.append(_pc(consider))
-                sums = _sum_many(ops_list, (0, 1))
-                counts = (
-                    jnp.stack(sums[:depth])
-                    if depth
-                    else jnp.zeros(0, jnp.int32)
+                sums = jax.lax.psum(
+                    sum_counts_xla(masked(slot, i_mask),
+                                   gather_planes(ops[i_pm], pspec)),
+                    SHARD_AXIS,
                 )
-                outs.append(jax.lax.psum(counts, SHARD_AXIS))
-                outs.append(jax.lax.psum(sums[depth], SHARD_AXIS))
+                outs.append(sums[:-1])
+                outs.append(sums[-1])
             elif kind == "minmax":
                 _, slot, i_mask, i_pm, pspec, is_min = e
                 f = masked(slot, i_mask)

@@ -422,6 +422,11 @@ GROUP_PREFIX_STATES = ("skipped", "scored")
 #       GroupCount objects (once a result) or the host iterator answered
 METRIC_EXECUTOR_GROUP_RESULTS = "pilosa_executor_group_results_total"
 GROUP_RESULT_FORMS = ("columns", "objects")
+#   pilosa_engine_sum_programs_total{body}       Sum programs dispatched, by
+#       the body ``kernels.sum_tree`` runs: body="pallas", the one-pass
+#       kernel a TPU backend runs, or "xla"
+METRIC_ENGINE_SUM_PROGRAMS = "pilosa_engine_sum_programs_total"
+SUM_BODIES = ("pallas", "xla")
 #   pilosa_engine_topn_rows_scored_total         rows the Tanimoto TopN program
 #       scored against its query row on the device (the narrow field's
 #       rows, every dispatch)
@@ -833,6 +838,12 @@ for _form in GROUP_RESULT_FORMS:
         METRIC_EXECUTOR_GROUP_RESULTS,
         help="GroupBy results by the form they left the executor in",
         form=_form,
+    )
+for _body in SUM_BODIES:
+    REGISTRY.counter(
+        METRIC_ENGINE_SUM_PROGRAMS,
+        help="Sum programs dispatched, by the body they run",
+        body=_body,
     )
 REGISTRY.counter(
     METRIC_ENGINE_TOPN_ROWS_SCORED,

@@ -38,7 +38,7 @@ ROW_BYTES = WORDS * 4  # one (row, shard)
 # (module, entry point) -> number of leading static (non-array) arguments
 ENTRY_POINTS = {
     (kernels, "count_tree"): 3, (kernels, "count_batch_tree"): 3,
-    (kernels, "sum_tree"): 4, (kernels, "minmax_tree"): 5,
+    (kernels, "sum_tree"): 5, (kernels, "minmax_tree"): 5,
     (kernels, "topn_full_tree"): 5, (kernels, "topn_slab_tree"): 6,
     (kernels, "group_tree"): 6, (kernels, "fused_tree"): 3,
     (sparse, "count_tree_blocks"): 2,
@@ -97,6 +97,8 @@ def recorded():
             "Count(Intersect(Row(f=1), Row(f=2)))",
             "Count(Intersect(Row(s=1), Row(s=2)))",
             "Sum(field=v)", "Min(field=v)", "Count(Range(v > 100))",
+            # ssb.flight1_stream's structure (Q1.3: two date rows)
+            "Sum(Intersect(Row(f=1), Row(s=2), Range(v == 7), Range(v >< [3, 200])), field=v)",
             "TopN(f, Row(s=1), n=2)", "GroupBy(Rows(field=f), Rows(field=s))",
         ):
             ex.execute("i", q)
@@ -149,6 +151,9 @@ TEMP_ROWS = {
     "count_tree": 1, "count_batch_tree": 1,
     "count_tree_blocks": 1, "count_tree_blocks_pallas": 1,
     "sum_tree": 1, "minmax_tree": 3, "topn_full_tree": 1, "topn_slab_tree": 1,
+    # The Sum program's Pallas body reads every plane in place: its
+    # scratch is the lane accumulators ([depth + 1, S, 128] int32).
+    "sum_tree_pallas": 1,
     # The XLA body's loop holds a prefix mask; the Pallas body's lane
     # accumulators ([groups, 8, 128] int32) are summed outside it.
     "group_tree": 2, "group_tree_pallas": 2,
@@ -173,6 +178,10 @@ def test_smoke_programs_compile_for_v5e(topology, recorded, n_dev):
             # the CPU engine above ran the XLA form): Mosaic must take it.
             temps["count_tree_blocks_pallas", static] = _temp_bytes(
                 sparse.count_tree_blocks_pallas, mesh, (*static, False), arrays)
+        if name == "sum_tree":
+            # The Sum program's Pallas body (static 4), which a TPU runs.
+            temps["sum_tree_pallas", static] = _temp_bytes(
+                fn, mesh, (*static[:3], True), arrays)
         if name == "group_tree":
             # Likewise the GroupBy program's Pallas body (static 4).
             temps["group_tree_pallas", static] = _temp_bytes(
@@ -274,6 +283,81 @@ def test_aggregated_group_by_compiles_for_v5e_at_flight_3s_shapes(topology, n_de
     row_block = shards // n_dev * ROW_BYTES
     accumulators = kernels.GROUP_ACC_GROUPS * 8 * 128 * 4 * 4  # four passes' lanes, summed outside
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * sum(dims) * row_block + accumulators
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("date_rows", [1, 2], ids=["one_date_row", "two_date_rows"])
+def test_sum_compiles_for_v5e_at_flight_1s_shapes(topology, n_dev, date_rows):
+    """``sum_tree`` on its Pallas body at ssb.flight1_stream's shapes:
+    64 shards, a 25-plane measure, ``Intersect(Row(date), [Row(date),]
+    Range(lo_discount == d), Range(lo_quantity >< [a, b]))`` over 7- and
+    53-row date stacks, 5 and 7 planes.  Mosaic must take it, the
+    program is the one kernel, and nothing of a plane's size is copied
+    or written: the XLA body copied whole date stacks and wrote the
+    date row, the broadcast mask and a zero row first."""
+    mesh = Mesh(np.asarray(topology.devices[:n_dev]), (SHARD_AXIS,))
+    shards = 64
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    def stack(rows):
+        return sds((rows, shards, WORDS), jnp.uint32, P(None, SHARD_AXIS))
+
+    mat = P(None, SHARD_AXIS)
+    ops = [stack(7), sds((), jnp.int32), stack(53), sds((), jnp.int32)][:2 * date_rows]
+    specs = [mat, P(), mat, P()][:2 * date_rows]
+    n = len(ops)
+    ops += [stack(5), sds((4,), jnp.uint32), stack(7), sds((6,), jnp.uint32), sds((6,), jnp.uint32)]
+    specs += [mat, P(), mat, P(), P()]
+    rows = tuple(("row", 2 * k, 2 * k + 1) for k in range(date_rows))
+    prog = ("and", *rows, ("range", "eq", n, ("slice", 0, 5), n + 1),
+            ("between", n + 2, ("slice", 0, 7), n + 3, n + 4))
+    compiled = kernels.sum_tree.lower(
+        mesh, prog, tuple(specs), ("slice", 0, 25), True,
+        sds((shards, 1), jnp.uint32, P(SHARD_AXIS)), stack(25), *ops).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "copy-start" not in text.split("ENTRY")[1].split("custom-call")[0]
+    accumulators = 25 * shards // n_dev * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= accumulators
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("n_rows", [kernels.SUM_MAX_INPUTS - 25, 600], ids=["at_the_cap", "600_rows"])
+def test_sum_of_a_wide_union_compiles_for_v5e(topology, n_dev, n_rows):
+    """``Sum(Union(Row(d=..), ..), field=v)`` at flight 1's shapes (64
+    shards, a 25-plane measure) over distinct rows of a 53-row stack:
+    with the measure, as many inputs as the Pallas body takes
+    (SUM_MAX_INPUTS), whose tiles fit its VMEM, and far more, whose
+    filter XLA evaluates into one row first.  Both are one kernel.  At
+    the cap the scratch is the accumulators and less than a row; past
+    it, XLA's union of 600 dynamic row slices holds what the XLA body's
+    does (1.47 GB on one device, 1.54 in the XLA body), within a
+    quarter of it."""
+    mesh = Mesh(np.asarray(topology.devices[:n_dev]), (SHARD_AXIS,))
+    shards = 64
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    def compile_sum(pallas):
+        prog = ("or",) + tuple(("row", 1, ("sv", j)) for j in range(n_rows))
+        return kernels.sum_tree.lower(
+            mesh, prog, (P(), P(None, SHARD_AXIS)), ("slice", 0, 25), pallas,
+            sds((shards, 1), jnp.uint32, P(SHARD_AXIS)),
+            sds((25, shards, WORDS), jnp.uint32, P(None, SHARD_AXIS)),
+            sds((n_rows,), jnp.int32),
+            sds((53, shards, WORDS), jnp.uint32, P(None, SHARD_AXIS))).compile()
+
+    compiled = compile_sum(True)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if n_rows + 25 <= kernels.SUM_MAX_INPUTS:
+        accumulators = 25 * shards // n_dev * 128 * 4
+        assert temp < accumulators + shards // n_dev * ROW_BYTES
+    else:
+        assert temp <= 1.25 * compile_sum(False).memory_analysis().temp_size_in_bytes
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
