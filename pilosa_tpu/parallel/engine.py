@@ -52,6 +52,7 @@ from ..util.stats import (
     ENGINE_CACHES,
     GROUP_PREFIX_STATES,
     METRIC_DEVICE_BYTES_SKIPPED,
+    METRIC_ENGINE_BATCH_PAD_SLOTS,
     METRIC_ENGINE_CACHE_HITS,
     METRIC_ENGINE_CACHE_MISSES,
     METRIC_ENGINE_COMPILE,
@@ -87,6 +88,7 @@ from ..util.stats import (
     METRIC_MESH_SHARDS_PER_DEVICE,
     METRIC_INGEST_SYNC_COALESCED,
     METRIC_INGEST_SYNC_DISPATCHES,
+    PAD_LOWERINGS,
     REGISTRY,
     SUM_BODIES,
 )
@@ -356,6 +358,10 @@ class _Lowering:
         # cold-stack miss can enqueue ONE promotion covering the whole
         # query's working set instead of converging one row per retry.
         self.row_hints: Dict[tuple, Optional[set]] = {}
+        # A list while the batched path lowers entry 0: the operand
+        # indices add_replicated appends, which its pad slots replay
+        # (MeshEngine._pad_count_batch).  None otherwise.
+        self.replicated_log: Optional[list] = None
         if slot_vector:
             self.scalar_values = []
             self.operands.append(None)  # slot vector, filled by finish()
@@ -422,6 +428,8 @@ class _Lowering:
     def add_replicated(self, arr) -> int:
         self.operands.append(arr)
         self.specs.append(P())
+        if self.replicated_log is not None:
+            self.replicated_log.append(len(self.operands) - 1)
         return len(self.operands) - 1
 
     def add_mask(self, mask) -> int:
@@ -436,6 +444,50 @@ class _Lowering:
             self.specs.append(P(SHARD_AXIS))
             self._mat_ids[key] = i
         return i
+
+
+# What a pad slot of the batched Count program renumbers in entry 0's
+# lowered prog, by leaf kind of the grammar kernels.apply_prog reads: the
+# positions of slot refs ("sv", j), and of replicated-operand indices.
+# Matrix indices and plane specs stay: a matrix is deduped by identity, so
+# lowering entry 0 again would name the same one.  Inner nodes recurse.
+_PAD_REFS = {
+    "row": ((2,), ()),
+    "rowm": ((2,), ()),
+    "rowb": ((), (2,)),
+    "range": ((), (4,)),
+    "between": ((), (3, 4)),
+    "zero": ((), ()),
+}
+_PAD_NODES = frozenset(("and", "or", "andnot", "xor"))
+
+
+def _replay_prog(prog, n_sv: int, d_sv: int, ops: dict):
+    """Entry 0's lowered ``prog`` as lowering entry 0 again would give it
+    after the slots and operands already laid out: each slot ref
+    ``("sv", j)``, j < ``n_sv``, becomes ``("sv", j + d_sv)`` and each
+    replicated-operand index i becomes ``ops[i]``.  None where the prog
+    holds a leaf kind ``_PAD_REFS`` does not know, or a ref outside what
+    entry 0's lowering appended."""
+    kind = prog[0]
+    if kind in _PAD_NODES:
+        subs = tuple(_replay_prog(p, n_sv, d_sv, ops) for p in prog[1:])
+        return None if None in subs else (kind,) + subs
+    refs = _PAD_REFS.get(kind)
+    if refs is None:
+        return None
+    out = list(prog)
+    for at in refs[0]:
+        ref = prog[at]
+        if not (isinstance(ref, tuple) and 0 <= ref[1] < n_sv):
+            return None
+        out[at] = ("sv", ref[1] + d_sv)
+    for at in refs[1]:
+        i = ops.get(prog[at])
+        if i is None:
+            return None
+        out[at] = i
+    return tuple(out)
 
 
 class _ResultMemo:
@@ -1019,6 +1071,14 @@ class MeshEngine:
             body: REGISTRY.counter(METRIC_ENGINE_SUM_PROGRAMS, body=body)
             for body in SUM_BODIES
         }
+        self._pad_slots_counters = {
+            how: REGISTRY.counter(METRIC_ENGINE_BATCH_PAD_SLOTS, lowering=how)
+            for how in PAD_LOWERINGS
+        }
+        # The batched Count program's pad entries, replayed from entry 0's
+        # lowering (_pad_count_batch), by what they are a pure function of;
+        # emptied when it reaches 64 keys.
+        self._pad_replays: Dict[tuple, list] = {}
         self._topn_rows_scored_counter = REGISTRY.counter(
             METRIC_ENGINE_TOPN_ROWS_SCORED
         )
@@ -3969,9 +4029,9 @@ class MeshEngine:
         # BEFORE tier padding, and unique entries lower in first-seen
         # order, so the padded program stays byte-identical for every
         # batch of the same structure + tier: slot indices depend only
-        # on the unique sequence, and the pad entries re-lower entry 0
-        # exactly as before (the compile-key property the fixed tiers
-        # exist for — see the round-4 note below).
+        # on the unique sequence, and the pad entries are replayed
+        # from entry 0's lowering (the compile-key property the fixed
+        # tiers exist for — see the round-4 note below).
         uniq: Dict[tuple, int] = {}
         mapping = np.empty(len(calls), dtype=np.int32)
         u_calls: list = []
@@ -4022,33 +4082,38 @@ class MeshEngine:
                 u_planes.append(self._hint_planes(hints))
                 fusion_mod.merge_hints(lw.row_hints, hints)
             progs = []
+            lw.replicated_log = []  # entry 0's appends, for the pads
             for c, shards in zip(u_calls, u_shards):
                 prog = self._lower(index, c, lw)
                 i_mask = lw.add_mask(self._mask_words(shards, canonical))
+                if not progs:
+                    entry0 = (prog, i_mask, len(lw.scalar_values),
+                              tuple(lw.replicated_log))
+                    lw.replicated_log = None
                 progs.append((prog, i_mask))
-            # Pad to the tier by RE-LOWERING query 0: padding entries
-            # then occupy their own deterministic slots, so the padded
-            # program is byte-identical for every batch of the same
-            # structure + tier.  The tier is the program's CAPACITY, not
-            # what the chip pays: the program takes the live count K as
-            # a traced operand and skips every slot at or beyond it
-            # (kernels.count_batch_tree), so the pad slots are lowered
-            # here, for their static positions, and never run.  (XLA
-            # cannot drop them itself: their row ids are data.)
-            # Repeating the LAST pair instead (round 4) kept the raw K
-            # in the operand indexing and compiled a fresh program per
-            # distinct drain size — ~2 s each, the entire QPS shortfall.
+            # Pad to the tier with copies of entry 0, each in slots and
+            # operands of its own, exactly as lowering entry 0 again
+            # would lay them out: the padded program is then
+            # byte-identical for every batch of the same structure +
+            # tier.  The tier is the program's CAPACITY, not what the
+            # chip pays: the program takes the live count K as a traced
+            # operand and skips every slot at or beyond it
+            # (kernels.count_batch_tree), so the pad slots exist here,
+            # for their static positions, and never run.  (XLA cannot
+            # drop them itself: their row ids are data.)  Repeating the
+            # LAST pair instead (round 4) kept the raw K in the operand
+            # indexing and compiled a fresh program per distinct drain
+            # size — ~2 s each, the entire QPS shortfall.
             K = len(progs)
             K_pad = next(
                 (t for t in self.BATCH_TIERS if K <= t),
                 max(1, 1 << (K - 1).bit_length()),
             )
-            for _ in range(K_pad - K):
-                prog = self._lower(index, u_calls[0], lw)
-                i_mask = lw.add_mask(
-                    self._mask_words(u_shards[0], canonical)
+            if K_pad > K:
+                progs += self._pad_count_batch(
+                    index, u_calls[0], u_shards[0], canonical, lw, entry0,
+                    K_pad - K,
                 )
-                progs.append((prog, i_mask))
             lw.finish()
             self._note_fused_dispatch()
             self._note_touches(lw)
@@ -4077,6 +4142,51 @@ class MeshEngine:
             # dispatch floor the dedup just saved K-U times over).
             return jnp.take(dev, jnp.asarray(mapping))
         return dev
+
+    def _pad_count_batch(
+        self, index, call0, shards0, canonical, lw, entry0, n_pads
+    ):
+        """The ``n_pads`` pad entries of a batched Count program, appended
+        to ``lw``: entry 0's lowering replayed, its slot values and its
+        replicated operands (the same arrays) appended again for each pad
+        and its prog renumbered to them (``_replay_prog``).  The pad progs
+        are a pure function of entry 0's and of the layout before the
+        first pad, so a drain of a known structure and size looks them up
+        (``_pad_replays``, touched under ``_dispatch_lock`` only).  A prog
+        the walk cannot renumber lowers ``call0`` again for each pad.
+        ``entry0`` is (prog, i_mask, the slot values its lowering
+        appended, the operand indices its add_replicated calls appended).
+        """
+        prog0, i_mask0, n_sv, rep = entry0
+        n_ops, n_slots = len(lw.operands), len(lw.scalar_values)
+        key = (prog0, i_mask0, n_sv, rep, n_ops, n_slots, n_pads)
+        pads = self._pad_replays.get(key)
+        if pads is None:
+            progs = [
+                _replay_prog(
+                    prog0, n_sv, n_slots + p * n_sv,
+                    {i: n_ops + p * len(rep) + k for k, i in enumerate(rep)},
+                )
+                for p in range(n_pads)
+            ]
+            if None in progs:
+                self._pad_slots_counters["lowered"].inc(n_pads)
+                return [
+                    (
+                        self._lower(index, call0, lw),
+                        lw.add_mask(self._mask_words(shards0, canonical)),
+                    )
+                    for _ in range(n_pads)
+                ]
+            pads = [(prog, i_mask0) for prog in progs]
+            if len(self._pad_replays) >= 64:
+                self._pad_replays.clear()
+            self._pad_replays[key] = pads
+        lw.scalar_values.extend(lw.scalar_values[:n_sv] * n_pads)
+        lw.operands.extend([lw.operands[i] for i in rep] * n_pads)
+        lw.specs.extend([lw.specs[i] for i in rep] * n_pads)
+        self._pad_slots_counters["replayed"].inc(n_pads)
+        return pads
 
     def bitmap_stack(
         self,

@@ -290,8 +290,9 @@ def count_batch_tree(mesh, progs, specs, n_live, *operands):
     is a traced replicated int32 scalar: slot j runs only if j < n_live
     and answers 0 otherwise, so device time follows the drain's live
     count, never the tier.  The branch is real control flow on purpose:
-    the engine fills the slots past n_live by re-lowering query 0, row
-    ids are slot-vector DATA, and XLA cannot CSE those copies away —
+    the engine fills the slots past n_live with copies of query 0 (its
+    lowering replayed into slots and operands of their own), row ids
+    are slot-vector DATA, and XLA cannot CSE those copies away —
     unconditioned, a tier-64 run read 64 slots' planes for 16 requests
     (61.5 ms against 15.4 on a v5e, PERF.md section 6, PR 27).
     n_live is the same on every device, so the psum stays outside the

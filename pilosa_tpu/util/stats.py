@@ -402,6 +402,12 @@ METRIC_ENGINE_DRAIN_SLOTS = "pilosa_engine_drain_slots_total"
 METRIC_ENGINE_DRAIN_REQUESTS = "pilosa_engine_drain_requests_total"
 METRIC_ENGINE_DRAIN_EVALUATED = "pilosa_engine_drain_evaluated_slots_total"
 METRIC_ENGINE_DRAIN_PLANE_BYTES = "pilosa_engine_drain_plane_bytes_total"
+#   pilosa_engine_batch_pad_slots_total{lowering}  pad slots of batched Count
+#       drains (the tier's slots past the drain's unique entries):
+#       lowering="replayed" where entry 0's lowering was replayed into them,
+#       "lowered" where entry 0 was lowered again for each
+METRIC_ENGINE_BATCH_PAD_SLOTS = "pilosa_engine_batch_pad_slots_total"
+PAD_LOWERINGS = ("replayed", "lowered")
 #   pilosa_engine_group_combos_total             GroupBy combinations the
 #       device evaluated (the count tensor's size, every dispatch)
 METRIC_ENGINE_GROUP_COMBOS = "pilosa_engine_group_combos_total"
@@ -838,6 +844,12 @@ for _form in GROUP_RESULT_FORMS:
         METRIC_EXECUTOR_GROUP_RESULTS,
         help="GroupBy results by the form they left the executor in",
         form=_form,
+    )
+for _lowering in PAD_LOWERINGS:
+    REGISTRY.counter(
+        METRIC_ENGINE_BATCH_PAD_SLOTS,
+        help="Pad slots of batched Count drains, by how they were lowered",
+        lowering=_lowering,
     )
 for _body in SUM_BODIES:
     REGISTRY.counter(

@@ -505,13 +505,22 @@ def test_batch_tier_compile_key_stability(holder, mesh):
         got = eng.count_many("i", calls, [shards] * k)
         assert got == [0] * k
 
+    pads0 = _pad_counts()
     run(9)  # tier 64: compiles once
     size_after_first = k_mod.count_batch_tree._cache_size()
-    for k in (10, 17, 23, 41, 64):  # all tier 64, different raw sizes
+    # All tier 64, different raw sizes: every drain's pads are replayed
+    # from entry 0's lowering, first with the replay cache cold for its
+    # size, then warm — and neither compiles.
+    sizes = (10, 17, 23, 41, 64)
+    for k in sizes + sizes:
         run(k)
     assert k_mod.count_batch_tree._cache_size() == size_after_first, (
         "a drain size within the tier compiled a new executable"
     )
+    pads = _pad_counts()
+    assert pads["lowered"] == pads0["lowered"]
+    assert pads["replayed"] - pads0["replayed"] == sum(
+        64 - k for k in (9,) + sizes + sizes)
     # Different ROW IDS in the same structure also reuse it (ids are
     # slot-vector data), including PRESENT rows mixed with missing.
     mixed = [
@@ -524,4 +533,131 @@ def test_batch_tier_compile_key_stability(holder, mesh):
     # in this process already compiled this structure at tier 8 — the
     # cache is process-global, which is itself the point).
     run(2)  # tier 8
-    assert k_mod.count_batch_tree._cache_size() <= size_after_first + 1
+    size_tier8 = k_mod.count_batch_tree._cache_size()
+    assert size_tier8 <= size_after_first + 1
+    for k in (5, 3, 5, 7):  # tier 8, replayed: no new executable
+        run(k)
+    assert k_mod.count_batch_tree._cache_size() == size_tier8
+    assert _pad_counts()["lowered"] == pads0["lowered"]
+
+
+# Entry i of a drain, by the leaf kind its prog holds: the text varies
+# with i where the kind allows, so a pad that copied another entry than
+# entry 0 would show.  Each entry also takes its own shard subset
+# (_pad_entries), so every entry is unique and none is CSE'd away.
+PAD_KINDS = {
+    "row": (lambda i: f"Row(f={10 + i % 2})", {"rowm"}),
+    "missing_row": (lambda i: f"Row(f={999 if i % 2 == 0 else 10})", {"rowm"}),
+    "union_band": (
+        lambda i: f"Union(Row(f={10 + i % 2}), Row(f=11), Row(f={998 - i % 3}))",
+        {"or", "rowm"},
+    ),
+    "range_cmp": (lambda i: f"Range(v > {300 + i})", {"range"}),
+    "range_between": (lambda i: f"Range(v >< [{100 + i}, {600 - i}])", {"between"}),
+    "not_null": (
+        lambda i: f"Intersect(Range(v >= 0), Row(f={10 + i % 2}))",
+        {"and", "row", "rowm"},
+    ),
+    "not": (lambda i: f"Not(Row(f={10 + i % 2}))", {"andnot", "rowm"}),
+    "time_range": (
+        lambda i: f"Range(t={1 + i % 2}, 2018-01-01T00:00, 2018-03-01T00:00)",
+        {"or", "row"},
+    ),
+    "zero": (lambda i: f"Union(Row(f={10 + i % 2}), Range(v > 5000))", {"or", "zero"}),
+}
+
+
+def _pad_entries(kind, k):
+    text = PAD_KINDS[kind][0]
+    return [(_call(text(i)), [s for s in range(8) if (i + 1) >> s & 1])
+            for i in range(k)]
+
+
+def _add_time_field(holder):
+    """A YM time field ``t`` with rows 1 and 2 set in both months that
+    ``PAD_KINDS["time_range"]`` covers, on every shard."""
+    import datetime as dt
+
+    t = holder.index("i").create_field(
+        "t", FieldOptions(type="time", time_quantum="YM"))
+    rows, cols, stamps = [], [], []
+    for s in range(8):
+        for j in range(40):
+            rows.append(1 + j % 2)
+            cols.append(s * SHARD_WIDTH + 7 * j)
+            stamps.append(dt.datetime(2018, 1 + j % 3, 5))
+    t.import_bulk(rows, cols, stamps)
+
+
+def _leaf_kinds(prog):
+    kind = prog[0]
+    if kind in ("and", "or", "andnot", "xor"):
+        return {kind}.union(*(_leaf_kinds(p) for p in prog[1:]))
+    return {kind}
+
+
+def _pad_counts():
+    from pilosa_tpu.util.stats import METRIC_ENGINE_BATCH_PAD_SLOTS, REGISTRY
+
+    return {how: REGISTRY.counter(METRIC_ENGINE_BATCH_PAD_SLOTS, lowering=how).get()
+            for how in ("replayed", "lowered")}
+
+
+def _drain_with_pad_counts(eng, entries):
+    before = _pad_counts()
+    args, answers = _captured_batch_program(eng, entries)
+    after = _pad_counts()
+    return args, answers, {h: after[h] - before[h] for h in after}
+
+
+@pytest.mark.parametrize("k", [2, 5, 9, 16, 63, 65])
+@pytest.mark.parametrize("kind", sorted(PAD_KINDS))
+def test_pad_replay_matches_relowering(holder, mesh, monkeypatch, kind, k):
+    """The pad slots replayed from entry 0's lowering are, byte for byte,
+    what lowering entry 0 again for each gave: the same progs and specs
+    (the compile key), operands of the same count, shapes, dtypes and
+    values, the same slot vector, the same answers.  The re-lowering is
+    what a prog the renumbering cannot walk still takes: emptied here."""
+    from pilosa_tpu.parallel import engine as engine_mod
+
+    _add_time_field(holder)
+    entries = _pad_entries(kind, k)
+    tier = 8 if k <= 8 else 64 if k <= 64 else 256
+    got, got_n, got_pads = _drain_with_pad_counts(
+        MeshEngine(holder, mesh), entries)
+    with monkeypatch.context() as mp:
+        mp.setattr(engine_mod, "_PAD_REFS", {})
+        want, want_n, want_pads = _drain_with_pad_counts(
+            MeshEngine(holder, mesh), entries)
+    assert got_pads == {"replayed": tier - k, "lowered": 0}
+    assert want_pads == {"replayed": 0, "lowered": tier - k}
+    _, progs, specs, n, *ops = got
+    _, w_progs, w_specs, w_n, *w_ops = want
+    assert len(progs) == tier and int(n) == int(w_n) == k
+    assert PAD_KINDS[kind][1] <= _leaf_kinds(progs[0][0])
+    assert progs == w_progs and specs == w_specs
+    assert len(ops) == len(w_ops)
+    for a, b in zip(ops, w_ops):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    if kind == "missing_row":
+        assert int(np.asarray(ops[0])[0]) == -1
+    assert got_n == want_n
+
+
+def test_pad_slots_counter(holder, mesh, monkeypatch):
+    """A tier-64 drain of 9 counts its 55 pad slots as replayed; where
+    entry 0's prog holds a leaf kind the renumbering does not know, they
+    are lowered again, counted so, and the answers stay the scalar
+    counts."""
+    from pilosa_tpu.parallel import engine as engine_mod
+
+    entries = _pad_entries("union_band", 9)
+    want = [MeshEngine(holder, mesh).count("i", c, s) for c, s in entries]
+    _, got, pads = _drain_with_pad_counts(MeshEngine(holder, mesh), entries)
+    assert pads == {"replayed": 55, "lowered": 0}
+    assert got == want
+    monkeypatch.delitem(engine_mod._PAD_REFS, "rowm")
+    _, got, pads = _drain_with_pad_counts(MeshEngine(holder, mesh), entries)
+    assert pads == {"replayed": 0, "lowered": 55}
+    assert got == want
