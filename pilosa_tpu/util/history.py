@@ -58,7 +58,7 @@ from .stats import (
 )
 
 # The shared presence/time field.  No leading underscore: PQL field
-# names must start with a letter (pql/parser.py _FIELD_RE), and
+# names must start with a letter (pql/parser.py _FIELD), and
 # ``Range(samples=<sid>, ...)`` is the documented query surface.
 SAMPLES_FIELD = "samples"
 # Fixed-point factor for stored values (reads report it back).

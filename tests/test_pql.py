@@ -177,6 +177,54 @@ def test_parse_errors():
         pql.parse("42")
 
 
+@pytest.mark.parametrize(
+    "text,keys,field",
+    [
+        ("Row(from=1, f=2)", ["from", "f"], "from"),
+        ("Rows(field=f, previous=3, limit=2)", ["field", "previous", "limit"], "field"),
+        ("Set(1, f=2, 2010-01-01T00:00)", ["_col", "f", "_timestamp"], "f"),
+        ("Set(_col=2, f=10)", ["_col", "f"], "f"),
+        ("SetRowAttrs(f, 1, a=1)", ["_field", "_row", "a"], "a"),
+        ("TopN(f, Row(g=1), n=5)", ["_field", "n"], "n"),
+        ("Range(f=1, 2010-01-01T00:00, 2010-01-02T00:00)", ["f", "_start", "_end"], "f"),
+        ("GroupBy(Rows(field=a), limit=7, filter=Row(f=10))", ["limit", "filter"], "limit"),
+        ("Row(_row=1, g=2)", ["_row", "g"], "g"),
+    ],
+)
+def test_args_keep_the_order_of_the_text(text, keys, field):
+    """Call.args holds the keys in the order the text gives them (the
+    positional ones where their form puts them), and field_arg() is the
+    first key that is not reserved."""
+    c = one(text)
+    assert list(c.args) == keys
+    assert c.field_arg() == field
+
+
+@pytest.mark.parametrize(
+    "text,where",
+    [
+        ("Row(f=", "line 1, col 7"),
+        ("Row(f=10", "line 1, col 9"),
+        ("42", "line 1, col 1"),
+        ("Row(f=1)\nRow(g=)", "line 2, col 7"),
+        ("Row(f=1)\n  Row(g=1, h)", "line 2, col 12"),
+        ("Count(Row(f=1),)", "line 1, col 16"),
+        ("Row(f=[1,])", "line 1, col 10"),
+        ("Set(1, a=4, 2017-94-03T19:34)", "line 1, col 11"),
+    ],
+)
+def test_parse_errors_name_line_and_column(text, where):
+    with pytest.raises(pql.ParseError, match=f"at {where}$"):
+        pql.parse(text)
+
+
+def test_unterminated_string_is_named():
+    with pytest.raises(pql.ParseError, match="^unterminated string at line 1, col 7$"):
+        pql.parse("Row(f='open)")
+    with pytest.raises(pql.ParseError, match="^unterminated string at line 1, col 5$"):
+        pql.parse('Set("open, f=1)')
+
+
 def test_write_call_n():
     q = pql.parse("Set(1, f=1) Row(f=1) Clear(1, f=1)")
     assert q.write_call_n() == 2

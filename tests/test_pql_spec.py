@@ -100,7 +100,7 @@ ERRORS = [
     "query", [q for _, q in ERRORS], ids=[name for name, _ in ERRORS]
 )
 def test_peg_errors(query):
-    with pytest.raises(pql.ParseError):
+    with pytest.raises(pql.ParseError, match=r" at line \d+, col \d+$"):
         pql.parse(query)
 
 
