@@ -709,9 +709,7 @@ class Executor:
 
     def _parse_cached(self, s: str) -> Query:
         """PQL text -> calls: the ``parse`` stage, parse-cache hits
-        included and tagged.  The plan the text runs under takes the
-        sequence miner's signature from the calls here, before any
-        in-place key translation (``QueryPlan.sign``)."""
+        included and tagged."""
         with tracing_mod.stage("parse", cache="hit") as st:
             with self._parse_lock:
                 q = self._parse_cache.get(s)
@@ -725,9 +723,6 @@ class Executor:
                         self._parse_cache[s] = q
                         while len(self._parse_cache) > self._PARSE_CACHE_MAX:
                             self._parse_cache.popitem(last=False)
-        plan = plans_mod.current_plan()
-        if plan is not None:
-            plan.sign(s, q)
         return q
 
     # -- entry point (executor.go Execute :84) -----------------------------

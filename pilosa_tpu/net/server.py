@@ -227,9 +227,6 @@ class Handler:
         r("GET", "/debug/faults", self._debug_faults_get)
         r("POST", "/debug/faults", self._debug_faults_post)
         r("GET", "/debug/history", self._debug_history)
-        r("GET", "/debug/heat", self._debug_heat)
-        r("GET", "/debug/sequences", self._debug_sequences)
-        r("GET", "/debug/prefetch_advice", self._debug_prefetch_advice)
         r("GET", "/debug/flightrecorder", self._debug_flightrecorder)
         r("GET", "/debug/pprof", self._debug_pprof)
         r("GET", "/debug/pprof/goroutine", self._debug_pprof)
@@ -1084,52 +1081,6 @@ class Handler:
             step=_num("step"),
             label=q.get("label", [None])[0],
         )
-
-    def _debug_heat(self, q, b, **kw):
-        """GET /debug/heat: per-(index, field) working-set heat tables —
-        top-K hot rows and 2KiB blocks by EWMA heat, each row flagged
-        resident-vs-host, plus the residency gap in bytes
-        (docs/observability.md "Working-set heat & sequences").
-        Filters: ?index= ?field= (substring-exact table keys),
-        ?topk=N rows/blocks per table (default 10)."""
-        from ..util import heat as heat_mod
-
-        try:
-            topk = int(q.get("topk", ["10"])[0])
-        except ValueError:
-            raise ValueError("topk must be an integer")
-        heat_mod.HEAT.refresh_gauges()
-        return heat_mod.HEAT.to_doc(
-            index=q.get("index", [None])[0],
-            field=q.get("field", [None])[0],
-            topk=topk,
-        )
-
-    def _debug_sequences(self, q, b, **kw):
-        """GET /debug/sequences: the first-order plan-signature
-        transition model the sequence miner learns online (same
-        canonicalization as /debug/plans subtrees) — per-signature
-        next-signature probabilities and average gaps.  ?top=N edges
-        per signature (default 5)."""
-        from ..util import plan_miner
-
-        try:
-            top = int(q.get("top", ["5"])[0])
-        except ValueError:
-            raise ValueError("top must be an integer")
-        return plan_miner.MINER.to_doc(top=top)
-
-    def _debug_prefetch_advice(self, q, b, **kw):
-        """GET /debug/prefetch_advice: the prefetch advisor's
-        outstanding advice set (predicted-next signature + concrete
-        (index, field, view, rows) promotion hints) and its running
-        self-score — hit/miss counts of advised rows against the rows
-        the next query actually touched.  Report-only this release:
-        drivesPromotions=false until the advisor feeds
-        ResidencyManager."""
-        from ..parallel.advisor import ADVISOR
-
-        return ADVISOR.to_doc()
 
     def _debug_flightrecorder(self, q, b, **kw):
         """GET /debug/flightrecorder: capture a flight-recorder bundle

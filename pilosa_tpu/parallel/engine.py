@@ -44,7 +44,6 @@ from ..core.view import VIEW_STANDARD, view_bsi_name
 from ..ops import bitops
 from ..pql import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ, Call, Condition
 from ..util import events as events_mod
-from ..util import heat as heat_mod
 from ..util import plans as plans_mod
 from ..util import tracing
 from ..util.stats import (
@@ -862,18 +861,6 @@ class MeshEngine:
         # into background working-set promotions + host-tier fallbacks
         # instead of blocking uploads or OOMs.
         self.residency = residency_mod.ResidencyManager(self)
-        # Working-set heat (docs/observability.md): the recorder asks
-        # this engine for the resident-vs-host split behind the
-        # /debug/heat tables and the pilosa_engine_residency_gap_bytes
-        # gauge.  Weak binding — heat must not pin a closed engine.
-        heat_mod.HEAT.bind_engine(self)
-        # Promote-ahead (docs/residency.md "Predictive promotion &
-        # block pool"): the prefetch advisor drives its hints into
-        # residency.request(cause="advisor") through this binding.
-        # Weak, like HEAT — advice must not pin a closed engine.
-        from . import advisor as advisor_mod
-
-        advisor_mod.ADVISOR.bind_engine(self)
         # Warm-start admissions count as promotions with their own
         # cause label (the residency worker owns cause=reactive).
         self._promotions_warm_counter = REGISTRY.counter(
@@ -1457,69 +1444,6 @@ class MeshEngine:
         resident-block summaries the residency layer keeps per stack."""
         return bitops.WORDS * 4 + 16
 
-    def residency_row_split(self, key, rows):
-        """(resident_row_subset, per_row_device_bytes) for ``key`` over
-        ``rows`` — the heat recorder's resident-vs-host split and the
-        pilosa_engine_residency_gap_bytes numerator.  Read-only: a
-        quick row_index membership walk under the stacks lock, never a
-        build or sync."""
-        with self._stacks_lock:
-            st = self._stacks.get(key)
-            if st is not None:
-                resident = {r for r in rows if int(r) in st.row_index}
-                S = (
-                    int(st.matrix.shape[1])
-                    if hasattr(st.matrix, "shape")
-                    else pad_shards(len(st.shards), self.mesh)
-                )
-                return resident, S * self._row_shard_bytes()
-        # No stack at all: nothing resident; price a row off the live
-        # canonical shard axis (outside the lock — canonical_shards is
-        # its own cached walk).
-        canonical = self.canonical_shards(key[0])
-        S = pad_shards(len(canonical), self.mesh) if canonical else 0
-        return set(), S * self._row_shard_bytes()
-
-    # -- working-set touch notes (util/heat.py) -----------------------------
-
-    @staticmethod
-    def _touch_of(key, st, rows):
-        """One heat-note touch tuple for ``key``: rows (None = whole
-        stack) plus their exact occupied-block count and the OR of
-        their 64-bit occupancy masks, read from the stack's host-side
-        summary (no device traffic)."""
-        if rows is None:
-            return (key[0], key[1], key[2], None, 0, 0)
-        rows_t = tuple(sorted(int(r) for r in rows))
-        n_blocks = 0
-        mask = 0
-        if st is not None and st.occ is not None:
-            R = st.occ.shape[0]
-            for r in rows_t:
-                ridx = st.row_index.get(r)
-                if ridx is None or ridx >= R:
-                    continue
-                m = int(np.bitwise_or.reduce(st.occ[ridx]))
-                n_blocks += m.bit_count()
-                mask |= m
-        return (key[0], key[1], key[2], rows_t, n_blocks, mask)
-
-    def _note_touches(self, lw: "_Lowering"):
-        """Stamp the dispatch note with the (index, field, view, rows,
-        blocks) touches this lowering's row hints resolve to — the heat
-        recorder's input.  Early-out when plans or heat are disabled so
-        the serving path pays nothing."""
-        if not (plans_mod.ENABLED and heat_mod.HEAT.enabled):
-            return
-        if not lw.row_hints:
-            return
-        touches = [
-            self._touch_of(key, lw._stacks.get(key), rows)
-            for key, rows in lw.row_hints.items()
-        ]
-        if touches:
-            plans_mod.note_dispatch(touches=touches)
-
     def _row_universe(self, index, field, view, canonical) -> List[int]:
         """Sorted distinct row ids across the view's local fragments —
         the denominator of partial residency and the input to the
@@ -1546,15 +1470,9 @@ class MeshEngine:
         ``need_bytes`` more fits under ``max_resident_bytes`` (a SOFT
         working-set target — when nothing more is evictable the caller
         still admits, trusting the next pressure cycle to converge).
-        Victims are priced by predicted-NEXT-touch blended with the
-        backward device-cost EWMA (lexicographic: a stack the prefetch
-        advisor's outstanding advice names is predicted to serve the
-        next query and survives any non-predicted stack — even a
-        hot-now one that won't recur; within each class the per-tenant
-        EWMA of the index orders victims, cold tenants first — PR 9's
-        measured signal — with LRU breaking ties).  Cold start (no
-        outstanding advice) reduces exactly to the backward ordering.
-        Runs under the engine locks."""
+        Victims are priced by the per-tenant device-cost EWMA of their
+        index, cold tenants first (the tenant ledger's measure), with LRU
+        breaking ties.  Runs under the engine locks."""
 
         def fits():
             return (
@@ -1565,20 +1483,10 @@ class MeshEngine:
 
         if fits():
             return True
-        try:
-            from . import advisor as advisor_mod
-
-            predicted = advisor_mod.ADVISOR.predicted_keys()
-        except Exception:  # noqa: BLE001 — pricing must never fail
-            predicted = frozenset()
         lru_pos = {k: i for i, k in enumerate(self._stacks)}
         order = sorted(
             (k for k in self._stacks if k not in protect),
-            key=lambda k: (
-                1 if k in predicted else 0,
-                self._index_cost(k[0]),
-                lru_pos[k],
-            ),
+            key=lambda k: (self._index_cost(k[0]), lru_pos[k]),
         )
         for k in order:
             if fits():
@@ -1611,18 +1519,10 @@ class MeshEngine:
             self.host_fallbacks += 1
             self.residency.note_host_fallback()
         self.residency.request(key, rows, cause="reactive")
-        # The miss IS a working-set touch: the heat recorder sees the
-        # rows this query wanted even though no device bytes moved, so
-        # the residency-gap gauge rises the moment traffic outruns
-        # promotion (not only once promotions land).
         plans_mod.note_dispatch(
             path="host_fallback",
             stack="/".join(key),
             resident_fraction=round(fraction, 4),
-            touches=[(
-                key[0], key[1], key[2],
-                None if rows is None else tuple(sorted(rows)), 0, 0,
-            )],
         )
         raise ResidencyMiss(msg, key=key, resident_fraction=fraction)
 
@@ -2061,8 +1961,7 @@ class MeshEngine:
         # Partial promotion: a packed 2 KiB-block device POOL holding
         # only the promoted rows' OCCUPIED blocks — partial HBM is
         # charged per block, and the compile key depends only on the
-        # pool-capacity tier (docs/residency.md "Predictive promotion &
-        # block pool").
+        # pool-capacity tier (docs/residency.md "Block pool").
         uni = set(universe)
         target = sorted(r for r in want if r in uni)
         absent = {r for r in want if r not in uni}
@@ -2103,7 +2002,7 @@ class MeshEngine:
         credited = True
         try:
             with self._dispatch_lock, self._stacks_lock:
-                # Make room up front (next-touch priced); the in-flight
+                # Make room up front (cost priced); the in-flight
                 # bytes are already counted so concurrent admissions
                 # can't stack on top of this upload.
                 self._evict_for(0, protect=frozenset((key,)))
@@ -3320,7 +3219,6 @@ class MeshEngine:
             mask = self._mask_words(shards, canonical)
             plan = self._sparse_plan(prog, lw, shards, canonical)
             self._note_fused_dispatch()
-            self._note_touches(lw)
             planes = self._hint_planes(lw.row_hints)
             per_request = (planes[0] * live, planes[1] * live)
         if plan is not None:
@@ -4116,7 +4014,6 @@ class MeshEngine:
                 )
             lw.finish()
             self._note_fused_dispatch()
-            self._note_touches(lw)
         plans_mod.note_dispatch(
             op="Count", path="dense_batch", fused=True,
             cse_unique=len(u_calls), cse_deduped=deduped,
@@ -5296,14 +5193,6 @@ class MeshEngine:
             METRIC_MESH_SHARDS_PER_DEVICE,
             pad_shards(widest, self.mesh) // n_dev if widest else 0,
         )
-        # Working-set heat gauges (tracked rows + residency gap): the
-        # recorder walks its tables and asks this engine for the
-        # resident split — refreshed at scrape so /metrics and
-        # /debug/heat never disagree.
-        try:
-            heat_mod.HEAT.refresh_gauges()
-        except Exception:  # noqa: BLE001 — telemetry never fails a scrape
-            pass
 
     def _working_set_snapshot(self) -> dict:
         """Per-index resident-vs-total working-set accounting for

@@ -40,7 +40,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-from ..util import heat as heat_mod
 from ..util import plans as plans_mod
 from ..util import tracing
 from . import kernels
@@ -393,9 +392,9 @@ def _item_hints(engine, index, spec) -> dict:
     """Row-hint map of ONE fused item: every (index, field, view) stack
     the item reads -> the row ids it reads there (None = the whole
     stack, e.g. a BSI plane walk or a TopN candidate sweep).  Feeds
-    both the heat touches (_item_touches) and the drain lowering's
-    ``row_hints`` — so a fused item missing a partial stack requests
-    promotion of exactly its rows, not the full stack."""
+    the drain lowering's ``row_hints`` — so a fused item missing a
+    partial stack requests promotion of exactly its rows, not the full
+    stack."""
     from ..core.view import VIEW_STANDARD, view_bsi_name
 
     kind = spec["kind"]
@@ -434,18 +433,6 @@ def merge_hints(into: dict, hints: dict) -> dict:
         else:
             into.setdefault(key, set()).update(rows)
     return into
-
-
-def _item_touches(engine, index, spec, stacks):
-    """Working-set touches of ONE fused item (util/heat.py note
-    format), derived from the same hint map the lowering used.
-    ``stacks`` is the drain's merged (index, field, view) -> stack map
-    so occupied-block counts come from the same summaries the dispatch
-    used."""
-    return [
-        engine._touch_of(key, stacks.get(key), rows)
-        for key, rows in _item_hints(engine, index, spec).items()
-    ]
 
 
 def build(engine, entries: List[tuple]) -> FusedPlan:
@@ -862,13 +849,6 @@ def build(engine, entries: List[tuple]) -> FusedPlan:
     masks_evaluated = len(slots)
     masks_referenced = refs_total[0]
     indexes = sorted({idx for idx, _, _ in entries})
-    # Per-item working-set touches (util/heat.py): resolved against the
-    # drain's merged stack map so peeled and fused items alike report
-    # exact occupied blocks.  The SHARED dispatch note stays touch-free
-    # — the batcher overlays each rider's item note onto its divided
-    # copy, so every plan carries only ITS OWN touches.
-    stacks_all = {**peel_stacks, **lw._stacks}
-    note_touches = plans_mod.ENABLED and heat_mod.HEAT.enabled
     for i in range(n_items):
         if routes[i] is None or routes[i][0] == "error":
             continue
@@ -888,15 +868,6 @@ def build(engine, entries: List[tuple]) -> FusedPlan:
             note.update(sparse_notes[i])
             note["op"] = "Count"
             note["path"] = "sparse"
-        if note_touches:
-            try:
-                touches = _item_touches(
-                    engine, entries[i][0], entries[i][1], stacks_all
-                )
-                if touches:
-                    note["touches"] = touches
-            except Exception:  # noqa: BLE001 — telemetry only
-                pass
         item_notes[i] = note
 
     # -- tier padding (compile-key discipline) ------------------------------

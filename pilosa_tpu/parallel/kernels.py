@@ -87,7 +87,7 @@ def apply_prog(prog, operands, slots=None):
         return jax.lax.dynamic_index_in_dim(mat, idx, axis=0, keepdims=False)
     if kind == "rowb":
         # Block-pool row gather (tiered residency, docs/residency.md
-        # "Predictive promotion & block pool"): the matrix is a packed
+        # "Block pool"): the matrix is a packed
         # 2 KiB-block pool uint32[Pcap, S_local, OCC_BLOCK_WORDS] and
         # prog[2] names a replicated int32[OCC_BLOCKS] slot vector
         # mapping each of the row's occupancy blocks to its pool slot.

@@ -88,7 +88,6 @@ class ResidencyManager:
         self.partial_promotions = 0
         self.declined = 0
         self.dropped = 0  # queue-overflow requests (host tier serves)
-        self.advisor_deferred = 0  # speculative requests refused under backlog
         self.promoted_bytes = 0
         self.promote_seconds = 0.0
         # Full-promotion counters resolve per cause at inc time (the
@@ -107,7 +106,7 @@ class ResidencyManager:
         """Enqueue (or merge into) a promotion for ``key``.  ``rows`` is
         the row-id working set the triggering query touched; None means
         the whole stack is required.  ``cause`` labels the promotion's
-        origin ("reactive" | "warm_start" | "advisor") and ``trace_id``
+        origin ("reactive" | "warm_start") and ``trace_id``
         joins it to the triggering query's trace (defaulting to the
         ambient span, so an engine miss inherits its query's trace
         without plumbing).  Returns False when the request was absorbed
@@ -134,18 +133,7 @@ class ResidencyManager:
                     cur[0].update(rows)
                 if not cur[2] and trace_id:
                     cur[2] = trace_id
-                if cause != "advisor" and cur[1] == "advisor":
-                    # A demand miss caught up with speculation: the
-                    # merged promotion is demand now (worker ordering +
-                    # the journal's cause both follow).
-                    cur[1] = cause
             else:
-                if cause == "advisor" and len(self._pending) >= MAX_PENDING // 2:
-                    # Speculative requests only get the queue's front
-                    # half: under backlog, promote-ahead yields before
-                    # it can crowd out a single demand promotion.
-                    self.advisor_deferred += 1
-                    return False
                 if len(self._pending) >= MAX_PENDING:
                     self.dropped += 1
                     return False
@@ -200,16 +188,7 @@ class ResidencyManager:
                     self._cv.wait()
                 if self._closed:
                     return
-                # Demand first: speculative (advisor) promotions only
-                # run when no reactive/warm-start request is waiting —
-                # promote-ahead competes for budget, never for the
-                # worker's next slot.
-                key = next(
-                    (k for k, v in self._pending.items() if v[1] != "advisor"),
-                    None,
-                )
-                if key is None:
-                    key = next(iter(self._pending))
+                key = next(iter(self._pending))
                 rows, cause, trace_id = self._pending.pop(key)
                 self._busy = True
             try:
@@ -269,7 +248,6 @@ class ResidencyManager:
                 "partialPromotions": self.partial_promotions,
                 "declined": self.declined,
                 "dropped": self.dropped,
-                "advisorDeferred": self.advisor_deferred,
                 "promotedBytes": self.promoted_bytes,
                 "promoteSeconds": round(self.promote_seconds, 6),
                 "cooldowns": len(self._cooldown),

@@ -786,13 +786,6 @@ class Server:
             retention=cfg.obs_retention,
             snapshot_fn=snapshot_fn,
         )
-        # Pull-time gauges refresh just before each sample so the
-        # _system history tracks them at tick granularity — the heat
-        # recorder's residency-gap gauge is what makes "gap over time"
-        # PQL-queryable (docs/observability.md).
-        from .util.heat import HEAT
-
-        self.api.history.pre_tick_hooks.append(HEAT.refresh_gauges)
         self.api.slo = SLOWatcher(
             self.api,
             self.api.history,

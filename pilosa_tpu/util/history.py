@@ -148,13 +148,6 @@ class HistorySampler:
         # clear+set import.
         self._seen_slots: set = set()
         self.last_tick_ts = 0.0
-        # Callables run (fenced) at the top of every tick BEFORE the
-        # registry snapshot — pull-time gauges that would otherwise only
-        # refresh at /metrics scrapes (the working-set heat gauges:
-        # tracked rows + residency gap) get a current value in every
-        # sampled point, so gap-over-time is PQL-queryable at the
-        # sampler's full resolution.
-        self.pre_tick_hooks: list = []
         self._c_ticks = REGISTRY.counter(METRIC_HISTORY_TICKS)
         self._c_samples = REGISTRY.counter(METRIC_HISTORY_SAMPLES)
         self._c_views_dropped = REGISTRY.counter(METRIC_HISTORY_VIEWS_DROPPED)
@@ -268,11 +261,6 @@ class HistorySampler:
             now = self._now()
         if not self._schema_ok:
             self.ensure_schema()
-        for hook in self.pre_tick_hooks:
-            try:
-                hook()
-            except Exception:  # noqa: BLE001 — a hook never fails a tick
-                pass
         snap = self._snapshot_fn()
         flat = _flatten_counters(snap)
         prev = self._prev

@@ -48,7 +48,6 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-from . import plan_miner
 from .stats import (
     METRIC_CACHE_ENTRIES,
     METRIC_CACHE_RECALC,
@@ -163,7 +162,6 @@ class QueryPlan:
         "fanouts",
         "annotations",
         "pipelined",
-        "signature",
     )
 
     def __init__(self, index: str, query: str, tenant: str = "default",
@@ -187,24 +185,6 @@ class QueryPlan:
         self.fanouts: List[tuple] = []
         self.annotations: List[str] = []
         self.pipelined = False
-        # The sequence miner's key, where ``sign`` had the request's parse.
-        self.signature: Optional[str] = None
-
-    def sign(self, text: str, query):
-        """Take the sequence miner's signature (``plan_miner.signature``)
-        from ``query``, the parse of ``text``, where the executor has
-        just made it: before any in-place key translation, and so that
-        the heat observer has no text to parse again.  Only the plan's
-        own text, uncut, is signed here (a text past the 512-character
-        cut is not the plan's ``query``: the observer signs it from the
-        cut text, as it always did), and no internal index (the
-        observer passes those over)."""
-        if (
-            self.signature is None
-            and (text is self.query or text == self.query)
-            and not self.index.startswith("_")
-        ):
-            self.signature = plan_miner.signature(self.index, text, query)
 
     # -- stamping (hot path: appends only) ---------------------------------
 
@@ -716,29 +696,6 @@ class TenantLedger:
 STORE = PlanStore()
 LEDGER = TenantLedger()
 
-# Finish-side observers: callables invoked with every recorded plan
-# AFTER the ring + ledger update.  This is the one seam the working-set
-# telemetry layer (util/heat.py: heat tables, the sequence miner, the
-# prefetch advisor) hangs off — observers see the SAME plan records the
-# ledger accounts, so derived byte tallies can never drift from the
-# pilosa_tenant_* / bytes-skipped counters.  Observers must be cheap
-# and must never raise (each call is fenced regardless).
-_OBSERVERS: List = []
-
-
-def add_observer(fn):
-    """Register a finish-side plan observer (idempotent per fn)."""
-    if fn not in _OBSERVERS:
-        _OBSERVERS.append(fn)
-
-
-def remove_observer(fn):
-    try:
-        _OBSERVERS.remove(fn)
-    except ValueError:
-        pass
-
-
 def begin(index: str, query: str, tenant: str = "default",
           profile: bool = False) -> Optional[QueryPlan]:
     """A fresh plan, or None when the layer is disabled."""
@@ -748,14 +705,8 @@ def begin(index: str, query: str, tenant: str = "default",
 
 
 def record(plan: Optional[QueryPlan]):
-    """Finish-side entry point: ring + analyzer + tenant ledger +
-    telemetry observers."""
+    """Finish-side entry point: ring + analyzer + tenant ledger."""
     if plan is None:
         return
     STORE.record(plan)
     LEDGER.account(plan)
-    for fn in _OBSERVERS:
-        try:
-            fn(plan)
-        except Exception:  # noqa: BLE001 — telemetry never fails a query
-            pass

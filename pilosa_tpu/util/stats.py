@@ -537,27 +537,8 @@ METRIC_ENGINE_HOST_FALLBACKS = "pilosa_engine_host_fallbacks_total"
 METRIC_ENGINE_RESIDENT_BLOCK_FRACTION = "pilosa_engine_resident_block_fraction"
 # ``pilosa_engine_promotions_total`` carries a {cause=} label naming WHY
 # the stack moved: "reactive" (a query missed and the residency worker
-# chased it), "warm_start" (EWMA-ordered restart admission), "advisor"
-# (reserved — the predictive follow-on promotes ahead of traffic).
-PROMOTION_CAUSES = ("reactive", "warm_start", "advisor")
-# -- working-set telemetry (docs/observability.md) --------------------------
-#   pilosa_engine_heat_tracked_rows         gauge: rows with live heat state
-#                                           across all heat tables
-#   pilosa_engine_residency_gap_bytes       gauge: bytes of HOT rows NOT
-#                                           resident on device — the single
-#                                           number that says "promotion is
-#                                           behind traffic" (0 when the
-#                                           working set is device-resident)
-#   pilosa_advisor_predictions_total        rows the prefetch advisor
-#                                           predicted the next query touches
-#   pilosa_advisor_hits_total               predicted rows the next query
-#                                           actually touched
-#   pilosa_advisor_misses_total             predicted rows it did not
-METRIC_ENGINE_HEAT_TRACKED_ROWS = "pilosa_engine_heat_tracked_rows"
-METRIC_ENGINE_RESIDENCY_GAP = "pilosa_engine_residency_gap_bytes"
-METRIC_ADVISOR_PREDICTIONS = "pilosa_advisor_predictions_total"
-METRIC_ADVISOR_HITS = "pilosa_advisor_hits_total"
-METRIC_ADVISOR_MISSES = "pilosa_advisor_misses_total"
+# chased it) or "warm_start" (EWMA-ordered restart admission).
+PROMOTION_CAUSES = ("reactive", "warm_start")
 METRIC_ENGINE_COMPILE = "pilosa_engine_compile_total"
 METRIC_ENGINE_COMPILE_SECONDS = "pilosa_engine_compile_seconds"
 METRIC_ENGINE_COMPILE_KEYS = "pilosa_engine_compile_cache_keys"
@@ -946,20 +927,6 @@ REGISTRY.counter(
     help="Queries served from the host tier while their stack promotes",
 )
 REGISTRY.set_gauge(METRIC_ENGINE_RESIDENT_BLOCK_FRACTION, 1.0)
-REGISTRY.set_gauge(METRIC_ENGINE_HEAT_TRACKED_ROWS, 0)
-REGISTRY.set_gauge(METRIC_ENGINE_RESIDENCY_GAP, 0)
-REGISTRY.counter(
-    METRIC_ADVISOR_PREDICTIONS,
-    help="Rows the prefetch advisor predicted the next query would touch",
-)
-REGISTRY.counter(
-    METRIC_ADVISOR_HITS,
-    help="Advisor-predicted rows the next query actually touched",
-)
-REGISTRY.counter(
-    METRIC_ADVISOR_MISSES,
-    help="Advisor-predicted rows the next query did not touch",
-)
 REGISTRY.counter(
     METRIC_ENGINE_COMPILE, help="XLA backend compiles observed in-process"
 )

@@ -13,13 +13,7 @@ per-query execution paid, and the evaluations a whole-program fuse
 would have saved — the same canonicalization the fused planner uses,
 so the projection is directly comparable to the live
 ``pilosa_engine_fused_program_masks_{evaluated,referenced}_total``
-counters after the traffic rides the fused path.
-
-``--sequences`` replays the same dump through the access-sequence
-transition model instead (the one the live ``/debug/sequences`` learns
-online), reporting per-signature next-signature probabilities — the
-offline view of what the prefetch advisor would predict
-(docs/observability.md "Working-set heat & sequences")."""
+counters after the traffic rides the fused path."""
 
 from __future__ import annotations
 
@@ -49,14 +43,6 @@ def main(argv=None) -> int:
                     help="top shared subtrees to list (default 20)")
     ap.add_argument("--json", action="store_true",
                     help="emit the raw JSON report")
-    ap.add_argument(
-        "--sequences", action="store_true",
-        help="mine access SEQUENCES instead of shared subtrees: replay "
-        "the dump through a fresh first-order transition model (same "
-        "signatures the live /debug/sequences learns) and report "
-        "per-signature next-signature probabilities; --window is the "
-        "transition window (default 5s for sequences)",
-    )
     args = ap.parse_args(argv)
 
     if args.url:
@@ -67,19 +53,6 @@ def main(argv=None) -> int:
         with open(args.file) as f:
             doc = json.load(f)
     plans = plan_miner.flatten_plans(doc)
-    if args.sequences:
-        window = args.window if "--window" in (argv or sys.argv) else (
-            plan_miner.WINDOW_S
-        )
-        report = plan_miner.mine_sequences(
-            plans, window_s=window, top=args.top
-        )
-        if args.json:
-            json.dump(report, sys.stdout, indent=2)
-            print()
-        else:
-            print(plan_miner.render_sequences(report))
-        return 0
     report = plan_miner.mine(plans, window_s=args.window, top=args.top)
     if args.json:
         json.dump(report, sys.stdout, indent=2)

@@ -116,8 +116,8 @@ def multiprocess_collectives() -> Tuple[bool, str]:
 
 def require_multiprocess_collectives():
     """Skip the calling test when this container's jaxlib cannot run
-    cross-process collectives on its backend (known environmental limit
-    — see ROADMAP.md 'durability + elasticity' note)."""
+    cross-process collectives on its backend (a known environmental
+    limit: see the module docstring)."""
     ok, reason = multiprocess_collectives()
     if not ok:
         pytest.skip(

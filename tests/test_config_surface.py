@@ -26,7 +26,6 @@ from pilosa_tpu.parallel import MeshEngine, make_mesh
 from pilosa_tpu.parallel.batcher import CountBatcher
 from pilosa_tpu.parallel.engine import DEFAULT_RESULT_MEMO
 from pilosa_tpu.util import fanout
-from pilosa_tpu.util.heat import HeatRecorder
 
 PACKAGE = pathlib.Path(__file__).resolve().parent.parent / "pilosa_tpu"
 
@@ -105,7 +104,6 @@ RETIRED = [
     ("PILOSA_PIPELINE_DEPTH", "9",
      _engine(lambda e: CountBatcher(e).max_inflight),
      CountBatcher.DEFAULT_INFLIGHT),
-    ("PILOSA_HEAT", "0", lambda: HeatRecorder().enabled, True),
     ("PILOSA_PLANS", "0", None, True),
     ("PILOSA_NATIVE_MERGE", "0",
      lambda: native.load_merge()

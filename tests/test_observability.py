@@ -435,11 +435,6 @@ SURFACE_SERIES = [
     "pilosa_engine_compile_total",
     "pilosa_engine_compile_seconds",
     "pilosa_engine_compile_cache_keys",
-    "pilosa_engine_heat_tracked_rows",
-    "pilosa_engine_residency_gap_bytes",
-    "pilosa_advisor_predictions_total",
-    "pilosa_advisor_hits_total",
-    "pilosa_advisor_misses_total",
     # docs/mesh.md
     "pilosa_mesh_devices",
     "pilosa_mesh_local_devices",

@@ -1,9 +1,7 @@
 """A request's PQL text is parsed once: the deferred attempt parses it
 and decides on the calls; a request it declines goes to the sync path
 with that parse (and the attempt's span and plan), offered to the
-deferred path once; the plan carries the sequence miner's signature,
-byte for byte what the heat observer made from the text
-(``pilosa_pql_parses_total`` counts every parse)."""
+deferred path once (``pilosa_pql_parses_total`` counts every parse)."""
 
 import json
 import urllib.request
@@ -19,7 +17,7 @@ from pilosa_tpu.executor import Executor
 from pilosa_tpu.net.wire import response_to_json
 from pilosa_tpu.ops import SHARD_WIDTH
 from pilosa_tpu.parallel import MeshEngine, make_mesh
-from pilosa_tpu.util import plan_miner, plans
+from pilosa_tpu.util import plans
 from pilosa_tpu.util.stats import METRIC_PQL_PARSES, REGISTRY
 
 SHARDS = 4
@@ -73,43 +71,6 @@ def _parses():
     return REGISTRY.counter(METRIC_PQL_PARSES).get()
 
 
-# name -> (index, text): every text first-seen where it is sent.
-LONG = "Count(Union(" + ", ".join(f"Row(f={10 + k % 4})" for k in range(40)) + "))"
-CORPUS = {
-    "count_tree": ("i", "Count(Intersect(Row(f=10), Union(Row(g=1), Row(g=2))))"),
-    "two_counts": ("i", "Count(Xor(Row(f=11), Row(g=3)))Count(Row(f=12))"),
-    "three_sums": ("i", "Sum(Intersect(Row(f=10), Range(v > 7)), field=v)"
-                        "Sum(Union(Row(f=11), Row(g=2)), field=v)"
-                        "Sum(Difference(Row(f=12), Range(v < 30)), field=v)"),
-    "groupby": ("i", "GroupBy(Rows(field=f, previous=10, limit=2), Rows(field=g, limit=2), "
-                     "filter=Intersect(Row(g=1), Range(v > 3)), aggregate=Sum(field=v))"),
-    "topn": ("i", "TopN(f, Intersect(Row(g=2), Range(v >< [5, 200])), n=3)"),
-    "keyed_row": ("k", 'Count(Row(kf="red"))'),
-    "keyed_bitmap": ("k", 'Row(kf="blue")'),
-    "past_the_cut": ("i", LONG),
-}
-
-
-@pytest.mark.parametrize("name", list(CORPUS))
-def test_the_carried_signature_is_the_texts(served, name):
-    """The signature the plan carries from the request's parse, before
-    the in-place key translation, is the one the observer makes from
-    the recorded text, byte for byte; a text past the plan's 512
-    characters carries none and is signed from the cut text as before."""
-    _eng, _api, uris = served
-    index, text = CORPUS[name]
-    doc = _post(uris["async"], index, text)
-    plan = plans.STORE.find(doc["traceID"])
-    plan_miner._SIG_CACHE.clear()
-    from_text = plan_miner.signature(index, plan.query)
-    if len(text) > 512:
-        assert plan.signature is None
-        assert from_text == f"{index}|{plan.query}"  # the cut text does not parse
-    else:
-        assert plan.signature == from_text
-    assert plan_miner.MINER._last_sig == from_text
-
-
 REQUESTS = {
     "deferred_count": ("i", "Count(Intersect(Row(f={a}), Row(g={b})))", False),
     "declined_sums": ("i", "Sum(Row(f={a}), field=v)Sum(Row(g={b}), field=v)"
@@ -117,6 +78,9 @@ REQUESTS = {
     "aggregated_groupby": ("i", "GroupBy(Rows(field=f, previous={a}), Rows(field=g), "
                                 "filter=Range(v > {b}), aggregate=Sum(field=v))", True),
     "keyed_count": ("k", 'Count(Union(Row(kf="red"), Row(kf="{c}")))', False),
+    # Longer than the plan's 512-character cut of the text.
+    "past_the_cut": ("i", "Count(Union(" + ", ".join(
+        ["Row(f={a})", "Row(g={b})"] * 25) + "))", False),
 }
 
 
@@ -146,7 +110,6 @@ def test_a_request_is_parsed_once(served, monkeypatch, backend, kind):
     assert offers == [declined]
     plan = plans.STORE.find(doc["traceID"])
     assert plan.pipelined is not declined
-    assert plan.signature is not None
     # The reply is the sync path's answer to the text.
     want = response_to_json(api.query(QueryRequest(index, body)))
     assert json.dumps(doc["results"]) == json.dumps(want["results"])

@@ -28,8 +28,9 @@ from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.parallel import MeshEngine, make_mesh
 from pilosa_tpu.parallel.errors import PeerlessMeshError, ResidencyMiss
+from pilosa_tpu.parallel.residency import ResidencyManager
 from pilosa_tpu.util import plans as plans_mod
-from pilosa_tpu.util.stats import REGISTRY
+from pilosa_tpu.util.stats import METRIC_ENGINE_PROMOTIONS, REGISTRY
 
 # One (row, shard) of device words + the occupancy/block-mask summaries
 # (engine._row_shard_bytes): the sizing unit for budgets below.
@@ -426,10 +427,9 @@ def test_block_pool_fuzz_promote_evict_sync(holder, mesh1):
 
 
 def test_write_during_promote_ahead_race(holder, mesh1):
-    """ISSUE 20 satellite: a write landing during an ADVISOR-driven
-    speculative promotion reconciles exactly like a demand one (token
-    re-check + incremental sync), and the journal records the
-    promotion with cause="advisor"."""
+    """A write landing during a block-pool promotion that no query is
+    waiting on reconciles through the token re-check + incremental
+    sync, and the journal records the promotion with its cause."""
     from pilosa_tpu.util.events import EventJournal
 
     idx = build_oversub(holder)
@@ -452,65 +452,20 @@ def test_write_during_promote_ahead_race(holder, mesh1):
         return out
 
     eng._assemble_pool_chunk = racing
-    # The promote-ahead path: a speculative request, no query driving it.
+    # A request with no query driving it: nothing serves from the host
+    # tier meanwhile, so only the commit's token gate sees the write.
     assert eng.residency.request(
-        ("i", "f", "standard"), {10, 11}, cause="advisor"
+        ("i", "f", "standard"), {10, 11}, cause="reactive"
     )
     assert eng.residency.flush(30.0)
     assert wrote.is_set()
     promos = journal.events(type="engine.promotion")
-    assert any(e.fields.get("cause") == "advisor" for e in promos), promos
+    assert any(e.fields.get("cause") == "reactive" for e in promos), promos
     # The first query reconciles through the token gate and serves the
     # post-write truth, never the pre-write snapshot the upload read.
     want = ex_host.execute("i", QUERIES[0]).results[0]
     assert ex.execute("i", QUERIES[0]).results[0] == want
     eng.close()
-
-
-def test_next_touch_eviction_prefers_predicted_stack(mesh1):
-    """ISSUE 20 satellite, the eviction-order differential: with no
-    outstanding advice the pricer reduces to the legacy cost/LRU blend
-    (cheapest tenant evicted first); with advice naming a stack, that
-    stack survives even though legacy pricing would evict it first,
-    and the non-predicted one goes instead."""
-    from pilosa_tpu.parallel.advisor import ADVISOR
-
-    h = Holder()
-    h.open()
-    for name in ("p1", "p2"):
-        h.create_index(name).create_field("f").import_bulk([1], [0])
-    h.index("p1").create_field("g").import_bulk([1], [0])
-    costs = {"p1": 0.0, "p2": 5.0}  # legacy order evicts p1 first
-
-    def admit_third(budget):
-        eng = MeshEngine(h, mesh1, max_resident_bytes=budget)
-        eng.cost_of_index = lambda index: costs.get(index, 0.0)
-        eng.field_stack("p1", "f", "standard")
-        eng.field_stack("p2", "f", "standard")
-        assert len(eng._stacks) == 2
-        eng.field_stack("p1", "g", "standard")
-        return eng
-
-    budget = 2 * ROW_SHARD + 4096
-    ADVISOR.reset()
-    eng = admit_third(budget)  # cold start: legacy blend
-    assert ("p1", "f", "standard") not in eng._stacks
-    assert ("p2", "f", "standard") in eng._stacks
-    eng.close()
-
-    ADVISOR.reset()
-    with ADVISOR._lock:
-        # Outstanding advice predicts p1/f serves the next query.
-        ADVISOR._outstanding = (
-            "sig", 1.0, {("p1", "f", "standard"): frozenset({1})}
-        )
-    try:
-        eng = admit_third(budget)
-        assert ("p1", "f", "standard") in eng._stacks  # predicted survives
-        assert ("p2", "f", "standard") not in eng._stacks
-        eng.close()
-    finally:
-        ADVISOR.reset()
 
 
 def test_promotion_declined_cooldown(holder, mesh1):
@@ -543,21 +498,15 @@ def test_promotion_declined_cooldown(holder, mesh1):
     eng.close()
 
 
-@pytest.mark.parametrize("drive", [False, True], ids=["demand", "advisor"])
-def test_promote_ahead_spares_host_fallbacks(mesh1, drive):
-    """The promote-ahead chain end to end, as an A/B at one budget: two
-    dashboards over disjoint fields alternate under a budget that holds
-    one block pool and a half, so every swing needs a promotion.  On
-    demand alone every query of the graded cycles falls back to the host
-    tier; once the miner has learned the alternation the advisor promotes
-    the other dashboard's rows ahead of its query and fewer fall back.
-    Every answer is the NumPy popcount's on both arms."""
+def test_promote_ahead_spares_host_fallbacks(mesh1):
+    """Promotion on demand alone, at a budget that holds one block pool
+    and a half: two dashboards over disjoint fields alternate, so every
+    swing needs a promotion and every query of the graded cycles falls
+    back to the host tier, with only reactive promotions journaled.
+    Every answer is the NumPy popcount's."""
     from pilosa_tpu.api import API, QueryRequest
     from pilosa_tpu.ops import bitops
-    from pilosa_tpu.parallel.advisor import ADVISOR
-    from pilosa_tpu.util import plan_miner
     from pilosa_tpu.util.events import EventJournal
-    from pilosa_tpu.util.heat import HEAT
 
     h = Holder()
     h.open()
@@ -586,11 +535,7 @@ def test_promote_ahead_spares_host_fallbacks(mesh1, drive):
             want,
         ))
     pool64 = 64 * len(shards) * bitops.OCC_BLOCK_WORDS * 4
-    cycles, learn = 8, 2
-    HEAT.reset()
-    plan_miner.MINER.reset()
-    ADVISOR.reset()
-    ADVISOR.drive_promotions = drive
+    cycles, warm = 8, 2  # the first two cycles are not graded
     journal = EventJournal()
     eng = MeshEngine(
         h, mesh1, max_resident_bytes=(3 * pool64) // 2, journal=journal
@@ -599,25 +544,42 @@ def test_promote_ahead_spares_host_fallbacks(mesh1, drive):
     api = API(holder=h, mesh_engine=eng)
     try:
         for cyc in range(cycles):
-            if cyc == learn:
+            if cyc == warm:
                 fb0 = eng.host_fallbacks
             for req, want in reqs:
                 assert int(api.query(req).results[0]) == want
                 assert eng.residency.flush(60.0)
         fallbacks = eng.host_fallbacks - fb0
     finally:
-        ADVISOR.drive_promotions = True
-        ADVISOR.reset()
-        plan_miner.MINER.reset()
-        HEAT.reset()
         eng.close()
     causes = {
         e.fields.get("cause") for e in journal.events(type="engine.promotion")
     }
-    graded = 2 * (cycles - learn)
-    if drive:
-        assert "advisor" in causes, causes
-        assert fallbacks < graded, (fallbacks, graded)
-    else:
-        assert causes == {"reactive"}, causes
-        assert fallbacks == graded, (fallbacks, graded)
+    assert causes == {"reactive"}, causes
+    assert fallbacks == 2 * (cycles - warm), fallbacks
+
+
+@pytest.mark.parametrize("cause", ["reactive", "warm_start"])
+def test_full_promotion_counter_labeled_by_cause(cause):
+    """The per-cause promotions counter and cause/trace plumbing
+    through the residency queue (stub engine: no device work)."""
+    calls = []
+
+    class StubEngine:
+        def _promote(self, key, rows, cause="reactive", trace_id=""):
+            calls.append((key, rows, cause, trace_id))
+            return "full", 123
+
+        def _log(self, msg):
+            pass
+
+    c = REGISTRY.counter(METRIC_ENGINE_PROMOTIONS, cause=cause)
+    c0 = c.get()
+    rm = ResidencyManager(StubEngine())
+    assert rm.request(("i", "f", "standard"), None,
+                      cause=cause, trace_id="abc123")
+    assert rm.flush(10.0)
+    assert calls == [(("i", "f", "standard"), None, cause, "abc123")]
+    assert c.get() == c0 + 1
+    assert rm.promoted_bytes == 123
+    rm.close()

@@ -157,7 +157,7 @@ TEMP_ROWS = {
     # The XLA body's loop holds a prefix mask; the Pallas body's lane
     # accumulators ([groups, 8, 128] int32) are summed outside it.
     "group_tree": 2, "group_tree_pallas": 2,
-    # KNOWN DEBT, pinned so it cannot grow (ROADMAP S4(c)): the fused
+    # KNOWN DEBT, pinned so it cannot grow (ROADMAP S6 (c)): the fused
     # "topnf" edge gathers its candidates by a TRACED index (jnp.take)
     # and XLA materializes the gather — 23.75 rows = 2.99 GB of temp on
     # one device for this 21-row drain, where solo topn_full_tree

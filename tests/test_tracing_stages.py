@@ -6,6 +6,7 @@ in-flight union, the HTTP layer's stages and their closure
 
 import gc
 import glob
+import itertools
 import json
 import os
 import threading
@@ -278,62 +279,99 @@ def test_drain_record_of_a_bsi_aggregate(served):
 
 @pytest.fixture(scope="module")
 def capture(served, tmp_path_factory):
-    """One profiler capture of a second of load: two clients, so that
-    drains of two open the batcher's window, and a full collector pass
-    planted inside one request's parse.  (trace dir, planes' lines,
-    pilosa.* events)."""
+    """One profiler capture in which two clients run rounds of load
+    until every annotation the tests below read has been made inside it,
+    and then one round more, so that each has closed and device work
+    follows the last: a full collector pass planted inside a request's
+    parse, the drain's window held open by a hot pipe, the reply's
+    wake-up.  (trace dir, planes' lines, pilosa.* events)."""
     from jax.profiler import ProfileData
 
     eng, _api, uri = served
-    stop = threading.Event()
-    plant = threading.Event()
-    parse = pql.parse
+    batcher = eng.batcher()
+    # (annotation, the wait it is written with; None: any)
+    wanted = {(name, None) for name in ANNOTATED} | {
+        ("accum_tail", None), ("lower", None), ("dispatch", None),
+        ("device_get", None), ("write", "respond_wake")}
+    seen = set()
+    plant, planted = threading.Event(), threading.Event()
+    rounds = itertools.count()
+    annotation, parse = tracing._annotation, pql.parse
+
+    def recording(name, path, tags):
+        seen.add((name, tags.get("waited")))
+        return annotation(name, path, tags)
 
     def collecting(text):
         if plant.is_set():
             plant.clear()
             gc.collect()
+            if tracing.capturing:
+                planted.set()
         return parse(text)
 
-    def load(sums):
-        k = 0
-        while not stop.is_set():
-            k += 1
-            if sums and k % 8 == 0:
-                plant.set()
-            # First-seen texts (a parse each): the row pair and a bound move.
-            _post(uri, (f"Count(Intersect(Row(f=10), Row(f={10 + k % 4}), "
-                        f"Range(v > {k % 250})))").encode())
-            if sums:
-                _post(uri, f"Sum(Range(v > {k % 250}), field=v)".encode())
+    def covered():
+        made = list(seen)
+        return planted.is_set() and all(
+            any(n == name and waited in (None, w) for n, w in made)
+            for name, waited in wanted)
 
-    pql.parse = collecting
-    workers = [threading.Thread(target=load, args=(n == 0,), daemon=True)
-               for n in range(2)]
-    for w in workers:
-        w.start()
+    def client(k, sums):
+        # First-seen texts (a parse each): the row pair and a bound move.
+        _post(uri, (f"Count(Intersect(Row(f=10), Row(f={10 + k % 4}), "
+                    f"Range(v > {k % 250})))").encode())
+        if sums:
+            _post(uri, f"Sum(Range(v > {k % 250}), field=v)".encode())
+
+    def run_rounds(done):
+        deadline = time.monotonic() + 60
+        while not tracing.capturing and time.monotonic() < deadline:
+            time.sleep(0.001)
+        first = True
+        while tracing.capturing:
+            last = covered()
+            if not (first or planted.is_set()):
+                plant.set()  # after a round: device work on both sides of it
+            first = False
+            batcher._last_fused = time.monotonic()  # a hot pipe: the window opens
+            # Texts no earlier capture sent: no memo answers them.
+            k = next(rounds)
+            clients = [threading.Thread(target=client, args=(2 * k + n, n == 0))
+                       for n in range(2)]
+            for c in clients:
+                c.start()
+            for c in clients:
+                c.join(60)
+            if last and tracing.capturing:
+                done.set()
+                return
+
+    tracing._annotation, pql.parse = recording, collecting
     try:
-        # A starved host (the suite's other workers) can hand back a
-        # capture in which no request ran at all: take another.
+        # A starved host (the suite's other workers) can end the capture
+        # before the rounds have made every annotation: take another.
         for attempt in range(3):
+            seen.clear()
+            plant.clear()
+            planted.clear()
+            done = threading.Event()
+            loader = threading.Thread(target=run_rounds, args=(done,), daemon=True)
+            loader.start()
             out = tmp_path_factory.mktemp("capture") / str(attempt)
-            doc = _post(uri, b"", path=f"/debug/pprof/trace?seconds=1&dir={out}",
+            doc = _post(uri, b"", path=f"/debug/pprof/trace?seconds=2&dir={out}",
                         timeout=120)
             assert doc["python"] is False and tracing.capturing is False
-            (pb,) = glob.glob(os.path.join(str(out), "plugins", "profile", "*",
-                                           "*.xplane.pb"))
-            data = ProfileData.from_file(pb)
-            lines = [(plane.name, line) for plane in data.planes
-                     for line in plane.lines]
-            events = [(line.name, ev) for _, line in lines for ev in line.events
-                      if ev.name.startswith("pilosa.")]
-            if {"pilosa.gc", "pilosa.accum_wait"} <= {ev.name for _, ev in events}:
+            loader.join(120)
+            if done.is_set():
                 break
     finally:
-        stop.set()
-        for w in workers:
-            w.join(60)
-        pql.parse = parse
+        plant.clear()
+        tracing._annotation, pql.parse = annotation, parse
+    (pb,) = glob.glob(os.path.join(str(out), "plugins", "profile", "*", "*.xplane.pb"))
+    data = ProfileData.from_file(pb)
+    lines = [(plane.name, line) for plane in data.planes for line in plane.lines]
+    events = [(line.name, ev) for _, line in lines for ev in line.events
+              if ev.name.startswith("pilosa.")]
     return str(out), lines, events
 
 
